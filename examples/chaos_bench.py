@@ -343,6 +343,9 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ns = ap.parse_args()
 
+    from paddle_tpu.core import compile_cache
+
+    compile_cache.enable()
     dev = jax.devices()[0]
     cfg, model = build_model(ns.model)
     ns.vocab = cfg.vocab_size

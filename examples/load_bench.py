@@ -336,6 +336,9 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ns = ap.parse_args()
 
+    from paddle_tpu.core import compile_cache
+
+    compile_cache.enable()
     dev = jax.devices()[0]
     name = ns.model or ("llama-345m" if dev.platform == "tpu"
                         else "llama-medium")

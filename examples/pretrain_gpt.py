@@ -8,7 +8,11 @@ metrics. Runs on one TPU chip or the CPU simulator.
 """
 
 import argparse
+import os
+import sys
 import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp

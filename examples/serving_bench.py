@@ -480,6 +480,9 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ns = ap.parse_args()
 
+    from paddle_tpu.core import compile_cache
+
+    compile_cache.enable()
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
     # CPU default is llama-small: big enough that per-step compute (not

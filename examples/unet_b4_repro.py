@@ -1,7 +1,7 @@
 """Minimized repro / bisect harness for the UNet b4 compiler crash.
 
 ROADMAP r5: SD-1.5 UNet *training* at batch 4 reproducibly crashes the
-compiler ("remote TPU compiler subprocess" on chip; also reported against
+compiler ("TPU compiler subprocess" on chip; also reported against
 the CPU sim) while every shape passes in isolation. This script bisects
 the two axes the crash correlates with — the BATCH and the number of
 ATTENTION LEVELS carrying transformer blocks — and prints the minimal

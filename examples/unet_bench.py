@@ -54,6 +54,9 @@ def main():
     from paddle_tpu.models.unet import UNetConfig, UNetModel
     from paddle_tpu.nn.layer import functional_call
 
+    from paddle_tpu.core import compile_cache
+
+    compile_cache.enable()
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
 

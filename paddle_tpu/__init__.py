@@ -17,12 +17,6 @@ from paddle_tpu import version as _version
 
 __version__ = _version.__version__
 
-# jax 0.9 API names on older jax installs — must run before any submodule
-# references jax.shard_map / jax.lax.pcast / pltpu.CompilerParams.
-from paddle_tpu.core import jaxcompat as _jaxcompat
-
-_jaxcompat.install()
-
 # Core tensor veneer --------------------------------------------------------
 from paddle_tpu.tensor import (  # noqa: F401
     Tensor,

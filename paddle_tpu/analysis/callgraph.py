@@ -16,16 +16,9 @@ linter; this module builds the standard cheap approximation:
   f`` / ``import paddle_tpu.x as m; m.f(...)``). ``self.f(...)`` and
   ``cls.f(...)`` resolve to any same-module method called ``f``.
 
-Tracing wrappers are matched by their 0.9 public names AND the
-``core/jaxcompat.py`` shim spellings: a from-import alias of a wrapper
-(``from jax.experimental.shard_map import shard_map as _esm`` — the
-0.4.x graft underneath ``jax.shard_map``) marks entries exactly like
-the canonical name, and function operands wrapped in
-``functools.partial(f, ...)`` are peeled (``shard_map(partial(local,
-axis_name=ax), ...)`` marks ``local``). Without this, call sites that
-spell the wrapper through the compat layer would silently fall out of
-the traced set on 0.4.x — the ``collective-axis``/``traced-branch``
-rules must resolve the same sites on both jax versions.
+Tracing wrappers are matched by their public names, and function
+operands wrapped in ``functools.partial(f, ...)`` are peeled
+(``shard_map(partial(local, axis_name=ax), ...)`` marks ``local``).
 
 False edges (two modules defining the same helper name) only ever make
 the dependent rules MORE conservative — a function is flagged as
@@ -142,19 +135,16 @@ def _call_root(node) -> Optional[Tuple[str, str]]:
     return None
 
 
-def _is_tracing_wrapper(fn, aliases: frozenset = frozenset()) -> bool:
+def _is_tracing_wrapper(fn) -> bool:
     """Does this callee trace its function arguments (jax.jit, pjit,
-    lax.scan, functools.partial(jax.jit, ...))? ``aliases`` carries the
-    module's from-import aliases of wrapper names (the jaxcompat shim
-    spelling ``from jax.experimental.shard_map import shard_map as
-    _esm``)."""
+    lax.scan, functools.partial(jax.jit, ...))?"""
     if isinstance(fn, ast.Name):
-        return fn.id in _TRACING_WRAPPERS or fn.id in aliases
+        return fn.id in _TRACING_WRAPPERS
     if isinstance(fn, ast.Attribute):
-        return fn.attr in _TRACING_WRAPPERS or fn.attr in aliases
+        return fn.attr in _TRACING_WRAPPERS
     if isinstance(fn, ast.Call):        # partial(jax.jit, ...)
-        return any(_is_tracing_wrapper(a, aliases) for a in fn.args) \
-            or _is_tracing_wrapper(fn.func, aliases)
+        return any(_is_tracing_wrapper(a) for a in fn.args) \
+            or _is_tracing_wrapper(fn.func)
     return False
 
 
@@ -177,10 +167,6 @@ class _ModuleVisitor(ast.NodeVisitor):
         # defs exist — a jax.jit(f) in module A may name a function
         # module A imports from module B
         self._pending = pending_entries
-        # local from-import aliases of tracing wrappers (the jaxcompat
-        # shim spelling): `from jax.experimental.shard_map import
-        # shard_map as _esm` makes _esm(f, ...) an entry mark
-        self.wrapper_aliases: set = set()
         graph.from_imports.setdefault(module, {})
         graph.module_imports.setdefault(module, {})
 
@@ -188,8 +174,6 @@ class _ModuleVisitor(ast.NodeVisitor):
     def visit_ImportFrom(self, node):
         if node.module and node.level == 0:
             for a in node.names:
-                if a.name in _TRACING_WRAPPERS and a.asname:
-                    self.wrapper_aliases.add(a.asname)
                 local = a.asname or a.name
                 self.graph.from_imports[self.module][local] = (
                     node.module, a.name)
@@ -212,11 +196,10 @@ class _ModuleVisitor(ast.NodeVisitor):
     def _visit_func(self, node):
         qual = ".".join(self.stack + [node.name])
         info = _FuncInfo(self.module, qual, node)
-        aliases = frozenset(self.wrapper_aliases)
         for dec in node.decorator_list:
-            if _is_tracing_wrapper(dec, aliases) or (
+            if _is_tracing_wrapper(dec) or (
                     isinstance(dec, ast.Call)
-                    and _is_tracing_wrapper(dec.func, aliases)):
+                    and _is_tracing_wrapper(dec.func)):
                 info.entry = True
         self.graph.add(info)
         self.stack.append(node.name)
@@ -248,7 +231,7 @@ class _ModuleVisitor(ast.NodeVisitor):
                 kind, name = root
                 if kind == "local" or kind not in _EXTERNAL_ROOTS:
                     self.func_stack[-1].calls.append((kind, name))
-        if _is_tracing_wrapper(node.func, frozenset(self.wrapper_aliases)):
+        if _is_tracing_wrapper(node.func):
             # jax.jit(f) / lax.scan(step, ...): every function-valued
             # argument becomes a trace entry
             for a in list(node.args) + [kw.value for kw in node.keywords]:

@@ -87,7 +87,7 @@ Rule catalog (docs/ANALYSIS.md has the workflow):
     Every named-axis collective (``lax.psum``/``pmean``/``pmax``/
     ``pmin``/``ppermute``/``all_gather``/``psum_scatter``/
     ``all_to_all``/``axis_index``/``axis_size``/``pcast``/
-    ``pbroadcast`` — 0.9 and jaxcompat-shim spellings alike) whose
+    ``pbroadcast``) whose
     axis-name argument resolves to a string literal (directly, via a
     parameter default, a local assign, or a module constant) must name
     an axis registered in ``parallel.topology.KNOWN_AXES`` — the axis
@@ -1517,8 +1517,7 @@ class _AxisScopes:
 # ------------------------------------------------------ collective-axis
 
 #: named-axis collectives -> positional index of the axis-name operand
-#: (0.9 names; pcast/pbroadcast are the vma-cast pair the jaxcompat
-#: shim grafts onto 0.4.x — the AST spelling is identical either way)
+#: (pcast/pbroadcast are the vma-cast pair)
 _COLLECTIVE_AXIS_POS = {
     "psum": 1, "pmean": 1, "pmax": 1, "pmin": 1, "ppermute": 1,
     "all_gather": 1, "psum_scatter": 1, "all_to_all": 1, "pshuffle": 1,

@@ -48,7 +48,7 @@ __all__ = ["CompileCounter", "DonationError", "DonationReport",
            "no_recompile", "no_transfer", "sanitize",
            "snapshot_roundtrip", "compile_events_supported"]
 
-#: the monitoring event one real XLA backend compile emits (jax 0.4+);
+#: the monitoring event one real XLA backend compile emits;
 #: trace-only events (jaxpr_trace) deliberately NOT counted — a
 #: retrace that hits the compile cache costs µs, a backend compile
 #: costs seconds
@@ -91,19 +91,16 @@ def _on_event(name: str, dur: float, **kwargs):
 def _ensure_listener() -> bool:
     with _listener_lock:
         if not _listener_state["registered"]:
-            try:
-                from jax import monitoring
-                monitoring.register_event_duration_secs_listener(_on_event)
-                _listener_state["supported"] = True
-            except Exception:   # pragma: no cover - jax too old
-                _listener_state["supported"] = False
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(_on_event)
+            _listener_state["supported"] = True
             _listener_state["registered"] = True
     return bool(_listener_state["supported"])
 
 
 def compile_events_supported() -> bool:
     """Whether this jax exposes the monitoring seam the compile guards
-    need (True on the supported 0.4.x/0.9 fleet)."""
+    need."""
     return _ensure_listener()
 
 
@@ -305,9 +302,8 @@ def donation_report(fn, *args, static_argnums=(), what="program",
     The declared side comes from ``Lowered.args_info`` (per-leaf
     ``donated`` flags); the actual side is parsed from the compiled
     module's ``input_output_alias`` header — one entry per flat
-    parameter XLA wired to an output buffer. A backend that drops
-    donation (old-jax CPU) shows declared > aliased, which is exactly
-    the BENCH_r06 chunked-capacity caveat made visible."""
+    parameter XLA wired to an output buffer. A program that cannot
+    use a donation shows declared > aliased."""
     target = fn
     bound = ()
     if not hasattr(target, "lower"):

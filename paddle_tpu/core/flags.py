@@ -71,7 +71,6 @@ define_flag("FLAGS_deterministic", False, "Force deterministic ops where possibl
 define_flag("FLAGS_allocator_strategy", "xla_bfc", "Informational: XLA owns allocation on TPU")
 define_flag("FLAGS_fraction_of_gpu_memory_to_use", 0.9, "Mapped to XLA mem fraction knob")
 define_flag("FLAGS_use_pallas_kernels", True, "Use Pallas fusion kernels when on TPU")
-define_flag("FLAGS_pallas_strict", False, "Raise (instead of XLA fallback) when a Pallas kernel fails")
 define_flag("FLAGS_fused_decode", True, "Use the fused decode-step path (fused_multi_transformer analog) in generate()")
 define_flag("FLAGS_vmem_mib", 0, "Override the device VMEM capacity (MiB) used for Pallas kernel budgets; 0 = derive from device_kind")
 define_flag("FLAGS_pallas_interpret", False, "Off-TPU, run Pallas kernels in interpret mode instead of the XLA fallback (CPU-CI kernel parity)")

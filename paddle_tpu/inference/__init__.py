@@ -107,39 +107,26 @@ def _fold_rows(keys, t):
 
 
 def carry_donate_argnums(*argnums):
-    """``donate_argnums`` for a chunked-decode KV carry: the given
-    argnums on accelerators, ``()`` on the CPU backend (jax-0.4 CPU
-    executes donation as a defensive copy per chunk — the BENCH_r06
-    capacity caveat — and older jaxlibs warn per program; the TPU path
-    aliases the carry away, which ``analysis.runtime.donation_report``
-    makes checkable). ONE definition shared by `generate`'s traced
-    chunk programs and the stacked decoder's — and the spelling the
-    ``donation`` lint rule recognizes as a sanctioned conditional
-    donation (docs/ANALYSIS.md §donation)."""
-    return tuple(argnums) if jax.default_backend() != "cpu" else ()
+    """``donate_argnums`` for a chunked-decode KV carry, on every
+    backend: the chunk program returns the carry it was given, so the
+    compiled module aliases it (``analysis.runtime.donation_report``
+    pins that). ONE definition shared by `generate`'s traced chunk
+    programs and the stacked decoder's — and a spelling the ``donation``
+    lint rule reads argnums through (docs/ANALYSIS.md §donation)."""
+    return tuple(argnums)
 
 
 def resident_carry_donate_argnums(*argnums):
     """``donate_argnums`` for a RESIDENT fixed-shape carry — the
     serving engine's fused-tick buffers (the paged KV pool, the
-    chunked-prefill KV carry, the ngram history): donated on EVERY
-    backend, unlike :func:`carry_donate_argnums`.
-
-    The distinction is shape growth vs shape identity. `generate`'s
-    traced chunk carry GROWS per chunk (input and output shapes
-    differ), so CPU donation buys nothing and jax-0.4 warns per
-    program — hence the conditional helper above. A resident carry is
+    chunked-prefill KV carry, the ngram history). A resident carry is
     RMW'd in place (``dynamic_update_slice`` at a static cursor; input
     shape == output shape), the caller rebinds it from the program
     output every tick, and the compiled module's ``input_output_alias``
-    table records the aliasing on every backend —
-    ``analysis.runtime.donation_report`` pins it
-    (tests/test_analysis.py), and the ``donation`` lint rule reads
-    argnums through this spelling like any ``*_donate_argnums``
-    helper. jax-0.4 CPU still executes the alias as a copy (the
-    SCALE.md §Donation aliasing caveat; the v5e re-measure removes
-    it), but the declaration is what makes the TPU path — and the
-    pin — real."""
+    table records the aliasing — ``analysis.runtime.donation_report``
+    pins it (tests/test_analysis.py), and the ``donation`` lint rule
+    reads argnums through this spelling like any ``*_donate_argnums``
+    helper."""
     return tuple(argnums)
 
 
@@ -383,8 +370,7 @@ def generate(model, input_ids, max_new_tokens=32, temperature=0.0, top_k=0,
             # donate the carry across the chunk dispatches so XLA
             # aliases the KV buffer instead of copying it per chunk (a 7B
             # cache copied every 32 tokens would skew the TPOT this mode
-            # measures and double peak HBM); carry_donate_argnums gates
-            # the CPU backend off
+            # measures and double peak HBM)
             traced_fns = (
                 jax.jit(_prefill_impl),
                 jax.jit(_decode_impl, static_argnums=(4,),

@@ -1321,7 +1321,7 @@ def rnnt_loss(logits, labels, input_lengths, label_lengths, blank=0,
 
     fastemit_lambda shapes the GRADIENT in the reference kernel (FastEmit
     regularization); only 0.0 is supported here — autodiff supplies the
-    exact lambda=0 gradient. (STATUS.md EXCLUSIONS.)
+    exact lambda=0 gradient.
     """
     if fastemit_lambda:
         raise NotImplementedError(

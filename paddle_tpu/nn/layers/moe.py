@@ -566,8 +566,7 @@ class MoELayer(Layer):
 
     def _forward_dropless(self, xt, dtype):
         """Sort + ragged grouped matmul: every routed token is computed
-        (MegaBlocks-style dropless, the expert-choice/dropless gap noted in
-        STATUS.md)."""
+        (MegaBlocks-style dropless)."""
         e = self.num_experts
         idx, vals, pos, keep, aux, stats, _ = self.gate.route(xt)
         t, k = idx.shape

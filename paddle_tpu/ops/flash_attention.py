@@ -33,8 +33,10 @@ Kernels compute internally in (b, h, s, d) so the trailing block dims
 meet TPU tiling (8, 128).
 """
 
+import contextlib
 import functools
 import math
+import threading
 from typing import Optional
 
 import jax
@@ -44,21 +46,6 @@ from jax import lax
 
 NEG_INF = -1e30
 LANES = 128
-
-import logging
-
-logger = logging.getLogger("paddle_tpu.ops.flash_attention")
-_fallback_logged = False
-
-
-def _log_fallback(which, e):
-    global _fallback_logged
-    if not _fallback_logged:
-        _fallback_logged = True
-        logger.warning(
-            "Pallas flash attention %s failed (%s: %s); falling back to the "
-            "XLA path. Set FLAGS_pallas_strict=1 to raise instead.",
-            which, type(e).__name__, e)
 
 
 def _repeat_kv(k, n_rep):
@@ -252,17 +239,11 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
                     kmask, ((0, 0), (0, 0), (0, 0),
                             (0, kp.shape[1] - kmask.shape[3])),
                     constant_values=pad_v)   # pad cols masked via kv_lens
-            try:
-                out = _flash_call(qp, kp, vp, is_causal, scale_p, klp,
-                                  seg_q, skp, window=window_size,
-                                  alibi_slopes=alibi_slopes, mask=kmask,
-                                  dropout_p=eff_dropout)
-                return out if out.shape[-1] == hd else out[..., :hd]
-            except Exception as e:
-                from paddle_tpu.core.flags import flag
-                if flag("FLAGS_pallas_strict"):
-                    raise
-                _log_fallback("forward", e)
+            out = _flash_call(qp, kp, vp, is_causal, scale_p, klp,
+                              seg_q, skp, window=window_size,
+                              alibi_slopes=alibi_slopes, mask=kmask,
+                              dropout_p=eff_dropout)
+            return out if out.shape[-1] == hd else out[..., :hd]
     return _xla_attention(q, k, v, attn_mask=attn_mask, is_causal=is_causal,
                           scale=scale, dropout_p=dropout_p,
                           training=training, kv_lens=kv_lens,
@@ -438,8 +419,13 @@ def _dropout_keep(pltpu, seed_ref, block_id, blk_q, blk_k, keep_p):
     return bits < jnp.uint32(min(int(keep_p * 4294967296.0), 4294967295))
 
 
-def _drop_block_id(bi, hi, qi, ki, h, nq, nk):
-    return ((bi * h + hi) * nq + qi) * nk + ki
+def _drop_block_id(seed_ref, bi, hi, qi, ki, nq, nk):
+    """(b, h, q-block, k-block) as one int32, with b and h counted in the
+    WHOLE call: seed_ref is (seed, first row, first head, heads), so one
+    shard of a partitioned call (`partitioned`) draws exactly the masks
+    the unpartitioned kernel draws for its rows and heads."""
+    return (((bi + seed_ref[1]) * seed_ref[3] + hi + seed_ref[2]) * nq
+            + qi) * nk + ki
 
 
 def _mask_block_bounds(mask, b, h, nq, nk, blk_q, blk_k, axis_q=True):
@@ -500,7 +486,7 @@ def _build_operands(qt, kt, vt, kv_lens, seg_q, seg_k, extra,
         ops.append(mask)                               # (mb, mh, sq, sk)
         ops.extend(bounds)                             # lo, hi (b, h, n)
     if seed is not None:
-        ops.append(seed)                               # (1,) int32
+        ops.append(seed)            # (4,) int32, _drop_block_id
     return ops + extra
 
 
@@ -603,7 +589,7 @@ def _fwd_kernels(qt, kt, vt, is_causal, sc, kv_lens=None, seg_q=None,
             if has_drop:   # l accumulates UNdropped p (flash-attn-2)
                 p = jnp.where(
                     _dropout_keep(pltpu, seed_ref,
-                                  _drop_block_id(bi, hi_, qi, ki, h,
+                                  _drop_block_id(seed_ref, bi, hi_, qi, ki,
                                                  sq // blk_q, sk // blk_k),
                                   blk_q, blk_k, keep_p), p, 0.0)
             acc = acc * alpha[:, None] + p @ vv
@@ -659,6 +645,7 @@ def _fwd_kernels(qt, kt, vt, is_causal, sc, kv_lens=None, seg_q=None,
             jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
             jax.ShapeDtypeStruct((b, h, sq, LANES), jnp.float32),
         ],
+        name="flash_attention_fwd",
     )(*_build_operands(qt, kt, vt, kv_lens, seg_q, seg_k, [],
                        alibi_slopes=alibi_slopes, mask=mask, bounds=bounds,
                        seed=seed))
@@ -735,7 +722,7 @@ def _bwd_dq_kernel(qt, kt, vt, dot, lse, delta, is_causal, sc,
             if has_drop:   # regenerate the forward's block mask
                 dp = jnp.where(
                     _dropout_keep(pltpu, seed_ref,
-                                  _drop_block_id(bi, hi_, qi, ki, h,
+                                  _drop_block_id(seed_ref, bi, hi_, qi, ki,
                                                  sq // blk_q, sk // blk_k),
                                   blk_q, blk_k, keep_p),
                     dp * (1.0 / keep_p), 0.0)
@@ -779,6 +766,7 @@ def _bwd_dq_kernel(qt, kt, vt, dot, lse, delta, is_causal, sc,
         in_specs=in_specs,
         out_specs=qblk(),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
+        name="flash_attention_bwd_dq",
     )(*_build_operands(qt, kt, vt, kv_lens, seg_q, seg_k,
                        [dot, lse, delta], alibi_slopes=alibi_slopes,
                        mask=mask, bounds=bounds, seed=seed))
@@ -861,8 +849,8 @@ def _bwd_dkv_kernel(qt, kt, vt, dot, lse, delta, is_causal, sc,
             dp = do @ vv.T
             if has_drop:   # same (bi, hi, qi, ki)-keyed mask as forward
                 dmask = _dropout_keep(pltpu, seed_ref,
-                                      _drop_block_id(bi, hi_, qi, ki, h,
-                                                     sq // blk_q,
+                                      _drop_block_id(seed_ref, bi, hi_, qi,
+                                                     ki, sq // blk_q,
                                                      sk // blk_k),
                                       blk_q, blk_k, keep_p)
                 dv_acc = dv_acc + jnp.where(
@@ -923,6 +911,7 @@ def _bwd_dkv_kernel(qt, kt, vt, dot, lse, delta, is_causal, sc,
         out_specs=[kblk(), kblk()],
         out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), qt.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), qt.dtype)],
+        name="flash_attention_bwd_dkv",
     )(*_build_operands(qt, kt, vt, kv_lens, seg_q, seg_k,
                        [dot, lse, delta], alibi_slopes=alibi_slopes,
                        mask=mask, bounds=bounds, seed=seed))
@@ -973,19 +962,14 @@ def _flash_call(q, k, v, is_causal, scale, kv_lens, seg_q, seg_k,
     if flags[4]:
         from paddle_tpu.core import rng as _rng
         if not _rng.has_rng("dropout"):
-            # Under jit tracing with no bound stream the fallback key
+            # Staged out (jit) with no bound stream, the fallback key
             # would be baked into the executable as a CONSTANT: every call
             # of the compiled function reapplies the exact same dropout
             # mask — silently biased training. Unlike the eager-friendly
             # warning in next_rng_key, in-kernel dropout refuses to trace.
-            try:
-                from jax._src import core as _core
-                traced = not _core.trace_state_clean()
-            except (ImportError, AttributeError):
-                # private probe symbol: module or attribute may be gone
-                # on other jax versions — treat as eager (warn path)
-                traced = False
-            if traced:
+            # An eager jax.grad keeps concrete values and draws a fresh
+            # key per call, so it passes.
+            if not jax.core.is_concrete(q):
                 raise RuntimeError(
                     "flash_attention dropout under jit with no bound "
                     "'dropout' rng stream: the kernel seed would become a "
@@ -996,9 +980,91 @@ def _flash_call(q, k, v, is_causal, scale, kv_lens, seg_q, seg_k,
                                   (1,), -2 ** 31, 2 ** 31 - 1, jnp.int32)
     else:
         seed = jnp.zeros((1,), jnp.int32)
-    return _flash_vjp_entry(q, k, v, dummy_len, dummy_sq, dummy_sk,
-                            dummy_al, dummy_mk, seed, flags, is_causal,
-                            scale, window, float(dropout_p))
+    # (seed, first row, first head, heads): see _drop_block_id
+    seed = jnp.concatenate(
+        [seed, jnp.asarray([0, 0, q.shape[2]], jnp.int32)])
+
+    def kernels(*arrays):
+        return _flash_vjp_entry(*arrays, flags, is_causal, scale, window,
+                                float(dropout_p))
+
+    arrays = (q, k, v, dummy_len, dummy_sq, dummy_sk, dummy_al, dummy_mk,
+              seed)
+    part = _partition.spec
+    if part is None or not jax.sharding.get_abstract_mesh().empty:
+        # one device, or a trace already inside a shard_map, whose
+        # caller owns the axes
+        return kernels(*arrays)
+    mesh, batch_axes, head_axis = part
+    in_specs, out_spec, B, H = _partition_specs(
+        dict(mesh.shape), batch_axes, head_axis, q.shape, k.shape[2],
+        flags, dummy_mk.shape)
+
+    def per_shard(*arrays):
+        *rest, seed = arrays
+        if flags[4]:   # this shard's place in the whole call
+            b_loc, h_loc = rest[0].shape[0], rest[0].shape[2]
+            row0 = lax.axis_index(B) * b_loc if B else 0
+            head0 = lax.axis_index(H) * h_loc if H else 0
+            seed = seed.at[1].set(row0).at[2].set(head0)
+        return kernels(*rest, seed)
+
+    return jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_spec, check_vma=False)(*arrays)
+
+
+class _Partition(threading.local):
+    spec = None      # (mesh, batch_axes, head_axis) inside `partitioned`
+
+
+_partition = _Partition()
+
+
+@contextlib.contextmanager
+def partitioned(mesh, batch_axes=(), head_axis=None):
+    """For the owner of a multi-device mesh, opened around the trace of
+    its GSPMD jit (`parallel.fleet.make_train_step` does): GSPMD cannot
+    partition a Mosaic call ("wrap the call in a shard_map"), so while
+    this is open the flash kernels run per shard under `shard_map` over
+    `mesh` — the batch over `batch_axes`, the heads over `head_axis`,
+    each only where it divides. Outside it a flash call is a bare Mosaic
+    call on the operands' device, whatever meshes the process holds."""
+    prev = _partition.spec
+    _partition.spec = ((mesh, tuple(batch_axes), head_axis)
+                       if mesh.size > 1 else None)
+    try:
+        yield
+    finally:
+        _partition.spec = prev
+
+
+def _partition_specs(mesh_shape, batch_axes, head_axis, q_shape, n_kv,
+                     flags, mask_shape):
+    """shard_map specs for `_flash_call`'s nine operands and its output,
+    and the axes actually used: (in_specs, out_spec, B, H). B is the
+    tuple of `batch_axes` larger than one when their product divides the
+    batch, else None; H is `head_axis` when it divides both head counts,
+    else None. q/k/v are (b, s, h, d)."""
+    from jax.sharding import PartitionSpec as P
+    b, h = q_shape[0], q_shape[2]
+    B = tuple(a for a in batch_axes if mesh_shape.get(a, 1) > 1)
+    if not B or b % math.prod(mesh_shape[a] for a in B):
+        B = None
+    n = mesh_shape.get(head_axis, 1)
+    H = head_axis if n > 1 and h % n == 0 and n_kv % n == 0 else None
+    has_len, has_seg, has_alibi, has_mask = flags[:4]
+    qkv = P(B, None, H, None)
+    in_specs = (
+        qkv, qkv, qkv,
+        P(B) if has_len else P(),
+        P(B, None) if has_seg else P(),
+        P(B, None) if has_seg else P(),
+        P(H) if has_alibi else P(),
+        P(B if mask_shape[0] == b else None,
+          H if mask_shape[1] == h else None, None, None)
+        if has_mask else P(),
+        P())                                    # seed: per_shard places it
+    return in_specs, qkv, B, H
 
 
 def _mask_kw(kv_lens, seg_q, seg_k, alibi, flags, window, mask=None,
@@ -1083,25 +1149,8 @@ def _flash_vjp_bwd(flags, is_causal, scale, window, dropout_p, res, g):
     q, k, v, out, lse, kv_lens, seg_q, seg_k, alibi, mask, seed = res
     kw = _mask_kw(kv_lens, seg_q, seg_k, alibi, flags, window, mask, seed,
                   dropout_p)
-    try:
-        dq, dk, dv = _pallas_bwd_impl(q, k, v, out, lse, g, is_causal,
-                                      scale, **kw)
-    except Exception as e:
-        from paddle_tpu.core.flags import flag
-        if flag("FLAGS_pallas_strict") or kw["dropout_p"] > 0.0:
-            # no XLA fallback under dropout: it could not reproduce the
-            # kernel's counter-based mask, silently mismatching the fwd
-            raise
-        _log_fallback("backward", e)
-        kw_x = dict(kw)
-        kw_x.pop("seed")
-        kw_x["attn_mask"] = _mask_as_attn(kw_x.pop("mask"))
-        _, pull = jax.vjp(
-            lambda q_, k_, v_: _xla_attention(
-                q_, k_, v_, is_causal=is_causal, scale=scale,
-                **kw_x),
-            q, k, v)
-        dq, dk, dv = pull(g)
+    dq, dk, dv = _pallas_bwd_impl(q, k, v, out, lse, g, is_causal,
+                                  scale, **kw)
     # kv_lens/segments are integer primals → float0; alibi is fp32 (a dummy
     # zeros(1) on non-ALiBi calls) so its cotangent must be a real float
     # zero — float0 for a float primal breaks under custom_vjp aval checks.
@@ -1113,13 +1162,6 @@ def _flash_vjp_bwd(flags, is_causal, scale, window, dropout_p, res, g):
     return (dq, dk, dv, _float0_like(res[5]), _float0_like(res[6]),
             _float0_like(res[7]), jnp.zeros(res[8].shape, res[8].dtype),
             mask_ct, _float0_like(res[10]))
-
-
-def _mask_as_attn(mask):
-    """int8 kernel mask back to bool for the XLA fallback path."""
-    if mask is None:
-        return None
-    return (mask != 0) if mask.dtype == jnp.int8 else mask
 
 
 _flash_vjp_entry.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -1196,16 +1238,9 @@ def _fwd_lse_vjp_bwd(is_causal, scale, res, cts):
     q, k, v, out, lse = res
     g_out, g_lse = cts
     if _pallas_lse_ok(q, k):
-        try:
-            lse_lanes = jnp.broadcast_to(lse[..., None],
-                                         lse.shape + (LANES,))
-            return _pallas_bwd_impl(q, k, v, out, lse_lanes, g_out,
-                                    is_causal, scale, g_lse=g_lse)
-        except Exception as e:
-            from paddle_tpu.core.flags import flag
-            if flag("FLAGS_pallas_strict"):
-                raise
-            _log_fallback("lse-backward", e)
+        lse_lanes = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))
+        return _pallas_bwd_impl(q, k, v, out, lse_lanes, g_out,
+                                is_causal, scale, g_lse=g_lse)
     _, pull = jax.vjp(
         lambda q_, k_, v_: _xla_fwd_lse(q_, k_, v_, is_causal, scale),
         q, k, v)

@@ -44,10 +44,9 @@ from jax import lax
 NEG_INF = -1e30
 
 # Per-generation VMEM capacity (MiB). The runtime exposes no VMEM
-# attribute, so `device_kind` is the spec handle; unknown kinds fall back
-# to the v5e value. Every public generation to date ships 128 MiB/core —
-# the table is the extension point for one that differs, and
-# FLAGS_vmem_mib the per-deployment escape hatch.
+# attribute, so `device_kind` is the spec handle. On a TPU a kind that
+# is not in the table is an error (add it, or set FLAGS_vmem_mib); off
+# the TPU the kernels run only in interpret mode and plan for the v5e.
 _VMEM_MIB_BY_KIND = {
     "TPU v4": 128,
     "TPU v5 lite": 128,     # v5e
@@ -56,12 +55,12 @@ _VMEM_MIB_BY_KIND = {
     "TPU v5p": 128,
     "TPU v6 lite": 128,     # v6e / trillium
 }
-_VMEM_MIB_FALLBACK = 128
+_VMEM_MIB_OFF_TPU = 128
 
 
 def _vmem_mib() -> int:
     """VMEM capacity of device 0 in MiB (flag override > Mosaic probe >
-    kind table > v5e fallback).
+    kind table).
 
     ``FLAGS_vmem_mib = -1`` runs the boot-time scoped-VMEM bisect probe
     (`ops/vmem_probe.py`, cached per device kind) instead of trusting the
@@ -72,20 +71,21 @@ def _vmem_mib() -> int:
     calibrated against *real* fused kernels) stay meaningful.
     """
     from paddle_tpu.core.flags import flag
-    override = flag("FLAGS_vmem_mib")
-    if override and int(override) > 0:
-        return int(override)
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return _VMEM_MIB_FALLBACK
-    if override and int(override) == -1:
-        try:
-            from paddle_tpu.ops.vmem_probe import probe_usable_vmem_mib
-            return probe_usable_vmem_mib(kind) + 4
-        except Exception:
-            pass   # non-TPU platform or probe failure → table
-    return _VMEM_MIB_BY_KIND.get(kind, _VMEM_MIB_FALLBACK)
+    override = int(flag("FLAGS_vmem_mib") or 0)
+    if override > 0:
+        return override
+    dev = jax.devices()[0]
+    if override == -1:
+        from paddle_tpu.ops.vmem_probe import probe_usable_vmem_mib
+        return probe_usable_vmem_mib(dev.device_kind) + 4
+    if dev.platform != "tpu":
+        return _VMEM_MIB_OFF_TPU
+    if dev.device_kind not in _VMEM_MIB_BY_KIND:
+        raise RuntimeError(
+            f"no VMEM capacity known for TPU device_kind "
+            f"{dev.device_kind!r}: add it to _VMEM_MIB_BY_KIND, or set "
+            f"FLAGS_vmem_mib (MiB, or -1 to probe)")
+    return _VMEM_MIB_BY_KIND[dev.device_kind]
 
 
 def _vmem_budget_bytes() -> int:
@@ -1528,9 +1528,6 @@ def _fused_decode_moe_pallas(x, params, kv_cache, pos, *,
     return out[0], out[1]
 
 
-_fallback_logged = False
-
-
 def fused_decode_step(x, params, kv_cache, pos, cos, sin, *,
                       num_heads: int, num_kv_heads: int, eps: float = 1e-5,
                       rope_base: float = 10000.0, arch: str = "llama",
@@ -1560,11 +1557,9 @@ def fused_decode_step(x, params, kv_cache, pos, cos, sin, *,
     interp = bool(flag("FLAGS_pallas_interpret")) and not use_pallas()
     if (use_pallas() or interp) and dkv % 128 == 0 \
             and kv_cache.shape[2] % 128 == 0:
-        # plan/cache consistency is a CONTRACT error, not a hardware
-        # failure: check it before the fallback try so a stale plan can't
-        # silently demote every kernel-eligible step to the jnp reference
-        # path. (The reference path itself ignores `blocks` — an f32
-        # cache on a non-kernel backend stays valid.)
+        # plan/cache consistency is a contract error with a message of
+        # its own. (The reference path ignores `blocks` — an f32 cache
+        # on a non-kernel backend stays valid.)
         cb = jnp.dtype(kv_cache.dtype).itemsize
         if blocks is not None and blocks.get("cache_wbytes", cb) != cb:
             raise ValueError(
@@ -1572,37 +1567,25 @@ def fused_decode_step(x, params, kv_cache, pos, cos, sin, *,
                 f"cache but the cache dtype is {kv_cache.dtype} ({cb} B); "
                 f"rebuild the plan with decode_block_plan(cache_wbytes="
                 f"{cb})")
-        try:
-            # named scopes mark the kernel phase boundary in xplane
-            # captures (trace-time only — no runtime cost)
-            if arch == "moe":
-                with jax.named_scope("fused_decode.kernel_moe"):
-                    return _fused_decode_moe_pallas(
-                        x, params, kv_cache, pos,
-                        num_heads=num_heads, num_kv_heads=num_kv_heads,
-                        head_dim=dkv // num_kv_heads, top_k=top_k,
-                        rope_base=rope_base, eps=eps, chunk=kv_chunk,
-                        blocks=blocks, kv_scales=kv_scales,
-                        interpret=interp)
-            with jax.named_scope("fused_decode.kernel"):
-                return _fused_decode_pallas(
+        # named scopes mark the kernel phase boundary in xplane
+        # captures (trace-time only — no runtime cost)
+        if arch == "moe":
+            with jax.named_scope("fused_decode.kernel_moe"):
+                return _fused_decode_moe_pallas(
                     x, params, kv_cache, pos,
                     num_heads=num_heads, num_kv_heads=num_kv_heads,
-                    head_dim=dkv // num_kv_heads,
+                    head_dim=dkv // num_kv_heads, top_k=top_k,
                     rope_base=rope_base, eps=eps, chunk=kv_chunk,
-                    arch=arch, blocks=blocks,
-                    kv_scales=kv_scales, interpret=interp)
-        except Exception as e:  # pragma: no cover - hardware-dependent
-            if flag("FLAGS_pallas_strict"):
-                raise
-            global _fallback_logged
-            if not _fallback_logged:
-                _fallback_logged = True
-                import logging
-                logging.getLogger("paddle_tpu.ops.fused_decode").warning(
-                    "Pallas fused decode failed (%s: %s); using the jnp "
-                    "reference path. FLAGS_pallas_strict=1 to raise.",
-                    type(e).__name__, e)
+                    blocks=blocks, kv_scales=kv_scales,
+                    interpret=interp)
+        with jax.named_scope("fused_decode.kernel"):
+            return _fused_decode_pallas(
+                x, params, kv_cache, pos,
+                num_heads=num_heads, num_kv_heads=num_kv_heads,
+                head_dim=dkv // num_kv_heads,
+                rope_base=rope_base, eps=eps, chunk=kv_chunk,
+                arch=arch, blocks=blocks,
+                kv_scales=kv_scales, interpret=interp)
     with jax.named_scope("fused_decode.reference"):
         return fused_decode_reference(
             x, params, kv_cache, pos, cos, sin,
@@ -1830,10 +1813,9 @@ def fused_paged_decode_reference(x, params, kv_pool, block_tables, positions,
         # gather the slot's logical cache view [0, S) for attention
         # (spare table entries gather a scratch block — masked below)
         # and inject this step's append into the GATHERED view; the pool
-        # itself is written once after the layer walk. A per-layer
-        # `kv_pool.at[l, ...].set` costs a full pool copy per LAYER on
-        # backends without in-place scatter (jax-0.4 CPU ignores
-        # donation: measured 211 -> ~55 ms per b=8 step); the values the
+        # itself is written once after the layer walk (a per-layer
+        # `kv_pool.at[l, ...].set` is L pool-sized scatters where the
+        # update is not done in place); the values the
         # attention sees are identical either way, because each row's
         # append block is private (copy-on-write invariant) and the
         # injected entry is exactly what the scatter would have stored.
@@ -2060,7 +2042,7 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
                     qkv[:, dq + dkv + g * hd:dq + dkv + (g + 1) * hd]
 
             if kvq:     # per-slot k-half dequant scales fold into q rows
-                qbd = q_s[...] * kvs_ref[...][:, None, :dkv]
+                qbd = q_s[...] * kvs_ref[...][:, :dkv][:, None]
             else:
                 qbd = q_s[...]
 
@@ -2124,7 +2106,7 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
 
             norm = accs / ls[..., None]                     # (b, nh, dkv)
             if kvq:     # per-slot v-half dequant scales, applied once
-                norm = norm * kvs_ref[...][:, None, dkv:]
+                norm = norm * kvs_ref[...][:, dkv:][:, None]
             if rep == 1:
                 bd = (lax.broadcasted_iota(jnp.int32, (1, nh, dkv), 2)
                       // hd == lax.broadcasted_iota(
@@ -2337,25 +2319,13 @@ def fused_paged_decode_step(x, params, kv_pool, block_tables, positions,
                 f"cache but the pool dtype is {kv_pool.dtype} ({cb} B); "
                 f"rebuild the plan with decode_block_plan(cache_wbytes="
                 f"{cb})")
-        try:
-            with jax.named_scope("fused_decode.kernel_paged"):
-                return _fused_paged_decode_pallas(
-                    x, params, kv_pool, block_tables, positions,
-                    num_heads=num_heads, num_kv_heads=num_kv_heads,
-                    head_dim=dkv // num_kv_heads, rope_base=rope_base,
-                    eps=eps, arch=arch, blocks=blocks,
-                    kv_scales=kv_scales, interpret=interp)
-        except Exception as e:  # pragma: no cover - hardware-dependent
-            if flag("FLAGS_pallas_strict"):
-                raise
-            global _fallback_logged
-            if not _fallback_logged:
-                _fallback_logged = True
-                import logging
-                logging.getLogger("paddle_tpu.ops.fused_decode").warning(
-                    "Pallas paged decode failed (%s: %s); using the jnp "
-                    "reference path. FLAGS_pallas_strict=1 to raise.",
-                    type(e).__name__, e)
+        with jax.named_scope("fused_decode.kernel_paged"):
+            return _fused_paged_decode_pallas(
+                x, params, kv_pool, block_tables, positions,
+                num_heads=num_heads, num_kv_heads=num_kv_heads,
+                head_dim=dkv // num_kv_heads, rope_base=rope_base,
+                eps=eps, arch=arch, blocks=blocks,
+                kv_scales=kv_scales, interpret=interp)
     with jax.named_scope("fused_decode.reference_paged"):
         return fused_paged_decode_reference(
             x, params, kv_pool, block_tables, positions, cos, sin,
@@ -2383,9 +2353,7 @@ def fused_paged_decode_step(x, params, kv_pool, block_tables, positions,
 # slot's block-table row points at scratch until adoption), so the
 # scheduler may overlap the scatter DMA with the decode kernel's
 # weight streaming. On the jnp reference path the win is one pool
-# traversal per tick instead of two (jax-0.4 CPU materializes each
-# program's pool output — BENCH_r06's chunked-capacity caveat;
-# BENCH_r09 measures the recovery).
+# traversal per tick instead of two.
 
 
 def paged_chunk_scatter(kv_pool, chunk_bids, chunk_kv):
@@ -2546,9 +2514,8 @@ def fused_paged_verify_reference(x, params, kv_pool, block_tables,
         return jnp.dot(act, w, preferred_element_type=jnp.float32)
 
     # per-layer gathered views, carried across the tail tokens so token
-    # j+1 sees token j's append without a per-token pool scatter (the
-    # jax-0.4 CPU donation caveat: each pool scatter is a full copy —
-    # one combined scatter at the end, like the decode reference)
+    # j+1 sees token j's append without a per-token pool scatter
+    # (one combined scatter at the end, like the decode reference)
     views = [kv_pool[l][block_tables].reshape(b, S, dkv2)
              for l in range(L)]
     app_news = []                   # per-token (L, b, dkv2) appends
@@ -2834,7 +2801,7 @@ def _fused_paged_verify_pallas(x, params, kv_pool, block_tables,
                         seg[:, dq + dkv + g * hd:dq + dkv + (g + 1) * hd]
 
             if kvq:     # per-slot k-half dequant scales fold into q rows
-                qbd = q_s[...] * kvs_ref[...][:, None, :dkv]
+                qbd = q_s[...] * kvs_ref[...][:, :dkv][:, None]
             else:
                 qbd = q_s[...]
 
@@ -2908,7 +2875,7 @@ def _fused_paged_verify_pallas(x, params, kv_pool, block_tables,
 
             norm = accs / ls[..., None]             # (b, K1*nh, dkv)
             if kvq:     # per-slot v-half dequant scales, applied once
-                norm = norm * kvs_ref[...][:, None, dkv:]
+                norm = norm * kvs_ref[...][:, dkv:][:, None]
             # o-proj per tail token over its static head-row slice
             for t in range(K1):
                 nt = norm[:, t * nh:(t + 1) * nh, :]    # (b, nh, dkv)
@@ -3123,30 +3090,18 @@ def fused_paged_verify_step(x, params, kv_pool, block_tables, positions,
                 f"cache but the pool dtype is {kv_pool.dtype} ({cb} B); "
                 f"rebuild the plan with decode_block_plan(cache_wbytes="
                 f"{cb})")
-        try:
-            with jax.named_scope("fused_decode.kernel_paged_verify"):
-                # token-major flat: token j's rows contiguous at [j*b,
-                # (j+1)*b) so the kernel's per-token stages are static
-                # slices
-                xf = x.transpose(1, 0, 2).reshape(K1 * b, h)
-                y, pool = _fused_paged_verify_pallas(
-                    xf, params, kv_pool, block_tables, positions,
-                    num_heads=num_heads, num_kv_heads=num_kv_heads,
-                    head_dim=dkv // num_kv_heads, rope_base=rope_base,
-                    eps=eps, arch=arch, blocks=blocks,
-                    kv_scales=kv_scales, interpret=interp)
-                return y.reshape(K1, b, h).transpose(1, 0, 2), pool
-        except Exception as e:  # pragma: no cover - hardware-dependent
-            if flag("FLAGS_pallas_strict"):
-                raise
-            global _fallback_logged
-            if not _fallback_logged:
-                _fallback_logged = True
-                import logging
-                logging.getLogger("paddle_tpu.ops.fused_decode").warning(
-                    "Pallas paged verify failed (%s: %s); using the jnp "
-                    "reference path. FLAGS_pallas_strict=1 to raise.",
-                    type(e).__name__, e)
+        with jax.named_scope("fused_decode.kernel_paged_verify"):
+            # token-major flat: token j's rows contiguous at [j*b,
+            # (j+1)*b) so the kernel's per-token stages are static
+            # slices
+            xf = x.transpose(1, 0, 2).reshape(K1 * b, h)
+            y, pool = _fused_paged_verify_pallas(
+                xf, params, kv_pool, block_tables, positions,
+                num_heads=num_heads, num_kv_heads=num_kv_heads,
+                head_dim=dkv // num_kv_heads, rope_base=rope_base,
+                eps=eps, arch=arch, blocks=blocks,
+                kv_scales=kv_scales, interpret=interp)
+            return y.reshape(K1, b, h).transpose(1, 0, 2), pool
     with jax.named_scope("fused_decode.reference_paged_verify"):
         return fused_paged_verify_reference(
             x, params, kv_pool, block_tables, positions, cos, sin,

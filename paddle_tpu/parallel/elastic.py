@@ -118,10 +118,7 @@ class CoordinationServiceStore(HeartbeatStore):
     @classmethod
     def connect(cls, address: str, rank: int, world_size: int,
                 prefix: str = "pt_elastic", timeout_s: float = 60.0):
-        try:
-            from jax._src.lib import _jax
-        except ImportError:     # jax 0.4.x module name for the same API
-            from jax._src.lib import xla_extension as _jax
+        from jax._src.lib import _jax
         service = None
         if rank == 0:
             service = _jax.get_distributed_runtime_service(

@@ -27,13 +27,10 @@ def init_parallel_env(coordinator_address=None, num_processes=None,
         (_env_int("PADDLE_TRAINER_ID") if "PADDLE_TRAINER_ID" in os.environ
          else _env_int("PROCESS_ID"))
     if coord and nproc and nproc > 1:
-        try:
-            # CPU cross-process collectives need the gloo implementation
-            # (the CPU-simulated analog of the reference's Gloo backend,
-            # SURVEY.md §2.5); harmless when the backend is TPU.
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, KeyError):
-            pass  # older jax without this config knob — TPU path unaffected
+        # CPU cross-process collectives need the gloo implementation
+        # (the CPU-simulated analog of the reference's Gloo backend,
+        # SURVEY.md §2.5); harmless when the backend is TPU.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=nproc, process_id=pid or 0)
     _initialized[0] = True

@@ -325,7 +325,18 @@ def make_train_step(model: Layer, optimizer, loss_fn: Callable,
         return (loss_sum * inv,
                 jax.tree_util.tree_map(lambda g: g * inv, g_sum))
 
+    from paddle_tpu.ops import flash_attention
+    from paddle_tpu.parallel.mp_layers import MP_AXIS
+    head_axis = MP_AXIS if hcg.axis_size(MP_AXIS) > 1 else None
+
     def _step(state, opt_state, batch, rngs):
+        # this trace is one GSPMD jit over `mesh`, which cannot partition
+        # a Mosaic call: its flash kernels run per shard
+        with flash_attention.partitioned(mesh, active_batch_axes,
+                                         head_axis):
+            return _step_on_mesh(state, opt_state, batch, rngs)
+
+    def _step_on_mesh(state, opt_state, batch, rngs):
         if scaler is not None:
             sstate = opt_state["scaler"]
             loss_s, grads = _value_and_grad(state, batch, rngs,

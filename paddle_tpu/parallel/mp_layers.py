@@ -58,25 +58,13 @@ def constrain(x, spec_for_ndim, axis: str = MP_AXIS):
     axes; axes the caller has taken Manual are skipped (explicit collectives
     own them there). Otherwise fall back to the hybrid group's concrete mesh.
     """
-    try:
-        from jax.sharding import get_abstract_mesh, AxisType
-        am = get_abstract_mesh()
-    except ImportError:                      # older jax
-        am = None
-    if am is not None and not am.empty and axis in am.axis_names:
+    from jax.sharding import get_abstract_mesh, AxisType
+    am = get_abstract_mesh()
+    if not am.empty and axis in am.axis_names:
         types = dict(zip(am.axis_names, am.axis_types))
         if types[axis] == AxisType.Manual or am.shape[axis] <= 1:
             return x
         return jax.lax.with_sharding_constraint(x, spec_for_ndim(x.ndim))
-    # old-jax (0.4.x) spelling of the same Manual-axis skip: inside a
-    # shard_map body the manual axes live in the trace's axis env, and a
-    # sharding constraint over one is an error, not a hint
-    try:
-        from jax._src import core as _core
-        if _core.get_axis_env().axis_exists(axis):
-            return x
-    except Exception:
-        pass
     mesh = _active_mesh(axis)
     if mesh is None:
         return x

@@ -66,11 +66,8 @@ def build_mesh(axis_dims: Dict[str, int], devices=None) -> Mesh:
         raise ValueError(
             f"mesh dims {dict(zip(names, dims))} multiply to {total}, "
             f"but {len(devices)} devices are available")
-    try:
-        from jax.experimental import mesh_utils
-        dev_array = mesh_utils.create_device_mesh(dims, devices=devices)
-    except Exception:
-        dev_array = np.asarray(devices).reshape(dims)
+    from jax.experimental import mesh_utils
+    dev_array = mesh_utils.create_device_mesh(dims, devices=devices)
     return Mesh(dev_array, axis_names=tuple(names))
 
 

@@ -145,15 +145,14 @@ TPU_PEAK_FLOPS = {
 }
 
 
-def detect_peak_flops(default=197e12):
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return default
+def detect_peak_flops() -> Optional[float]:
+    """bf16 peak FLOP/s of device 0, or None for a device kind that is
+    not in the table (a CPU included): no peak, no MFU."""
+    kind = jax.devices()[0].device_kind.lower()
     for k, v in TPU_PEAK_FLOPS.items():
         if k in kind:
             return v
-    return default
+    return None
 
 
 class StepTimer:
@@ -204,6 +203,8 @@ class StepTimer:
         if not mst:
             return None     # no completed step yet
         peak = peak or detect_peak_flops()
+        if peak is None:
+            return None     # unknown device kind: no guessed peak
         achieved = self.flops_per_token * tokens_per_step / mst
         return achieved / (peak * n_chips)
 

@@ -148,9 +148,8 @@ def remaining_deadline(deadline_s: Optional[float],
 # ---- error-class predicates (shared across the degradation ladders) --------
 #
 # jax surfaces device/runtime failures as XlaRuntimeError with the gRPC
-# status-code NAME in the message; matching on the string keeps these
-# predicates working across jax versions (the exception class moved
-# modules between 0.4 and 0.9) and lets the simulated faults match too.
+# status-code NAME in the message; matching on the string lets the
+# simulated faults match too.
 
 def is_resource_exhausted(e: BaseException) -> bool:
     """Accelerator OOM (or the injected stand-in)."""

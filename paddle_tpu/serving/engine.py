@@ -167,14 +167,28 @@ _req_id_state = {"next": 0}
 _req_id_lock = threading.Lock()
 
 
+def _abstract_operand(x):
+    aval = jax.typeof(x)
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                weak_type=aval.weak_type,
+                                sharding=getattr(x, "sharding", None))
+
+
 def _program_handle(jitted, bound):
     """Wrap a jitted program with its bound leading arguments and
     attach the ``.jitted``/``.bound`` audit handle
     ``analysis.runtime.donation_report`` lowers the REAL program
     through (docs/ANALYSIS.md §Donation report). ``bound`` is a
-    thunk so the handle tracks state swaps (restore/recover)."""
-    fn = lambda *a: jitted(*bound(), *a)    # noqa: E731
-    fn.jitted, fn.bound = jitted, bound
+    thunk so the handle tracks state swaps (restore/recover). The
+    first call keeps its operands' shape, dtype and sharding in
+    ``.ran`` (None until then): what
+    :meth:`ServingEngine.lowered_programs` lowers the program from."""
+    def fn(*a):
+        args = (*bound(), *a)
+        if fn.ran is None:
+            fn.ran = jax.tree.map(_abstract_operand, args)
+        return jitted(*args)
+    fn.jitted, fn.bound, fn.ran = jitted, bound, None
     return fn
 
 
@@ -1297,19 +1311,13 @@ class ServingEngine:
         ``jax.shard_map``: per-head math is local, the o-proj/logits
         boundary gathers (inside fused_decode), and sampling runs
         replicated on every device so per-slot ``fold_in`` RNG streams
-        survive verbatim. check_vma/check_rep=False is REQUIRED: the
-        replication checker cannot infer that all_gather outputs under
-        replicated out_specs are in fact replicated (jaxcompat
-        forwards the flag on 0.4.x)."""
+        survive verbatim. check_vma=False is REQUIRED: the replication
+        checker cannot infer that all_gather outputs under replicated
+        out_specs are in fact replicated."""
         if self.mesh is None:
             return jax.jit(impl, donate_argnums=donate_argnums)
-        try:
-            sm = jax.shard_map(impl, mesh=self.mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False,
-                               check_rep=False)
-        except TypeError:   # newer jax: check_rep renamed to check_vma
-            sm = jax.shard_map(impl, mesh=self.mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
+        sm = jax.shard_map(impl, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(sm, donate_argnums=donate_argnums)
 
     def _gather_stacked(self, stacked):
@@ -3272,11 +3280,9 @@ class ServingEngine:
                         seeds, counts, kv_scales)
 
         # donate the pool: the reference path batches every layer's
-        # append into ONE scatter (jax-0.4 CPU ignores donation, so each
-        # scatter costs one full pool copy — per step, not per layer);
-        # on TPU the Pallas kernel aliases the pool and donation skips
-        # the defensive copy (per SHARD under mp — the donation_report
-        # pin covers the sharded tick too)
+        # append into ONE scatter; on TPU the Pallas kernel aliases the
+        # pool and donation skips the defensive copy (per SHARD under
+        # mp — the donation_report pin covers the sharded tick too)
         from jax.sharding import PartitionSpec as P
         lay = self.layout
         pspec = lay.pool_spec() if lay is not None else None
@@ -4375,6 +4381,24 @@ class ServingEngine:
                for p in prompts]
         self.drain()
         return [self.results[i].ids for i in ids]
+
+    def lowered_programs(self, *kinds) -> Dict[tuple, "jax.stages.Lowered"]:
+        """The programs this engine has dispatched, as
+        ``jax.stages.Lowered``, keyed by cache key — ``("step",)``,
+        ``("verify", K)``, ``("draft", K)``, ``("prefill", ...)``,
+        ``("tick", "mid" | "last", ...)``, ``("draft_prefill", s_pad)`` —
+        and narrowed to the keys that start with one of ``kinds`` when
+        any is given. Each is the engine's own jitted object lowered
+        from the shapes, dtypes and shardings of its first call.
+        Read-only: ``.as_text()`` shows which kernels a program holds,
+        ``.compile()`` what it costs."""
+        handles = {("step",): self._step_fn, **self._jit_cache}
+        handles.update({("verify", k): f
+                        for k, f in self._verify_fns.items()})
+        handles.update({("draft", k): f for k, f in self._draft_fns.items()})
+        return {key: f.jitted.lower(*f.ran) for key, f in handles.items()
+                if f is not None and f.ran is not None
+                and (not kinds or key[0] in kinds)}
 
     # ------------------------------------------------- lifecycle: close
     def close(self):

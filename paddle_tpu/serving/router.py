@@ -89,6 +89,17 @@ REPLICA_STATES = ("healthy", "suspect", "dead", "draining", "removed")
 _STATE_RANK = {s: i for i, s in enumerate(REPLICA_STATES)}
 
 
+def _accelerator_held() -> Optional[str]:
+    """The accelerator platform this process's JAX has already
+    initialised (and so holds), or None. Never initialises a backend."""
+    import jax
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
 class ReplicaRole:
     """Splitwise/DistServe-style role disaggregation: a ``prefill``
     replica takes fresh admissions and releases each request at first
@@ -214,6 +225,16 @@ class Router:
                     "processes=True requires model_factory= (a picklable "
                     "zero-arg callable; each worker builds its OWN model "
                     "— weights must be deterministic so replicas agree)")
+            held = _accelerator_held()
+            if held is not None:
+                raise RuntimeError(
+                    f"Router(processes=True): this process has already "
+                    f"initialised JAX on platform {held!r} and therefore "
+                    f"holds the chip; a chip belongs to one process, so no "
+                    f"worker could reach it (it would wait out "
+                    f"start_timeout_s or come up on the CPU). Start the "
+                    f"process tier from a parent that never touches JAX, "
+                    f"or use in-process replicas (processes=False)")
             for k in ("mesh", "layout", "speculate"):
                 if engine_kwargs.get(k) is not None:
                     raise ValueError(

@@ -18,7 +18,7 @@ connection).  Everything in the payload is compact sorted-key JSON:
 the protocol stays greppable in a pipe dump and versionable without a
 schema compiler.
 
-Failure taxonomy — typed so the proxy's retry policy can distinguish
+Failure classes — typed so the proxy's retry policy can distinguish
 "ask again" from "the worker is gone":
 
 * :class:`TransportCorruption` — bad magic / unsupported version /

@@ -222,6 +222,8 @@ def worker_main(conn, spec: Dict[str, Any]):
     signal.signal(signal.SIGINT, signal.SIG_IGN)
     chan = Channel(conn)
     try:
+        from paddle_tpu.core import compile_cache
+        compile_cache.enable()
         eng, restored, covered = _build_engine(spec)
     except BaseException as e:  # noqa: BLE001 — report, then die
         try:

@@ -231,7 +231,7 @@ def test_span_drift_skipped_without_docs_file(tmp_path):
 
 def test_span_names_documented_in_observability_table():
     """Every serving.*/decode.* span literal in paddle_tpu/ must appear
-    in docs/OBSERVABILITY.md's span taxonomy table — the timeline
+    in docs/OBSERVABILITY.md's span-name table — the timeline
     export's track names cannot silently rot. Same shared-implementation
     pattern as the metric-drift delegate in tests/test_slo.py:
     suppressions and the baseline are DISABLED here."""
@@ -626,22 +626,19 @@ def test_donation_rule_donated_then_reused():
     assert [f.line for f in res2.findings] == [7], res2.findings
 
 
-def test_callgraph_shim_aliases_and_partial_peeling():
-    """The jaxcompat spellings reach the traced set: a from-import
-    alias of shard_map marks entries, and partial(f, ...) operands
-    are peeled — so reachability-scoped rules resolve the same sites
-    on 0.4.x and 0.9."""
+def test_callgraph_partial_peeling():
+    """partial(f, ...) operands of a tracing wrapper are peeled, so
+    shard_map(partial(local, ...)) puts `local` in the traced set."""
     src = (
         "import jax\n"
         "from functools import partial\n"
-        "from jax.experimental.shard_map import shard_map as _esm\n"
         "def local_fn(x):\n"
         "    return float(x.sum())\n"          # flagged iff reachable
         "def outer(x, mesh):\n"
-        "    return _esm(partial(local_fn), mesh=mesh)(x)\n")
+        "    return jax.shard_map(partial(local_fn), mesh=mesh)(x)\n")
     res = _lint(_files(mod=src), rules=("host-sync",))
     assert [(f.path, f.line) for f in res.findings] == [
-        ("paddle_tpu/mod.py", 5)], res.findings
+        ("paddle_tpu/mod.py", 4)], res.findings
 
 
 # ------------------------------------------- suppressions and baseline
@@ -1420,11 +1417,9 @@ def test_donation_report_spec_verify_history():
 
 
 def test_donation_report_inference_chunk_carry():
-    """The traced chunk-decode program's KV-carry donation follows
-    carry_donate_argnums — donated and fully aliased on accelerators,
-    explicitly gated OFF on the CPU backend (the BENCH_r06 capacity
-    caveat, now visible in the report instead of prose)."""
-    from paddle_tpu.inference import carry_donate_argnums, generate
+    """The traced chunk-decode program's KV carry is donated and fully
+    aliased (carry_donate_argnums), on every backend."""
+    from paddle_tpu.inference import generate
     m = _tiny_llama()
     state = m.state_dict(include_buffers=False)
     rng = np.random.RandomState(9)
@@ -1440,15 +1435,8 @@ def test_donation_report_inference_chunk_carry():
     rep = rt.donation_report(dc, state, carry, aux, 1, 4,
                              static_argnums=(4,),
                              what="chunk-carry decode program")
-    expected = carry_donate_argnums(1)
-    if expected:
-        assert rep.donated_argnums == [1]
-        rep.expect_aliased(1)       # the carry aliases away on-device
-    else:
-        # CPU gate: the helper declares nothing, and the report shows
-        # the per-chunk carry copy the TPU re-measure removes
-        assert jax.default_backend() == "cpu"
-        assert rep.donated_argnums == []
+    assert rep.donated_argnums == [1]
+    rep.expect_aliased(1)
 
 
 @pytest.mark.parametrize("cache_dtype", ["bf16", "int8"])

@@ -81,6 +81,7 @@ def test_step_timer_and_metrics(tmp_path):
     assert t.mean_step_time() >= 0.01
     assert t.tokens_per_sec(100) > 0
     assert t.mfu(100, peak=1e6) is not None
+    assert t.mfu(100) is None   # CPU: unknown device kind, no guessed peak
     ml = MetricsLogger(str(tmp_path / "m.jsonl"))
     ml.log(step=1, loss=2.5)
     rec = json.loads(open(tmp_path / "m.jsonl").read().strip())

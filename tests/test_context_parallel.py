@@ -46,11 +46,6 @@ def test_context_parallel_matches_full(sep_fleet, mode, causal):
 
 @pytest.mark.parametrize("mode", ["ring", "ulysses"])
 def test_context_parallel_grads_match(sep_fleet, mode):
-    from paddle_tpu.core import jaxcompat
-    if mode == "ring" and jaxcompat.active():
-        pytest.skip("vjp through the ring lax.switch needs jax 0.9 "
-                    "vma-typed branches (0.4.x rep checker rejects the "
-                    "mixed-rep cond)")
     q, k, v = _qkv(seed=3)
     mesh = sep_fleet.mesh
 

@@ -111,11 +111,10 @@ def test_attention_soft_penalty_mask_routes_to_xla(monkeypatch):
     """A concrete float mask with FINITE entries <= -1e9 that are not
     -inf (a -1e10 soft penalty) must skip the Pallas path — the kernel
     would block-skip it exactly while XLA suppresses it exponentially.
-    Force use_pallas() True with strict mode on: the penalty mask must
-    come back via XLA (no kernel error), while an eligible bool mask
-    proves the patch really drives the kernel path (raises off-TPU)."""
+    Force use_pallas() True: the penalty mask must come back via XLA
+    (no kernel error), while an eligible bool mask proves the patch
+    really drives the kernel path (raises off-TPU)."""
     import paddle_tpu.ops as ops_pkg
-    from paddle_tpu.core.flags import set_flags
 
     b, s, h, d = 1, 1024, 2, 64       # >= 1024: kernel-eligible seq
     q = jnp.asarray(rs.randn(b, s, h, d).astype(np.float32))
@@ -123,23 +122,19 @@ def test_attention_soft_penalty_mask_routes_to_xla(monkeypatch):
         -1e10)
     ref = F.scaled_dot_product_attention(q, q, q, attn_mask=penalty)
     monkeypatch.setattr(ops_pkg, "use_pallas", lambda: True)
-    set_flags({"FLAGS_pallas_strict": True})
-    try:
-        out = F.scaled_dot_product_attention(q, q, q, attn_mask=penalty)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-6)
-        # -1e10 entries are NOT fully masked on the XLA path: they must
-        # still contribute (exp(-1e10 - max) == 0 in fp32 — but rows
-        # fully under the penalty keep finite outputs, no NaNs)
-        assert np.isfinite(np.asarray(out)).all()
-        with pytest.raises(Exception):
-            # an eligible bool mask heads INTO the kernel path — which
-            # cannot lower off-TPU, proving the routing check (not the
-            # patch) is what saved the penalty mask above
-            F.scaled_dot_product_attention(
-                q, q, q, attn_mask=jnp.ones((1, 1, s, s), bool))
-    finally:
-        set_flags({"FLAGS_pallas_strict": False})
+    out = F.scaled_dot_product_attention(q, q, q, attn_mask=penalty)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-6)
+    # -1e10 entries are NOT fully masked on the XLA path: they must
+    # still contribute (exp(-1e10 - max) == 0 in fp32 — but rows
+    # fully under the penalty keep finite outputs, no NaNs)
+    assert np.isfinite(np.asarray(out)).all()
+    with pytest.raises(Exception):
+        # an eligible bool mask heads INTO the kernel path — which
+        # cannot lower off-TPU, proving the routing check (not the
+        # patch) is what saved the penalty mask above
+        F.scaled_dot_product_attention(
+            q, q, q, attn_mask=jnp.ones((1, 1, s, s), bool))
 
 
 def test_attention_kv_lens_masks_padding():
@@ -326,3 +321,89 @@ def test_flash_dropout_under_jit_without_rng_raises():
         assert "dropout" not in str(e)
     except Exception:
         pass    # CPU cannot lower the Pallas kernels; the gate passed
+
+
+def test_flash_partition_specs():
+    """shard_map specs of a partitioned flash call: the batch over the
+    data axes and the heads over the head axis, each only where it
+    divides; dummies and the seed replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.ops.flash_attention import _partition_specs
+
+    shape = {"dp": 2, "sharding": 2, "mp": 2, "pp": 1}
+    none = (False,) * 5
+    ins, out, B, H = _partition_specs(
+        shape, ("dp", "sharding"), "mp", (8, 1024, 16, 64), 16, none,
+        (1, 1, 1, 1))
+    assert (B, H) == (("dp", "sharding"), "mp")
+    assert out == ins[0] == ins[2] == P(("dp", "sharding"), None, "mp", None)
+    assert ins[3:] == (P(),) * 6
+    # kv_lens, segment ids, ALiBi slopes and a (1, h, sq, sk) mask follow
+    ins, _, _, _ = _partition_specs(
+        shape, ("dp", "sharding"), "mp", (8, 1024, 16, 64), 16,
+        (True, True, True, True, True), (1, 16, 1024, 1024))
+    assert ins[3:] == (P(B), P(B, None), P(B, None), P("mp"),
+                       P(None, "mp", None, None), P())
+    # batch 6 is not a multiple of 4, 2 kv heads not of mp 4: replicate
+    _, out, B, H = _partition_specs(
+        {"dp": 2, "sharding": 2, "mp": 4}, ("dp", "sharding"), "mp",
+        (6, 1024, 16, 64), 2, none, (1, 1, 1, 1))
+    assert (B, H, out) == (None, None, P(None, None, None, None))
+    # an axis of size one is not named
+    _, _, B, H = _partition_specs(
+        {"dp": 4, "sharding": 1, "mp": 1}, ("dp", "sharding"), "mp",
+        (8, 1024, 16, 64), 16, none, (1, 1, 1, 1))
+    assert (B, H) == (("dp",), None)
+
+
+def test_flash_partitioned_scope(monkeypatch):
+    """Only the owner of a mesh partitions a flash call, and only inside
+    its `partitioned` scope: a mesh the process merely holds (fleet.init)
+    changes nothing. Inside, each shard learns its first row and head, so
+    its dropout masks are those of the unpartitioned call. The kernels
+    need a TPU; a stand-in shows what each shard was handed."""
+    from paddle_tpu.core.rng import rng_guard
+    from paddle_tpu.ops import flash_attention as fa
+    from paddle_tpu.parallel.strategy import DistributedStrategy
+    from paddle_tpu.parallel.topology import (
+        HybridCommunicateGroup, set_hybrid_communicate_group)
+
+    seen = []
+
+    def stand_in(q, k, v, lens, sq, sk, alibi, mask, seed, *static):
+        seen.append(q.shape)
+        return (jnp.zeros_like(q) + seed[1] * 100 + seed[2]
+                + seed[3] * 10000).astype(q.dtype)
+
+    monkeypatch.setattr(fa, "_flash_vjp_entry", stand_in)
+    q = jnp.zeros((8, 128, 4, 64), jnp.float32)
+
+    def call(q):
+        with rng_guard(dropout=jax.random.PRNGKey(0)):
+            return fa._flash_call(q, q, q, True, None, None, None, None,
+                                  dropout_p=0.5)
+
+    strategy = DistributedStrategy()
+    strategy.hybrid_configs = {"dp_degree": 2, "sharding_degree": 2,
+                               "mp_degree": 2}
+    hcg = HybridCommunicateGroup(strategy=strategy)
+    mesh = hcg.mesh
+    set_hybrid_communicate_group(hcg)
+    try:
+        bare = jax.jit(call)(q)
+    finally:
+        set_hybrid_communicate_group(None)
+    assert seen.pop() == q.shape          # whole operands, no shard_map
+    assert np.unique(np.asarray(bare)).tolist() == [40000.0]
+
+    def owner(q):
+        with fa.partitioned(mesh, ("dp", "sharding"), "mp"):
+            return call(q)
+
+    out = np.asarray(jax.jit(owner)(q))
+    assert seen.pop() == (2, 128, 2, 64)  # 8 rows / 4, 4 heads / 2
+    assert fa._partition.spec is None     # closed again
+    want = (np.arange(8)[:, None, None, None] // 2 * 2 * 100
+            + np.arange(4)[None, None, :, None] // 2 * 2 + 40000)
+    np.testing.assert_array_equal(out, np.broadcast_to(want, out.shape))

@@ -286,11 +286,9 @@ class TestInterpretKernelParity:
 
     @pytest.fixture(autouse=True)
     def _interp(self):
-        set_flags({"FLAGS_pallas_interpret": True,
-                   "FLAGS_pallas_strict": True})
+        set_flags({"FLAGS_pallas_interpret": True})
         yield
-        set_flags({"FLAGS_pallas_interpret": False,
-                   "FLAGS_pallas_strict": False})
+        set_flags({"FLAGS_pallas_interpret": False})
 
     # nkv=2 (dkv=64) is below the kernel's 128-lane gate and rides the
     # jnp reference — sibling-covered by test_generate_fused_matches_
@@ -493,15 +491,17 @@ class TestInterpretKernelParity:
 
 
 def test_vmem_mib_flag_dispatch():
-    """FLAGS_vmem_mib: >0 overrides; -1 asks the Mosaic probe (which
-    raises off-TPU, so the kind table wins here on CPU); 0 = table."""
-    from paddle_tpu.ops.fused_decode import _vmem_mib, _VMEM_MIB_FALLBACK
+    """FLAGS_vmem_mib: >0 overrides; -1 asks the Mosaic probe, which
+    raises off-TPU (no silent table answer); 0 = kind table on a TPU,
+    the v5e planning size for interpret mode elsewhere."""
+    from paddle_tpu.ops.fused_decode import _vmem_mib, _VMEM_MIB_OFF_TPU
     try:
         set_flags({"FLAGS_vmem_mib": 192})
         assert _vmem_mib() == 192
-        set_flags({"FLAGS_vmem_mib": -1})   # CPU: probe refuses -> table
-        assert _vmem_mib() == _VMEM_MIB_FALLBACK
+        set_flags({"FLAGS_vmem_mib": -1})
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            _vmem_mib()
         set_flags({"FLAGS_vmem_mib": 0})
-        assert _vmem_mib() == _VMEM_MIB_FALLBACK
+        assert _vmem_mib() == _VMEM_MIB_OFF_TPU
     finally:
         set_flags({"FLAGS_vmem_mib": 0})

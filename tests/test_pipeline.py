@@ -227,13 +227,6 @@ def _run_schedule(schedule, vpp=1, acc=4, n_layers=2, steps=2):
         set_hybrid_communicate_group(None)
 
 
-_OLD_JAX = pytest.mark.skipif(
-    __import__("paddle_tpu.core.jaxcompat", fromlist=["active"]).active(),
-    reason="grad through partial-manual shard_map needs jax 0.9 (0.4.x "
-    "cannot spec scalar device-varying residuals of the transposed body)")
-
-
-@_OLD_JAX
 def test_1f1b_matches_gpipe_and_single_device():
     ref_g, losses_g, st_g = _run_schedule("FThenB")
     ref_f, losses_f, st_f = _run_schedule("1F1B")
@@ -245,7 +238,6 @@ def test_1f1b_matches_gpipe_and_single_device():
                                    err_msg=k)
 
 
-@_OLD_JAX
 def test_interleaved_matches_gpipe():
     S, v = 2, 2
     ref_g, losses_g, st_g = _run_schedule("FThenB", n_layers=4)

@@ -541,7 +541,7 @@ def test_decode_oom_halved_chunk_interpret_kernel(llama):
     OOM and stays token-exact vs the un-faulted kernel run."""
     cfg, m, prompt, base = llama
     m._generate_jit_cache = {}
-    set_flags({"FLAGS_pallas_interpret": True, "FLAGS_pallas_strict": True})
+    set_flags({"FLAGS_pallas_interpret": True})
     try:
         ref = generate(m, prompt, max_new_tokens=8, temperature=0.0)
         m._generate_jit_cache = {}
@@ -550,8 +550,7 @@ def test_decode_oom_halved_chunk_interpret_kernel(llama):
             out = generate(m, prompt, max_new_tokens=8, temperature=0.0)
         assert p.faults[0].fired == 1
     finally:
-        set_flags({"FLAGS_pallas_interpret": False,
-                   "FLAGS_pallas_strict": False})
+        set_flags({"FLAGS_pallas_interpret": False})
         m._generate_jit_cache = {}
     assert np.asarray(ref).tolist() == np.asarray(out).tolist()
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(base))

@@ -46,8 +46,7 @@ def tiny_gpt():
 @pytest.fixture(autouse=True)
 def _restore_flags():
     yield
-    set_flags({"FLAGS_fused_decode": True, "FLAGS_pallas_interpret": False,
-               "FLAGS_pallas_strict": False})
+    set_flags({"FLAGS_fused_decode": True, "FLAGS_pallas_interpret": False})
 
 
 # ---------------------------------------------------------------- block pool
@@ -218,6 +217,12 @@ def test_eos_retires_slot_and_frees_blocks():
     assert res.tokens.tolist() == full[:5].tolist()
     assert eng.pool.used_blocks == 0          # blocks freed immediately
     assert eng.stats["decode_tokens"] == 4    # no eos-padding steps
+    # the programs that ran, lowered from what they ran on
+    progs = eng.lowered_programs()
+    assert sorted(k[0] for k in progs) == ["prefill", "step"]
+    assert list(eng.lowered_programs("step")) == [("step",)]
+    pool_type = "x".join(map(str, eng.kv_pool.shape)) + "xbf16"
+    assert pool_type in progs[("step",)].as_text()
 
 
 # ------------------------------------------------------------ prefix reuse
@@ -403,11 +408,9 @@ class TestInterpretKernelParity:
 
     @pytest.fixture(autouse=True)
     def _interp(self):
-        set_flags({"FLAGS_pallas_interpret": True,
-                   "FLAGS_pallas_strict": True})
+        set_flags({"FLAGS_pallas_interpret": True})
         yield
-        set_flags({"FLAGS_pallas_interpret": False,
-                   "FLAGS_pallas_strict": False})
+        set_flags({"FLAGS_pallas_interpret": False})
 
     @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8])
     def test_llama_paged_kernel_token_exact(self, cache_dtype):

@@ -45,8 +45,7 @@ def tiny_llama(L=2, seed=0):
 @pytest.fixture(autouse=True)
 def _restore_flags():
     yield
-    set_flags({"FLAGS_fused_decode": True, "FLAGS_pallas_interpret": False,
-               "FLAGS_pallas_strict": False})
+    set_flags({"FLAGS_fused_decode": True, "FLAGS_pallas_interpret": False})
 
 
 def _spec_workload(rng):
@@ -447,12 +446,11 @@ def _verify_twin_case(cache_dtype):
     yr, pr = fd.fused_paged_verify_reference(
         x, params, pool, jnp.asarray(tables), jnp.asarray(positions),
         cos, sin, **kw)
-    set_flags({"FLAGS_pallas_interpret": True, "FLAGS_pallas_strict": True})
+    set_flags({"FLAGS_pallas_interpret": True})
     yk, pk = fd.fused_paged_verify_step(
         x, params, pool, jnp.asarray(tables), jnp.asarray(positions),
         cos, sin, rope_base=plan["rope_base"], blocks=None, **kw)
-    set_flags({"FLAGS_pallas_interpret": False,
-               "FLAGS_pallas_strict": False})
+    set_flags({"FLAGS_pallas_interpret": False})
     yr32 = np.asarray(yr, np.float32)
     yk32 = np.asarray(yk, np.float32)
     # hidden states agree to bf16 resolution (the kernel computes rope
@@ -499,12 +497,11 @@ def test_spec_engine_on_interpret_kernel_token_exact():
         return toks, st
 
     ref_toks, _ = run()
-    set_flags({"FLAGS_pallas_interpret": True, "FLAGS_pallas_strict": True})
+    set_flags({"FLAGS_pallas_interpret": True})
     try:
         kern_toks, st = run()
     finally:
-        set_flags({"FLAGS_pallas_interpret": False,
-                   "FLAGS_pallas_strict": False})
+        set_flags({"FLAGS_pallas_interpret": False})
     assert kern_toks == ref_toks
     assert st["spec_ticks"] > 0
 

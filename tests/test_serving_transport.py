@@ -1,7 +1,7 @@
 """The CRC-framed RPC transport (docs/SERVING.md §Cross-process tier).
 
 Pins the frame discipline (reject, never guess: magic / version /
-length / CRC / JSON all checked), the typed failure taxonomy
+length / CRC / JSON all checked), the typed failure classes
 (corruption vs timeout vs EOF), the fault sites firing BEFORE I/O (a
 raising fault never consumes the queued frame), and the payload codecs
 round-tripping requests / results / typed errors — including the

@@ -1,4 +1,4 @@
-"""Pallas-vs-XLA numeric parity on the real TPU, strict mode.
+"""Pallas-vs-XLA numeric parity on the real TPU (a kernel that raises fails).
 
 Covers every Pallas kernel in paddle_tpu/ops: flash attention (forward,
 backward, LSE variant, GQA), the fused decode-step kernel, and the rms_norm
@@ -64,6 +64,26 @@ def test_flash_backward_parity():
     gr = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(gp, gr):
         assert_close(a, b_, rtol=5e-2, atol=5e-2)
+
+
+def test_flash_uneven_seq_parity():
+    """s=1280 (not a 512-multiple) rides the Pallas path through the
+    adaptive block size; fwd and bwd must match the XLA reference
+    (moved here from tests/test_api_breadth.py, where it only skipped)."""
+    rng = np.random.RandomState(0)
+    b, s, h, d = 1, 1280, 2, 128
+    q, k, v = (jnp.asarray(rng.standard_normal(
+        (b, s, h, d)).astype(np.float32) * 0.3) for _ in range(3))
+    o1, g1 = jax.value_and_grad(
+        lambda *a: fa._flash_attention_vjp(*a, True, None).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    o2, g2 = jax.value_and_grad(
+        lambda *a: fa._xla_attention(*a, is_causal=True).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    assert np.allclose(float(o1), float(o2), rtol=2e-3)
+    for a, b_ in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-2, atol=5e-3)
 
 
 def test_flash_lse_parity():
@@ -275,6 +295,48 @@ def test_flash_dropout_in_kernel():
         np.abs(gmean - g_p0).mean() / denom
 
 
+def test_flash_dropout_shard_draws_the_whole_calls_masks():
+    """A partitioned flash call (`fa.partitioned`, multi-chip training)
+    hands each shard its first row and head with the seed. The shard must
+    then draw exactly the dropout masks the whole call draws for those
+    rows and heads, forward and in both backward kernels — otherwise
+    every dp shard and every mp head-shard would repeat one mask."""
+    b, s, h, d = 4, 512, 8, 64
+    q, k, v = (rand(i, b, s, h, d, dtype=jnp.float32, scale=0.3)
+               for i in range(3))
+    proj = rand(3, b, s, h, d, dtype=jnp.float32, scale=1.0)
+    flags = (False, False, False, False, True)
+    none = (jnp.zeros((1,), jnp.int32), jnp.zeros((1, 1), jnp.int32),
+            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.float32),
+            jnp.zeros((1, 1, 1, 1), jnp.int8))
+
+    def run(rows, heads):
+        cut = lambda x: x[rows, :, heads]
+        seed = jnp.asarray([1234, rows.start, heads.start, h], jnp.int32)
+
+        def loss(q, k, v):
+            out = fa._flash_vjp_entry(q, k, v, *none, seed, flags, True,
+                                      None, None, 0.3)
+            return (out * cut(proj)).sum(), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(cut(q), cut(k), cut(v))
+        return (out,) + grads
+
+    whole = run(slice(0, b), slice(0, h))
+    rows, heads = slice(2, 4), slice(4, 8)
+    for part, full in zip(run(rows, heads), whole):
+        np.testing.assert_array_equal(np.asarray(part),
+                                      np.asarray(full[rows, :, heads]))
+    # and without its place the shard draws other masks (rows 0.., heads
+    # 0.. of the whole call): the place is what the masks are keyed on
+    seed0 = jnp.asarray([1234, 0, 0, h], jnp.int32)
+    cut = lambda x: x[rows, :, heads]
+    out0 = fa._flash_vjp_entry(cut(q), cut(k), cut(v), *none, seed0, flags,
+                               True, None, None, 0.3)
+    assert np.abs(np.asarray(out0)
+                  - np.asarray(whole[0][rows, :, heads])).max() > 1e-3
+
+
 # ---------------------------------------------------------------------------
 # fused decode step
 # ---------------------------------------------------------------------------
@@ -435,7 +497,7 @@ def test_fused_decode_int8_generate_on_tpu():
     out_fused = generate(m, prompt, max_new_tokens=16, temperature=0.0,
                          state=state)
     m._generate_jit_cache = {}
-    set_flags({"FLAGS_fused_decode": False, "FLAGS_pallas_strict": False})
+    set_flags({"FLAGS_fused_decode": False})
     out_ref = generate(m, prompt, max_new_tokens=16, temperature=0.0,
                        state=state)
     set_flags({"FLAGS_fused_decode": True})
@@ -460,7 +522,7 @@ def test_fused_decode_gpt_arch_on_tpu():
     prompt = jnp.asarray(np.random.RandomState(0).randint(0, 512, (2, 9)))
     out_fused = generate(g, prompt, max_new_tokens=16, temperature=0.0)
     g._generate_jit_cache = {}
-    set_flags({"FLAGS_fused_decode": False, "FLAGS_pallas_strict": False})
+    set_flags({"FLAGS_fused_decode": False})
     out_ref = generate(g, prompt, max_new_tokens=16, temperature=0.0)
     set_flags({"FLAGS_fused_decode": True})
     match = (np.asarray(out_fused) == np.asarray(out_ref)).mean()
@@ -1027,12 +1089,35 @@ def test_serving_speculative_on_tpu(cache_dtype):
     eng.close()
 
 
+def _assert_same_up_to_near_tie(m, prompt, got, ref, tol=0.02):
+    """Token-exact, or the FIRST divergence is a bf16 near-tie: the two
+    tokens' logits from an fp32 full forward differ by < tol (a few bf16
+    ulps at these logit magnitudes). Past a tie the sequences are free."""
+    from paddle_tpu.nn.layer import functional_call
+
+    got, ref = list(got), list(ref)
+    d = next((i for i in range(len(ref)) if got[i] != ref[i]), None)
+    if d is None:
+        return
+    st32 = {k: (v.astype(jnp.float32)
+                if jnp.issubdtype(v.dtype, jnp.floating) else v)
+            for k, v in m.trainable_state().items()}
+    ids = np.concatenate([prompt, ref[:d]])[None]
+    lg = np.asarray(functional_call(m, st32, jnp.asarray(ids)))[0, -1]
+    gap = abs(float(lg[got[d]]) - float(lg[ref[d]]))
+    assert gap < tol and lg.max() - max(lg[got[d]], lg[ref[d]]) < tol, \
+        (d, got[d], ref[d], gap, got, ref)
+
+
 def test_serving_speculative_draft_on_tpu():
     """Draft-model proposer on-chip: the draft rides its own paged
     pool through the real kernels (round = scanned paged decode steps,
-    prefill scatter), target verify through the verify kernel —
-    token-exact vs isolated generate with near-total acceptance for a
-    same-weights draft."""
+    prefill scatter), target verify through the verify kernel — tokens
+    bit-identical to the non-speculative engine, near-total acceptance
+    for a same-weights draft, and equal to isolated generate up to a
+    bf16 near-tie (on the v5e this prompt hits one at token 2: fp32
+    logits 0.5753 vs 0.5720, where the paged and the contiguous kernel
+    round apart; every engine variant agrees with the fp32 argmax)."""
     from paddle_tpu import serving
     from paddle_tpu.inference import generate
 
@@ -1043,14 +1128,22 @@ def test_serving_speculative_draft_on_tpu():
     iso = [np.asarray(generate(m, p[None], max_new_tokens=10,
                                temperature=0.0))[0, len(p):]
            for p in prompts]
-    eng = serving.ServingEngine(
-        m, max_slots=2, block_tokens=16, max_seq_len=64,
-        speculate=serving.SpecConfig(k=3, proposer="draft",
-                                     draft_model=draft))
-    rids = [eng.submit(serving.Request(p, max_new_tokens=10))
-            for p in prompts]
-    eng.drain(max_steps=100)
-    for rid, ref in zip(rids, iso):
-        assert eng.results[rid].tokens.tolist() == ref.tolist()
-    assert eng.stats["spec_accepted"] > 0
-    eng.close()
+
+    def served(**kw):
+        eng = serving.ServingEngine(m, max_slots=2, block_tokens=16,
+                                    max_seq_len=64, **kw)
+        rids = [eng.submit(serving.Request(p, max_new_tokens=10))
+                for p in prompts]
+        eng.drain(max_steps=100)
+        out = [eng.results[rid].tokens.tolist() for rid in rids]
+        stats = dict(eng.stats)
+        eng.close()
+        return out, stats
+
+    plain, _ = served()
+    spec, stats = served(speculate=serving.SpecConfig(
+        k=3, proposer="draft", draft_model=draft))
+    assert spec == plain
+    assert stats["spec_accepted"] > 0
+    for p, got, ref in zip(prompts, spec, iso):
+        _assert_same_up_to_near_tie(m, p, got, ref)
