@@ -232,7 +232,8 @@ def calibrate(eng, reqs, reps=1):
 def step_breakdown(stats):
     steps = max(stats["steps"], 1)
     return {k: round(stats[f"step_{k}_s"] / steps, 6)
-            for k in ("admit", "prefill", "dispatch", "sync")}
+            for k in ("admit", "prefill", "dispatch", "sync", "commit",
+                      "tail")}
 
 
 def main():

@@ -39,10 +39,11 @@ Rule catalog (docs/ANALYSIS.md has the workflow):
 
 ``span-drift``
     Every ``serving.``/``decode.`` span-name literal
-    (``tracer.span(...)`` / ``tr.record(...)``) must appear in
-    docs/OBSERVABILITY.md's span table — metric-drift's twin for the
-    tracing plane, so timeline output never carries spans a reader
-    cannot look up.
+    (``tracer.span(...)`` / ``tr.record(...)``, the engine's
+    ``self._phase(...)`` and a bare ``TraceAnnotation(...)``) must
+    appear in docs/OBSERVABILITY.md's span table — metric-drift's twin
+    for the tracing plane, so neither timeline output nor a profile
+    carries spans a reader cannot look up.
 
 ``fault-site``
     ``maybe_fire(...)`` / ``Fault(...)`` site literals must be
@@ -155,12 +156,14 @@ _METRIC_CALL = re.compile(
     r'(?:counter|gauge|histogram|sketch)\(\s*'
     r'"((?:serving|resilience|decode)\.[a-z0-9_.]+)"')
 
-# span-name literals — Tracer span/record calls whose first argument
-# is a ``serving.``/``decode.``-prefixed string: the span-drift rule
-# pins every one against the span table in docs/OBSERVABILITY.md,
-# exactly like _METRIC_CALL pins metric names
+# span-name literals — Tracer span/record calls, the serving engine's
+# phase helper (``self._phase(...)``) and bare profiler annotations
+# (``TraceAnnotation(...)``, ``StepTraceAnnotation(...)``) whose first
+# argument is a ``serving.``/``decode.``-prefixed string: the
+# span-drift rule pins every one against the span table in
+# docs/OBSERVABILITY.md, exactly like _METRIC_CALL pins metric names
 _SPAN_CALL = re.compile(
-    r'(?:\.record|\.span|record_span)\(\s*'
+    r'(?:\.record|\.span|record_span|\._phase|TraceAnnotation)\(\s*'
     r'"((?:serving|decode)\.[a-z0-9_.]+)"')
 
 
@@ -653,7 +656,8 @@ def check_metric_drift(sources: Dict[str, str], docs_text: str,
 def collect_span_names(sources: Dict[str, str]) -> Dict[str, List]:
     """name -> [(path, line)] for every ``serving.``/``decode.`` span
     literal created in the package (``tracer.span("...")`` /
-    ``tr.record("...")``). The span twin of
+    ``tr.record("...")`` / ``self._phase("...")`` /
+    ``TraceAnnotation("...")``). The span twin of
     :func:`collect_metric_names` — whole-file scan, wrapped calls
     included."""
     names: Dict[str, List] = {}

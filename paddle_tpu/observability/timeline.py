@@ -6,8 +6,9 @@ touched which request when — three true but disjoint views. This module
 folds all three into ONE Chrome trace-event JSON file
 (``chrome://tracing`` / https://ui.perfetto.dev): per-replica process
 tracks, per-request thread tracks, tick-segment duration events
-(admit / prefill / dispatch / sync, reconstructed from the step
-breakdown each flight event carries), journal instants, and **flow
+(admit with its prefill inside / dispatch / sync / commit,
+reconstructed from the step breakdown each flight event carries),
+journal instants, and **flow
 arrows keyed by ``trace_id``** — so a request that was preempted,
 resumed, or migrated off a killed replica renders as one connected
 chain across process tracks instead of disconnected fragments. This is
@@ -34,9 +35,12 @@ __all__ = ["clock_anchor", "build_timeline", "write_timeline",
            "verify_trace_continuity", "TICK_SEGMENTS"]
 
 #: the per-tick step segments, in dispatch order, with their flight
-#: event fields (docs/OBSERVABILITY.md §Timelines)
+#: event fields (docs/OBSERVABILITY.md §Timelines). ``commit`` is the
+#: last phase before the tail stamps the event, which is why the
+#: segments end at the stamp; ``prefill`` runs inside admission
 TICK_SEGMENTS = (("admit", "t_admit_s"), ("prefill", "t_prefill_s"),
-                 ("dispatch", "t_dispatch_s"), ("sync", "t_sync_s"))
+                 ("dispatch", "t_dispatch_s"), ("sync", "t_sync_s"),
+                 ("commit", "t_commit_s"))
 
 #: flight tick-event list fields that name requests → per-request
 #: instant events; (field, event name, entry shape)
@@ -140,15 +144,21 @@ def _flight_event(b: _Builder, pid: int, evt: Dict,
         return
     if "step" not in evt:
         return
-    # tick event: segment durations end-aligned at the record stamp
-    segs = [(nm, float(evt.get(f) or 0.0)) for nm, f in TICK_SEGMENTS]
-    total = sum(d for _, d in segs)
-    cursor = ts - total
-    for nm, dur in segs:
-        if dur > 0.0:
-            b.duration(pid, 0, nm, cursor, dur,
+    # tick event: the segments partition the tick up to the record
+    # stamp (an older dump has no t_commit_s and is drawn late by the
+    # commit's length). t_admit_s is admission's time LESS the wave
+    # prefill that runs inside it, right behind the queue pop: admit is
+    # drawn around its prefill, not in front of it
+    dur = {nm: float(evt.get(f) or 0.0) for nm, f in TICK_SEGMENTS}
+    start = cursor = ts - sum(dur.values())
+    dur["admit"] += dur["prefill"]
+    for nm, _ in TICK_SEGMENTS:
+        inside = nm == "prefill"
+        if dur[nm] > 0.0:
+            b.duration(pid, 0, nm, start if inside else cursor, dur[nm],
                        {"step": evt.get("step")})
-        cursor += dur
+        if not inside:
+            cursor += dur[nm]
     if evt.get("err"):
         b.instant(pid, 0, "tick_error", ts, {"err": evt["err"]})
     # per-request instants on their own thread tracks, flow-touched

@@ -617,6 +617,70 @@ class _Ewma:
                       + self.alpha * float(x))
 
 
+# the tick's phases: span name -> (the ``stats`` key its seconds add
+# to, the key of the enclosing segment they come off again). Six self
+# times partition step(): admit + prefill + dispatch + sync + commit +
+# tail. upload is a PART of admit, told apart beside it, never a
+# seventh segment (docs/OBSERVABILITY.md §Span names).
+_PHASES = {
+    "serving.step.admit": ("step_admit_s", None),
+    "serving.step.prefill": ("step_prefill_s", "step_admit_s"),
+    "serving.step.upload": ("step_upload_s", None),
+    "serving.step.dispatch": ("step_dispatch_s", None),
+    "serving.step.sync": ("step_sync_s", None),
+    "serving.step.commit": ("step_commit_s", None),
+    "serving.step.tail": ("step_tail_s", None),
+}
+
+
+def _round6(seconds: Optional[float]) -> Optional[float]:
+    """A flight event's segment field: microseconds kept, ``None`` for
+    a phase the tick never reached."""
+    return None if seconds is None else round(seconds, 6)
+
+
+class _Phase:
+    """One named phase of a tick, as a context manager — the ONE place
+    a step segment is timed (:meth:`ServingEngine._phase`). It opens a
+    ``jax.profiler.TraceAnnotation``, so the phase is an event on the
+    device trace's clock whenever anyone is taking a profile (with none
+    running that is one object and one C++ flag test), and on exit adds
+    the phase's seconds to the engine's cumulative ``stats`` and to
+    ``_tick_s``, this tick's segments, which the ``serving.step_*_s``
+    histograms and the flight event are read from. Attributes go to the
+    annotation as keyword arguments, never into the name. Clock and
+    annotation both run from construction to ``__exit__``, so the
+    helper's own cost lies inside the phase it times and two phases in
+    a row leave next to nothing between them."""
+
+    __slots__ = ("_eng", "_name", "_ann", "_t0", "dur_s")
+
+    def __init__(self, eng, name, attrs):
+        self._t0 = time.perf_counter()
+        self._ann = jax.profiler.TraceAnnotation(name, **attrs)
+        self._eng = eng
+        self._name = name
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def set(self, **attrs):
+        """Attributes known only once the phase has run."""
+        self._ann.set_metadata(**attrs)
+
+    def __exit__(self, *exc):
+        key, parent = _PHASES[self._name]
+        stats, tick = self._eng.stats, self._eng._tick_s
+        self.dur_s = dt = time.perf_counter() - self._t0
+        stats[key] += dt
+        tick[key] = tick.get(key, 0.0) + dt
+        if parent is not None:
+            stats[parent] -= dt
+            tick[parent] = tick.get(parent, 0.0) - dt
+        self._ann.__exit__(*exc)
+
+
 def _swap_bucket(n: int) -> int:
     """Power-of-two bucket for whole-block gather/scatter widths —
     bounds the swap-path compile set to O(log max_blocks_per_slot)
@@ -645,8 +709,10 @@ class ServingEngine:
     b=1 ``generate`` computes the same scales, which is what keeps int8
     parity token-exact).
 
-    Observability: every ``step()`` is wall-timed in four segments
-    (``serving.step_*_s`` histograms), per-request TTFT/TPOT land in
+    Observability: every ``step()`` is wall-timed in six phases that
+    partition it (:class:`_Phase`: ``serving.step.*`` profiler spans,
+    ``stats["step_*_s"]``, ``serving.step_*_s`` histograms, flight
+    fields, all from one clock reading), per-request TTFT/TPOT land in
     the ``serving.ttft_s``/``serving.tpot_s`` quantile sketches, and a
     flight-recorder ring (last ``flight_capacity`` step events,
     auto-dumped to ``flight_dump_path`` on a fired fault /
@@ -1181,7 +1247,7 @@ class ServingEngine:
         # tpu-lint: volatile(per-tick flight marker)
         self._tick_prefills: List = []
         # tpu-lint: volatile(per-tick segment timing)
-        self._tick_prefill_s = 0.0
+        self._tick_s: Dict[str, float] = {}     # this tick's _Phase times
         # overload-control tick markers + capacity estimator state
         # tpu-lint: volatile(per-tick flight marker)
         self._tick_preempted: List[int] = []
@@ -1347,6 +1413,12 @@ class ServingEngine:
                 self.host_store.capacity)
         self._update_gauges()
 
+    def _phase(self, name: str, **attrs) -> _Phase:
+        """``with self._phase("serving.step.<phase>", ...):`` — profiler
+        span, ``stats`` seconds and this tick's segment in one
+        (:class:`_Phase`)."""
+        return _Phase(self, name, attrs)
+
     def _update_gauges(self):
         r = self._metrics
         active = sum(s is not None for s in self._slots)
@@ -1370,7 +1442,10 @@ class ServingEngine:
         and reset_stats both take it from here, so a new field (the
         step-segment times, admission count) cannot drift between the
         two copies. ``step_*_s`` are cumulative wall seconds per step
-        segment; per-step distributions live in the
+        phase (:class:`_Phase`): admit, prefill, dispatch, sync, commit
+        and tail partition ``step()``; ``step_upload_s`` is the part of
+        ``step_admit_s`` spent re-uploading the dirty mirrors, on
+        ``upload_ticks`` ticks. Per-step distributions live in the
         ``serving.step_*_s`` registry histograms."""
         return dict(steps=0, decode_tokens=0, idle_slot_steps=0,
                     prefill_tokens=0, prefill_tokens_reused=0,
@@ -1385,7 +1460,9 @@ class ServingEngine:
                     swap_out_bytes=0, swap_in_bytes=0,
                     prefetch_hits=0, prefetch_misses=0,
                     step_admit_s=0.0, step_prefill_s=0.0,
-                    step_dispatch_s=0.0, step_sync_s=0.0)
+                    step_dispatch_s=0.0, step_sync_s=0.0,
+                    step_commit_s=0.0, step_tail_s=0.0,
+                    step_upload_s=0.0, upload_ticks=0)
 
     def reset_stats(self):
         """Zero the cumulative throughput counters and step-segment
@@ -1586,27 +1663,30 @@ class ServingEngine:
             raise RuntimeError("ServingEngine is closed")
         if not isinstance(request, Request):
             request = Request(request)
-        self._check_fits(request, count=True)
-        if self.shed_infeasible and request.deadline_s is not None:
-            est = self.estimated_ttft_s(request)
-            if est is not None and est > request.deadline_s:
-                self._count_rejected(request, "deadline_infeasible")
-                raise Rejected(
-                    "deadline_infeasible",
-                    f"request {request.request_id} deadline "
-                    f"{request.deadline_s:.3f}s < estimated "
-                    f"queue-wait+prefill {est:.3f}s — it would expire "
-                    f"before its first token")
-        if self.max_queue is not None and len(self._queue) >= self.max_queue:
-            victim = self._queue.lowest_below(request.rank)
-            if victim is None:
-                self._count_rejected(request, "queue_full")
-                raise Rejected(
-                    "queue_full",
-                    f"queue at capacity ({self.max_queue}) with no "
-                    f"lower-priority request to displace")
-            self._shed_queued(victim, "displaced")
-        return self._enqueue(request)
+        with jax.profiler.TraceAnnotation(
+                "serving.submit", request_id=request.request_id):
+            self._check_fits(request, count=True)
+            if self.shed_infeasible and request.deadline_s is not None:
+                est = self.estimated_ttft_s(request)
+                if est is not None and est > request.deadline_s:
+                    self._count_rejected(request, "deadline_infeasible")
+                    raise Rejected(
+                        "deadline_infeasible",
+                        f"request {request.request_id} deadline "
+                        f"{request.deadline_s:.3f}s < estimated "
+                        f"queue-wait+prefill {est:.3f}s — it would expire "
+                        f"before its first token")
+            if self.max_queue is not None \
+                    and len(self._queue) >= self.max_queue:
+                victim = self._queue.lowest_below(request.rank)
+                if victim is None:
+                    self._count_rejected(request, "queue_full")
+                    raise Rejected(
+                        "queue_full",
+                        f"queue at capacity ({self.max_queue}) with no "
+                        f"lower-priority request to displace")
+                self._shed_queued(victim, "displaced")
+            return self._enqueue(request)
 
     def admit_resumable(self, request,
                         tokens: Optional[Sequence[int]] = None) -> int:
@@ -2334,7 +2414,7 @@ class ServingEngine:
         return fn, False
 
     def _commit_chunk(self, g: "_ChunkGroup", start, kind, ctok_np,
-                      lanes_np, kvfull_np, t_wall, warm):
+                      lanes_np, kvfull_np, warm):
         """Host-side tail of a fused tick's chunk half: advance every
         row's cursor (mid) or adopt it into the decode batch (last —
         :meth:`_adopt_slot`, the one join path), then the chunk
@@ -2348,6 +2428,7 @@ class ServingEngine:
         CT = g.chunk
         n = g.n
         last = kind == "last"
+        t_wall = self._tick_decode_s()
         for r, (slot_idx, s) in enumerate(g.rows):
             ntok = min(CT, len(s.feed) - start)
             self._tick_chunks.append((s.req.request_id, start, ntok))
@@ -3001,70 +3082,69 @@ class ServingEngine:
         into the running decode batch. The whole group (program + host
         pulls + slot adoption) is timed as the step's wave-prefill
         segment."""
-        t_pf0 = time.perf_counter()
         n = len(grp)
-        BT = self.block_tokens
-        L = self._num_layers
-        hb = R // BT
-        ids = np.zeros((n, s_pad), np.int32)
-        last_idx = np.zeros(n, np.int32)
-        seeds = np.zeros(n, np.uint32)
-        valid = np.zeros(n, np.int32)
-        for r, (slot_idx, slot, hits, _, _) in enumerate(grp):
-            P = len(slot.feed)
-            ids[r, :P - R] = slot.feed[R:]
-            last_idx[r] = P - 1 - R
-            seeds[r] = np.uint32(slot.req.seed)
-            # int8 calibration runs over the ORIGINAL prompt positions
-            # only — for a fresh request that is the whole feed; for a
-            # resume it reproduces the scales the uninterrupted run
-            # calibrated at ITS prefill (appends beyond the prompt were
-            # quantized with prompt-only scales there too, so resume
-            # stays token-exact)
-            valid[r] = len(slot.req.prompt)
-        fn, warm = self._prefill_wave_fn(R, s_pad, n)
-        if self.kv_int8:
-            new_bids = np.asarray([s.blocks for _, s, _, _, _ in grp],
-                                  np.int32)                    # (n, n0)
-            prefix = (self._up(np.stack(
-                [np.concatenate([e.kv_host for e in hits], axis=1)
-                 for _, _, hits, _, _ in grp], axis=1)) if hb
-                else self._up(np.zeros((L, n, 0, 2 * self._dkv),
-                                       np.float32).astype(jnp.bfloat16)))
-            tok, self.kv_pool, lanes, kv_flat = fn(
-                self.kv_pool, prefix, self._up(ids),
-                self._up(last_idx), self._up(seeds),
-                self._up(new_bids), self._up(valid))
-            # tpu-lint: allow(host-sync): once-per-wave D2H — int8 scales
-            lanes_np = np.asarray(lanes)
-            # tpu-lint: allow(host-sync): once-per-wave D2H — the prefix
-            # cache keeps exact bf16 host copies of int8 blocks
-            kv_np = (np.asarray(kv_flat)
-                     if self.prefix_cache is not None else None)
-        else:
-            new_bids = np.asarray(
-                [s.blocks[hb:] for _, s, _, _, _ in grp], np.int32)
-            prefix = (np.asarray([[e.block_id for e in hits]
-                                  for _, _, hits, _, _ in grp], np.int32)
-                      if hb else np.zeros((n, 0), np.int32))
-            tok, self.kv_pool = fn(
-                self.kv_pool, self._up(prefix), self._up(ids),
-                self._up(last_idx), self._up(seeds),
-                self._up(new_bids), self._up(valid))
-            lanes_np = kv_np = None
-        # tpu-lint: allow(host-sync): once-per-wave D2H — first tokens
-        tok_np = np.asarray(tok)
-        for r, (slot_idx, slot, hits, _, _) in enumerate(grp):
-            self._adopt_slot(
-                slot_idx, slot, int(tok_np[r]),
-                None if lanes_np is None else lanes_np[:, r],
-                None if kv_np is None else kv_np[:, r])
-        self._tick_prefills.append((R, s_pad, n))
-        t_grp = time.perf_counter() - t_pf0
-        self._tick_prefill_s += t_grp
+        with self._phase("serving.step.prefill", rows=n, s_pad=s_pad,
+                         R=R) as ph:
+            BT = self.block_tokens
+            L = self._num_layers
+            hb = R // BT
+            ids = np.zeros((n, s_pad), np.int32)
+            last_idx = np.zeros(n, np.int32)
+            seeds = np.zeros(n, np.uint32)
+            valid = np.zeros(n, np.int32)
+            for r, (slot_idx, slot, hits, _, _) in enumerate(grp):
+                P = len(slot.feed)
+                ids[r, :P - R] = slot.feed[R:]
+                last_idx[r] = P - 1 - R
+                seeds[r] = np.uint32(slot.req.seed)
+                # int8 calibration runs over the ORIGINAL prompt positions
+                # only — for a fresh request that is the whole feed; for a
+                # resume it reproduces the scales the uninterrupted run
+                # calibrated at ITS prefill (appends beyond the prompt were
+                # quantized with prompt-only scales there too, so resume
+                # stays token-exact)
+                valid[r] = len(slot.req.prompt)
+            fn, warm = self._prefill_wave_fn(R, s_pad, n)
+            if self.kv_int8:
+                new_bids = np.asarray([s.blocks for _, s, _, _, _ in grp],
+                                      np.int32)                    # (n, n0)
+                prefix = (self._up(np.stack(
+                    [np.concatenate([e.kv_host for e in hits], axis=1)
+                     for _, _, hits, _, _ in grp], axis=1)) if hb
+                    else self._up(np.zeros((L, n, 0, 2 * self._dkv),
+                                           np.float32).astype(jnp.bfloat16)))
+                tok, self.kv_pool, lanes, kv_flat = fn(
+                    self.kv_pool, prefix, self._up(ids),
+                    self._up(last_idx), self._up(seeds),
+                    self._up(new_bids), self._up(valid))
+                # tpu-lint: allow(host-sync): once-per-wave D2H — int8 scales
+                lanes_np = np.asarray(lanes)
+                # tpu-lint: allow(host-sync): once-per-wave D2H — the prefix
+                # cache keeps exact bf16 host copies of int8 blocks
+                kv_np = (np.asarray(kv_flat)
+                         if self.prefix_cache is not None else None)
+            else:
+                new_bids = np.asarray(
+                    [s.blocks[hb:] for _, s, _, _, _ in grp], np.int32)
+                prefix = (np.asarray([[e.block_id for e in hits]
+                                      for _, _, hits, _, _ in grp], np.int32)
+                          if hb else np.zeros((n, 0), np.int32))
+                tok, self.kv_pool = fn(
+                    self.kv_pool, self._up(prefix), self._up(ids),
+                    self._up(last_idx), self._up(seeds),
+                    self._up(new_bids), self._up(valid))
+                lanes_np = kv_np = None
+            # tpu-lint: allow(host-sync): once-per-wave D2H — first tokens
+            tok_np = np.asarray(tok)
+            for r, (slot_idx, slot, hits, _, _) in enumerate(grp):
+                self._adopt_slot(
+                    slot_idx, slot, int(tok_np[r]),
+                    None if lanes_np is None else lanes_np[:, r],
+                    None if kv_np is None else kv_np[:, r])
+            self._tick_prefills.append((R, s_pad, n))
         if warm:        # compile spikes must not poison the estimator
             new_toks = sum(len(s.feed) - s.R for _, s, _, _, _ in grp)
-            self._ewma_prefill_tok.update(t_grp / max(new_toks, 1))
+            self._ewma_prefill_tok.update(ph.dur_s / max(new_toks, 1))
 
     def _replay_resume(self, slot_idx: int, s: "_Slot"):
         """Replay a resumed request's generated-so-far tokens through
@@ -3798,19 +3878,61 @@ class ServingEngine:
         run ONE fused paged decode step for every active slot, retire
         slots that finished. Returns a small status dict.
 
-        Each tick is wall-timed in four segments — admit (scheduling +
-        deadline sweep + block-table bookkeeping), wave-prefill, fused
-        decode dispatch (program call; on async backends this is enqueue
-        time), host sync (the sampled-token D2H pull, where device wait
-        surfaces) — recorded into the ``serving.step_*_s`` histograms,
-        the cumulative ``stats["step_*_s"]`` fields and this tick's
-        flight-recorder event, so a TPOT spike is attributable to a
-        phase. A tick that dies mid-flight (injected fault,
-        ``PoolExhausted``) still records a partial event carrying the
-        error, auto-dumps the ring, and re-raises.
+        Each tick is six phases (:class:`_Phase`), from its first line
+        to its return — admit (scheduling + deadline sweep + block-table
+        bookkeeping + the dirty-mirror upload, which ``step_upload_s``
+        tells apart), wave-prefill, fused decode dispatch (program call;
+        on async backends this is enqueue time), host sync (the
+        sampled-token D2H pull, where device wait surfaces), commit (the
+        per-slot host loop and retirements behind the pull) and tail
+        (this telemetry itself). Each is a ``serving.step.*`` span in
+        any profile being taken, a cumulative ``stats["step_*_s"]``
+        field, a ``serving.step_*_s`` histogram and (the tail apart) a
+        field of this tick's flight-recorder event, so a TPOT spike or
+        an idle device is attributable to a phase. A tick that dies
+        mid-flight (injected fault, ``PoolExhausted``) closes its open
+        spans, still records a partial event carrying the error,
+        auto-dumps the ring, and re-raises.
         """
         if self._closed:
             raise RuntimeError("ServingEngine is closed")
+        with jax.profiler.StepTraceAnnotation("serving.step",
+                                              step_num=self._step_seq):
+            self._tick_s = {}
+            try:
+                with self._phase("serving.step.admit"):
+                    todo = self._schedule_tick()
+                if todo is not None:
+                    self._decode(*todo)
+                with self._phase("serving.step.tail") as tail:
+                    self._record_segments()
+                    self._record_flight()
+                    self._after_flight()
+                    status = dict(active=self.active_slots,
+                                  queued=len(self._queue),
+                                  finished=self._finished_tick)
+                # the one segment that cannot observe itself from inside
+                self._metrics.histogram("serving.step_tail_s").observe(
+                    tail.dur_s)
+                return status
+            except Exception as e:
+                self._record_flight(err=f"{type(e).__name__}: {e}")
+                self.flight.auto_dump(f"error:{type(e).__name__}")
+                # the error dump supersedes any dump this tick queued
+                # (e.g. a deadline retirement swept just before the
+                # dispatch died) — without this, the NEXT successful
+                # tick would emit a spurious "deadline_retirement" dump
+                self._dump_pending = None
+                raise
+
+    def _schedule_tick(self):
+        """The admit phase: everything up to the dispatch call (the
+        prefill programs inside it are their own phase and come off
+        it). Returns :meth:`_decode`'s arguments, or ``None`` when the
+        tick has nothing to dispatch."""
+        from paddle_tpu.resilience import faults as _faults
+        from paddle_tpu.resilience import record_event
+
         # shed events between ticks (submit-time displacement) surface
         # in THIS tick's finished list — step()['finished'] stays the
         # complete result-collection contract
@@ -3820,7 +3942,6 @@ class ServingEngine:
         self._tick_retired = []
         self._tick_prefills = []
         self._tick_chunks = []
-        self._tick_prefill_s = 0.0
         self._tick_preempted = []
         self._tick_resumed = []
         self._tick_swapped_out = []
@@ -3828,25 +3949,6 @@ class ServingEngine:
         self._tick_spec = None
         # _tick_shed keeps accumulating across submit() calls between
         # ticks; _record_flight drains it into this tick's event
-        t0 = time.perf_counter()
-        try:
-            return self._step_inner(t0)
-        except Exception as e:
-            admit_s = max(0.0,
-                          time.perf_counter() - t0 - self._tick_prefill_s)
-            self._record_flight(admit_s, None, None,
-                                err=f"{type(e).__name__}: {e}")
-            self.flight.auto_dump(f"error:{type(e).__name__}")
-            # the error dump supersedes any dump this tick queued (e.g.
-            # a deadline retirement swept just before the dispatch died)
-            # — without this, the NEXT successful tick would emit a
-            # spurious "deadline_retirement" dump
-            self._dump_pending = None
-            raise
-
-    def _step_inner(self, t0: float) -> Dict:
-        from paddle_tpu.resilience import faults as _faults
-        from paddle_tpu.resilience import record_event
 
         # host-tier housekeeping BEFORE admission: land last tick's
         # swap-out gathers and stage predicted swap-ins (both gated on
@@ -3884,7 +3986,6 @@ class ServingEngine:
                         or self._decode_since_chunk
                         >= self.decode_per_chunk):
                     grp = front
-        dispatch_s = sync_s = None
         spec = self.speculate is not None
         spec_tick = False
         K_eff = 0
@@ -3940,39 +4041,34 @@ class ServingEngine:
             # device-resident from admission.
             steady = self._step_fn_warm and not self._dirty and tick_warm
             if self._dirty:
-                self._dev = (self._up(self._tables),
-                             self._up(self._positions),
-                             self._up(self._toks),
-                             self._up(self._seeds),
-                             self._up(self._counts),
-                             self._up_scales())
-                if self._history is not None:
-                    self._dev_hist = self._up(self._history)
-                    # a join/leave tick drops the carried proposals —
-                    # the device matcher re-primes them at the end of
-                    # this tick's verify (one plain-decode tick per
-                    # event, never a wrong speculation)
-                    self._dev_prop = (self._prop_zero(self._spec_k_eff)
-                                      if spec_tick else None)
-                if spec:
-                    self._dev_cap = self._up(self._spec_cap)
-                if self._draft_tables is not None:
-                    self._draft_dev = self._up(self._draft_tables)
-                self._dirty = False
-        # everything up to the dispatch call is the admit segment
-        # (minus the prefill programs, which _run_prefill_group timed)
-        admit_s = max(0.0, time.perf_counter() - t0 - self._tick_prefill_s)
-        if spec_tick:
-            dispatch_s, sync_s = self._spec_decode(
-                active, steady, grp, tick_fn, tick_warm, g_start, g_kind)
-        elif active or grp is not None:
-            dispatch_s, sync_s = self._plain_decode(
-                active, steady, grp, tick_fn, tick_warm, g_start, g_kind)
-        self._record_segments(admit_s, dispatch_s, sync_s)
-        self._record_flight(admit_s, dispatch_s, sync_s)
-        self._after_flight()
-        return dict(active=self.active_slots, queued=len(self._queue),
-                    finished=self._finished_tick)
+                with self._phase("serving.step.upload"):
+                    self._upload_mirrors(spec, spec_tick)
+            return (spec_tick, active, steady, grp, tick_fn, tick_warm,
+                    g_start, g_kind)
+        return None
+
+    def _upload_mirrors(self, spec: bool, spec_tick: bool):
+        """Re-upload the host mirrors a join or a leave made dirty."""
+        self._dev = (self._up(self._tables),
+                     self._up(self._positions),
+                     self._up(self._toks),
+                     self._up(self._seeds),
+                     self._up(self._counts),
+                     self._up_scales())
+        if self._history is not None:
+            self._dev_hist = self._up(self._history)
+            # a join/leave tick drops the carried proposals — the
+            # device matcher re-primes them at the end of this tick's
+            # verify (one plain-decode tick per event, never a wrong
+            # speculation)
+            self._dev_prop = (self._prop_zero(self._spec_k_eff)
+                              if spec_tick else None)
+        if spec:
+            self._dev_cap = self._up(self._spec_cap)
+        if self._draft_tables is not None:
+            self._draft_dev = self._up(self._draft_tables)
+        self._dirty = False
+        self.stats["upload_ticks"] += 1
 
     def _select_chunk_outs(self, grp, g_kind, chunk_outs):
         """Split the chunk half's outputs off a fused-tick result —
@@ -4036,12 +4132,41 @@ class ServingEngine:
             self._decode_since_chunk = 0
         return head_np, ctok_np, lanes_np, kvfull_np
 
-    def _plain_decode(self, active, steady, grp=None, tick_fn=None,
-                      tick_warm=True, g_start=None, g_kind=None):
-        """One plain (non-speculative) tick's dispatch + host commit:
-        the fused tick program when a chunk is due (``grp``), else the
-        per-token step program. Returns (dispatch_s, sync_s)."""
-        t_d0 = time.perf_counter()
+    def _decode(self, spec_tick, active, steady, grp, tick_fn, tick_warm,
+                g_start, g_kind):
+        """The dispatch, sync and commit phases of a tick — the plain,
+        the speculative and the fused chunk tick all pass through here,
+        so all three carry the same phases. ``sync`` ends when the pull
+        returns; everything the host does with the pulled tokens
+        (per-slot commit, retirements, :meth:`_commit_chunk`) is
+        ``commit``."""
+        dispatch, commit = ((self._dispatch_spec, self._commit_spec)
+                            if spec_tick else
+                            (self._dispatch_plain, self._commit_plain))
+        with self._phase("serving.step.dispatch"):
+            head, chunk_outs = dispatch(active, steady, grp, tick_fn)
+        with self._phase("serving.step.sync"):
+            head_np, ctok_np, lanes_np, kvfull_np = self._fence_chunk_pulls(
+                grp, g_kind, chunk_outs, head)
+        with self._phase("serving.step.commit") as ph:
+            n0 = len(self._tick_retired)
+            commit(active, head_np)
+            if grp is not None:
+                self._commit_chunk(grp, g_start, g_kind, ctok_np, lanes_np,
+                                   kvfull_np, tick_warm)
+            ph.set(retired=len(self._tick_retired) - n0)
+
+    def _tick_decode_s(self) -> float:
+        """This tick's dispatch + sync seconds: the wall time of its
+        decode (or fused chunk) program, as the EWMAs are fed it."""
+        t = self._tick_s
+        return t["step_dispatch_s"] + t["step_sync_s"]
+
+    def _dispatch_plain(self, active, steady, grp, tick_fn):
+        """One plain (non-speculative) tick's dispatch: the fused tick
+        program when a chunk is due (``grp``), else the per-token step
+        program. Returns the arrays the host needs and the chunk
+        half's outputs, for :meth:`_fence_chunk_pulls`."""
         if grp is not None:
             fn = tick_fn
             args = (self.kv_pool, *grp.args(), *self._dev)
@@ -4057,16 +4182,15 @@ class ServingEngine:
         else:
             out = fn(*args)
         d_nxt, self.kv_pool, d_pos, d_cnt = out[:4]
-        chunk_outs = out[4:]
         # toks <- sampled ids; tables/seeds/scales are event-driven
         self._dev = (self._dev[0], d_pos, d_nxt, self._dev[3], d_cnt,
                      self._dev[5])
-        t_s0 = time.perf_counter()
-        dispatch_s = t_s0 - t_d0
-        head_np, ctok_np, lanes_np, kvfull_np = self._fence_chunk_pulls(
-            grp, g_kind, chunk_outs, [d_nxt if active else None])
+        return [d_nxt if active else None], out[4:]
+
+    def _commit_plain(self, active, head_np):
+        """A plain tick's host commit: every active slot's sampled
+        token into its slot and mirrors, retiring what finished."""
         nxt = head_np[0]
-        sync_s = time.perf_counter() - t_s0
         if active:
             self._decode_since_chunk += 1
             self.stats["steps"] += 1
@@ -4107,29 +4231,16 @@ class ServingEngine:
                     self._retire(i, "eos")
                 elif s.count >= s.req.max_new_tokens:
                     self._retire(i, "length")
-        if grp is not None:
-            self._commit_chunk(grp, g_start, g_kind, ctok_np, lanes_np,
-                               kvfull_np, dispatch_s + sync_s, tick_warm)
-        return dispatch_s, sync_s
 
-    def _spec_decode(self, active, steady, grp=None, tick_fn=None,
-                     tick_warm=True, g_start=None, g_kind=None):
-        """One speculative tick's decode: the (optional) draft round
+    def _dispatch_spec(self, active, steady, grp, tick_fn):
+        """One speculative tick's dispatch: the (optional) draft round
         plus ONE batched verify dispatch — the fused tick program when
         a chunk is due (``grp``), carrying the front group's chunk in
-        the same program — then the host commit of each slot's
-        accepted prefix + corrected/bonus token. Returns
-        (dispatch_s, sync_s) for the step-segment telemetry. Mirrors
-        stay in lockstep with the device state for surviving slots; a
-        retirement inside the commit loop marks the mirrors dirty like
-        any other leave event."""
-        from paddle_tpu import observability as obs
-
+        the same program. Returns what :meth:`_dispatch_plain` does."""
         ngram = self._history is not None
         K_eff = self._spec_k_eff
         verify_fn = tick_fn if grp is not None else self._verify_fns[K_eff]
         draft_fn = self._draft_fns.get(K_eff)
-        t_d0 = time.perf_counter()
 
         def dispatch():
             if draft_fn is not None:
@@ -4166,13 +4277,19 @@ class ServingEngine:
             chunk_outs = out[6:]
         self._dev = (self._dev[0], d_pos, d_tok, self._dev[3], d_cnt,
                      self._dev[5])
-        t_s0 = time.perf_counter()
-        dispatch_s = t_s0 - t_d0
-        head_np, ctok_np, lanes_np, kvfull_np = self._fence_chunk_pulls(
-            grp, g_kind, chunk_outs, [g, acc, props_dev, nprop_dev])
-        g_np, acc_np, prop_np, nprop_np = head_np
-        sync_s = time.perf_counter() - t_s0
+        return [g, acc, props_dev, nprop_dev], chunk_outs
 
+    def _commit_spec(self, active, head_np):
+        """A speculative tick's host commit: each slot's accepted
+        prefix + corrected/bonus token. Mirrors stay in lockstep with
+        the device state for surviving slots; a retirement inside the
+        commit loop marks the mirrors dirty like any other leave
+        event."""
+        from paddle_tpu import observability as obs
+
+        ngram = self._history is not None
+        K_eff = self._spec_k_eff
+        g_np, acc_np, prop_np, nprop_np = head_np
         self._decode_since_chunk += 1
         self.stats["steps"] += 1
         self.stats["spec_ticks"] += 1
@@ -4237,7 +4354,7 @@ class ServingEngine:
             self._close_probe_window()
         tr = obs.active_tracer()
         if tr is not None:
-            dur = dispatch_s + sync_s
+            dur = self._tick_decode_s()
             tr.record("serving.spec_verify", ts=time.time() - dur,
                       dur_s=dur, slots=len(active),
                       trace_ids=[self._slots[i].req.trace_id
@@ -4245,10 +4362,6 @@ class ServingEngine:
                                  if self._slots[i] is not None],
                       proposed=proposed_total, accepted=accepted_total,
                       committed=committed_total)
-        if grp is not None:
-            self._commit_chunk(grp, g_start, g_kind, ctok_np, lanes_np,
-                               kvfull_np, dispatch_s + sync_s, tick_warm)
-        return dispatch_s, sync_s
 
     def _after_flight(self):
         """Post-event tail of a tick: flush any queued flight dump and
@@ -4258,25 +4371,25 @@ class ServingEngine:
             self._dump_pending = None
         self._update_gauges()
 
-    def _record_segments(self, admit_s, dispatch_s, sync_s):
-        """Step-segment telemetry: cumulative stats + registry
-        histograms. admit is observed every tick; prefill only on ticks
-        that ran a wave, dispatch/sync only on ticks that decoded — so
-        each histogram is the distribution of the segment when it
-        actually happened, not diluted by structural zeros."""
-        st = self.stats
-        st["step_admit_s"] += admit_s
-        st["step_prefill_s"] += self._tick_prefill_s
+    def _record_segments(self):
+        """Step-segment telemetry: this tick's :class:`_Phase` times
+        into the registry histograms (``stats`` has them already).
+        admit is observed every tick; prefill only on ticks that ran a
+        wave, dispatch/sync/commit only on ticks that decoded — so each
+        histogram is the distribution of the segment when it actually
+        happened, not diluted by structural zeros."""
+        t = self._tick_s
         r = self._metrics
-        r.histogram("serving.step_admit_s").observe(admit_s)
+        r.histogram("serving.step_admit_s").observe(t["step_admit_s"])
         if self._tick_prefills:
             r.histogram("serving.step_prefill_s").observe(
-                self._tick_prefill_s)
-        if dispatch_s is not None:
-            st["step_dispatch_s"] += dispatch_s
-            st["step_sync_s"] += sync_s
-            r.histogram("serving.step_dispatch_s").observe(dispatch_s)
-            r.histogram("serving.step_sync_s").observe(sync_s)
+                t["step_prefill_s"])
+        if "step_dispatch_s" in t:
+            r.histogram("serving.step_dispatch_s").observe(
+                t["step_dispatch_s"])
+            r.histogram("serving.step_sync_s").observe(t["step_sync_s"])
+            r.histogram("serving.step_commit_s").observe(
+                t["step_commit_s"])
             # capacity-estimator feed: the same decode-step cost the
             # histograms just observed (shed_infeasible prices deadlines
             # against this EWMA) — except fused CHUNK ticks, whose wall
@@ -4292,12 +4405,16 @@ class ServingEngine:
             self._step_fn_warm = True
             if not self._tick_chunks:
                 if self._ewma_step_warm:
-                    self._ewma_step.update(dispatch_s + sync_s)
+                    self._ewma_step.update(self._tick_decode_s())
                 else:
                     self._ewma_step_warm = True
 
-    def _record_flight(self, admit_s, dispatch_s, sync_s, err=None):
-        """One compact JSON-ready event per tick into the flight ring."""
+    def _record_flight(self, err=None):
+        """One compact JSON-ready event per tick into the flight ring.
+        The segment fields are this tick's :class:`_Phase` times;
+        ``None`` for a phase the tick never reached. The tail writes
+        this event, so it cannot be in it."""
+        t = self._tick_s.get
         evt = {"step": self._step_seq, "ts": round(time.time(), 6),
                "ts_mono": round(time.perf_counter(), 6),
                "active": self.active_slots, "queued": len(self._queue),
@@ -4326,11 +4443,11 @@ class ServingEngine:
                                  else self._tick_spec[0]),
                "spec_accepted": (None if self._tick_spec is None
                                  else self._tick_spec[1]),
-               "t_admit_s": round(admit_s, 6),
-               "t_prefill_s": round(self._tick_prefill_s, 6),
-               "t_dispatch_s": (None if dispatch_s is None
-                                else round(dispatch_s, 6)),
-               "t_sync_s": (None if sync_s is None else round(sync_s, 6))}
+               "t_admit_s": _round6(t("step_admit_s")),
+               "t_prefill_s": _round6(t("step_prefill_s", 0.0)),
+               "t_dispatch_s": _round6(t("step_dispatch_s")),
+               "t_sync_s": _round6(t("step_sync_s")),
+               "t_commit_s": _round6(t("step_commit_s"))}
         if err is not None:
             evt["err"] = err
         self.flight.record(evt)

@@ -220,6 +220,30 @@ def test_span_drift_rule_shared_implementation():
                           "decode.wrapped_rotten_span"}
 
 
+def test_span_drift_sees_phase_helper_and_profiler_annotations():
+    """The serving engine's phase spans are opened through
+    ``self._phase(...)`` and bare profiler annotations, not a Tracer:
+    the same rule pins those names to the span table."""
+    sources = {"paddle_tpu/a.py":
+               'with self._phase("serving.step.good", rows=1):\n'
+               '    pass\n'
+               'with self._phase(\n'
+               '        "serving.step.rotten"):\n'
+               '    pass\n'
+               'with jax.profiler.TraceAnnotation(\n'
+               '        "serving.submit_rotten", request_id=1):\n'
+               '    pass\n'
+               'with jax.profiler.StepTraceAnnotation("serving.tick_rotten",\n'
+               '                                      step_num=3):\n'
+               '    pass\n'}
+    docs = "| `serving.step.good` | documented |\n"
+    found = rules_mod.check_span_drift(sources, docs, lambda p, ln: "")
+    assert sorted(f.line for f in found) == [3, 6, 9]
+    assert set(rules_mod.collect_span_names(sources)) == {
+        "serving.step.good", "serving.step.rotten",
+        "serving.submit_rotten", "serving.tick_rotten"}
+
+
 def test_span_drift_skipped_without_docs_file(tmp_path):
     """Installed-package run (docs/ not shipped): span-drift is dropped
     like metric-drift instead of flagging every span literal."""
@@ -1154,10 +1178,14 @@ def test_router_steady_state_zero_h2d_zero_recompiles():
                         max_seq_len=128, sanitize=True) as router:
         # short prompts (no full affinity block) spread least-loaded
         # across both replicas; each replica's prefill + step programs
-        # compile during these warmup ticks
+        # compile during these warmup ticks. All four are placed while
+        # both replicas are cold, so only the queue depths decide: an
+        # estimated TTFT from two ticks of a loaded machine now and then
+        # put three on one replica, and the loop below never ran
         for i in range(4):
             router.submit(serving.Request(rng.randint(3, 500, (12,)),
                                           max_new_tokens=24, seed=i))
+        for _ in range(4):
             router.step()
         assert all(e.active_slots
                    for e in (router.replica_engine(0),

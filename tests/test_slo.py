@@ -588,8 +588,8 @@ def test_load_bench_smoke_emits_slo_percentiles(tmp_path):
         assert rec["ttft_p99_s"] >= rec["ttft_p95_s"] >= rec["ttft_p50_s"]
         assert rec["tpot_p99_s"] >= rec["tpot_p50_s"] > 0
         assert 0.0 <= rec["goodput"] <= 1.0
-        assert set(rec["step_breakdown_s"]) == {"admit", "prefill",
-                                                "dispatch", "sync"}
+        assert set(rec["step_breakdown_s"]) == {
+            "admit", "prefill", "dispatch", "sync", "commit", "tail"}
         # the robustness fields ride every point (small queue bound +
         # no deadlines here, so typically zero — presence and type are
         # the contract, schema-validated above)

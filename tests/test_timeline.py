@@ -26,6 +26,8 @@ def _tick(step, ts, *, admitted=(), retired=(), preempted=(),
            "t_prefill_s": seg.get("prefill", 0.0),
            "t_dispatch_s": seg.get("dispatch", 0.0),
            "t_sync_s": seg.get("sync", 0.0)}
+    if "commit" in seg:     # flight dumps from before PR 25 have none
+        evt["t_commit_s"] = seg["commit"]
     if err is not None:
         evt["err"] = err
     return evt
@@ -68,16 +70,20 @@ def test_build_timeline_tracks_segments_and_instants():
     assert tnames[0] == "ticks" and tnames[3] == "journal"
     assert tnames[16] == "req 7"            # dense per-request track
 
-    # tick 0: four segments end-aligned at the record stamp, in
-    # TICK_SEGMENTS order, summing back to the tick's total
+    # tick 0: the segments in TICK_SEGMENTS order, summing back to the
+    # tick's total and ending at the record stamp; prefill is drawn
+    # INSIDE admit (t_admit_s is admission's time less it), the rest
+    # follow one another
     segs = [e for e in evts if e["ph"] == "X" and e["tid"] == 0
             and e["args"].get("step") == 0]
     assert [e["name"] for e in segs] == ["admit", "prefill", "dispatch",
                                          "sync"]
-    assert segs[0]["ts"] == tl._us(100.0 - 1.0)     # total 1.0s
-    assert segs[-1]["ts"] + segs[-1]["dur"] == tl._us(100.0)
-    for a, b in zip(segs, segs[1:]):
-        assert a["ts"] + a["dur"] == b["ts"]        # contiguous
+    admit, prefill, dispatch, sync = segs
+    assert admit["ts"] == prefill["ts"] == tl._us(100.0 - 1.0)
+    assert admit["dur"] == tl._us(0.75) and prefill["dur"] == tl._us(0.25)
+    assert admit["ts"] + admit["dur"] == dispatch["ts"]
+    assert dispatch["ts"] + dispatch["dur"] == sync["ts"]
+    assert sync["ts"] + sync["dur"] == tl._us(100.0)
 
     # tick 1: zero-duration segments are dropped, the error instants
     inst = {(e["name"], e["tid"]) for e in evts if e["ph"] == "i"}
@@ -91,6 +97,38 @@ def test_build_timeline_tracks_segments_and_instants():
     assert kinds[:len(meta)] == ["M"] * len(meta)
     stamped = [e.get("ts", 0) for e in evts if e["ph"] != "M"]
     assert stamped == sorted(stamped)
+
+
+def test_tick_segments_end_at_the_stamp_because_of_commit():
+    """The flight stamp is taken in the tail, after the commit: with
+    ``t_commit_s`` in the event every segment is drawn where it
+    happened, and without it (an older dump) the four old ones still
+    end at the stamp as they used to."""
+    assert tl.TICK_SEGMENTS[-1] == ("commit", "t_commit_s")
+    new = _tick(3, 50.0, admit=0.002, prefill=0.004, dispatch=0.001,
+                sync=0.005, commit=0.003)
+    old = _tick(4, 60.0, admit=0.002, prefill=0.004, dispatch=0.001,
+                sync=0.005)
+    evts = tl.build_timeline([{"name": "engine", "flight": [new, old]}]
+                             )["traceEvents"]
+
+    def segs(step):
+        return {e["name"]: (e["ts"], e["ts"] + e["dur"]) for e in evts
+                if e["ph"] == "X" and e["args"].get("step") == step}
+
+    s = segs(3)
+    assert list(s) == ["admit", "prefill", "dispatch", "sync", "commit"]
+    assert s["commit"] == (tl._us(50.0 - 0.003), tl._us(50.0))
+    assert s["sync"] == (tl._us(50.0 - 0.008), tl._us(50.0 - 0.003))
+    assert s["dispatch"][1] == s["sync"][0]
+    # admit spans its own 2 ms AND the 4 ms of prefill inside it
+    assert s["admit"] == (tl._us(50.0 - 0.015), tl._us(50.0 - 0.009))
+    assert s["admit"][0] == s["prefill"][0]
+    assert s["prefill"][1] <= s["admit"][1] == s["dispatch"][0]
+    s = segs(4)
+    assert list(s) == ["admit", "prefill", "dispatch", "sync"]
+    assert s["sync"][1] == tl._us(60.0)
+    assert s["admit"][0] == tl._us(60.0 - 0.012)
 
 
 def test_build_timeline_flows_cross_process_tracks():
