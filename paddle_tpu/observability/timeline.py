@@ -37,7 +37,10 @@ __all__ = ["clock_anchor", "build_timeline", "write_timeline",
 #: the per-tick step segments, in dispatch order, with their flight
 #: event fields (docs/OBSERVABILITY.md §Timelines). ``commit`` is the
 #: last phase before the tail stamps the event, which is why the
-#: segments end at the stamp; ``prefill`` runs inside admission
+#: segments end at the stamp; ``prefill`` runs inside admission. An
+#: event tick that landed the step program in flight before its upload
+#: (docs/SERVING.md §The tick's order) is drawn in this order too: the
+#: lengths are its own, its sync and commit ran before the dispatch
 TICK_SEGMENTS = (("admit", "t_admit_s"), ("prefill", "t_prefill_s"),
                  ("dispatch", "t_dispatch_s"), ("sync", "t_sync_s"),
                  ("commit", "t_commit_s"))

@@ -101,6 +101,8 @@ steady-state H2D) or a draft model riding its own block tables over
 the same paged machinery.
 """
 
+import collections
+import contextlib
 import heapq
 import json
 import logging
@@ -618,18 +620,23 @@ class _Ewma:
 
 
 # the tick's phases: span name -> (the ``stats`` key its seconds add
-# to, the key of the enclosing segment they come off again). Six self
-# times partition step(): admit + prefill + dispatch + sync + commit +
-# tail. upload is a PART of admit, told apart beside it, never a
-# seventh segment (docs/OBSERVABILITY.md §Span names).
+# to, whether they come off the phase that encloses it). Six self times
+# partition step(): admit + prefill + dispatch + sync + commit + tail.
+# prefill runs inside admit; on the ticks that land the program in
+# flight before an upload (:meth:`ServingEngine._land`) its sync and
+# commit run inside admit, and a wave's joint pull is timed as the
+# prefill it belongs to with the commit inside it; otherwise sync and
+# commit are top-level. upload
+# is a PART of admit, told apart beside it, never a seventh segment
+# (docs/OBSERVABILITY.md §Span names).
 _PHASES = {
-    "serving.step.admit": ("step_admit_s", None),
-    "serving.step.prefill": ("step_prefill_s", "step_admit_s"),
-    "serving.step.upload": ("step_upload_s", None),
-    "serving.step.dispatch": ("step_dispatch_s", None),
-    "serving.step.sync": ("step_sync_s", None),
-    "serving.step.commit": ("step_commit_s", None),
-    "serving.step.tail": ("step_tail_s", None),
+    "serving.step.admit": ("step_admit_s", True),
+    "serving.step.prefill": ("step_prefill_s", True),
+    "serving.step.upload": ("step_upload_s", False),
+    "serving.step.dispatch": ("step_dispatch_s", True),
+    "serving.step.sync": ("step_sync_s", True),
+    "serving.step.commit": ("step_commit_s", True),
+    "serving.step.tail": ("step_tail_s", True),
 }
 
 
@@ -651,9 +658,12 @@ class _Phase:
     annotation as keyword arguments, never into the name. Clock and
     annotation both run from construction to ``__exit__``, so the
     helper's own cost lies inside the phase it times and two phases in
-    a row leave next to nothing between them."""
+    a row leave next to nothing between them. A phase opened inside
+    another (the wave prefill in admit; the sync and commit of a
+    program landed before an upload) comes off the one around it, so what is
+    summed is every phase's self time."""
 
-    __slots__ = ("_eng", "_name", "_ann", "_t0", "dur_s")
+    __slots__ = ("_eng", "_name", "_ann", "_t0", "_outer", "dur_s")
 
     def __init__(self, eng, name, attrs):
         self._t0 = time.perf_counter()
@@ -663,6 +673,8 @@ class _Phase:
 
     def __enter__(self):
         self._ann.__enter__()
+        self._outer = self._eng._open_phase
+        self._eng._open_phase = self
         return self
 
     def set(self, **attrs):
@@ -670,15 +682,42 @@ class _Phase:
         self._ann.set_metadata(**attrs)
 
     def __exit__(self, *exc):
-        key, parent = _PHASES[self._name]
+        key, carved = _PHASES[self._name]
         stats, tick = self._eng.stats, self._eng._tick_s
         self.dur_s = dt = time.perf_counter() - self._t0
         stats[key] += dt
         tick[key] = tick.get(key, 0.0) + dt
-        if parent is not None:
+        outer = self._eng._open_phase = self._outer
+        if carved and outer is not None:
+            parent = _PHASES[outer._name][0]
             stats[parent] -= dt
             tick[parent] = tick.get(parent, 0.0) - dt
         self._ann.__exit__(*exc)
+
+
+class _InFlight:
+    """One decode program that has been dispatched and whose tokens the
+    host has not pulled yet (``ServingEngine._flight_q``). ``rows`` are
+    the (slot index, slot) pairs it computes a token for — at the pull a
+    row whose slot has gone or changed hands is thrown away. ``head``
+    and ``chunk_outs`` are the device arrays :meth:`ServingEngine.
+    _fence_chunk_pulls` pulls, ``spec`` says which host commit takes
+    them; ``t_launch`` is on the engine's clock."""
+
+    __slots__ = ("rows", "head", "chunk_outs", "spec", "grp", "g_start",
+                 "g_kind", "tick_warm", "t_launch")
+
+    def __init__(self, rows, head, chunk_outs, spec, grp, g_start,
+                 g_kind, tick_warm, t_launch):
+        self.rows = rows
+        self.head = head
+        self.chunk_outs = chunk_outs
+        self.spec = spec
+        self.grp = grp
+        self.g_start = g_start
+        self.g_kind = g_kind
+        self.tick_warm = tick_warm
+        self.t_launch = t_launch
 
 
 def _swap_bucket(n: int) -> int:
@@ -708,6 +747,12 @@ class ServingEngine:
     prefill is its own calibration pass (per-SLOT scales — an isolated
     b=1 ``generate`` computes the same scales, which is what keeps int8
     parity token-exact).
+
+    The plain steady tick keeps ONE step program in flight: ``step()``
+    dispatches the next decode step before it pulls the tokens of the
+    one dispatched a call earlier, so the device does not wait for the
+    way back from the chip (docs/SERVING.md §The tick's order). Which
+    ticks do is decided by what the tick is, never by an option.
 
     Observability: every ``step()`` is wall-timed in six phases that
     partition it (:class:`_Phase`: ``serving.step.*`` profiler spans,
@@ -1248,6 +1293,31 @@ class ServingEngine:
         self._tick_prefills: List = []
         # tpu-lint: volatile(per-tick segment timing)
         self._tick_s: Dict[str, float] = {}     # this tick's _Phase times
+        # tpu-lint: volatile(per-tick segment timing)
+        self._open_phase: Optional[_Phase] = None   # innermost open one
+        # decode programs dispatched and not yet pulled, oldest first:
+        # one between two plain steady ticks, two for an instant inside
+        # one, none after any other kind of tick (docs/SERVING.md §The
+        # tick's order)
+        # tpu-lint: volatile(snapshot() lands them first; a crash loses
+        # at most the one uncommitted token a row, which restore
+        # recomputes)
+        self._flight_q: collections.deque = collections.deque()
+        # tpu-lint: volatile(per-tick marker)
+        self._tick_landed = False       # this tick has committed a step
+        # tpu-lint: volatile(per-tick flight marker)
+        self._tick_lookahead = False
+        # the engine's clock: perf_counter less the time spent outside
+        # step(), so that a program's seconds (from its launch, or from
+        # the pull before it, to its own pull) leave the caller out
+        # tpu-lint: volatile(estimator timing, per incarnation)
+        self._away_s = 0.0
+        # tpu-lint: volatile(estimator timing, per incarnation)
+        self._t_out: Optional[float] = None
+        # tpu-lint: volatile(estimator timing, per incarnation)
+        self._t_landed = 0.0
+        # tpu-lint: volatile(per-tick segment timing)
+        self._tick_program_s: Optional[float] = None
         # overload-control tick markers + capacity estimator state
         # tpu-lint: volatile(per-tick flight marker)
         self._tick_preempted: List[int] = []
@@ -1445,8 +1515,12 @@ class ServingEngine:
         phase (:class:`_Phase`): admit, prefill, dispatch, sync, commit
         and tail partition ``step()``; ``step_upload_s`` is the part of
         ``step_admit_s`` spent re-uploading the dirty mirrors, on
-        ``upload_ticks`` ticks. Per-step distributions live in the
-        ``serving.step_*_s`` registry histograms."""
+        ``upload_ticks`` ticks. ``lookahead_ticks`` counts the ticks
+        that dispatched a step program before the tokens of the one
+        before it were pulled, ``lookahead_discarded_tokens`` the tokens
+        such a program computed for rows that had left by its pull.
+        Per-step distributions live in the ``serving.step_*_s``
+        registry histograms."""
         return dict(steps=0, decode_tokens=0, idle_slot_steps=0,
                     prefill_tokens=0, prefill_tokens_reused=0,
                     prefill_chunks=0, replay_tokens=0,
@@ -1462,7 +1536,8 @@ class ServingEngine:
                     step_admit_s=0.0, step_prefill_s=0.0,
                     step_dispatch_s=0.0, step_sync_s=0.0,
                     step_commit_s=0.0, step_tail_s=0.0,
-                    step_upload_s=0.0, upload_ticks=0)
+                    step_upload_s=0.0, upload_ticks=0,
+                    lookahead_ticks=0, lookahead_discarded_tokens=0)
 
     def reset_stats(self):
         """Zero the cumulative throughput counters and step-segment
@@ -1730,6 +1805,7 @@ class ServingEngine:
         if self._closed:
             raise RuntimeError("ServingEngine is closed")
         rid = int(request_id)
+        self._settle()
         slot_idx = next((i for i, s in enumerate(self._slots)
                          if s is not None and s.req.request_id == rid),
                         None)
@@ -2990,6 +3066,11 @@ class ServingEngine:
                 victim = self._preempt_victim(rank, wave_idx)
                 if victim is None:
                     break
+                if self._flight_q:
+                    # a victim is requeued with every token computed
+                    # for it; the landed step may free a slot by itself
+                    self._land_all()
+                    continue
                 self._preempt(victim)
                 slot_idx = victim
                 if self.prefix_cache is not None:
@@ -3014,6 +3095,9 @@ class ServingEngine:
                 victim = self._preempt_victim(rank, wave_idx)
                 if victim is None:
                     break
+                if self._flight_q:
+                    self._land_all()    # as above; then look again
+                    continue
                 self._preempt(victim)
                 if self.prefix_cache is not None:
                     # the victim donated its blocks to the cache —
@@ -3134,8 +3218,17 @@ class ServingEngine:
                     self._up(last_idx), self._up(seeds),
                     self._up(new_bids), self._up(valid))
                 lanes_np = kv_np = None
-            # tpu-lint: allow(host-sync): once-per-wave D2H — first tokens
-            tok_np = np.asarray(tok)
+            if self._flight_q:
+                # the wave went out BEHIND the step program in flight
+                # (it needs the pool and its own uploads, nothing of the
+                # host's): its first tokens and that step's come back in
+                # one device_get, and the step commits before the rows
+                # join (the upload that follows needs its tokens)
+                (tok_np,) = self._land(extra=(tok,))
+            else:
+                # tpu-lint: allow(host-sync): once-per-wave D2H — first
+                # tokens
+                tok_np = np.asarray(tok)
             for r, (slot_idx, slot, hits, _, _) in enumerate(grp):
                 self._adopt_slot(
                     slot_idx, slot, int(tok_np[r]),
@@ -3875,17 +3968,35 @@ class ServingEngine:
 
     def step(self) -> Dict:
         """One scheduler tick: admit what fits, retire expired deadlines,
-        run ONE fused paged decode step for every active slot, retire
+        commit ONE fused paged decode step for every active slot, retire
         slots that finished. Returns a small status dict.
+
+        The plain steady tick keeps one step program in flight
+        (:meth:`_decode`; docs/SERVING.md §The tick's order): it
+        dispatches the NEXT step program first and only then pulls and
+        commits the tokens of the one dispatched a call earlier, so the
+        device never waits for the way back from the chip. A tick after
+        an event (a join, a leave, a lazy block) lands the program in
+        flight before its mirror upload, dispatches the next one and
+        returns with it in flight; every other kind of tick
+        (speculative, fused chunk, anything parked, a deadline sweep, a
+        preemption) lands it first and then runs as it always did. A
+        row that left at the commit before is thrown away at the next
+        (``stats["lookahead_discarded_tokens"]``). Either way a call
+        that decodes commits exactly one plain step, and ``finished``
+        is complete for result collection.
 
         Each tick is six phases (:class:`_Phase`), from its first line
         to its return — admit (scheduling + deadline sweep + block-table
         bookkeeping + the dirty-mirror upload, which ``step_upload_s``
         tells apart), wave-prefill, fused decode dispatch (program call;
         on async backends this is enqueue time), host sync (the
-        sampled-token D2H pull, where device wait surfaces), commit (the
-        per-slot host loop and retirements behind the pull) and tail
-        (this telemetry itself). Each is a ``serving.step.*`` span in
+        sampled-token D2H pull of the oldest program in flight, where
+        device wait surfaces), commit (the per-slot host loop and
+        retirements behind the pull) and tail (this telemetry itself);
+        a sync and a commit that land a program inside admit come off
+        it, and a wave's joint pull is part of its prefill. Each is a
+        ``serving.step.*`` span in
         any profile being taken, a cumulative ``stats["step_*_s"]``
         field, a ``serving.step_*_s`` histogram and (the tail apart) a
         field of this tick's flight-recorder event, so a TPOT spike or
@@ -3896,6 +4007,8 @@ class ServingEngine:
         """
         if self._closed:
             raise RuntimeError("ServingEngine is closed")
+        if self._t_out is not None:
+            self._away_s += time.perf_counter() - self._t_out
         with jax.profiler.StepTraceAnnotation("serving.step",
                                               step_num=self._step_seq):
             self._tick_s = {}
@@ -3924,6 +4037,8 @@ class ServingEngine:
                 # tick would emit a spurious "deadline_retirement" dump
                 self._dump_pending = None
                 raise
+            finally:
+                self._t_out = time.perf_counter()
 
     def _schedule_tick(self):
         """The admit phase: everything up to the dispatch call (the
@@ -3947,6 +4062,8 @@ class ServingEngine:
         self._tick_swapped_out = []
         self._tick_swapped_in = []
         self._tick_spec = None
+        self._tick_landed = False
+        self._tick_lookahead = False
         # _tick_shed keeps accumulating across submit() calls between
         # ticks; _record_flight drains it into this tick's event
 
@@ -3956,6 +4073,7 @@ class ServingEngine:
         # nothing parked runs the exact steady tick — the 0-H2D pin in
         # tests/test_analysis.py covers offload=True idle ticks)
         if self._parked:
+            self._land_all()    # the swap paths see the state they saw
             self._drain_swaps()
             self._offload_prefetch()
         # every _retire this tick (deadline sweep, instant finish on the
@@ -3964,11 +4082,16 @@ class ServingEngine:
         # for result collection
         self._admit()
         now = time.perf_counter()
-        for i, s in enumerate(self._slots):
-            if s is not None and s.deadline_at is not None \
-                    and now > s.deadline_at:
-                record_event("deadline_exceeded")
-                self._retire(i, "deadline")
+        expired = [(i, s) for i, s in enumerate(self._slots)
+                   if s is not None and s.deadline_at is not None
+                   and now > s.deadline_at]
+        if expired:
+            # a swept row retires with every token computed for it
+            self._land_all()
+            for i, s in expired:
+                if self._slots[i] is s:
+                    record_event("deadline_exceeded")
+                    self._retire(i, "deadline")
         # chunked-prefill interleave (the ONE-PROGRAM tick): when a
         # chunk is due — every `decode_per_chunk` decode dispatches
         # while decode-ready slots exist, unconditionally otherwise —
@@ -3986,13 +4109,12 @@ class ServingEngine:
                         or self._decode_since_chunk
                         >= self.decode_per_chunk):
                     grp = front
+        if grp is not None:
+            self._land_all()    # a fused chunk tick keeps today's order
         spec = self.speculate is not None
         spec_tick = False
         K_eff = 0
-        # prefilling slots stay OUT of the decode batch: their mirror
-        # rows idle against scratch until the last chunk adopts them
-        active = [i for i, s in enumerate(self._slots)
-                  if s is not None and not s.prefilling]
+        active = self._decode_ready()
         if active or grp is not None:
             if spec and active:
                 if self.speculate.adaptive:
@@ -4016,11 +4138,28 @@ class ServingEngine:
                 # active slot sits at k=0 ride the plain per-token
                 # dispatch — the "stops paying the verify tail" case
                 self._step_fn = self._build_step_fn()
-            for i in active:
-                self._ensure_blocks(i, self._spec_k if spec else 0)
-                if self._draft_tables is not None:
-                    self._ensure_draft_blocks(i)
+            ahead = None
+            if self._flight_q:
+                # a plain tick that finds a program in flight: if nothing
+                # has happened, the next one goes out before its pull
+                ahead = self._rows_ahead()
+            else:
+                for i in active:
+                    self._ensure_blocks(i, self._spec_k if spec else 0)
+                    if self._draft_tables is not None:
+                        self._ensure_draft_blocks(i)
             _faults.maybe_fire("decode.dispatch")
+            if self._flight_q and self._dirty:
+                # an event (a join, a leave at the last commit, a lazy
+                # block): the full-mirror upload needs the host's newest
+                # tokens, so the program in flight lands first
+                self._land_all()
+                ahead = None
+                active = self._decode_ready()
+                if not active:
+                    return None
+                for i in active:
+                    self._ensure_blocks(i)
             # the fused tick program for this chunk bucket (cursor +
             # tail width); built before the steady/dirty decision so a
             # compile never counts as a steady dispatch
@@ -4044,8 +4183,37 @@ class ServingEngine:
                 with self._phase("serving.step.upload"):
                     self._upload_mirrors(spec, spec_tick)
             return (spec_tick, active, steady, grp, tick_fn, tick_warm,
-                    g_start, g_kind)
+                    g_start, g_kind, ahead)
         return None
+
+    def _decode_ready(self) -> List[int]:
+        """The slots of the decode batch. Prefilling slots stay OUT of
+        it: their mirror rows idle against scratch until the last chunk
+        adopts them."""
+        return [i for i, s in enumerate(self._slots)
+                if s is not None and not s.prefilling]
+
+    def _rows_ahead(self):
+        """The (slot index, slot) rows of the step program AFTER the
+        newest one in flight, when that program may go out before the
+        newest one's tokens are pulled; else ``None``. It may when the
+        next tick is known to be the plain one and needs nothing the
+        host has: no speculation (an adaptive engine's next tick may be
+        a probe), nothing parked, no chunk group waiting, clean mirrors,
+        a step program that has run. Positions and counts advance by one
+        a step whatever the token is, so the host can tell before the
+        pull which rows leave at their length (they are left out) and
+        which append position needs a fresh block: a lazy block is an
+        event like any other, and the program waits for the upload."""
+        if (self.speculate is not None or self._dirty or self._parked
+                or not self._ewma_step_warm
+                or self._front_prefill() is not None):
+            return None
+        rows = [(i, s) for i, s in self._flight_q[-1].rows
+                if s.count + 1 < s.req.max_new_tokens]
+        for i, _ in rows:
+            self._ensure_blocks(i, 1)
+        return rows if rows and not self._dirty else None
 
     def _upload_mirrors(self, spec: bool, spec_tick: bool):
         """Re-upload the host mirrors a join or a leave made dirty."""
@@ -4133,34 +4301,143 @@ class ServingEngine:
         return head_np, ctok_np, lanes_np, kvfull_np
 
     def _decode(self, spec_tick, active, steady, grp, tick_fn, tick_warm,
-                g_start, g_kind):
+                g_start, g_kind, ahead):
         """The dispatch, sync and commit phases of a tick — the plain,
         the speculative and the fused chunk tick all pass through here,
-        so all three carry the same phases. ``sync`` ends when the pull
-        returns; everything the host does with the pulled tokens
-        (per-slot commit, retirements, :meth:`_commit_chunk`) is
-        ``commit``."""
-        dispatch, commit = ((self._dispatch_spec, self._commit_spec)
-                            if spec_tick else
-                            (self._dispatch_plain, self._commit_plain))
+        so all three carry the same phases, and :meth:`_launch` and
+        :meth:`_land` are the one place each is written.
+
+        A speculative or fused tick launches its program and lands it.
+        The PLAIN tick keeps one step program in flight: it launches
+        the step after the one in flight (``ahead``, its rows; or, with
+        nothing in flight, this step and then the one after it) and only
+        then lands the older one, so the device has a program queued
+        while the pull travels back and the host commits. A tick that
+        has landed a step already (an event tick: :meth:`_schedule_tick`
+        or the wave prefill landed it before the upload) launches the
+        next program and returns with it in flight. Every call lands at
+        most one step of the plain kind."""
+        plain = not spec_tick and grp is None
+        held = plain and self._tick_landed
         with self._phase("serving.step.dispatch"):
-            head, chunk_outs = dispatch(active, steady, grp, tick_fn)
-        with self._phase("serving.step.sync"):
+            if not self._flight_q:
+                self._launch(spec_tick, active, steady, grp, tick_fn,
+                             tick_warm, g_start, g_kind)
+                if plain and not held:
+                    ahead = self._rows_ahead()
+            if ahead:
+                self._launch(False, [i for i, _ in ahead], True)
+                left = len(self._flight_q[0].rows) - len(ahead)
+                self._tick_lookahead = True
+                self.stats["lookahead_ticks"] += 1
+                self.stats["lookahead_discarded_tokens"] += left
+                self._metrics.counter("serving.lookahead_ticks").inc()
+        if not held:
+            self._land()
+
+    def _launch(self, spec_tick, active, steady, grp=None, tick_fn=None,
+                tick_warm=True, g_start=None, g_kind=None):
+        """Dispatch one decode program (plain step, verify, or fused
+        chunk tick) for the slots ``active`` and queue it as in flight:
+        THE one dispatch of a tick's decode."""
+        dispatch = self._dispatch_spec if spec_tick else self._dispatch_plain
+        t_launch = self._clock()
+        head, chunk_outs = dispatch(active, steady, grp, tick_fn)
+        self._flight_q.append(_InFlight(
+            [(i, self._slots[i]) for i in active], head, chunk_outs,
+            spec_tick, grp, g_start, g_kind, tick_warm, t_launch))
+
+    def _land(self, extra=()):
+        """Pull the tokens of the OLDEST program in flight and commit
+        them: THE one sync and commit of a decode program, whichever
+        phase of the tick it runs in (:class:`_Phase` takes its seconds
+        off the phase around it). ``sync`` ends when the pull returns;
+        everything the host does with the pulled tokens (per-slot
+        commit, retirements, :meth:`_commit_chunk`) is ``commit``.
+        ``extra`` device arrays ride the same ``device_get`` (the wave
+        prefill's first tokens) and are returned as host arrays; that
+        pull is timed as the prefill phase it runs in.
+
+        A row whose slot has gone or changed hands since the launch (it
+        left at the commit before this one, one token after the program
+        went out) is thrown away: never appended, never counted in
+        ``decode_tokens``. Its stray KV append went to the retired
+        row's own last block, or to scratch, at a position past the
+        row's last valid one, and in device order before anything a
+        later owner of that block writes. The prefix cache shares only
+        whole blocks of positions below the prompt's (or, on a
+        preemption, the written) length, so no shared block holds it."""
+        rec = self._flight_q[0]
+        # a wave's joint pull stays a part of its prefill phase, which
+        # has always held the wait for the wave's program; and what it
+        # waited for says nothing of a step program's own seconds
+        with (contextlib.nullcontext() if extra
+              else self._phase("serving.step.sync")):
             head_np, ctok_np, lanes_np, kvfull_np = self._fence_chunk_pulls(
-                grp, g_kind, chunk_outs, head)
+                rec.grp, rec.g_kind, rec.chunk_outs, [*rec.head, *extra])
+            self._flight_q.popleft()
+            now = self._clock()
+            self._tick_program_s = (None if extra else
+                                    now - max(rec.t_launch, self._t_landed))
+            self._t_landed = now
+            self._tick_landed = True
         with self._phase("serving.step.commit") as ph:
             n0 = len(self._tick_retired)
-            commit(active, head_np)
-            if grp is not None:
-                self._commit_chunk(grp, g_start, g_kind, ctok_np, lanes_np,
-                                   kvfull_np, tick_warm)
+            active = [i for i, s in rec.rows if self._slots[i] is s]
+            gone = len(rec.rows) - len(active)
+            commit = self._commit_spec if rec.spec else self._commit_plain
+            commit(active, head_np[:len(rec.head)])
+            if rec.grp is not None:
+                self._commit_chunk(rec.grp, rec.g_start, rec.g_kind,
+                                   ctok_np, lanes_np, kvfull_np,
+                                   rec.tick_warm)
+            nxt = self._flight_q[0] if self._flight_q else None
+            if nxt is not None and not any(self._slots[i] is s
+                                           for i, s in nxt.rows):
+                # every row of the program behind it has left: nothing
+                # of it is worth a pull (the mirrors are dirty, so the
+                # next program starts from an upload)
+                gone += len(nxt.rows)
+                self._flight_q.clear()
+            self.stats["lookahead_discarded_tokens"] += gone
             ph.set(retired=len(self._tick_retired) - n0)
+            pulled = head_np[len(rec.head):]
+            # what was pulled and the record's device arrays are
+            # released inside the phase, not between two of them
+            rec = nxt = head_np = ctok_np = lanes_np = kvfull_np = None
+        return pulled
 
-    def _tick_decode_s(self) -> float:
-        """This tick's dispatch + sync seconds: the wall time of its
-        decode (or fused chunk) program, as the EWMAs are fed it."""
-        t = self._tick_s
-        return t["step_dispatch_s"] + t["step_sync_s"]
+    def _land_all(self):
+        """Land whatever is in flight (between ticks: at most one
+        program) before anything that reads or moves slot state."""
+        while self._flight_q:
+            self._land()
+
+    def _settle(self):
+        """:meth:`_land_all` from OUTSIDE a tick (``snapshot``,
+        ``release_request``): what retires surfaces in the next
+        ``step()``'s ``finished`` list, like a shed between ticks."""
+        if self._flight_q:
+            self._finished_tick = []
+            self._land_all()
+            self._pending_finished.extend(self._finished_tick)
+            self._finished_tick = []
+
+    def _clock(self) -> float:
+        """The engine's clock: ``perf_counter`` less the seconds spent
+        outside ``step()``."""
+        return time.perf_counter() - self._away_s
+
+    def _tick_decode_s(self) -> Optional[float]:
+        """The seconds the program landed last took alone, as the EWMAs
+        are fed it: from its launch, or from the pull of the program
+        before it when it was launched ahead of that pull, to its own
+        pull, on the engine's clock (the caller's time between two
+        ``step()`` calls left out). For a tick that dispatches and
+        pulls one program that is dispatch + sync; on a lookahead tick
+        it is the tick's period; ``None`` when it came back with a
+        wave's first tokens, behind the wave's program."""
+        return self._tick_program_s
 
     def _dispatch_plain(self, active, steady, grp, tick_fn):
         """One plain (non-speculative) tick's dispatch: the fused tick
@@ -4375,21 +4652,24 @@ class ServingEngine:
         """Step-segment telemetry: this tick's :class:`_Phase` times
         into the registry histograms (``stats`` has them already).
         admit is observed every tick; prefill only on ticks that ran a
-        wave, dispatch/sync/commit only on ticks that decoded — so each
-        histogram is the distribution of the segment when it actually
-        happened, not diluted by structural zeros."""
+        wave, dispatch/sync/commit only on ticks that ran the phase (an
+        event tick that lands a step before its upload and leaves the
+        next program in flight has a sync and a commit inside admit and
+        a dispatch after it) — so each histogram is the distribution of
+        the segment when it actually happened, not diluted by
+        structural zeros."""
         t = self._tick_s
         r = self._metrics
         r.histogram("serving.step_admit_s").observe(t["step_admit_s"])
         if self._tick_prefills:
             r.histogram("serving.step_prefill_s").observe(
                 t["step_prefill_s"])
+        for key in ("step_dispatch_s", "step_sync_s", "step_commit_s"):
+            if key in t:
+                r.histogram(f"serving.{key}").observe(t[key])
         if "step_dispatch_s" in t:
-            r.histogram("serving.step_dispatch_s").observe(
-                t["step_dispatch_s"])
-            r.histogram("serving.step_sync_s").observe(t["step_sync_s"])
-            r.histogram("serving.step_commit_s").observe(
-                t["step_commit_s"])
+            self._step_fn_warm = True
+        if self._tick_landed:
             # capacity-estimator feed: the same decode-step cost the
             # histograms just observed (shed_infeasible prices deadlines
             # against this EWMA) — except fused CHUNK ticks, whose wall
@@ -4401,9 +4681,10 @@ class ServingEngine:
             # right after startup. The two warm flags are distinct on
             # purpose: _step_fn_warm (the steady/sanitize gate) flips
             # on ANY first dispatch, including a fused chunk tick —
-            # the plain step program may not have compiled yet.
-            self._step_fn_warm = True
-            if not self._tick_chunks:
+            # the plain step program may not have compiled yet. A tick
+            # that launched a program and left it in flight feeds
+            # nothing: its seconds are fed by the tick that lands it.
+            if not self._tick_chunks and self._tick_program_s is not None:
                 if self._ewma_step_warm:
                     self._ewma_step.update(self._tick_decode_s())
                 else:
@@ -4447,7 +4728,8 @@ class ServingEngine:
                "t_prefill_s": _round6(t("step_prefill_s", 0.0)),
                "t_dispatch_s": _round6(t("step_dispatch_s")),
                "t_sync_s": _round6(t("step_sync_s")),
-               "t_commit_s": _round6(t("step_commit_s"))}
+               "t_commit_s": _round6(t("step_commit_s")),
+               "lookahead": self._tick_lookahead}
         if err is not None:
             evt["err"] = err
         self.flight.record(evt)
@@ -4530,6 +4812,15 @@ class ServingEngine:
         if self._closed:
             return
         self._closed = True
+        if self._flight_q:
+            # its tokens are dropped with the requests; let it finish
+            # before the buffers it writes are deleted
+            try:
+                # tpu-lint: allow(host-sync): close() is off the tick
+                jax.block_until_ready(self.kv_pool)
+            except Exception:   # noqa: BLE001 — best-effort release
+                pass
+            self._flight_q.clear()
         for a in (self.kv_pool, self._stacked, self.draft_kv_pool,
                   getattr(self, "_draft_stacked", None)):
             try:
@@ -4594,7 +4885,10 @@ class ServingEngine:
         Call between ``step()`` calls, or after a ``step()`` that died
         on a fault — the host-side scheduler state stays consistent
         across an aborted tick (the fault sites fire *before* queue
-        pops / token appends)."""
+        pops / token appends). A step program in flight is landed
+        first, so the snapshot holds every token the device has been
+        asked for."""
+        self._settle()
         now = time.perf_counter()
 
         def _req(req: Request, tokens, deadline_at=None):

@@ -136,13 +136,15 @@ def test_the_four_old_segments_keep_their_boundaries(ticks):
                     + d["step_dispatch_s"] + d["step_sync_s"]) <= r["wall"]
         else:
             assert d["step_prefill_s"] == 0.0       # exactly: no _Phase ran
-        # the flight event and stats hold the same numbers
+        # the flight event and stats hold the same numbers (a join
+        # behind a step program in flight has no sync phase: the joint
+        # pull is a part of its prefill, and the field reads None)
         for key, field in (("step_admit_s", "t_admit_s"),
                            ("step_prefill_s", "t_prefill_s"),
                            ("step_dispatch_s", "t_dispatch_s"),
                            ("step_sync_s", "t_sync_s"),
                            ("step_commit_s", "t_commit_s")):
-            assert evt[field] == pytest.approx(d[key], abs=2e-6)
+            assert (evt[field] or 0.0) == pytest.approx(d[key], abs=2e-6)
 
 
 def test_sync_ends_when_the_pull_returns_and_commit_takes_the_rest():
