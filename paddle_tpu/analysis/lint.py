@@ -33,6 +33,7 @@ from paddle_tpu.analysis import baseline as baseline_mod
 from paddle_tpu.analysis import callgraph as callgraph_mod
 from paddle_tpu.analysis import rules as rules_mod
 from paddle_tpu.analysis.rules import ALL_RULES, Finding, SourceFile
+from paddle_tpu.analysis.rules import walk as rules_walk
 
 __all__ = ["ALL_RULES", "Finding", "LintResult", "repo_root",
            "package_sources", "run_lint"]
@@ -93,7 +94,7 @@ def _suppressions(sf: SourceFile) -> Tuple[Dict[int, set], set]:
     covering the whole block would let a future violation inside it
     ride an annotation written for the header."""
     spans = []
-    for node in ast.walk(sf.tree):
+    for node in rules_walk(sf.tree):
         if not isinstance(node, ast.stmt):
             continue
         body = getattr(node, "body", None)

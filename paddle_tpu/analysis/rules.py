@@ -126,6 +126,7 @@ Rule catalog (docs/ANALYSIS.md has the workflow):
 """
 
 import ast
+import collections
 import os
 import re
 from typing import Dict, Iterator, List, Optional, Set
@@ -137,6 +138,41 @@ __all__ = ["Finding", "ALL_RULES", "KERNEL_DIRS", "SNAPSHOT_OWNED",
 
 KERNEL_DIRS = ("paddle_tpu/ops", "paddle_tpu/inference",
                "paddle_tpu/serving")
+
+
+def child_nodes(node) -> tuple:
+    """``ast.iter_child_nodes`` as a tuple kept on the node: every rule
+    walks every tree, and listing a node's fields anew each time was two
+    fifths of the check's time."""
+    try:
+        return node._child_nodes
+    except AttributeError:
+        kids = node._child_nodes = tuple(ast.iter_child_nodes(node))
+        return kids
+
+
+def walk(node) -> list:
+    """``ast.walk`` (the same breadth-first order) over ``child_nodes``,
+    as a list kept on the node it starts from."""
+    try:
+        return node._walk
+    except AttributeError:
+        out, todo = [], collections.deque([node])
+        while todo:
+            n = todo.popleft()
+            todo.extend(child_nodes(n))
+            out.append(n)
+        node._walk = out
+        return out
+
+
+class _Visitor(ast.NodeVisitor):
+    """``ast.NodeVisitor`` whose ``generic_visit`` goes over
+    ``child_nodes`` (the same order)."""
+
+    def generic_visit(self, node):
+        for child in child_nodes(node):
+            self.visit(child)
 
 _NUMPY_CREATORS = {"zeros", "ones", "empty", "full", "arange",
                    "linspace", "eye", "identity"}
@@ -221,7 +257,7 @@ class SourceFile:
 
 def _numpy_aliases(tree: ast.Module) -> Set[str]:
     names = set()
-    for node in ast.walk(tree):
+    for node in walk(tree):
         if isinstance(node, ast.Import):
             for a in node.names:
                 if a.name == "numpy":
@@ -340,7 +376,7 @@ def _jax_chain(node) -> List[str]:
     return list(reversed(chain))
 
 
-class _FuncScoper(ast.NodeVisitor):
+class _FuncScoper(_Visitor):
     """Shared walk that attributes nodes to their enclosing function's
     qualname (matching analysis/callgraph.py) before dispatching to a
     per-rule ``handle(node, qualname)``."""
@@ -478,7 +514,7 @@ class _TracedBranchVisitor(_FuncScoper):
         # taints even with one-pass visiting order quirks
         traced: Set[str] = set()
         for _ in range(2):
-            for sub in ast.walk(node):
+            for sub in walk(node):
                 if isinstance(sub, ast.Assign) and _tainted(sub.value,
                                                             traced):
                     for t in sub.targets:
@@ -539,7 +575,7 @@ def check_traced_branch(sf: SourceFile, graph) -> List[Finding]:
 
 # -------------------------------------------------------- default-dtype
 
-class _DefaultDtypeVisitor(ast.NodeVisitor):
+class _DefaultDtypeVisitor(_Visitor):
     def __init__(self, sf: SourceFile, np_aliases: Set[str],
                  findings: List[Finding]):
         self.sf = sf
@@ -693,7 +729,7 @@ def known_fault_sites(faults_source: str) -> Set[str]:
     linter must not import the package (no jax import on the lint
     path)."""
     tree = ast.parse(faults_source)
-    for node in ast.walk(tree):
+    for node in walk(tree):
         if isinstance(node, ast.Assign):
             for t in node.targets:
                 if isinstance(t, ast.Name) and t.id == "KNOWN_SITES":
@@ -706,7 +742,7 @@ def check_fault_site(sf: SourceFile, sites: Set[str]) -> List[Finding]:
     if sf.path.replace(os.sep, "/").endswith("resilience/faults.py"):
         return []       # the registry itself (defaults, docstrings)
     findings: List[Finding] = []
-    for node in ast.walk(sf.tree):
+    for node in walk(sf.tree):
         if not isinstance(node, ast.Call):
             continue
         f = node.func
@@ -767,7 +803,7 @@ def _mutated_attrs(fn, receiver="self") -> Set[str]:
     """Attribute names this function mutates on ``receiver``: direct /
     subscript / augmented stores plus in-place mutator calls."""
     out: Set[str] = set()
-    for sub in ast.walk(fn):
+    for sub in walk(fn):
         if isinstance(sub, ast.Assign):
             for t in sub.targets:
                 elts = (t.elts if isinstance(t, (ast.Tuple, ast.List))
@@ -797,7 +833,7 @@ def _name_refs(fns) -> Set[str]:
     serialized dict keys (``rs["tokens"]``)."""
     names: Set[str] = set()
     for fn in fns:
-        for sub in ast.walk(fn):
+        for sub in walk(fn):
             if isinstance(sub, ast.Attribute):
                 names.add(sub.attr)
             elif isinstance(sub, ast.Constant) \
@@ -812,7 +848,7 @@ def _init_fields(init) -> Dict[str, ast.stmt]:
     fields: Dict[str, ast.stmt] = {}
     if init is None:
         return fields
-    for sub in ast.walk(init):
+    for sub in walk(init):
         targets = []
         if isinstance(sub, ast.Assign):
             targets = sub.targets
@@ -834,7 +870,7 @@ def _journal_emitters(methods: Dict[str, ast.FunctionDef]) -> List[str]:
         if name == "__init__":
             continue
         if any(_journal_append_kind(sub) is not _NOT_JOURNAL
-               for sub in ast.walk(fn) if isinstance(sub, ast.Call)):
+               for sub in walk(fn) if isinstance(sub, ast.Call)):
             out.append(name)
     return out
 
@@ -846,7 +882,7 @@ def _class_methods(cls: ast.ClassDef) -> Dict[str, ast.FunctionDef]:
 
 def check_snapshot_coverage(sf: SourceFile) -> List[Finding]:
     findings: List[Finding] = []
-    classes = {n.name: n for n in ast.walk(sf.tree)
+    classes = {n.name: n for n in walk(sf.tree)
                if isinstance(n, ast.ClassDef)}
     protocols = {}      # class name -> (save fns, load fns, methods)
     for cname, cls in classes.items():
@@ -997,7 +1033,7 @@ def known_journal_events(journal_source: str) -> Set[str]:
     """Parse serving/journal.py for the KNOWN_EVENTS literal (dict or
     tuple) without importing it — no jax on the lint path."""
     tree = ast.parse(journal_source)
-    for node in ast.walk(tree):
+    for node in walk(tree):
         if isinstance(node, ast.Assign):
             for t in node.targets:
                 if isinstance(t, ast.Name) and t.id == "KNOWN_EVENTS":
@@ -1158,7 +1194,7 @@ def _is_jax_random(node, random_aliases: Set[str]):
 def _random_from_imports(tree: ast.Module) -> Set[str]:
     """Local names bound by ``from jax.random import X [as y]``."""
     out: Set[str] = set()
-    for node in ast.walk(tree):
+    for node in walk(tree):
         if isinstance(node, ast.ImportFrom) \
                 and node.module == "jax.random":
             for a in node.names:
@@ -1188,7 +1224,7 @@ def _expr_is_folded(node, folded_vars: Set[str],
     within it references ``fold_in`` (jax.random.fold_in, vmapped or
     not), calls a known fold-returning helper, or reads a local already
     carrying a folded key."""
-    for sub in ast.walk(node):
+    for sub in walk(node):
         if isinstance(sub, ast.Attribute) and sub.attr == "fold_in":
             return True
         if isinstance(sub, ast.Name) and (sub.id == "fold_in"
@@ -1213,7 +1249,7 @@ def _fn_params(args: ast.arguments) -> Dict[str, int]:
     return params
 
 
-class _RngVisitor(ast.NodeVisitor):
+class _RngVisitor(_Visitor):
     def __init__(self, sf: SourceFile, random_aliases: Set[str],
                  folding_fns: Set[str], infos: Dict[str, "_RngFuncInfo"],
                  findings: List[Finding]):
@@ -1239,7 +1275,7 @@ class _RngVisitor(ast.NodeVisitor):
                             _fn_params(node.args))
         # two-pass local taint: locals assigned from folded expressions
         for _ in range(2):
-            for sub in ast.walk(node):
+            for sub in walk(node):
                 if isinstance(sub, ast.Assign) and _expr_is_folded(
                         sub.value, info.folded, self.folding_fns):
                     for t in sub.targets:
@@ -1360,12 +1396,12 @@ def check_rng_stream(files: Dict[str, "SourceFile"]) -> List[Finding]:
     # whose body references fold_in returns folded keys (_fold_rows)
     folding_fns: Set[str] = set()
     for sf in scope.values():
-        for node in ast.walk(sf.tree):
+        for node in walk(sf.tree):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 if any(isinstance(s, ast.Attribute)
                        and s.attr == "fold_in"
                        or isinstance(s, ast.Name) and s.id == "fold_in"
-                       for s in ast.walk(node)):
+                       for s in walk(node)):
                     folding_fns.add(node.name)
     infos: Dict[str, List[_RngFuncInfo]] = {}
     for sf in scope.values():
@@ -1427,7 +1463,7 @@ def known_mesh_axes(topology_source: str) -> Dict[str, Optional[int]]:
     axis name -> validated degree (or None) — without importing the
     package (no jax on the lint path)."""
     tree = ast.parse(topology_source)
-    for node in ast.walk(tree):
+    for node in walk(tree):
         if not isinstance(node, ast.Assign):
             continue
         for t in node.targets:
@@ -1600,7 +1636,7 @@ def _lax_collective_aliases(tree: ast.Module) -> Dict[str, str]:
     jax.lax.psum`` re-exports (parallel/collective.py's in-jit
     primitives)."""
     out: Dict[str, str] = {}
-    for node in ast.walk(tree):
+    for node in walk(tree):
         if isinstance(node, ast.ImportFrom) and node.module == "jax.lax":
             for a in node.names:
                 if a.name in _COLLECTIVE_AXIS_POS:
@@ -1628,7 +1664,7 @@ def check_collective_axis(sf: SourceFile,
 
 def _pspec_aliases(tree: ast.Module) -> Set[str]:
     out = {"PartitionSpec"}
-    for node in ast.walk(tree):
+    for node in walk(tree):
         if isinstance(node, ast.ImportFrom):
             for a in node.names:
                 if a.name == "PartitionSpec":
@@ -1696,7 +1732,7 @@ class _PspecVisitor(_FuncScoper):
         spec = None
         for kw in node.keywords:
             if kw.arg == "sharding":
-                for sub in ast.walk(kw.value):
+                for sub in walk(kw.value):
                     if isinstance(sub, ast.Call) \
                             and self._is_pspec(sub.func):
                         spec = sub
@@ -1746,7 +1782,7 @@ class _FnEntry:
     unit of analysis."""
 
     __slots__ = ("sf", "module", "qualname", "node", "parent", "locals",
-                 "params", "vararg", "nparams", "rmw", "taint")
+                 "params", "vararg", "nparams", "rmw", "taint", "calls")
 
     def __init__(self, sf, module, qualname, node, parent):
         self.sf = sf
@@ -1770,6 +1806,9 @@ class _FnEntry:
         #: params/assigns, never on other entries' facts, so it is
         #: invariant across fixpoint sweeps
         self.taint: Optional[Dict[str, Set[tuple]]] = None
+        #: the body's own calls (``_walk_shallow``), listed once for
+        #: every fixpoint sweep
+        self.calls: Optional[List[ast.Call]] = None
 
     def rmw_argnums(self) -> Set[int]:
         return {p for p, _ in self.rmw}
@@ -1787,13 +1826,13 @@ def _walk_shallow(node):
     """Walk a function body without descending into nested function /
     class definitions (their params shadow; they are entries of their
     own)."""
-    stack = list(ast.iter_child_nodes(node))
+    stack = list(child_nodes(node))
     while stack:
         n = stack.pop()
         yield n
         if not isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef,
                               ast.Lambda, ast.ClassDef)):
-            stack.extend(ast.iter_child_nodes(n))
+            stack.extend(child_nodes(n))
 
 
 class _DonationIndex:
@@ -1811,7 +1850,7 @@ class _DonationIndex:
             self._collect(sf, module, sf.tree, None, [])
 
     def _collect(self, sf, module, node, parent, qual):
-        for child in ast.iter_child_nodes(node):
+        for child in child_nodes(node):
             if isinstance(child, (ast.FunctionDef,
                                   ast.AsyncFunctionDef)):
                 q = ".".join(qual + [child.name])
@@ -1960,10 +1999,11 @@ def _rmw_pass(index: _DonationIndex) -> bool:
         if entry.taint is None:
             entry.taint = _fn_taint(entry)
         taint = entry.taint
+        if entry.calls is None:
+            entry.calls = [sub for sub in _walk_shallow(entry.node)
+                           if isinstance(sub, ast.Call)]
         found: Set[tuple] = set()
-        for sub in _walk_shallow(entry.node):
-            if not isinstance(sub, ast.Call):
-                continue
+        for sub in entry.calls:
             f = sub.func
             # x.at[...].set(...) — receiver buffer is RMW'd
             if isinstance(f, ast.Attribute) and f.attr in _AT_MUTATORS \

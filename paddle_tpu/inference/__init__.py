@@ -212,6 +212,10 @@ def generate(model, input_ids, max_new_tokens=32, temperature=0.0, top_k=0,
             and hasattr(model, "fused_decode_plan") else None)
     if plan is not None and b > plan.get("max_batch", b):
         plan = None     # e.g. MoE no-drop bound b ≤ per-expert capacity
+    if plan is not None and "cache_lanes" in plan:
+        # a plan for the paged engine alone (its own step body over its
+        # own pool rows): a contiguous cache rides the layered path
+        plan = None
     if plan is not None and not kv_int8 \
             and jnp.dtype(cache_dtype).itemsize != 2:
         # the fused kernel's cache layouts are 2-byte (bf16) or int8; an

@@ -23,3 +23,4 @@ from paddle_tpu.models.ernie import (  # noqa: F401
     ErnieForPretraining,
 )
 from paddle_tpu.models.unet import UNetConfig, UNetModel  # noqa: F401
+from paddle_tpu.models.xing4 import Xing4Config, Xing4ForCausalLM  # noqa: F401

@@ -266,6 +266,23 @@ def topk_gating(logits, k: int, capacity: int, normalize_topk: bool = True):
     return combine, dispatch, aux
 
 
+def sigmoid_topk_routing(logits, correction_bias, k: int, *,
+                         scaling: float = 1.0, normalize_topk: bool = True):
+    """DeepSeek-V3's ``noaux_tc`` router for one expert group (no group
+    limit): scores are ``sigmoid(logits)`` in float32; the ``k`` experts
+    are chosen by ``score + correction_bias`` (the bias that balances
+    load without an auxiliary loss steers the CHOICE only); the weights
+    are the chosen experts' own scores, normalised to sum to 1 when
+    ``normalize_topk`` and multiplied by ``scaling``. No capacity: no
+    token is dropped. Returns ``(idx (T, k) int32, weights (T, k) f32)``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(scores + correction_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalize_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scaling
+
+
 class GShardGate(Layer):
     """Top-2 gate (reference: moe/gate/gshard_gate.py)."""
 

@@ -103,6 +103,7 @@ the same paged machinery.
 
 import collections
 import contextlib
+import functools
 import heapq
 import json
 import logging
@@ -720,6 +721,30 @@ class _InFlight:
         self.t_launch = t_launch
 
 
+def _kv_from_lanes(cache, pk, *, nkv: int, hd: int):
+    """The llama/gpt cache adapter, pool to cache: rows ``pk`` (L, n, R,
+    [k | v] lanes) into the first R positions of a ``{"k", "v"}``
+    cache."""
+    n, R = pk.shape[1:3]
+    dkv = nkv * hd
+    cache = list(cache)
+    for l in range(len(cache)):
+        kl = pk[l, :, :, :dkv].reshape(n, R, nkv, hd)
+        vl = pk[l, :, :, dkv:].reshape(n, R, nkv, hd)
+        cache[l] = {
+            "k": cache[l]["k"].at[:, :R].set(kl.astype(cache[l]["k"].dtype)),
+            "v": cache[l]["v"].at[:, :R].set(vl.astype(cache[l]["v"].dtype))}
+    return cache
+
+
+def _kv_to_lanes(cache):
+    """Cache to pool: (L, n, cache_len, [k | v] lanes)."""
+    n, cache_len = cache[0]["k"].shape[:2]
+    return jnp.stack([jnp.concatenate(
+        [c["k"].reshape(n, cache_len, -1),
+         c["v"].reshape(n, cache_len, -1)], axis=-1) for c in cache])
+
+
 def _swap_bucket(n: int) -> int:
     """Power-of-two bucket for whole-block gather/scatter widths —
     bounds the swap-path compile set to O(log max_blocks_per_slot)
@@ -862,9 +887,33 @@ class ServingEngine:
                 "(llama/gpt); this model/config cannot ride the paged "
                 "kernel")
         self.arch = meta.get("arch", "llama")
-        if self.arch not in ("llama", "gpt"):
+        # a plan that names its pool rows (``cache_lanes``) brings its
+        # own step body and cache adapter (docs/SERVING.md
+        # §Architectures the engine takes); any other plan rides the
+        # fused llama/gpt kernel
+        self._own_step = "cache_lanes" in meta
+        if not self._own_step and self.arch not in ("llama", "gpt"):
             raise ValueError(
                 f"paged serving supports arch llama/gpt, got {self.arch!r}")
+        if self._own_step:
+            refused = dict(
+                cache_dtype=jnp.dtype(cache_dtype) == jnp.int8,
+                speculate=speculate is not None,
+                chunk_tokens=chunk_tokens is not None,
+                mesh=mesh is not None and getattr(mesh, "size", 1) > 1,
+                layout=layout is not None,
+                offload=bool(offload))
+            for option, given in refused.items():
+                if given:
+                    raise ValueError(
+                        f"ServingEngine option {option!r} is not carried "
+                        f"to arch {self.arch!r} yet (docs/SERVING.md "
+                        f"§Architectures the engine takes)")
+        # int32 counters the plan's step returns behind the hidden
+        # state; they ride the tick's one pull behind the tokens
+        self._step_counters = tuple(meta.get("step_counters", ()))
+        # tpu-lint: volatile(a property of the backend)
+        self._host_aliased = jax.default_backend() == "cpu"
         blocks_plan = meta.get("blocks")
         if blocks_plan is not None and blocks_plan.get("q_split", 1) != 1:
             raise ValueError(
@@ -887,8 +936,10 @@ class ServingEngine:
         self.max_blocks_per_slot = max_seq_len // block_tokens
 
         L = self._num_layers = self._count_layers()
-        nkv, hd = meta["num_kv_heads"], meta["head_dim"]
-        self._dkv = nkv * hd
+        # one pool row: a plan's own ``cache_lanes``, else [k | v]
+        nkv, hd = meta.get("num_kv_heads"), meta.get("head_dim")
+        self._cache_lanes = int(meta["cache_lanes"] if self._own_step
+                                else 2 * nkv * hd)
 
         # ---- tensor-parallel replica (docs/SERVING.md §Tensor-parallel
         # replicas): mesh + ServingLayout shard THIS replica over
@@ -922,7 +973,7 @@ class ServingEngine:
             # dispatch — the 0-H2D steady-tick pin holds under mp too)
             self._state = layout.place_replicated(self._state)
         bpb = self.block_bytes = (
-            L * block_tokens * 2 * self._dkv
+            L * block_tokens * self._cache_lanes
             * (1 if self.kv_int8 else 2))
         if num_blocks is None:
             if pool_bytes is not None:
@@ -935,7 +986,8 @@ class ServingEngine:
         # tpu-lint: volatile(device KV never survives a crash by design
         # — restore re-prefills prompts and replays generated tokens)
         self.kv_pool = jnp.zeros(
-            (L, num_blocks, block_tokens, 2 * self._dkv), self.cache_dtype)
+            (L, num_blocks, block_tokens, self._cache_lanes),
+            self.cache_dtype)
         if layout is not None:
             # head-dim sharded: each shard's block-table walk reads only
             # its own heads' [k_s|v_s] lanes (zeros are permutation-
@@ -1036,8 +1088,11 @@ class ServingEngine:
         self._closed = False
 
         from paddle_tpu.ops import rope as rope_ops
-        self._cos_tab, self._sin_tab = rope_ops.rope_cos_sin(
-            max_seq_len, hd, base=meta["rope_base"])
+        # tpu-lint: volatile(tables of constants)
+        self._cos_tab = self._sin_tab = None    # an own step has its own
+        if not self._own_step:
+            self._cos_tab, self._sin_tab = rope_ops.rope_cos_sin(
+                max_seq_len, hd, base=meta["rope_base"])
         if layout is not None:
             # closed-over rope tables must be mesh-committed too, or
             # every program would mix mesh and single-device operands
@@ -1054,13 +1109,14 @@ class ServingEngine:
         # tpu-lint: volatile(rebuilt by resume admission)
         self._positions = np.zeros(ms, np.int32)
         # tpu-lint: volatile(rebuilt by resume admission)
-        self._toks = np.zeros(ms, np.int32)
+        self._toks = np.zeros(ms + len(self._step_counters), np.int32)
         # tpu-lint: volatile(rebuilt by resume admission)
         self._seeds = np.zeros(ms, np.uint32)
         # tpu-lint: volatile(rebuilt by resume admission)
         self._counts = np.zeros(ms, np.int32)
         # tpu-lint: volatile(int8 calibration reproduces scales exactly)
-        self._kv_scales = np.ones((L, ms, 2 * self._dkv), np.float32)
+        self._kv_scales = (None if self._own_step else np.ones(
+            (L, ms, self._cache_lanes), np.float32))
 
         # ---- speculative decoding (docs/SERVING.md §Speculative) ----
         self.speculate = speculate
@@ -1236,7 +1292,9 @@ class ServingEngine:
         # no scan to amortize the in-trace rebuild over (generate()'s
         # decode program runs build_fused_params once per max_new_tokens
         # steps; a serving step would run it once per token)
-        self._stacked = jax.jit(
+        # (an own step reads the state's own leaves: the weights are
+        # held once)
+        self._stacked = None if self._own_step else jax.jit(
             lambda st: model.fused_decode_plan(st)["params"])(self._state)
         # tpu-lint: volatile(per-leaf PartitionSpecs, derived from layout)
         self._stacked_specs = None
@@ -1307,6 +1365,10 @@ class ServingEngine:
         self._tick_landed = False       # this tick has committed a step
         # tpu-lint: volatile(per-tick flight marker)
         self._tick_lookahead = False
+        # tpu-lint: volatile(per-tick flight marker)
+        self._tick_counters: Dict[str, int] = {}    # plan's step counters
+        # tpu-lint: volatile(per-program span attributes)
+        self._landed_counters: Dict[str, int] = {}
         # the engine's clock: perf_counter less the time spent outside
         # step(), so that a program's seconds (from its launch, or from
         # the pull before it, to its own pull) leave the caller out
@@ -1423,6 +1485,14 @@ class ServingEngine:
         explicit NamedSharding (replicated unless ``spec`` says
         otherwise) so dispatch inputs never mix mesh and single-device
         placements."""
+        if self._host_aliased:
+            # the CPU backend may alias a host array instead of copying
+            # it, and the mirrors are written again (admission, commit)
+            # while a program launched with them can still be waiting
+            # to run: it must see what it was launched with. A device
+            # backend copies on the way up.
+            # tpu-lint: allow(host-sync): inputs are host-canonical mirrors
+            x = np.array(x)
         if self.layout is None:
             return jnp.asarray(x)
         from jax.sharding import PartitionSpec
@@ -1434,8 +1504,10 @@ class ServingEngine:
         """The int8 per-slot scale device twin: canonical on the host,
         shard-major permuted + head-dim sharded on the mesh (lockstep
         with the pool's last dim)."""
+        if self._kv_scales is None:     # a plan's own step keeps none
+            return None
         if self.layout is None:
-            return jnp.asarray(self._kv_scales)
+            return self._up(self._kv_scales)
         return self.layout.shard_kv_scales(
             self._kv_scales, num_kv_heads=self.meta["num_kv_heads"],
             head_dim=self.meta["head_dim"])
@@ -1519,8 +1591,11 @@ class ServingEngine:
         that dispatched a step program before the tokens of the one
         before it were pulled, ``lookahead_discarded_tokens`` the tokens
         such a program computed for rows that had left by its pull.
-        Per-step distributions live in the ``serving.step_*_s``
-        registry histograms."""
+        A plan's ``step_counters`` (``mla_moe``: ``moe_layer_steps``,
+        ``moe_experts_touched``, ``moe_rows_max``, ``moe_rows``) are
+        summed over the step programs whose tokens were pulled, as the
+        program counted them. Per-step distributions live in the
+        ``serving.step_*_s`` registry histograms."""
         return dict(steps=0, decode_tokens=0, idle_slot_steps=0,
                     prefill_tokens=0, prefill_tokens_reused=0,
                     prefill_chunks=0, replay_tokens=0,
@@ -1537,7 +1612,8 @@ class ServingEngine:
                     step_dispatch_s=0.0, step_sync_s=0.0,
                     step_commit_s=0.0, step_tail_s=0.0,
                     step_upload_s=0.0, upload_ticks=0,
-                    lookahead_ticks=0, lookahead_discarded_tokens=0)
+                    lookahead_ticks=0, lookahead_discarded_tokens=0,
+                    **{name: 0 for name in self._step_counters})
 
     def reset_stats(self):
         """Zero the cumulative throughput counters and step-segment
@@ -1915,7 +1991,7 @@ class ServingEngine:
             dbids = np.full(m, SCRATCH_BLOCK, np.int32)
             dbids[:len(todo)] = bids
             buf = np.zeros((self._num_layers, m, self.block_tokens,
-                            2 * self._dkv), jnp.dtype(self.cache_dtype))
+                            self._cache_lanes), jnp.dtype(self.cache_dtype))
             for c, (_, _, kv) in enumerate(todo):
                 buf[:, c] = kv
             dev = (self.layout.place(buf, self.layout.pool_spec())
@@ -1958,8 +2034,18 @@ class ServingEngine:
         fn = self._jit_cache.get(key)
         if fn is not None:
             return fn, True
-        nkv, hd = self.meta["num_kv_heads"], self.meta["head_dim"]
-        dkv = self._dkv
+        # the cache adapter and the head's rows: a plan's own, else the
+        # [k | v] rows of a llama/gpt cache and every position's logits
+        own = self._own_step
+        lanes_w = self._cache_lanes
+        dkv = lanes_w // 2
+        if own:
+            to_lanes = self.meta["to_lanes"]
+            from_lanes = self.meta["from_lanes"]
+        else:
+            nkv, hd = self.meta["num_kv_heads"], self.meta["head_dim"]
+            to_lanes, from_lanes = _kv_to_lanes, functools.partial(
+                _kv_from_lanes, nkv=nkv, hd=hd)
         BT = self.block_tokens
         cache_len = R + s_pad
         hb = R // BT                 # shared prefix blocks per row
@@ -1988,22 +2074,15 @@ class ServingEngine:
                         len(cache), n, R, pool.shape[-1])
                     if mp_axis is not None:
                         pk = mp_gather_kv_lastdim(pk, mp_axis)
-                for l in range(len(cache)):
-                    kl = pk[l, :, :, :dkv].reshape(n, R, nkv, hd)
-                    vl = pk[l, :, :, dkv:].reshape(n, R, nkv, hd)
-                    cache[l] = {
-                        "k": cache[l]["k"].at[:, :R].set(
-                            kl.astype(cache[l]["k"].dtype)),
-                        "v": cache[l]["v"].at[:, :R].set(
-                            vl.astype(cache[l]["v"].dtype))}
+                cache = from_lanes(cache, pk)
             with jax.named_scope("decode.prefill"):
-                out, cache = functional_call(model, state, ids,
-                                             cache=cache, start_pos=R)
-            kv_flat = jnp.stack([jnp.concatenate(
-                [c["k"].reshape(n, cache_len, dkv),
-                 c["v"].reshape(n, cache_len, dkv)], axis=-1)
-                for c in cache])                 # (L, n, cache_len, 2dkv)
-            logits = jnp.take_along_axis(
+                # an own plan's model computes the head at each row's
+                # last position only
+                out, cache = functional_call(
+                    model, state, ids, cache=cache, start_pos=R,
+                    **({"positions": last_idx} if own else {}))
+            kv_flat = to_lanes(cache)            # (L, n, cache_len, lanes)
+            logits = out if own else jnp.take_along_axis(
                 out, last_idx[:, None, None], axis=1)[:, 0]   # (n, vocab)
             keys = _row_keys(seeds)
             with jax.named_scope("decode.sample"):
@@ -2031,7 +2110,7 @@ class ServingEngine:
                 pool = pool.at[:, new_bids].set(blkq)
                 return tok, pool, lanes, kv_flat
             blk = kv_flat[:, :, R:cache_len].reshape(
-                -1, n, nb_new, BT, 2 * dkv)
+                -1, n, nb_new, BT, lanes_w)
             if mp_axis is not None:
                 blk = mp_local_kv_lastdim(blk, mp_axis)
             pool = pool.at[:, new_bids].set(blk.astype(pool.dtype))
@@ -2190,7 +2269,7 @@ class ServingEngine:
                     g.dev_prefix = self._up(np.stack(
                         [np.concatenate([e.kv_host for e in hs], axis=1)
                          for hs in hit_rows], axis=1))   # (L, n, R, 2dkv)
-                    assert g.dev_prefix.shape == (L, n, R, 2 * self._dkv)
+                    assert g.dev_prefix.shape == (L, n, R, self._cache_lanes)
             for _, s in rows:
                 s.hits = None       # consumed; drop the cache refs
             self._prefill_fifo.append(g)
@@ -2267,7 +2346,7 @@ class ServingEngine:
         from paddle_tpu.nn.layer import functional_call
 
         nkv, hd = self.meta["num_kv_heads"], self.meta["head_dim"]
-        dkv = self._dkv
+        dkv = nkv * hd
         BT = self.block_tokens
         cache_len = start + CT
         model = self.model
@@ -2755,7 +2834,7 @@ class ServingEngine:
         t0 = time.perf_counter()
         m = _swap_bucket(pk.n)
         buf = np.zeros((self._num_layers, m, self.block_tokens,
-                        2 * self._dkv), jnp.dtype(self.cache_dtype))
+                        self._cache_lanes), jnp.dtype(self.cache_dtype))
         for c, p in enumerate(self.host_store.get(pk.host_ids)):
             buf[:, c] = p
         dev = (self.layout.place(buf, self.layout.pool_spec())
@@ -3195,7 +3274,7 @@ class ServingEngine:
                 prefix = (self._up(np.stack(
                     [np.concatenate([e.kv_host for e in hits], axis=1)
                      for _, _, hits, _, _ in grp], axis=1)) if hb
-                    else self._up(np.zeros((L, n, 0, 2 * self._dkv),
+                    else self._up(np.zeros((L, n, 0, self._cache_lanes),
                                            np.float32).astype(jnp.bfloat16)))
                 tok, self.kv_pool, lanes, kv_flat = fn(
                     self.kv_pool, prefix, self._up(ids),
@@ -3269,7 +3348,7 @@ class ServingEngine:
             tables = np.full((ms, self.max_blocks_per_slot),
                              SCRATCH_BLOCK, np.int32)
             positions = np.zeros(ms, np.int32)
-            toks = np.zeros(ms, np.int32)
+            toks = np.zeros(len(self._toks), np.int32)
             seeds = np.zeros(ms, np.uint32)
             counts = np.zeros(ms, np.int32)
             seeds[slot_idx] = np.uint32(s.req.seed)
@@ -3398,6 +3477,28 @@ class ServingEngine:
         model, cos_tab, sin_tab = self.model, self._cos_tab, self._sin_tab
         temperature, top_k, top_p = self.temperature, self.top_k, self.top_p
         pos_cap = self.max_seq_len - 1
+        if self._own_step:
+            ms = self.max_slots
+
+            def own_body(state, _stacked, pool, tables, positions, toks,
+                         seeds, counts, _kv_scales):
+                # the plan's step over the state's own leaves (no
+                # stacked copy, no scales: both arrive as None); `toks`
+                # carries the last program's counters behind its tokens
+                plan_t = model.fused_decode_plan(state)
+                x = plan_t["embed"](toks[:ms], positions)
+                x, pool, tallies = plan_t["step"](x, pool, tables,
+                                                  positions)
+                with jax.named_scope("decode.sample"):
+                    keys = _row_keys(seeds)
+                    ki = jax.vmap(jax.random.fold_in)(keys, counts)
+                    nxt = _sample_logits(plan_t["head"](x), ki, temperature,
+                                         top_k, top_p)
+                pos2 = jnp.minimum(positions + 1, pos_cap)
+                return (jnp.concatenate([nxt, tallies.astype(nxt.dtype)]),
+                        pool, pos2, counts + 1)
+
+            return own_body
         # under mp the body runs INSIDE shard_map: each shard walks its
         # own heads over its own pool lanes (local counts), and the
         # fused op gathers at the o-proj boundary; mp=1 passes the full
@@ -4064,6 +4165,7 @@ class ServingEngine:
         self._tick_spec = None
         self._tick_landed = False
         self._tick_lookahead = False
+        self._tick_counters = {}
         # _tick_shed keeps accumulating across submit() calls between
         # ticks; _record_flight drains it into this tick's event
 
@@ -4386,6 +4488,7 @@ class ServingEngine:
             active = [i for i, s in rec.rows if self._slots[i] is s]
             gone = len(rec.rows) - len(active)
             commit = self._commit_spec if rec.spec else self._commit_plain
+            self._landed_counters = {}
             commit(active, head_np[:len(rec.head)])
             if rec.grp is not None:
                 self._commit_chunk(rec.grp, rec.g_start, rec.g_kind,
@@ -4400,7 +4503,10 @@ class ServingEngine:
                 gone += len(nxt.rows)
                 self._flight_q.clear()
             self.stats["lookahead_discarded_tokens"] += gone
-            ph.set(retired=len(self._tick_retired) - n0)
+            # the landed program's own counters ride its commit span, so
+            # that a trace can be read without the engine's stats
+            ph.set(retired=len(self._tick_retired) - n0,
+                   **self._landed_counters)
             pulled = head_np[len(rec.head):]
             # what was pulled and the record's device arrays are
             # released inside the phase, not between two of them
@@ -4469,6 +4575,13 @@ class ServingEngine:
         token into its slot and mirrors, retiring what finished."""
         nxt = head_np[0]
         if active:
+            for name, v in zip(self._step_counters, nxt[self.max_slots:]):
+                v = int(v)
+                self.stats[name] += v
+                self._metrics.counter(f"serving.{name}").inc(v)
+                self._landed_counters[name] = v
+                self._tick_counters[name] = (
+                    self._tick_counters.get(name, 0) + v)
             self._decode_since_chunk += 1
             self.stats["steps"] += 1
             self.stats["decode_tokens"] += len(active)
@@ -4729,7 +4842,8 @@ class ServingEngine:
                "t_dispatch_s": _round6(t("step_dispatch_s")),
                "t_sync_s": _round6(t("step_sync_s")),
                "t_commit_s": _round6(t("step_commit_s")),
-               "lookahead": self._tick_lookahead}
+               "lookahead": self._tick_lookahead,
+               **self._tick_counters}
         if err is not None:
             evt["err"] = err
         self.flight.record(evt)
@@ -4961,7 +5075,7 @@ class ServingEngine:
                   "offload_prefetch": self.offload_prefetch,
                   "sanitize": self._sanitize_mode}
         fingerprint = {"arch": self.arch, "num_layers": self._num_layers,
-                       "dkv": self._dkv}
+                       "dkv": self._cache_lanes // 2}
         return {"schema": ENGINE_SNAPSHOT_SCHEMA, "ts": time.time(),
                 "step_seq": self._step_seq, "config": config,
                 "model": fingerprint, "slots": slots, "queue": queue,
@@ -5089,13 +5203,13 @@ class ServingEngine:
         fp = snap.get("model", {})
         if fp and (fp.get("arch") != eng.arch
                    or fp.get("num_layers") != eng._num_layers
-                   or fp.get("dkv") != eng._dkv):
+                   or fp.get("dkv") != eng._cache_lanes // 2):
             eng.close()     # the mismatched engine must not leak its pool
             raise RestoreError(
                 "model_fingerprint",
                 f"model mismatch: snapshot was taken on "
                 f"{fp}, restoring onto arch={eng.arch} "
-                f"L={eng._num_layers} dkv={eng._dkv}")
+                f"L={eng._num_layers} dkv={eng._cache_lanes // 2}")
         eng._seeds_issued = int(snap.get("seeds_issued", 0))
         eng._submit_seq = int(snap.get("submit_seq", 0))
         now = time.perf_counter()

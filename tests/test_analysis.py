@@ -1350,7 +1350,7 @@ def test_donation_report_serving_pool_step_and_chunk_programs():
         tick_fn = eng._jit_cache.get(
             ("tick", "mid", True, 32, 1, 96, 32, 0, 0))
         assert tick_fn is not None, list(eng._jit_cache)
-        L, dkv2 = eng._num_layers, 2 * eng._dkv
+        L, dkv2 = eng._num_layers, eng._cache_lanes
         carry = jnp.zeros((L, 1, 96, dkv2), jnp.bfloat16)
         ids = jnp.zeros((1, 96), jnp.int32)
         bids = jnp.zeros((1, 3), jnp.int32)

@@ -1147,3 +1147,60 @@ def test_serving_speculative_draft_on_tpu():
     assert stats["spec_accepted"] > 0
     for p, got, ref in zip(prompts, spec, iso):
         _assert_same_up_to_near_tie(m, p, got, ref)
+
+
+# ---------------------------------------------------------------------------
+# Xing4's decode kernels at the published widths (PR 27)
+# ---------------------------------------------------------------------------
+
+def test_mla_paged_decode_parity_at_published_widths():
+    """32 heads over 640-lane pool rows (512 latent + 64 rope + 64 zero),
+    blocks of 256: rows of uneven length, on block edges, and idle."""
+    from paddle_tpu.ops import mla_decode as md
+    L, BT, P, dc, H, MB = 2, 256, 640, 512, 32, 4
+    pos = np.asarray([1000, 0, 255, 256, 257, 0, 700, 1023], np.int32)
+    b = len(pos)
+    tables = np.zeros((b, MB), np.int32)
+    nxt = 1
+    for r in (0, 2, 3, 4, 6, 7):        # rows 1 and 5 idle against scratch
+        n = pos[r] // BT + 1
+        tables[r, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    pool = rand(0, L, nxt, BT, P)
+    q = rand(1, b, H, P, scale=0.2)
+    new = rand(2, b, P)
+    kw = dict(layer=1, d_c=dc, scale=0.1447)
+    want_o, want_pool = jax.jit(
+        lambda *a: md.mla_paged_decode_reference(*a, **kw))(
+            q, new, pool, jnp.asarray(tables), jnp.asarray(pos))
+    got_o, got_pool = jax.jit(
+        lambda *a: md._mla_paged_decode_pallas(*a, **kw))(
+            q, new, pool, jnp.asarray(tables), jnp.asarray(pos))
+    live = [0, 2, 3, 4, 6, 7]
+    assert_close(np.asarray(got_o)[live], np.asarray(want_o)[live],
+                 rtol=2e-2, atol=5e-3)
+    # the appended rows, bit for bit; scratch block 0 takes the idle rows
+    got_pool, want_pool = np.asarray(got_pool), np.asarray(want_pool)
+    assert (got_pool[:, 1:] == want_pool[:, 1:]).all()
+
+
+def test_moe_grouped_ffn_parity_at_published_widths():
+    """64 experts of 3584 x 1024, 64 rows top-4, a third of the rows
+    idle and a block of experts that nobody chose."""
+    from paddle_tpu.ops import moe_grouped as mg
+    b, E, C, F, k = 64, 64, 3584, 1024, 4
+    x = rand(0, b, C, scale=1.0)
+    wg, wu = rand(1, E, C, F, scale=0.02), rand(2, E, C, F, scale=0.02)
+    wd = rand(3, E, F, C, scale=0.02)
+    rng = np.random.default_rng(0)
+    idx = np.stack([rng.choice(40, k, replace=False) + 8
+                    for _ in range(b)]).astype(np.int32)  # experts 8..47
+    w = jnp.asarray(rng.uniform(0.2, 0.8, (b, k)), jnp.float32)
+    active = jnp.asarray(np.arange(b) % 3 != 0)
+    dense = mg.dense_weights(jnp.asarray(idx), w, active, E)
+    want = jax.jit(mg.moe_grouped_ffn_reference)(x, dense, wg, wu, wd)
+    got = jax.jit(mg._moe_grouped_ffn_pallas)(x, dense, wg, wu, wd)
+    assert_close(got, want, rtol=2e-2, atol=2e-2 * float(
+        np.abs(np.asarray(want, np.float32)).max()))
+    assert np.abs(np.asarray(got, np.float32)[::3]).max() == 0.0
+    assert np.abs(np.asarray(want, np.float32)).max() > 0.1
