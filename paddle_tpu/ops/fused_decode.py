@@ -30,6 +30,12 @@ rope / GELU) — the architecture the reference's fused_multi_transformer
 itself serves. `fused_decode_reference` is the jnp twin used for numerics
 tests and as the non-TPU fallback; `examples/decode_bench.py` measures
 the win.
+
+The serving engine's PAGED variants (`fused_paged_decode_step`, further
+down) keep the cache as a pool of blocks behind a block table. There a
+chunk of the walk is one block, and the decode kernel walks a flat list
+of (row, block) pairs, each row's own blocks (`paged_walk`), where the
+contiguous kernel above walks one length for all rows.
 """
 
 import functools
@@ -1612,9 +1618,63 @@ def fused_decode_step(x, params, kv_cache, pos, cos, sin, *,
 #
 # One block == one KV chunk of the kernel's online-softmax walk, so the
 # chunk copy indexes through the block table (the same SMEM-addressed DMA
-# technique the MoE kernel uses for routed expert weights) and slots of
-# wildly different lengths share one dispatch: per-row chunk counts only
-# mask (an all-masked online-softmax merge is an exact no-op).
+# technique the MoE kernel uses for routed expert weights). Slots of
+# wildly different lengths share one dispatch because the decode kernel
+# walks a FLAT list of (row, chunk) pairs (`paged_walk`): each row's own
+# blocks and nothing else, so a step reads the cache that is live. (The
+# verify kernel still walks every row to the longest row's length, where
+# an all-masked online-softmax merge is an exact no-op.)
+
+
+# Block buffers of the paged decode kernel's walk: a pair's DMA is started
+# `_WALK_RING - 1` pairs ahead of its merge. Chosen on the chip (PERF.md
+# §6, PR 28).
+_WALK_RING = 4
+
+
+def paged_walk_blocks(positions, block_tokens: int):
+    """How many blocks of each row the paged decode kernel's walk covers.
+
+    Row r holds ``positions[r]`` cached tokens; the walk covers its whole
+    groups of 8 (the open group is read, merged with the new token and
+    written back apart from the walk): ``nc_r = ceil((positions[r] // 8
+    * 8) / block_tokens)`` blocks, none for a row at position 0 (an idle
+    slot). Returns ``(nc, total, dense)``: the counts, their sum (the
+    length of `paged_walk`'s list) and ``rows x max(nc)``, which is what
+    a walk of every row to the longest row's length would cover; a full
+    batch of equal rows gives ``total == dense``.
+
+    ``positions`` as a numpy array gives numpy results (the serving
+    engine's counters, from its host mirror); anything else is traced
+    with ``jnp`` (the kernel's wrapper, inside the step program).
+    """
+    xp = np if isinstance(positions, np.ndarray) else jnp
+    pos = xp.asarray(positions, xp.int32).reshape(-1)
+    nc = (pos // 8 * 8 + block_tokens - 1) // block_tokens
+    return nc, nc.sum(), pos.shape[0] * nc.max()
+
+
+def paged_walk(positions, block_tokens: int, max_blocks: int):
+    """The paged decode kernel's walk over cached KV, as a flat work list:
+    the pairs (r, c) for c < nc_r (`paged_walk_blocks`), row-major.
+
+    Returns ``(work_row, work_chunk, total)``: pair t is ``(work_row[t],
+    work_chunk[t])`` for ``t < total``, in int32 arrays of the fixed
+    length ``rows x max_blocks`` (entries from ``total`` on are in range
+    and never walked). A full batch of equal rows gives the dense walk's
+    pairs. numpy in, numpy out, as in `paged_walk_blocks`.
+    """
+    nc, total, _ = paged_walk_blocks(positions, block_tokens)
+    xp = np if isinstance(nc, np.ndarray) else jnp
+    b = nc.shape[0]
+    end = xp.cumsum(nc)
+    # pair t belongs to the first row whose running sum passes t; one
+    # masked reduction over (pairs, rows) each, no search loop, no gather
+    t = xp.arange(b * max_blocks)
+    before = t[:, None] >= end[None, :]
+    row = xp.minimum(before.sum(1), b - 1)
+    chunk = xp.where(before[:, -1], 0, t - (before * nc[None, :]).sum(1))
+    return row.astype(xp.int32), chunk.astype(xp.int32), total
 
 
 def paged_pool_shape(num_layers: int, num_blocks: int, block_tokens: int,
@@ -1876,13 +1936,19 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
     * the KV cache is the (L, NB, BT, 2*nkv*hd) pool; every chunk copy /
       RMW append resolves its physical block through the SMEM block table
       (`bt_ref[r, c]` — the data-dependent DMA addressing the MoE kernel
-      pioneered for routed expert weights), so the copies are per-ROW
-      (b DMAs per chunk instead of 1) — serving batches are small and
-      decode is bandwidth-bound, so the extra descriptors are noise;
+      pioneered for routed expert weights);
     * `positions` is per-row: rope angles, the append RMW offset and the
-      online-softmax limits all broadcast (b, 1, 1) instead of scalar.
-      Rows past their own prefix mask every lane of a merge — an exact
-      no-op — so one dispatch serves slots of different lengths;
+      online-softmax limits are per row instead of scalar;
+    * the walk over cached KV is RAGGED: one loop over the flat list of
+      (row, chunk) pairs of `paged_walk`, each row's own blocks and no
+      others. A pair's block arrives through a ring of `_WALK_RING`
+      block buffers whose prefetch runs ahead ACROSS row boundaries (and
+      from one layer's FFN phase into the next layer's walk), and is
+      merged into that row's online-softmax state, which lives in VMEM
+      scratch indexed by row. A block costs a DMA and two (nh, BT)
+      matmuls, so a step's attention time follows the blocks that are
+      live; an idle slot (position 0) costs no walk. The scratch holds
+      the ring however many slots there are;
     * int8 pool scales are per-SLOT ((L, b, 2*nkv*hd)).
     """
     from jax.experimental import pallas as pl
@@ -1925,20 +1991,23 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
             ffn, h, fixed_bytes=(dqkv + dq) * h * wbytes, wbytes=wbytes)
     dtype = x.dtype
     scale = 1.0 / math.sqrt(hd)
+    ring = _WALK_RING
 
     def kernel(*refs):
         if gpt:
-            (pos_ref, bt_ref, posv_ref, x_in_ref, ln1_ref, wqkv_ref,
-             wo_ref, ln2_ref, wg_ref, wd_ref) = refs[:10]
+            (pos_ref, bt_ref, wrow_ref, wchunk_ref, total_ref, posv_ref,
+             x_in_ref, ln1_ref, wqkv_ref, wo_ref, ln2_ref, wg_ref,
+             wd_ref) = refs[:13]
             wu_ref = None
-            i = 10
+            i = 13
             (ln1b_ref, ln2b_ref, bqkv_ref, bo_ref, bg_ref,
              bd_ref) = refs[i:i + 6]
             i += 6
         else:
-            (pos_ref, bt_ref, posv_ref, x_in_ref, ln1_ref, wqkv_ref,
-             wo_ref, ln2_ref, wg_ref, wu_ref, wd_ref) = refs[:11]
-            i = 11
+            (pos_ref, bt_ref, wrow_ref, wchunk_ref, total_ref, posv_ref,
+             x_in_ref, ln1_ref, wqkv_ref, wo_ref, ln2_ref, wg_ref, wu_ref,
+             wd_ref) = refs[:14]
+            i = 14
         if int8:
             sqkv_ref, so_ref, sg_ref, su_ref, sd_ref = refs[i:i + 5]
             i += 5
@@ -1947,7 +2016,7 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
             i += 1
         kv_in = refs[i]                # aliased with kv_ref
         x_out_ref, kv_ref = refs[i + 1], refs[i + 2]
-        (x_s, xn_s, acc_s, q_s, kv32_s, kvblk_s, kvch_s,
+        (x_s, xn_s, acc_s, q_s, kv32_s, kvblk_s, kvch_s, m_s, l_s, o_s,
          wsem, rsem) = refs[i + 3:]
         del kv_in
 
@@ -1978,17 +2047,26 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
                 kv_ref.at[l, bid, pl.ds((p % BT) // 8 * 8, 8)],
                 wsem.at[r])
 
-        def chunk_copy(l, c, slot, r):
-            return pltpu.make_async_copy(
-                kv_ref.at[l, bt_ref[r, c]], kvch_s.at[slot, r],
-                rsem.at[slot, r])
+        # ---- the ragged walk: pair t of the flat (row, chunk) list ----
+        total = total_ref[0]
 
-        # chunk walk bound: the LONGEST row's full-8-block prefix (rows
-        # past their own prefix contribute all-masked merges — exact
-        # no-ops, the price of one shared dispatch)
-        nc = (pos_ref[0] // 8 * 8 + ck - 1) // ck
-        for r in range(1, b):
-            nc = jnp.maximum(nc, (pos_ref[r] // 8 * 8 + ck - 1) // ck)
+        def pair_copy(l, t):
+            slot = lax.rem(t, ring)
+            return pltpu.make_async_copy(
+                kv_ref.at[l, bt_ref[wrow_ref[t], wchunk_ref[t]]],
+                kvch_s.at[slot], rsem.at[slot])
+
+        def start_pair(l, t):
+            @pl.when(t < total)
+            def _():
+                pair_copy(l, t).start()
+
+        def start_layer(l):
+            """Layer l's RMW reads and the first pairs of its walk."""
+            for r in range(b):
+                rmw_read(l, r).start()
+            for t in range(ring - 1):
+                start_pair(l, t)
 
         @pl.when(j == 0)
         def attention_phase():
@@ -2002,13 +2080,7 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
                 # one-time zero of the block-diagonal q staging (layers
                 # rewrite the same in-block lanes; off-block lanes stay 0)
                 q_s[...] = jnp.zeros_like(q_s)
-                for r in range(b):
-                    rmw_read(li, r).start()
-
-                @pl.when(nc > 0)
-                def _():
-                    for r in range(b):
-                        chunk_copy(li, 0, 0, r).start()
+                start_layer(li)
 
             if gpt:
                 xn = _layernorm(x_s[...], ln1_ref[...].reshape(h),
@@ -2030,60 +2102,61 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
                 rope2 = lambda t: (t * cos_b + jnp.concatenate(
                     [-t[:, hd // 2:], t[:, :hd // 2]], axis=-1) * sin_b)
             # q staged block-diagonally over kv-group lane blocks (see
-            # _fused_decode_pallas); new k/v staged flat for the RMW merge
+            # _fused_decode_pallas), an int8 pool's per-slot k-half
+            # dequant scales folded in; new k/v staged flat for the RMW
+            # merge
             for n in range(nh):
                 g = n // rep
-                q_s[:, n, g * hd:(g + 1) * hd] = rope2(
-                    qkv[:, n * hd:(n + 1) * hd]) * scale
+                qn = rope2(qkv[:, n * hd:(n + 1) * hd]) * scale
+                if kvq:
+                    qn = qn * kvs_ref[...][:, g * hd:(g + 1) * hd]
+                q_s[:, n, g * hd:(g + 1) * hd] = qn
             for g in range(nkv):
                 kv32_s[:, g * hd:(g + 1) * hd] = rope2(
                     qkv[:, dq + g * hd:dq + (g + 1) * hd])
                 kv32_s[:, dkv + g * hd:dkv + (g + 1) * hd] = \
                     qkv[:, dq + dkv + g * hd:dq + dkv + (g + 1) * hd]
 
-            if kvq:     # per-slot k-half dequant scales fold into q rows
-                qbd = q_s[...] * kvs_ref[...][:, :dkv][:, None]
-            else:
-                qbd = q_s[...]
-
-            def merge(carry, kvblk, idx, limit):
-                """Online-softmax block update over ALL heads; `limit` is
-                per-row (b, 1, 1) — an all-masked row is an exact no-op
-                (alpha = 1, pp = 0), which is what lets one dispatch
-                serve slots of different lengths."""
+            def merge(carry, q, kvblk, live):
+                """Online-softmax update of (m, l, acc) with one block of
+                keys and values, over ALL heads: for one row (q (nh, dkv),
+                kvblk (w, 2*dkv)) in the walk, for every row at once
+                (leading b) at the new token's group."""
                 m, l, acc = carry
-                kf = kvblk[:, :, :dkv].astype(jnp.float32)
-                vf = kvblk[:, :, dkv:].astype(jnp.float32)
+                kf = kvblk[..., :dkv].astype(jnp.float32)
+                vf = kvblk[..., dkv:].astype(jnp.float32)
+                nb = q.ndim - 2                     # batch dims: 0 or 1
+                bd = tuple(range(nb))
                 sc = lax.dot_general(
-                    qbd, kf, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)      # (b, nh, w)
-                sc = jnp.where(idx < limit, sc, NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+                    q, kf, (((nb + 1,), (nb + 1,)), (bd, bd)),
+                    preferred_element_type=jnp.float32)      # (.., nh, w)
+                sc = jnp.where(live, sc, NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
-                pp = jnp.exp(sc - m_new[..., None])
-                acc = acc * alpha[..., None] + lax.dot_general(
-                    pp, vf, (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)      # (b, nh, dkv)
-                return m_new, l * alpha + jnp.sum(pp, axis=-1), acc
+                pp = jnp.exp(sc - m_new)
+                acc = acc * alpha + lax.dot_general(
+                    pp, vf, (((nb + 1,), (nb,)), (bd, bd)),
+                    preferred_element_type=jnp.float32)      # (.., nh, dkv)
+                return (m_new,
+                        l * alpha + jnp.sum(pp, axis=-1, keepdims=True), acc)
 
-            def body(c, carry):
-                slot = lax.rem(c, 2)
+            m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
+            l_s[...] = jnp.zeros_like(l_s)
+            o_s[...] = jnp.zeros_like(o_s)
 
-                @pl.when(c + 1 < nc)
-                def _():
-                    for r in range(b):
-                        chunk_copy(li, c + 1, lax.rem(c + 1, 2), r).start()
+            def body(t, carry):
+                # the state is in scratch: the loop carries nothing
+                start_pair(li, t + ring - 1)
+                pair_copy(li, t).wait()
+                r = wrow_ref[t]
+                idx = wchunk_ref[t] * ck + lax.broadcasted_iota(
+                    jnp.int32, (1, ck), 1)
+                m_s[r], l_s[r], o_s[r] = merge(
+                    (m_s[r], l_s[r], o_s[r]), q_s[r],
+                    kvch_s[lax.rem(t, ring)], idx < pos_ref[r] // 8 * 8)
+                return carry
 
-                for r in range(b):
-                    chunk_copy(li, c, slot, r).wait()
-                idx = c * ck + lax.broadcasted_iota(
-                    jnp.int32, (1, 1, ck), 2)
-                return merge(carry, kvch_s[slot], idx, blk3)
-
-            carry = lax.fori_loop(0, nc, body, (
-                jnp.full((b, nh), NEG_INF, jnp.float32),
-                jnp.zeros((b, nh), jnp.float32),
-                jnp.zeros((b, nh, dkv), jnp.float32)))
+            lax.fori_loop(0, total, body, 0)
 
             # merge each row's new token into its RMW block, attend to it
             # from VMEM, write the block back (waited in FFN j==1)
@@ -2101,10 +2174,10 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
             for r in range(b):
                 rmw_write(li, r).start()
             bidx = blk3 + lax.broadcasted_iota(jnp.int32, (1, 1, 8), 2)
-            ms, ls, accs = merge(carry, kvblk_s[...], bidx,
-                                 posv.reshape(b, 1, 1) + 1)
+            _, ls, accs = merge((m_s[...], l_s[...], o_s[...]), q_s[...],
+                                kvblk_s[...], bidx < posv.reshape(b, 1, 1) + 1)
 
-            norm = accs / ls[..., None]                     # (b, nh, dkv)
+            norm = accs / ls                                # (b, nh, dkv)
             if kvq:     # per-slot v-half dequant scales, applied once
                 norm = norm * kvs_ref[...][:, dkv:][:, None]
             if rep == 1:
@@ -2147,19 +2220,13 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
             @pl.when(j == 1)
             def prefetch_next_layer():
                 # drain this layer's per-row write-backs, then issue the
-                # next layer's RMW + chunk-0 reads
+                # next layer's RMW reads and the head of its walk
                 for r in range(b):
                     rmw_write(li, r).wait()
 
                 @pl.when(li + 1 < L)
                 def _():
-                    for r in range(b):
-                        rmw_read(li + 1, r).start()
-
-                    @pl.when(nc > 0)
-                    def _():
-                        for r in range(b):
-                            chunk_copy(li + 1, 0, 0, r).start()
+                    start_layer(li + 1)
 
             xn = xn_s[...]
             g = wdot(xn, wg_ref, sg_ref if int8 else None)
@@ -2194,6 +2261,9 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                 # positions
         pl.BlockSpec(memory_space=pltpu.SMEM),                 # block table
+        pl.BlockSpec(memory_space=pltpu.SMEM),                 # work_row
+        pl.BlockSpec(memory_space=pltpu.SMEM),                 # work_chunk
+        pl.BlockSpec(memory_space=pltpu.SMEM),                 # total
         pl.BlockSpec((b, 1), lambda l, j: (0, 0)),             # posv
         pl.BlockSpec((b, h), lambda l, j: (0, 0)),             # x
         pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),    # ln1
@@ -2229,10 +2299,13 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
     ] if kvq else []) + [
         pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),      # kv pool
     ]
+    positions = jnp.asarray(positions, jnp.int32).reshape(b)
+    work_row, work_chunk, total = paged_walk(positions, BT, MB)
     operands = [
-        jnp.asarray(positions, jnp.int32).reshape(b),
+        positions,
         jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(positions, jnp.int32).reshape(b, 1),
+        work_row, work_chunk, total.astype(jnp.int32).reshape(1),
+        positions.reshape(b, 1),
         x,
         params["ln1"][:, None], params["wqkv"], params["wo"],
         params["ln2"][:, None], params["wg"],
@@ -2265,9 +2338,12 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
             pltpu.VMEM((b, nh, dkv), jnp.float32),    # q_s (block-diag)
             pltpu.VMEM((b, 2 * dkv), jnp.float32),    # kv32_s staging
             pltpu.VMEM((b, 8, 2 * dkv), kv_pool.dtype),    # kvblk_s RMW
-            pltpu.VMEM((2, b, ck, 2 * dkv), kv_pool.dtype),  # kvch_s dbuf
+            pltpu.VMEM((ring, ck, 2 * dkv), kv_pool.dtype),  # kvch_s ring
+            pltpu.VMEM((b, nh, 1), jnp.float32),      # m_s  } a row's
+            pltpu.VMEM((b, nh, 1), jnp.float32),      # l_s  } online-softmax
+            pltpu.VMEM((b, nh, dkv), jnp.float32),    # o_s  } state
             pltpu.SemaphoreType.DMA((b,)),            # wsem (per row)
-            pltpu.SemaphoreType.DMA((2, b)),          # rsem (slot, row)
+            pltpu.SemaphoreType.DMA((ring,)),         # rsem (per buffer)
         ],
         input_output_aliases={len(in_specs) - 1: 1},
         compiler_params=pltpu.CompilerParams(

@@ -1594,8 +1594,12 @@ class ServingEngine:
         A plan's ``step_counters`` (``mla_moe``: ``moe_layer_steps``,
         ``moe_experts_touched``, ``moe_rows_max``, ``moe_rows``) are
         summed over the step programs whose tokens were pulled, as the
-        program counted them. Per-step distributions live in the
-        ``serving.step_*_s`` registry histograms."""
+        program counted them. ``kv_blocks_walked`` and ``kv_blocks_dense``
+        (the fused paged kernel's engines only) sum, over the plain and
+        fused-chunk steps landed, the (row, block) pairs of the decode
+        kernel's walk and what a walk of every slot to the longest row's
+        length would cover (:meth:`_record_walk`). Per-step distributions
+        live in the ``serving.step_*_s`` registry histograms."""
         return dict(steps=0, decode_tokens=0, idle_slot_steps=0,
                     prefill_tokens=0, prefill_tokens_reused=0,
                     prefill_chunks=0, replay_tokens=0,
@@ -1613,6 +1617,8 @@ class ServingEngine:
                     step_commit_s=0.0, step_tail_s=0.0,
                     step_upload_s=0.0, upload_ticks=0,
                     lookahead_ticks=0, lookahead_discarded_tokens=0,
+                    **({} if self._own_step else
+                       dict(kv_blocks_walked=0, kv_blocks_dense=0)),
                     **{name: 0 for name in self._step_counters})
 
     def reset_stats(self):
@@ -3536,11 +3542,14 @@ class ServingEngine:
                 nxt = _sample_logits(plan_t["head"](x), ki, temperature,
                                      top_k, top_p)
             # advance the per-slot state in-program so event-free steps
-            # re-dispatch with NO host->device uploads; the clamp only
-            # ever binds on retired rows (an active row's position is
-            # bounded by its admission-checked worst case), keeping their
-            # table lookups in range while they idle against scratch
-            pos2 = jnp.minimum(positions + 1, pos_cap)
+            # re-dispatch with NO host->device uploads. A row whose table
+            # row is scratch (idle: released, or still prefilling) stays
+            # where the upload put it, at 0, so the kernel's walk
+            # (`ops.fused_decode.paged_walk`) has no pair for it; the
+            # clamp binds on no active row (its position is bounded by
+            # its admission-checked worst case)
+            pos2 = jnp.where(tables[:, 0] == SCRATCH_BLOCK, positions,
+                             jnp.minimum(positions + 1, pos_cap))
             return nxt, pool, pos2, counts + 1
 
         return body
@@ -4120,6 +4129,7 @@ class ServingEngine:
                     self._decode(*todo)
                 with self._phase("serving.step.tail") as tail:
                     self._record_segments()
+                    self._record_walk()
                     self._record_flight()
                     self._after_flight()
                     status = dict(active=self.active_slots,
@@ -4802,6 +4812,23 @@ class ServingEngine:
                     self._ewma_step.update(self._tick_decode_s())
                 else:
                     self._ewma_step_warm = True
+
+    def _record_walk(self):
+        """The paged decode kernel's walk, counted on a tick that landed
+        a plain or fused-chunk step: the length of the list that
+        ``ops.fused_decode.paged_walk`` builds (``paged_walk_blocks``) from
+        the host's positions as the tick leaves them, which are what the
+        next step program reads (one a step, whichever of two
+        neighbouring steps it is). A verify step walks densely and an
+        engine with its own step has no such kernel: neither counts."""
+        if (self._own_step or not self._tick_landed
+                or self._tick_spec is not None):
+            return
+        from paddle_tpu.ops.fused_decode import paged_walk_blocks
+        _, walked, dense = paged_walk_blocks(self._positions,
+                                             self.block_tokens)
+        self.stats["kv_blocks_walked"] += int(walked)
+        self.stats["kv_blocks_dense"] += int(dense)
 
     def _record_flight(self, err=None):
         """One compact JSON-ready event per tick into the flight ring.

@@ -490,6 +490,144 @@ class TestInterpretKernelParity:
                                    rtol=5e-2, atol=5e-2)
 
 
+# ------------------------------------------------ the paged kernel's walk
+
+def _paged_setup(arch, int8_pool):
+    """A ragged batch over a tiny pool (BT 16, 4 blocks a slot, so
+    `max_seq_len` 64): an idle slot, a row below one group of 8, a row on
+    a block boundary, a row at `max_seq_len` - 1, a released row whose
+    position the program advanced (its table row is scratch), a row in
+    the middle of a block."""
+    L, h, hd, ffn, BT, MB = 2, 128, 64, 256, 16, 4
+    nh, nkv = (2, 2) if arch == "gpt" else (4, 2)
+    dq, dkv = nh * hd, nkv * hd
+    positions = np.asarray([0, 5, 32, MB * BT - 1, 11, 21], np.int32)
+    live = [False, True, True, True, False, True]
+    b = len(positions)
+    r = np.random.RandomState(3)
+    f = lambda *s: jnp.asarray(r.randn(*s) * 0.05, jnp.bfloat16)
+    params = {"ln1": 1 + f(L, h), "ln2": 1 + f(L, h),
+              "wqkv": f(L, h, dq + 2 * dkv), "wo": f(L, dq, h),
+              "wg": f(L, h, ffn), "wd": f(L, ffn, h)}
+    if arch == "gpt":
+        params.update(ln1_b=f(L, h), ln2_b=f(L, h), bqkv=f(L, dq + 2 * dkv),
+                      bo=f(L, h), bg=f(L, ffn), bd=f(L, h))
+    else:
+        params["wu"] = f(L, h, ffn)
+    NB = 1 + b * MB
+    tables = np.zeros((b, MB), np.int32)          # 0 is the scratch block
+    for i in range(b):
+        if live[i]:
+            tables[i] = 1 + i * MB + np.arange(MB)
+    pool = f(L, NB, BT, 2 * dkv)
+    scales = None
+    if int8_pool:
+        scales = jnp.asarray(
+            0.002 + 0.001 * r.rand(L, b, 2 * dkv), jnp.float32)
+        pool = jnp.asarray(r.randint(-127, 128, pool.shape), jnp.int8)
+    cos, sin = rope_cos_sin(MB * BT, hd)
+    return dict(x=f(b, h), params=params, pool=pool, tables=tables,
+                positions=positions, cos=cos[positions], sin=sin[positions],
+                kw=dict(num_heads=nh, num_kv_heads=nkv, eps=1e-5, arch=arch,
+                        kv_scales=scales), hd=hd)
+
+
+@pytest.mark.parametrize("int8_pool", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("arch", ["gpt", "llama"])
+def test_paged_kernel_ragged_batch(arch, int8_pool):
+    """The paged kernel (interpret mode) on rows of every kind at once,
+    against the jnp reference: the step's output and the pool."""
+    s = _paged_setup(arch, int8_pool)
+    xr, pr = jax.jit(lambda x, p, pool: fd.fused_paged_decode_reference(
+        x, p, pool, s["tables"], s["positions"], s["cos"], s["sin"],
+        **s["kw"]))(s["x"], s["params"], s["pool"])
+    xk, pk = jax.jit(lambda x, p, pool: fd._fused_paged_decode_pallas(
+        x, p, pool, s["tables"], s["positions"], head_dim=s["hd"],
+        interpret=True, **s["kw"]))(s["x"], s["params"], s["pool"])
+    np.testing.assert_allclose(np.asarray(xk, np.float32),
+                               np.asarray(xr, np.float32),
+                               rtol=2e-2, atol=2e-2)
+    pr, pk = np.asarray(pr, np.float32), np.asarray(pk, np.float32)
+    np.testing.assert_allclose(pk, pr, atol=1.0 if int8_pool else 2e-2)
+    # nothing but the appended rows is written
+    p0 = np.asarray(s["pool"], np.float32)
+    BT = p0.shape[2]
+    for i, pos in enumerate(s["positions"]):
+        p0[:, s["tables"][i, pos // BT], pos % BT] = \
+            pk[:, s["tables"][i, pos // BT], pos % BT]
+    np.testing.assert_array_equal(pk, p0)
+
+
+def test_paged_walk_list():
+    """`paged_walk`: each row's own blocks, row-major, as long as
+    `paged_walk_blocks` says; numpy in gives numpy out and agrees with the
+    traced list; a full batch of equal rows is the dense walk."""
+    BT, MB = 16, 4
+    pos = np.asarray([0, 5, 32, 63, 11, 21], np.int32)
+    nc, walked, dense = fd.paged_walk_blocks(pos, BT)
+    # whole groups of 8 below the position, in blocks
+    assert nc.tolist() == [0, 0, 2, 4, 1, 1]
+    assert int(walked) == 8 and int(dense) == 6 * 4
+    row, chunk, total = fd.paged_walk(pos, BT, MB)
+    assert isinstance(row, np.ndarray) and row.shape == (len(pos) * MB,)
+    assert row.dtype == chunk.dtype == np.int32 and int(total) == walked
+    assert list(zip(row[:8].tolist(), chunk[:8].tolist())) == [
+        (2, 0), (2, 1), (3, 0), (3, 1), (3, 2), (3, 3), (4, 0), (5, 0)]
+    assert row.max() < len(pos) and chunk.min() >= 0 and chunk.max() < MB
+    jrow, jchunk, jtotal = jax.jit(
+        lambda p: fd.paged_walk(p, BT, MB))(jnp.asarray(pos))
+    assert (np.asarray(jrow) == row).all()
+    assert (np.asarray(jchunk) == chunk).all()
+    assert int(jtotal) == total
+    equal = np.full(5, 40, np.int32)
+    _, walked, dense = fd.paged_walk_blocks(equal, BT)
+    row, chunk, total = fd.paged_walk(equal, BT, MB)
+    assert int(total) == int(walked) == int(dense) == 15
+    assert row[:15].tolist() == [r for r in range(5) for _ in range(3)]
+    assert chunk[:15].tolist() == [0, 1, 2] * 5
+    full = np.full(3, MB * BT - 1, np.int32)        # every entry a pair
+    row, chunk, total = fd.paged_walk(full, BT, MB)
+    assert int(total) == 3 * MB and chunk.tolist() == list(range(MB)) * 3
+    _, walked, dense = fd.paged_walk_blocks(np.zeros(3, np.int32), BT)
+    assert int(walked) == 0 and int(dense) == 0
+    assert int(fd.paged_walk(np.zeros(3, np.int32), BT, MB)[2]) == 0
+
+
+def test_engine_counts_the_walk_and_idle_rows_cost_none():
+    """`engine.stats` sums the length of `paged_walk`'s list and its dense
+    counterpart over the landed steps, from the host's positions; and the
+    step program leaves an idle row (scratch table row) at position 0, so
+    the device's positions stay the host's and the row has no pair."""
+    from paddle_tpu import serving
+    cfg, m = tiny_model(4)
+    m.eval()
+    eng = serving.ServingEngine(m, max_slots=4, block_tokens=16,
+                                max_seq_len=128, prefix_caching=False)
+    rng = np.random.RandomState(5)
+    for n, new in ((20, 14), (37, 6), (9, 10)):
+        eng.submit(serving.Request(rng.randint(3, 512, (n,)),
+                                   max_new_tokens=new))
+    walked = dense = 0
+    while not eng.idle:
+        steps0 = eng.stats["steps"]
+        eng.step()
+        if eng.stats["steps"] > steps0:
+            t = fd.paged_walk(eng._positions, 16, 8)[2]
+            d = fd.paged_walk_blocks(eng._positions, 16)[2]
+            walked, dense = walked + int(t), dense + int(d)
+        if not eng._dirty and eng._dev is not None:
+            # between uploads the program has advanced the live rows only
+            dev = np.asarray(eng._dev[1])
+            idle = [i for i, sl in enumerate(eng._slots) if sl is None]
+            assert (dev[idle] == 0).all(), dev
+    assert eng.stats["steps"] >= 12
+    assert eng.stats["kv_blocks_walked"] == walked > 0
+    assert eng.stats["kv_blocks_dense"] == dense > walked
+    eng.reset_stats()
+    assert eng.stats["kv_blocks_walked"] == eng.stats["kv_blocks_dense"] == 0
+    eng.close()
+
+
 def test_vmem_mib_flag_dispatch():
     """FLAGS_vmem_mib: >0 overrides; -1 asks the Mosaic probe, which
     raises off-TPU (no silent table answer); 0 = kind table on a TPU,

@@ -1204,3 +1204,92 @@ def test_moe_grouped_ffn_parity_at_published_widths():
         np.abs(np.asarray(want, np.float32)).max()))
     assert np.abs(np.asarray(got, np.float32)[::3]).max() == 0.0
     assert np.abs(np.asarray(want, np.float32)).max() > 0.1
+
+
+# ---------------------------------------------------------------------------
+# the paged decode kernel's ragged walk at the chat cells' widths (PR 28)
+# ---------------------------------------------------------------------------
+
+def _paged_cell(arch, b, L):
+    """(shapes of) a step of the paged kernel at a chat cell's widths:
+    gpt2-345m (MHA, 16 heads of 64, 8 blocks a slot) or internlm2-1.8b
+    (GQA, 16 heads of 128 over 8, 16 blocks a slot); blocks of 128."""
+    from paddle_tpu.ops import fused_decode as fd
+    if arch == "gpt":
+        h, nh, nkv, hd, ffn, MB = 1024, 16, 16, 64, 4096, 8
+    else:
+        h, nh, nkv, hd, ffn, MB = 2048, 16, 8, 128, 8192, 16
+    dq, dkv = nh * hd, nkv * hd
+    shapes = {"ln1": (L, h), "ln2": (L, h), "wqkv": (L, h, dq + 2 * dkv),
+              "wo": (L, dq, h), "wg": (L, h, ffn), "wd": (L, ffn, h)}
+    if arch == "gpt":
+        shapes.update(ln1_b=(L, h), ln2_b=(L, h), bqkv=(L, dq + 2 * dkv),
+                      bo=(L, h), bg=(L, ffn), bd=(L, h))
+    else:
+        shapes["wu"] = (L, h, ffn)
+    kw = dict(num_heads=nh, num_kv_heads=nkv, arch=arch, eps=1e-5)
+    blocks = fd.decode_block_plan(h, dq + 2 * dkv, dq, hd, ffn, 2)
+    return shapes, (L, b * MB + 1, 128, 2 * dkv), MB, hd, h, kw, blocks
+
+
+@pytest.mark.parametrize("arch, b", [("gpt", 32), ("llama", 16)])
+def test_paged_decode_ragged_parity_at_cell_widths(arch, b):
+    """Rows of every kind in one batch, at the two chat cells' widths and
+    slot counts: idle against scratch, 5 tokens, on a block boundary, at
+    `max_seq_len` - 1, released with an advanced position, and the rest
+    of uneven lengths; the output and the pool against the jnp twin."""
+    from paddle_tpu.ops import fused_decode as fd
+    from paddle_tpu.ops.rope import rope_cos_sin
+    L = 3
+    shapes, pool_shape, MB, hd, h, kw, blocks = _paged_cell(arch, b, L)
+    params = {k: rand(10 + i, *s, scale=0.01)
+              for i, (k, s) in enumerate(sorted(shapes.items()))}
+    params["ln1"], params["ln2"] = 1 + params["ln1"], 1 + params["ln2"]
+    S = MB * 128
+    pos = np.random.RandomState(0).randint(1, S - 1, b).astype(np.int32)
+    pos[:5] = [0, 5, 256, S - 1, 11]
+    live = np.ones(b, bool)
+    live[[0, 4, b - 1, b - 3]] = False          # idle or released rows
+    pos[[b - 1, b - 3]] = 0
+    tables = np.zeros((b, MB), np.int32)
+    for r in np.flatnonzero(live):
+        tables[r] = 1 + r * MB + np.arange(MB)
+    pool = rand(1, *pool_shape)
+    x = rand(2, b, h)
+    cos, sin = rope_cos_sin(S, hd)
+    want_x, want_pool = jax.jit(
+        lambda x, p, pool: fd.fused_paged_decode_reference(
+            x, p, pool, tables, pos, cos[pos], sin[pos], **kw))(
+                x, params, pool)
+    got_x, got_pool = jax.jit(
+        lambda x, p, pool: fd._fused_paged_decode_pallas(
+            x, p, pool, tables, pos, head_dim=hd, blocks=blocks, **kw))(
+                x, params, pool)
+    assert_close(np.asarray(got_x)[live], np.asarray(want_x)[live])
+    got_pool = np.asarray(got_pool, np.float32)
+    want_pool = np.asarray(want_pool, np.float32)
+    assert_close(got_pool[:, 1:], want_pool[:, 1:], frac=1.0)
+    # no row but the appended ones is written (scratch takes the idle rows')
+    p0 = np.asarray(pool, np.float32)
+    for r in np.flatnonzero(live):
+        p0[:, tables[r, pos[r] // 128], pos[r] % 128] = \
+            got_pool[:, tables[r, pos[r] // 128], pos[r] % 128]
+    assert (got_pool[:, 1:] == p0[:, 1:]).all()
+
+
+@pytest.mark.parametrize("arch, b", [("gpt", 64), ("llama", 32)])
+def test_paged_decode_compiles_at_twice_the_cells_slots(arch, b):
+    """The kernel's scratch no longer grows with the slots (a ring of
+    block buffers for 1 MiB a slot), so 64 slots at the 345 M widths and
+    32 at the 1.8 B widths, which the dense walk's scratch did not fit,
+    compile at the cells' 24 layers. A fact for PERF.md §7; no cell uses
+    it."""
+    from paddle_tpu.ops import fused_decode as fd
+    shapes, pool_shape, MB, hd, h, kw, blocks = _paged_cell(arch, b, 24)
+    bf = jnp.bfloat16
+    S = jax.ShapeDtypeStruct
+    jax.jit(lambda x, p, pool, t, q: fd._fused_paged_decode_pallas(
+        x, p, pool, t, q, head_dim=hd, blocks=blocks, **kw)).lower(
+            S((b, h), bf), {k: S(s, bf) for k, s in shapes.items()},
+            S(pool_shape, bf), S((b, MB), jnp.int32),
+            S((b,), jnp.int32)).compile()
