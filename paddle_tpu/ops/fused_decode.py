@@ -33,9 +33,11 @@ the win.
 
 The serving engine's PAGED variants (`fused_paged_decode_step`, further
 down) keep the cache as a pool of blocks behind a block table. There a
-chunk of the walk is one block, and the decode kernel walks a flat list
-of (row, block) pairs, each row's own blocks (`paged_walk`), where the
-contiguous kernel above walks one length for all rows.
+chunk of the walk is one block, and the ONE paged kernel walks a flat
+list of (row, block) pairs, each row's own blocks (`paged_walk`), where
+the contiguous kernel above walks one length for all rows. It takes a
+tail of K1 tokens a row: one is a decode step, k+1 the verify step of
+speculative decoding (`fused_paged_verify_step`).
 """
 
 import functools
@@ -1619,14 +1621,13 @@ def fused_decode_step(x, params, kv_cache, pos, cos, sin, *,
 # One block == one KV chunk of the kernel's online-softmax walk, so the
 # chunk copy indexes through the block table (the same SMEM-addressed DMA
 # technique the MoE kernel uses for routed expert weights). Slots of
-# wildly different lengths share one dispatch because the decode kernel
-# walks a FLAT list of (row, chunk) pairs (`paged_walk`): each row's own
-# blocks and nothing else, so a step reads the cache that is live. (The
-# verify kernel still walks every row to the longest row's length, where
-# an all-masked online-softmax merge is an exact no-op.)
+# wildly different lengths share one dispatch because the kernel walks a
+# FLAT list of (row, chunk) pairs (`paged_walk`): each row's own blocks
+# and nothing else, so a step reads the cache that is live. A verify step
+# is the same kernel and the same walk, with a tail of k+1 tokens a row.
 
 
-# Block buffers of the paged decode kernel's walk: a pair's DMA is started
+# Block buffers of the paged kernel's walk: a pair's DMA is started
 # `_WALK_RING - 1` pairs ahead of its merge. Chosen on the chip (PERF.md
 # §6, PR 28).
 _WALK_RING = 4
@@ -1929,7 +1930,12 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
                                eps: float = 1e-5, arch: str = "llama",
                                blocks: Optional[Dict] = None,
                                kv_scales=None, interpret: bool = False):
-    """Paged-pool variant of `_fused_decode_pallas` (llama/gpt, no q-split).
+    """Paged-pool variant of `_fused_decode_pallas` (llama/gpt, no q-split),
+    over a tail of K1 tokens a row: K1 == 1 is a decode step, K1 > 1 the
+    verify step of speculative decoding. K1 is read off ``x``, which is
+    TOKEN-MAJOR flat (K1*b, h): tail token t's rows are the contiguous
+    slice [t*b, (t+1)*b), so every per-token stage is a static slice
+    (Mosaic cannot stride sublanes) and K1 == 1 is plain (b, h).
 
     Differences from the contiguous kernel:
 
@@ -1937,18 +1943,31 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
       RMW append resolves its physical block through the SMEM block table
       (`bt_ref[r, c]` — the data-dependent DMA addressing the MoE kernel
       pioneered for routed expert weights);
-    * `positions` is per-row: rope angles, the append RMW offset and the
-      online-softmax limits are per row instead of scalar;
+    * `positions` is per-row (a row's append position for tail token 0):
+      rope angles, the append RMW offset and the online-softmax limits
+      are per row instead of scalar;
     * the walk over cached KV is RAGGED: one loop over the flat list of
       (row, chunk) pairs of `paged_walk`, each row's own blocks and no
       others. A pair's block arrives through a ring of `_WALK_RING`
       block buffers whose prefetch runs ahead ACROSS row boundaries (and
       from one layer's FFN phase into the next layer's walk), and is
       merged into that row's online-softmax state, which lives in VMEM
-      scratch indexed by row. A block costs a DMA and two (nh, BT)
+      scratch indexed by row. A block costs a DMA and two (K1*nh, BT)
       matmuls, so a step's attention time follows the blocks that are
       live; an idle slot (position 0) costs no walk. The scratch holds
       the ring however many slots there are;
+    * the tail: one qkv matmul over all K1*b rows; token t's heads are
+      staged block-diagonally at q rows [t*nh, (t+1)*nh) of a row's
+      (K1*nh, dkv) staging, so one pair of the walk scores ALL tail
+      queries (each attends the whole committed prefix). The append
+      window [pos//8*8, pos+K1) is NW 8-aligned segments a row, each
+      resolved through the block table on its own (BT % 8 == 0: a
+      segment never straddles a block; one past the table, a row near its
+      cap that over-speculates, is redirected to the scratch block).
+      Tail k/v merge at offsets off+t, the window is attended with
+      PER-QUERY causal limits (query t masks to pos+t) and written back;
+      the o-projection and residual run per tail token. At K1 == 1 the
+      window is the one 8-token RMW block of the new token;
     * int8 pool scales are per-SLOT ((L, b, 2*nkv*hd)).
     """
     from jax.experimental import pallas as pl
@@ -1956,6 +1975,10 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
 
     L, NB, BT, dkv2 = kv_pool.shape
     b, MB = block_tables.shape
+    K1 = x.shape[0] // b
+    assert K1 >= 1 and x.shape[0] == K1 * b, (x.shape, b)
+    K1b = K1 * b
+    NW = (7 + K1 + 7) // 8      # segments of a row's append window
     dkv = dkv2 // 2
     nh = num_heads
     nkv = num_kv_heads
@@ -2016,9 +2039,18 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
             i += 1
         kv_in = refs[i]                # aliased with kv_ref
         x_out_ref, kv_ref = refs[i + 1], refs[i + 2]
-        (x_s, xn_s, acc_s, q_s, kv32_s, kvblk_s, kvch_s, m_s, l_s, o_s,
+        (x_s, xn_s, acc_s, q_s, kv32_s, kvwin_s, kvch_s, m_s, l_s, o_s,
          wsem, rsem) = refs[i + 3:]
         del kv_in
+
+        def tail(v, t):
+            """v at tail token t; token 0 adds no op to the trace, so that
+            K1 == 1 is the decode program and nothing more."""
+            return v + t if t else v
+
+        def rows_of(t):
+            """Tail token t's rows of the token-major (K1*b, ...) arrays."""
+            return slice(t * b, (t + 1) * b)
 
         def wdot(act, wref, sref):
             w = wref[...]
@@ -2032,20 +2064,33 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
         j = pl.program_id(1)
 
         # ---- per-row paged DMA descriptors (block table in SMEM) ----
-        def rmw_read(l, r):
+        def seg_pool(l, r, m):
+            """Segment m of row r's append window in the pool: 8 tokens
+            from pos // 8 * 8 + 8 m, through the block table."""
             p = pos_ref[r]
-            bid = bt_ref[r, p // BT]
-            return pltpu.make_async_copy(
-                kv_ref.at[l, bid, pl.ds((p % BT) // 8 * 8, 8)],
-                kvblk_s.at[r], wsem.at[r])
+            if m == 0:      # the open group of 8: always inside the table
+                return kv_ref.at[l, bt_ref[r, p // BT],
+                                 pl.ds((p % BT) // 8 * 8, 8)]
+            q0 = p // 8 * 8 + m * 8
+            c = q0 // BT
+            # past the table (over-speculation near the cap): to scratch
+            bid = jnp.where(c < MB, bt_ref[r, jnp.minimum(c, MB - 1)], 0)
+            return kv_ref.at[l, bid, pl.ds(q0 % BT, 8)]
 
-        def rmw_write(l, r):
-            p = pos_ref[r]
-            bid = bt_ref[r, p // BT]
+        def seg_read(l, r, m):
             return pltpu.make_async_copy(
-                kvblk_s.at[r],
-                kv_ref.at[l, bid, pl.ds((p % BT) // 8 * 8, 8)],
-                wsem.at[r])
+                seg_pool(l, r, m), kvwin_s.at[r, pl.ds(m * 8, 8)],
+                wsem.at[m * b + r])
+
+        def seg_write(l, r, m):
+            return pltpu.make_async_copy(
+                kvwin_s.at[r, pl.ds(m * 8, 8)], seg_pool(l, r, m),
+                wsem.at[m * b + r])
+
+        def each_seg(do):
+            for r in range(b):
+                for m in range(NW):
+                    do(r, m)
 
         # ---- the ragged walk: pair t of the flat (row, chunk) list ----
         total = total_ref[0]
@@ -2062,9 +2107,8 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
                 pair_copy(l, t).start()
 
         def start_layer(l):
-            """Layer l's RMW reads and the first pairs of its walk."""
-            for r in range(b):
-                rmw_read(l, r).start()
+            """Layer l's window reads and the first pairs of its walk."""
+            each_seg(lambda r, m: seg_read(l, r, m).start())
             for t in range(ring - 1):
                 start_pair(l, t)
 
@@ -2090,38 +2134,44 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
             qkv = wdot(xn, wqkv_ref, sqkv_ref if int8 else None)
             if gpt:
                 qkv = qkv + bqkv_ref[...]
-                rope2 = lambda t: t
             else:
                 # per-row rope angles from the per-row positions
                 half = (lax.broadcasted_iota(jnp.int32, (1, hd), 1)
                         % (hd // 2)).astype(jnp.float32)
                 inv_freq = jnp.exp(half * (-2.0 * math.log(rope_base) / hd))
-                ang = posv.astype(jnp.float32) * inv_freq      # (b, hd)
-                cos_b = jnp.cos(ang)
-                sin_b = jnp.sin(ang)
-                rope2 = lambda t: (t * cos_b + jnp.concatenate(
-                    [-t[:, hd // 2:], t[:, :hd // 2]], axis=-1) * sin_b)
+
+                def rope_at(t):
+                    ang = tail(posv, t).astype(jnp.float32) * inv_freq
+                    cos_b = jnp.cos(ang)                       # (b, hd)
+                    sin_b = jnp.sin(ang)
+                    return lambda v: (v * cos_b + jnp.concatenate(
+                        [-v[:, hd // 2:], v[:, :hd // 2]], axis=-1) * sin_b)
             # q staged block-diagonally over kv-group lane blocks (see
-            # _fused_decode_pallas), an int8 pool's per-slot k-half
-            # dequant scales folded in; new k/v staged flat for the RMW
-            # merge
-            for n in range(nh):
-                g = n // rep
-                qn = rope2(qkv[:, n * hd:(n + 1) * hd]) * scale
-                if kvq:
-                    qn = qn * kvs_ref[...][:, g * hd:(g + 1) * hd]
-                q_s[:, n, g * hd:(g + 1) * hd] = qn
-            for g in range(nkv):
-                kv32_s[:, g * hd:(g + 1) * hd] = rope2(
-                    qkv[:, dq + g * hd:dq + (g + 1) * hd])
-                kv32_s[:, dkv + g * hd:dkv + (g + 1) * hd] = \
-                    qkv[:, dq + dkv + g * hd:dq + dkv + (g + 1) * hd]
+            # _fused_decode_pallas), token t's heads at q rows [t*nh,
+            # (t+1)*nh) with rope at pos + t, an int8 pool's per-slot
+            # k-half dequant scales folded in; new k/v staged flat,
+            # token-major like x, for the window merge
+            for t in range(K1):
+                rows = rows_of(t)
+                rope2 = (lambda v: v) if gpt else rope_at(t)
+                for n in range(nh):
+                    g = n // rep
+                    qn = rope2(qkv[rows, n * hd:(n + 1) * hd]) * scale
+                    if kvq:
+                        qn = qn * kvs_ref[...][:, g * hd:(g + 1) * hd]
+                    q_s[:, t * nh + n, g * hd:(g + 1) * hd] = qn
+                for g in range(nkv):
+                    kv32_s[rows, g * hd:(g + 1) * hd] = rope2(
+                        qkv[rows, dq + g * hd:dq + (g + 1) * hd])
+                    kv32_s[rows, dkv + g * hd:dkv + (g + 1) * hd] = \
+                        qkv[rows, dq + dkv + g * hd:dq + dkv + (g + 1) * hd]
 
             def merge(carry, q, kvblk, live):
                 """Online-softmax update of (m, l, acc) with one block of
-                keys and values, over ALL heads: for one row (q (nh, dkv),
-                kvblk (w, 2*dkv)) in the walk, for every row at once
-                (leading b) at the new token's group."""
+                keys and values, over ALL heads: for one row and all its
+                tail queries (q (K1*nh, dkv), kvblk (w, 2*dkv)) in the
+                walk, for every row at once (leading b) and one tail
+                query (nh heads) at the append window."""
                 m, l, acc = carry
                 kf = kvblk[..., :dkv].astype(jnp.float32)
                 vf = kvblk[..., dkv:].astype(jnp.float32)
@@ -2129,14 +2179,14 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
                 bd = tuple(range(nb))
                 sc = lax.dot_general(
                     q, kf, (((nb + 1,), (nb + 1,)), (bd, bd)),
-                    preferred_element_type=jnp.float32)      # (.., nh, w)
+                    preferred_element_type=jnp.float32)      # (.., heads, w)
                 sc = jnp.where(live, sc, NEG_INF)
                 m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
                 pp = jnp.exp(sc - m_new)
                 acc = acc * alpha + lax.dot_general(
                     pp, vf, (((nb + 1,), (nb,)), (bd, bd)),
-                    preferred_element_type=jnp.float32)      # (.., nh, dkv)
+                    preferred_element_type=jnp.float32)   # (.., heads, dkv)
                 return (m_new,
                         l * alpha + jnp.sum(pp, axis=-1, keepdims=True), acc)
 
@@ -2158,71 +2208,85 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
 
             lax.fori_loop(0, total, body, 0)
 
-            # merge each row's new token into its RMW block, attend to it
-            # from VMEM, write the block back (waited in FFN j==1)
-            for r in range(b):
-                rmw_read(li, r).wait()
+            # merge each row's tail tokens into its append window at
+            # offsets off + t, attend to it from VMEM with query t masked
+            # to its own position, write the segments back (waited in FFN
+            # j==1)
+            each_seg(lambda r, m: seg_read(li, r, m).wait())
             off3 = (posv - blk_v).reshape(b, 1, 1)
-            sel = lax.broadcasted_iota(jnp.int32, (1, 8, 1), 1) == off3
-            newtok = kv32_s[...]
-            if kvq:     # quantize the append with the per-slot scales
-                newtok = jnp.clip(
-                    jnp.round(newtok / kvs_ref[...]), -127.0, 127.0)
-            kvblk_s[...] = jnp.where(
-                sel, newtok[:, None, :],
-                kvblk_s[...].astype(jnp.float32)).astype(kv_pool.dtype)
-            for r in range(b):
-                rmw_write(li, r).start()
-            bidx = blk3 + lax.broadcasted_iota(jnp.int32, (1, 1, 8), 2)
-            _, ls, accs = merge((m_s[...], l_s[...], o_s[...]), q_s[...],
-                                kvblk_s[...], bidx < posv.reshape(b, 1, 1) + 1)
-
-            norm = accs / ls                                # (b, nh, dkv)
-            if kvq:     # per-slot v-half dequant scales, applied once
-                norm = norm * kvs_ref[...][:, dkv:][:, None]
-            if rep == 1:
-                bd = (lax.broadcasted_iota(jnp.int32, (1, nh, dkv), 2)
-                      // hd == lax.broadcasted_iota(
-                          jnp.int32, (1, nh, dkv), 1))
-                attn = jnp.sum(jnp.where(bd, norm, 0.0), axis=1)  # (b, dq)
-                oacc = wdot(attn.astype(dtype), wo_ref,
-                            so_ref if int8 else None)
-            else:
-                oacc = jnp.zeros((b, h), jnp.float32)
-                for g in range(nkv):
-                    ng = norm[:, g * rep:(g + 1) * rep,
-                              g * hd:(g + 1) * hd]          # (b, rep, hd)
-                    w3 = wo_ref[g * rep * hd:(g + 1) * rep * hd,
-                                :].reshape(rep, hd, h)
-                    part = lax.dot_general(
-                        ng.astype(dtype),
-                        w3.astype(dtype) if int8 else w3,
-                        (((2,), (1,)), ((1,), (0,))),
-                        preferred_element_type=jnp.float32)  # (rep, b, h)
-                    oacc = oacc + jnp.sum(part, axis=0)
-                if int8:
-                    oacc = oacc * so_ref[...]
-            if gpt:
-                oacc = oacc + bo_ref[...]
-            xr = x_s[...] + oacc
-            x_s[...] = xr
-            if gpt:
-                xn_s[...] = _layernorm(xr, ln2_ref[...].reshape(h),
-                                       ln2b_ref[...].reshape(h),
-                                       eps).astype(dtype)
-            else:
-                xn_s[...] = _rms(xr, ln2_ref[...].reshape(h),
-                                 eps).astype(dtype)
+            wi = lax.broadcasted_iota(jnp.int32, (1, NW * 8, 1), 1)
+            # (where in the window, token t's k/v), staged before the
+            # window is loaded: the op order of the one-token program
+            places = []
+            for t in range(K1):
+                sel = wi == tail(off3, t)
+                newtok = kv32_s[rows_of(t)]
+                if kvq:     # quantize the append with the per-slot scales
+                    newtok = jnp.clip(
+                        jnp.round(newtok / kvs_ref[...]), -127.0, 127.0)
+                places.append((sel, newtok[:, None, :]))
+            win = kvwin_s[...].astype(jnp.float32)
+            for sel, newtok in places:
+                win = jnp.where(sel, newtok, win)
+            kvwin_s[...] = win.astype(kv_pool.dtype)
+            each_seg(lambda r, m: seg_write(li, r, m).start())
+            widx = blk3 + lax.broadcasted_iota(jnp.int32, (1, 1, NW * 8), 2)
+            # per tail token, over its static slices of q rows and of x
+            # rows: attend to the window, then the o-projection, the
+            # residual and the FFN's norm
+            for t in range(K1):
+                rows = rows_of(t)
+                hs = slice(t * nh, (t + 1) * nh)
+                _, ls, accs = merge(
+                    (m_s[:, hs], l_s[:, hs], o_s[:, hs]), q_s[:, hs],
+                    kvwin_s[...],
+                    widx < tail(posv.reshape(b, 1, 1), t) + 1)
+                norm = accs / ls                            # (b, nh, dkv)
+                if kvq:     # per-slot v-half dequant scales, applied once
+                    norm = norm * kvs_ref[...][:, dkv:][:, None]
+                if rep == 1:
+                    bd = (lax.broadcasted_iota(jnp.int32, (1, nh, dkv), 2)
+                          // hd == lax.broadcasted_iota(
+                              jnp.int32, (1, nh, dkv), 1))
+                    attn = jnp.sum(jnp.where(bd, norm, 0.0),
+                                   axis=1)                       # (b, dq)
+                    oacc = wdot(attn.astype(dtype), wo_ref,
+                                so_ref if int8 else None)
+                else:
+                    oacc = jnp.zeros((b, h), jnp.float32)
+                    for g in range(nkv):
+                        ng = norm[:, g * rep:(g + 1) * rep,
+                                  g * hd:(g + 1) * hd]      # (b, rep, hd)
+                        w3 = wo_ref[g * rep * hd:(g + 1) * rep * hd,
+                                    :].reshape(rep, hd, h)
+                        part = lax.dot_general(
+                            ng.astype(dtype),
+                            w3.astype(dtype) if int8 else w3,
+                            (((2,), (1,)), ((1,), (0,))),
+                            preferred_element_type=jnp.float32)  # (rep,b,h)
+                        oacc = oacc + jnp.sum(part, axis=0)
+                    if int8:
+                        oacc = oacc * so_ref[...]
+                if gpt:
+                    oacc = oacc + bo_ref[...]
+                xr = x_s[rows] + oacc
+                x_s[rows] = xr
+                if gpt:
+                    xn_s[rows] = _layernorm(xr, ln2_ref[...].reshape(h),
+                                            ln2b_ref[...].reshape(h),
+                                            eps).astype(dtype)
+                else:
+                    xn_s[rows] = _rms(xr, ln2_ref[...].reshape(h),
+                                      eps).astype(dtype)
             acc_s[...] = jnp.zeros_like(acc_s)
 
         @pl.when(j >= 1)
         def ffn_phase():
             @pl.when(j == 1)
             def prefetch_next_layer():
-                # drain this layer's per-row write-backs, then issue the
-                # next layer's RMW reads and the head of its walk
-                for r in range(b):
-                    rmw_write(li, r).wait()
+                # drain this layer's window write-backs, then issue the
+                # next layer's window reads and the head of its walk
+                each_seg(lambda r, m: seg_write(li, r, m).wait())
 
                 @pl.when(li + 1 < L)
                 def _():
@@ -2265,7 +2329,7 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
         pl.BlockSpec(memory_space=pltpu.SMEM),                 # work_chunk
         pl.BlockSpec(memory_space=pltpu.SMEM),                 # total
         pl.BlockSpec((b, 1), lambda l, j: (0, 0)),             # posv
-        pl.BlockSpec((b, h), lambda l, j: (0, 0)),             # x
+        pl.BlockSpec((K1b, h), lambda l, j: (0, 0)),           # x
         pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),    # ln1
         pl.BlockSpec((None, h, dqkv), lambda l, j: (l, 0, 0)),  # wqkv
         pl.BlockSpec((None, dq, h), lambda l, j: (l, 0, 0)),   # wo
@@ -2324,35 +2388,68 @@ def _fused_paged_decode_pallas(x, params, kv_pool, block_tables, positions,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((b, h), lambda l, j: (0, 0)),
+            pl.BlockSpec((K1b, h), lambda l, j: (0, 0)),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h), dtype),
+            jax.ShapeDtypeStruct((K1b, h), dtype),
             jax.ShapeDtypeStruct(kv_pool.shape, kv_pool.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((b, h), jnp.float32),          # x_s
-            pltpu.VMEM((b, h), dtype),                # xn_s
-            pltpu.VMEM((b, h), jnp.float32),          # acc_s
-            pltpu.VMEM((b, nh, dkv), jnp.float32),    # q_s (block-diag)
-            pltpu.VMEM((b, 2 * dkv), jnp.float32),    # kv32_s staging
-            pltpu.VMEM((b, 8, 2 * dkv), kv_pool.dtype),    # kvblk_s RMW
+            pltpu.VMEM((K1b, h), jnp.float32),        # x_s
+            pltpu.VMEM((K1b, h), dtype),              # xn_s
+            pltpu.VMEM((K1b, h), jnp.float32),        # acc_s
+            pltpu.VMEM((b, K1 * nh, dkv), jnp.float32),   # q_s (block-diag)
+            pltpu.VMEM((K1b, 2 * dkv), jnp.float32),  # kv32_s staging
+            pltpu.VMEM((b, NW * 8, 2 * dkv), kv_pool.dtype),  # kvwin_s
             pltpu.VMEM((ring, ck, 2 * dkv), kv_pool.dtype),  # kvch_s ring
-            pltpu.VMEM((b, nh, 1), jnp.float32),      # m_s  } a row's
-            pltpu.VMEM((b, nh, 1), jnp.float32),      # l_s  } online-softmax
-            pltpu.VMEM((b, nh, dkv), jnp.float32),    # o_s  } state
-            pltpu.SemaphoreType.DMA((b,)),            # wsem (per row)
+            pltpu.VMEM((b, K1 * nh, 1), jnp.float32),     # m_s  } a row's
+            pltpu.VMEM((b, K1 * nh, 1), jnp.float32),     # l_s  } online-
+            pltpu.VMEM((b, K1 * nh, dkv), jnp.float32),   # o_s  } softmax
+            pltpu.SemaphoreType.DMA((NW * b,)),       # wsem (segment, row)
             pltpu.SemaphoreType.DMA((ring,)),         # rsem (per buffer)
         ],
         input_output_aliases={len(in_specs) - 1: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem_limit_bytes()),
-        name="fused_paged_decode_step",
+        name=("fused_paged_decode_step" if K1 == 1
+              else "fused_paged_verify_step"),
         interpret=interpret,
     )(*operands)
     return out[0], out[1]
+
+
+def _paged_pallas_interpret(kv_pool, arch: str, blocks: Optional[Dict],
+                            mp_axis: Optional[str]) -> Optional[bool]:
+    """May the paged Pallas kernel take this call? ``None`` says no (the
+    jnp reference runs), otherwise the kernel's ``interpret`` argument.
+
+    The kernel runs on a TPU (or under FLAGS_pallas_interpret elsewhere)
+    when the call is not tensor-parallel, the pool's [k|v] halves are lane
+    multiples of 128 and a block is whole groups of 8 tokens. A plan made
+    for another cache dtype than the pool's is refused, not routed.
+    """
+    from paddle_tpu.core.flags import flag
+    from paddle_tpu.ops import use_pallas
+    if arch not in ("llama", "gpt"):
+        raise NotImplementedError(
+            f"paged decode supports arch llama/gpt, got {arch!r}")
+    dkv = kv_pool.shape[-1] // 2
+    BT = kv_pool.shape[2]
+    # tpu-lint: allow(host-sync): flag() is a host-side config read
+    interp = bool(flag("FLAGS_pallas_interpret")) and not use_pallas()
+    if mp_axis is not None or not (use_pallas() or interp) \
+            or dkv % 128 or BT % 8:
+        return None
+    cb = jnp.dtype(kv_pool.dtype).itemsize
+    if blocks is not None and blocks.get("cache_wbytes", cb) != cb:
+        raise ValueError(
+            f"decode plan assumed a {blocks['cache_wbytes']}-byte KV "
+            f"cache but the pool dtype is {kv_pool.dtype} ({cb} B); "
+            f"rebuild the plan with decode_block_plan(cache_wbytes="
+            f"{cb})")
+    return interp
 
 
 def fused_paged_decode_step(x, params, kv_pool, block_tables, positions,
@@ -2377,30 +2474,14 @@ def fused_paged_decode_step(x, params, kv_pool, block_tables, positions,
     per-head math, so the XLA path shards cleanly today; teaching the
     Pallas kernel a local-shard mode is a later PR.
     """
-    from paddle_tpu.core.flags import flag
-    from paddle_tpu.ops import use_pallas
-    if arch not in ("llama", "gpt"):
-        raise NotImplementedError(
-            f"paged decode supports arch llama/gpt, got {arch!r}")
-    dkv = kv_pool.shape[-1] // 2
-    BT = kv_pool.shape[2]
-    # tpu-lint: allow(host-sync): flag() is a host-side config read
-    interp = bool(flag("FLAGS_pallas_interpret")) and not use_pallas()
-    if mp_axis is None and (use_pallas() or interp) and dkv % 128 == 0 \
-            and BT % 8 == 0:
-        cb = jnp.dtype(kv_pool.dtype).itemsize
-        if blocks is not None and blocks.get("cache_wbytes", cb) != cb:
-            raise ValueError(
-                f"decode plan assumed a {blocks['cache_wbytes']}-byte KV "
-                f"cache but the pool dtype is {kv_pool.dtype} ({cb} B); "
-                f"rebuild the plan with decode_block_plan(cache_wbytes="
-                f"{cb})")
+    interp = _paged_pallas_interpret(kv_pool, arch, blocks, mp_axis)
+    if interp is not None:
         with jax.named_scope("fused_decode.kernel_paged"):
             return _fused_paged_decode_pallas(
                 x, params, kv_pool, block_tables, positions,
                 num_heads=num_heads, num_kv_heads=num_kv_heads,
-                head_dim=dkv // num_kv_heads, rope_base=rope_base,
-                eps=eps, arch=arch, blocks=blocks,
+                head_dim=kv_pool.shape[-1] // 2 // num_kv_heads,
+                rope_base=rope_base, eps=eps, arch=arch, blocks=blocks,
                 kv_scales=kv_scales, interpret=interp)
     with jax.named_scope("fused_decode.reference_paged"):
         return fused_paged_decode_reference(
@@ -2517,12 +2598,13 @@ def fused_paged_tick_step(x, params, kv_pool, block_tables, positions,
 # Speculative decoding turns k proposed tokens per slot into ONE scoring
 # dispatch instead of k serial decode dispatches: the verify pass runs
 # the whole stack over the tail [t0, p1..pk] (t0 = the slot's last
-# sampled token, p* the proposals), appends every tail token's KV
-# through the PR 10 multi-token append path, and returns the k+1 hidden
-# states the engine samples the target tokens from. Decode is
-# bandwidth-bound, so weights streamed once per k+1 tokens instead of
-# once per token is the whole win (ROADMAP "Speculative decoding on the
-# paged engine").
+# sampled token, p* the proposals), appends every tail token's KV, and
+# returns the k+1 hidden states the engine samples the target tokens
+# from. On the TPU it is the paged decode kernel, given the tail
+# (`_fused_paged_decode_pallas`: K1 = k+1); there is no second kernel.
+# Decode is bandwidth-bound, so weights streamed once per k+1 tokens
+# instead of once per token is the whole win (ROADMAP "Speculative
+# decoding on the paged engine").
 #
 # Rejected-token KV is handled by POSITION, not by rollback: a slot's
 # attention always masks to its own append position, and future appends
@@ -2682,452 +2764,6 @@ def fused_paged_verify_reference(x, params, kv_pool, block_tables,
     return jnp.stack(outs, axis=1), kv_pool
 
 
-def _fused_paged_verify_pallas(x, params, kv_pool, block_tables,
-                               positions, *, num_heads: int,
-                               num_kv_heads: int, head_dim: int,
-                               rope_base: float = 10000.0,
-                               eps: float = 1e-5, arch: str = "llama",
-                               blocks: Optional[Dict] = None,
-                               kv_scales=None, interpret: bool = False):
-    """Paged verify kernel: `_fused_paged_decode_pallas` with the
-    single-token RMW append widened to a K1-token causal tail.
-
-    x arrives TOKEN-MAJOR flat (K1*b, h) — token j's rows are the
-    contiguous slice [j*b, (j+1)*b) so every per-token stage is a
-    static slice (Mosaic cannot stride sublanes). Per layer:
-
-    * the qkv pass runs ONE matmul over all K1*b rows; tail token j's
-      heads are staged block-diagonally into q rows [j*nh, (j+1)*nh)
-      of a (b, K1*nh, dkv) staging, so the prefix chunk walk scores
-      ALL tail queries with one dot_general per KV block (every tail
-      query attends the whole committed prefix — one shared walk);
-    * the append window [pos//8*8, pos+K1) replaces the 8-token RMW
-      block: NW 8-aligned segments per row, each resolved through the
-      block table independently (BT % 8 == 0 means an 8-aligned
-      segment never straddles a physical block; segments past the
-      table range redirect to the scratch block). Tail k/v merge at
-      offsets off+j, the window is attended with PER-QUERY causal
-      limits (query j masks to pos+j), and the segments write back —
-      the multi-token append path;
-    * the o-proj/FFN run per tail token over the same static slices.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    L, NB, BT, dkv2 = kv_pool.shape
-    b, MB = block_tables.shape
-    K1b = x.shape[0]
-    K1 = K1b // b
-    assert K1 * b == K1b, (x.shape, b)
-    dkv = dkv2 // 2
-    nh = num_heads
-    nkv = num_kv_heads
-    hd = head_dim
-    assert hd == dkv // nkv
-    rep = nh // nkv
-    h = x.shape[1]
-    dq = nh * hd
-    dqkv = dq + 2 * dkv
-    ffn = params["wg"].shape[2]
-    int8 = "wqkv_s" in params
-    kvq = kv_scales is not None
-    assert kvq == (jnp.dtype(kv_pool.dtype) == jnp.int8), \
-        "int8 KV pool needs kv_scales (and vice versa)"
-    gpt = arch == "gpt"
-    wbytes = 1 if int8 else 2
-    cb = jnp.dtype(kv_pool.dtype).itemsize
-    ck = BT
-    assert BT % 8 == 0, f"block_tokens {BT} must be a multiple of 8"
-    assert dkv % 128 == 0, f"nkv*hd={dkv} must be a lane multiple of 128"
-    # append-window segments: off <= 7 plus K1 tail tokens, 8-aligned
-    NW = (7 + K1 + 7) // 8
-    if blocks is not None:
-        assert blocks.get("cache_wbytes", cb) == cb, \
-            (f"decode plan assumed a {blocks['cache_wbytes']}-byte KV "
-             f"cache but the pool dtype is {kv_pool.dtype} ({cb} B)")
-        if blocks.get("q_split", 1) != 1:
-            raise ValueError(
-                "paged verify does not support the q-split (big-model) "
-                "regime yet; build the plan with q_split=1")
-        J, fblk = blocks["ffn_blocks"], blocks["fblk"]
-        assert ffn == J * fblk, (ffn, blocks)
-    else:
-        J, fblk = _pick_ffn_blocks(
-            ffn, h, fixed_bytes=(dqkv + dq) * h * wbytes, wbytes=wbytes)
-    dtype = x.dtype
-    scale = 1.0 / math.sqrt(hd)
-
-    def kernel(*refs):
-        if gpt:
-            (pos_ref, bt_ref, posv_ref, x_in_ref, ln1_ref, wqkv_ref,
-             wo_ref, ln2_ref, wg_ref, wd_ref) = refs[:10]
-            wu_ref = None
-            i = 10
-            (ln1b_ref, ln2b_ref, bqkv_ref, bo_ref, bg_ref,
-             bd_ref) = refs[i:i + 6]
-            i += 6
-        else:
-            (pos_ref, bt_ref, posv_ref, x_in_ref, ln1_ref, wqkv_ref,
-             wo_ref, ln2_ref, wg_ref, wu_ref, wd_ref) = refs[:11]
-            i = 11
-        if int8:
-            sqkv_ref, so_ref, sg_ref, su_ref, sd_ref = refs[i:i + 5]
-            i += 5
-        if kvq:
-            kvs_ref = refs[i]          # (b, 2*dkv) per-SLOT pool scales
-            i += 1
-        kv_in = refs[i]
-        x_out_ref, kv_ref = refs[i + 1], refs[i + 2]
-        (x_s, xn_s, acc_s, q_s, kv32_s, kvtl_s, kvch_s,
-         wsem, rsem) = refs[i + 3:]
-        del kv_in
-
-        def wdot(act, wref, sref):
-            w = wref[...]
-            if int8:
-                y = jnp.dot(act, w.astype(act.dtype),
-                            preferred_element_type=jnp.float32)
-                return y if sref is None else y * sref[...]
-            return jnp.dot(act, w, preferred_element_type=jnp.float32)
-
-        li = pl.program_id(0)
-        j = pl.program_id(1)
-
-        # ---- per-row paged DMA descriptors (block table in SMEM) ----
-        def seg_src(l, r, m):
-            """The m-th 8-token segment of row r's append window,
-            resolved through its block table; past-the-table segments
-            (over-speculation near the cap) redirect to scratch."""
-            q0 = pos_ref[r] // 8 * 8 + m * 8
-            c = q0 // BT
-            bid = jnp.where(c < MB, bt_ref[r, jnp.minimum(c, MB - 1)], 0)
-            return kv_ref.at[l, bid, pl.ds(q0 % BT, 8)]
-
-        def seg_read(l, r, m):
-            return pltpu.make_async_copy(
-                seg_src(l, r, m), kvtl_s.at[r, pl.ds(m * 8, 8)],
-                wsem.at[m, r])
-
-        def seg_write(l, r, m):
-            return pltpu.make_async_copy(
-                kvtl_s.at[r, pl.ds(m * 8, 8)], seg_src(l, r, m),
-                wsem.at[m, r])
-
-        def chunk_copy(l, c, slot, r):
-            return pltpu.make_async_copy(
-                kv_ref.at[l, bt_ref[r, c]], kvch_s.at[slot, r],
-                rsem.at[slot, r])
-
-        # chunk walk bound: the LONGEST row's committed full-8 prefix
-        nc = (pos_ref[0] // 8 * 8 + ck - 1) // ck
-        for r in range(1, b):
-            nc = jnp.maximum(nc, (pos_ref[r] // 8 * 8 + ck - 1) // ck)
-
-        @pl.when(j == 0)
-        def attention_phase():
-            posv = posv_ref[...]                        # (b, 1) int32
-            blk_v = posv // 8 * 8
-            blk3 = blk_v.reshape(b, 1, 1)
-
-            @pl.when(li == 0)
-            def _():
-                x_s[...] = x_in_ref[...].astype(jnp.float32)
-                q_s[...] = jnp.zeros_like(q_s)
-                for r in range(b):
-                    for m in range(NW):
-                        seg_read(li, r, m).start()
-
-                @pl.when(nc > 0)
-                def _():
-                    for r in range(b):
-                        chunk_copy(li, 0, 0, r).start()
-
-            if gpt:
-                xn = _layernorm(x_s[...], ln1_ref[...].reshape(h),
-                                ln1b_ref[...].reshape(h), eps)
-            else:
-                xn = _rms(x_s[...], ln1_ref[...].reshape(h), eps)
-            qkv = wdot(xn, wqkv_ref, sqkv_ref if int8 else None)
-            if gpt:
-                qkv = qkv + bqkv_ref[...]
-            half = (lax.broadcasted_iota(jnp.int32, (1, hd), 1)
-                    % (hd // 2)).astype(jnp.float32)
-            inv_freq = jnp.exp(half * (-2.0 * math.log(rope_base) / hd))
-            # per-(token, row) staging: token t's heads land in q rows
-            # [t*nh, (t+1)*nh) block-diagonally; its k/v in kv32_s[:, t]
-            for t in range(K1):
-                seg = qkv[t * b:(t + 1) * b]            # (b, dqkv)
-                if gpt:
-                    rope2 = lambda v: v                 # noqa: E731
-                else:
-                    ang = (posv + t).astype(jnp.float32) * inv_freq
-                    cos_b = jnp.cos(ang)
-                    sin_b = jnp.sin(ang)
-                    rope2 = lambda v: (v * cos_b + jnp.concatenate(
-                        [-v[:, hd // 2:], v[:, :hd // 2]],
-                        axis=-1) * sin_b)               # noqa: E731
-                for n in range(nh):
-                    g = n // rep
-                    q_s[:, t * nh + n, g * hd:(g + 1) * hd] = rope2(
-                        seg[:, n * hd:(n + 1) * hd]) * scale
-                for g in range(nkv):
-                    kv32_s[:, t, g * hd:(g + 1) * hd] = rope2(
-                        seg[:, dq + g * hd:dq + (g + 1) * hd])
-                    kv32_s[:, t, dkv + g * hd:dkv + (g + 1) * hd] = \
-                        seg[:, dq + dkv + g * hd:dq + dkv + (g + 1) * hd]
-
-            if kvq:     # per-slot k-half dequant scales fold into q rows
-                qbd = q_s[...] * kvs_ref[...][:, :dkv][:, None]
-            else:
-                qbd = q_s[...]
-
-            def merge(carry, kvblk, idx, limit):
-                """Online-softmax block update over all K1*nh queries;
-                `limit` is per-(row, query) — the causal tail masks
-                query j to its own position."""
-                m, l, acc = carry
-                kf = kvblk[:, :, :dkv].astype(jnp.float32)
-                vf = kvblk[:, :, dkv:].astype(jnp.float32)
-                sc = lax.dot_general(
-                    qbd, kf, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)  # (b, K1*nh, w)
-                sc = jnp.where(idx < limit, sc, NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
-                alpha = jnp.exp(m - m_new)
-                pp = jnp.exp(sc - m_new[..., None])
-                acc = acc * alpha[..., None] + lax.dot_general(
-                    pp, vf, (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)
-                return m_new, l * alpha + jnp.sum(pp, axis=-1), acc
-
-            def body(c, carry):
-                slot = lax.rem(c, 2)
-
-                @pl.when(c + 1 < nc)
-                def _():
-                    for r in range(b):
-                        chunk_copy(li, c + 1, lax.rem(c + 1, 2), r).start()
-
-                for r in range(b):
-                    chunk_copy(li, c, slot, r).wait()
-                idx = c * ck + lax.broadcasted_iota(
-                    jnp.int32, (1, 1, ck), 2)
-                # every tail query attends the whole committed prefix
-                return merge(carry, kvch_s[slot], idx, blk3)
-
-            carry = lax.fori_loop(0, nc, body, (
-                jnp.full((b, K1 * nh), NEG_INF, jnp.float32),
-                jnp.zeros((b, K1 * nh), jnp.float32),
-                jnp.zeros((b, K1 * nh, dkv), jnp.float32)))
-
-            # merge the K1 tail tokens into the append window at
-            # offsets off+t, attend it with per-query causal limits,
-            # write the segments back (waited in FFN j==1)
-            for r in range(b):
-                for m in range(NW):
-                    seg_read(li, r, m).wait()
-            off3 = (posv - blk_v).reshape(b, 1, 1)
-            wi = lax.broadcasted_iota(jnp.int32, (1, NW * 8, 1), 1)
-            win = kvtl_s[...].astype(jnp.float32)
-            newtok = kv32_s[...]                        # (b, K1, 2dkv)
-            if kvq:     # quantize the appends with the per-slot scales
-                newtok = jnp.clip(
-                    jnp.round(newtok / kvs_ref[...][:, None]),
-                    -127.0, 127.0)
-            for t in range(K1):
-                win = jnp.where(wi == off3 + t, newtok[:, t][:, None],
-                                win)
-            kvtl_s[...] = win.astype(kv_pool.dtype)
-            for r in range(b):
-                for m in range(NW):
-                    seg_write(li, r, m).start()
-            widx = blk3 + lax.broadcasted_iota(
-                jnp.int32, (1, 1, NW * 8), 2)
-            # query t of each row masks to its own position pos+t
-            jq = (lax.broadcasted_iota(jnp.int32, (1, K1 * nh, 1), 1)
-                  // nh)
-            ms_, ls, accs = merge(carry, kvtl_s[...], widx,
-                                  posv.reshape(b, 1, 1) + jq + 1)
-
-            norm = accs / ls[..., None]             # (b, K1*nh, dkv)
-            if kvq:     # per-slot v-half dequant scales, applied once
-                norm = norm * kvs_ref[...][:, dkv:][:, None]
-            # o-proj per tail token over its static head-row slice
-            for t in range(K1):
-                nt = norm[:, t * nh:(t + 1) * nh, :]    # (b, nh, dkv)
-                if rep == 1:
-                    bd = (lax.broadcasted_iota(
-                        jnp.int32, (1, nh, dkv), 2) // hd
-                        == lax.broadcasted_iota(
-                            jnp.int32, (1, nh, dkv), 1))
-                    attn = jnp.sum(jnp.where(bd, nt, 0.0), axis=1)
-                    oacc = wdot(attn.astype(dtype), wo_ref,
-                                so_ref if int8 else None)
-                else:
-                    oacc = jnp.zeros((b, h), jnp.float32)
-                    for g in range(nkv):
-                        ng = nt[:, g * rep:(g + 1) * rep,
-                                g * hd:(g + 1) * hd]
-                        w3 = wo_ref[g * rep * hd:(g + 1) * rep * hd,
-                                    :].reshape(rep, hd, h)
-                        part = lax.dot_general(
-                            ng.astype(dtype),
-                            w3.astype(dtype) if int8 else w3,
-                            (((2,), (1,)), ((1,), (0,))),
-                            preferred_element_type=jnp.float32)
-                        oacc = oacc + jnp.sum(part, axis=0)
-                    if int8:
-                        oacc = oacc * so_ref[...]
-                if gpt:
-                    oacc = oacc + bo_ref[...]
-                x_s[t * b:(t + 1) * b, :] = \
-                    x_s[t * b:(t + 1) * b, :] + oacc
-            xr = x_s[...]
-            if gpt:
-                xn_s[...] = _layernorm(xr, ln2_ref[...].reshape(h),
-                                       ln2b_ref[...].reshape(h),
-                                       eps).astype(dtype)
-            else:
-                xn_s[...] = _rms(xr, ln2_ref[...].reshape(h),
-                                 eps).astype(dtype)
-            acc_s[...] = jnp.zeros_like(acc_s)
-
-        @pl.when(j >= 1)
-        def ffn_phase():
-            @pl.when(j == 1)
-            def prefetch_next_layer():
-                for r in range(b):
-                    for m in range(NW):
-                        seg_write(li, r, m).wait()
-
-                @pl.when(li + 1 < L)
-                def _():
-                    for r in range(b):
-                        for m in range(NW):
-                            seg_read(li + 1, r, m).start()
-
-                    @pl.when(nc > 0)
-                    def _():
-                        for r in range(b):
-                            chunk_copy(li + 1, 0, 0, r).start()
-
-            xn = xn_s[...]
-            g = wdot(xn, wg_ref, sg_ref if int8 else None)
-            if gpt:
-                g = g + bg_ref[...]
-                act = jax.nn.gelu(g, approximate=True).astype(dtype)
-            else:
-                u = wdot(xn, wu_ref, su_ref if int8 else None)
-                act = (jax.nn.silu(g) * u).astype(dtype)
-            acc_s[...] += wdot(act, wd_ref, sd_ref if int8 else None)
-
-            if gpt:
-                @pl.when(j == J)
-                def _():
-                    acc_s[...] += jnp.broadcast_to(bd_ref[...],
-                                                   acc_s.shape)
-
-            @pl.when(j == J)
-            def _():
-                xr = x_s[...] + acc_s[...]
-                x_s[...] = xr
-                x_out_ref[...] = xr.astype(dtype)
-
-    def jm(ll, jj):
-        return jnp.where(jj < 1, J - 1, jj - 1)
-
-    def fl(ll, jj):
-        return lax.max(ll - (jj < 1), 0)
-
-    grid = (L, 1 + J)
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),                 # positions
-        pl.BlockSpec(memory_space=pltpu.SMEM),                 # block table
-        pl.BlockSpec((b, 1), lambda l, j: (0, 0)),             # posv
-        pl.BlockSpec((K1b, h), lambda l, j: (0, 0)),           # x
-        pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),    # ln1
-        pl.BlockSpec((None, h, dqkv), lambda l, j: (l, 0, 0)),  # wqkv
-        pl.BlockSpec((None, dq, h), lambda l, j: (l, 0, 0)),   # wo
-        pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),    # ln2
-        pl.BlockSpec((None, h, fblk),
-                     lambda l, j: (fl(l, j), 0, jm(l, j))),     # wg
-    ] + ([] if gpt else [
-        pl.BlockSpec((None, h, fblk),
-                     lambda l, j: (fl(l, j), 0, jm(l, j))),     # wu
-    ]) + [
-        pl.BlockSpec((None, fblk, h),
-                     lambda l, j: (fl(l, j), jm(l, j), 0)),     # wd
-    ] + ([
-        pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),     # ln1_b
-        pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),     # ln2_b
-        pl.BlockSpec((None, 1, dqkv), lambda l, j: (l, 0, 0)),  # bqkv
-        pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),     # bo
-        pl.BlockSpec((None, 1, fblk),
-                     lambda l, j: (fl(l, j), 0, jm(l, j))),     # bg
-        pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),     # bd
-    ] if gpt else []) + ([
-        pl.BlockSpec((None, 1, dqkv), lambda l, j: (l, 0, 0)),  # sqkv
-        pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),     # so
-        pl.BlockSpec((None, 1, fblk),
-                     lambda l, j: (fl(l, j), 0, jm(l, j))),     # sg
-        pl.BlockSpec((None, 1, fblk),
-                     lambda l, j: (fl(l, j), 0, jm(l, j))),     # su
-        pl.BlockSpec((None, 1, h), lambda l, j: (l, 0, 0)),     # sd
-    ] if int8 else []) + ([
-        pl.BlockSpec((None, b, 2 * dkv), lambda l, j: (l, 0, 0)),  # kvs
-    ] if kvq else []) + [
-        pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),      # kv pool
-    ]
-    operands = [
-        jnp.asarray(positions, jnp.int32).reshape(b),
-        jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(positions, jnp.int32).reshape(b, 1),
-        x,
-        params["ln1"][:, None], params["wqkv"], params["wo"],
-        params["ln2"][:, None], params["wg"],
-        *(() if gpt else (params["wu"],)),
-        params["wd"],
-        *((params["ln1_b"][:, None], params["ln2_b"][:, None],
-           params["bqkv"][:, None], params["bo"][:, None],
-           params["bg"][:, None], params["bd"][:, None]) if gpt else ()),
-        *((params["wqkv_s"], params["wo_s"], params["wg_s"],
-           params["wu_s"], params["wd_s"]) if int8 else ()),
-        *((jnp.asarray(kv_scales, jnp.float32),) if kvq else ()),
-        kv_pool,
-    ]
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((K1b, h), lambda l, j: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((K1b, h), dtype),
-            jax.ShapeDtypeStruct(kv_pool.shape, kv_pool.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((K1b, h), jnp.float32),        # x_s
-            pltpu.VMEM((K1b, h), dtype),              # xn_s
-            pltpu.VMEM((K1b, h), jnp.float32),        # acc_s
-            pltpu.VMEM((b, K1 * nh, dkv), jnp.float32),   # q_s
-            pltpu.VMEM((b, K1, 2 * dkv), jnp.float32),    # kv32_s
-            pltpu.VMEM((b, NW * 8, 2 * dkv), kv_pool.dtype),  # kvtl_s
-            pltpu.VMEM((2, b, ck, 2 * dkv), kv_pool.dtype),   # kvch_s
-            pltpu.SemaphoreType.DMA((NW, b)),         # wsem (seg, row)
-            pltpu.SemaphoreType.DMA((2, b)),          # rsem (slot, row)
-        ],
-        input_output_aliases={len(in_specs) - 1: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit_bytes()),
-        name="fused_paged_verify_step",
-        interpret=interpret,
-    )(*operands)
-    return out[0], out[1]
-
-
 def fused_paged_verify_step(x, params, kv_pool, block_tables, positions,
                             cos, sin, *, num_heads: int, num_kv_heads: int,
                             eps: float = 1e-5, rope_base: float = 10000.0,
@@ -3135,8 +2771,8 @@ def fused_paged_verify_step(x, params, kv_pool, block_tables, positions,
                             blocks: Optional[Dict] = None, kv_scales=None,
                             mp_axis: Optional[str] = None):
     """Dispatch one PAGED verify step (speculative decoding's scoring
-    pass): Pallas kernel on TPU (or under FLAGS_pallas_interpret), jnp
-    verify reference elsewhere.
+    pass): the paged Pallas kernel with a tail of K1 tokens on TPU (or
+    under FLAGS_pallas_interpret), jnp verify reference elsewhere.
 
     x (b, K1, h) — the K1 tail tokens (the slot's last sampled token
     followed by its K proposals) embedded at positions ``positions + j``;
@@ -3147,35 +2783,19 @@ def fused_paged_verify_step(x, params, kv_pool, block_tables, positions,
     longest proposal prefix that matches its own stream's samples —
     docs/SERVING.md §Speculative decoding.
     """
-    from paddle_tpu.core.flags import flag
-    from paddle_tpu.ops import use_pallas
-    if arch not in ("llama", "gpt"):
-        raise NotImplementedError(
-            f"paged verify supports arch llama/gpt, got {arch!r}")
     b, K1, h = x.shape
-    dkv = kv_pool.shape[-1] // 2
-    BT = kv_pool.shape[2]
-    # tpu-lint: allow(host-sync): flag() is a host-side config read
-    interp = bool(flag("FLAGS_pallas_interpret")) and not use_pallas()
-    if mp_axis is None and (use_pallas() or interp) and dkv % 128 == 0 \
-            and BT % 8 == 0:
-        cb = jnp.dtype(kv_pool.dtype).itemsize
-        if blocks is not None and blocks.get("cache_wbytes", cb) != cb:
-            raise ValueError(
-                f"decode plan assumed a {blocks['cache_wbytes']}-byte KV "
-                f"cache but the pool dtype is {kv_pool.dtype} ({cb} B); "
-                f"rebuild the plan with decode_block_plan(cache_wbytes="
-                f"{cb})")
+    interp = _paged_pallas_interpret(kv_pool, arch, blocks, mp_axis)
+    if interp is not None:
         with jax.named_scope("fused_decode.kernel_paged_verify"):
             # token-major flat: token j's rows contiguous at [j*b,
             # (j+1)*b) so the kernel's per-token stages are static
             # slices
             xf = x.transpose(1, 0, 2).reshape(K1 * b, h)
-            y, pool = _fused_paged_verify_pallas(
+            y, pool = _fused_paged_decode_pallas(
                 xf, params, kv_pool, block_tables, positions,
                 num_heads=num_heads, num_kv_heads=num_kv_heads,
-                head_dim=dkv // num_kv_heads, rope_base=rope_base,
-                eps=eps, arch=arch, blocks=blocks,
+                head_dim=kv_pool.shape[-1] // 2 // num_kv_heads,
+                rope_base=rope_base, eps=eps, arch=arch, blocks=blocks,
                 kv_scales=kv_scales, interpret=interp)
             return y.reshape(K1, b, h).transpose(1, 0, 2), pool
     with jax.named_scope("fused_decode.reference_paged_verify"):

@@ -89,11 +89,12 @@ Speculative decoding (``speculate=SpecConfig(...)``; docs/SERVING.md
 chunked prefill, decode's remaining cost is its *serial step count* —
 every token pays one full weight stream. With speculation armed, each
 tick verifies k proposed tokens per active slot in ONE
-``fused_paged_verify_step`` dispatch (the kernel's KV chunk walk plus a
-k-token causal tail) and commits the longest proposal prefix that
-matches the engine's OWN samples — token-exact acceptance off each
-slot's ``fold_in(seed, count)`` stream, so committed tokens are
-bit-identical to the non-speculative engine (and to isolated
+``fused_paged_verify_step`` dispatch (the paged decode kernel itself,
+given a tail of k+1 tokens a slot: the same walk of each row's own
+blocks, then a causal append window) and commits the longest proposal
+prefix that matches the engine's OWN samples — token-exact acceptance
+off each slot's ``fold_in(seed, count)`` stream, so committed tokens
+are bit-identical to the non-speculative engine (and to isolated
 ``generate``; tests/test_serving_spec.py pins greedy+sampled ×
 bf16+int8, through preempt/resume and snapshot/restore). Proposals come
 from a device-side per-slot n-gram matcher (no extra model, zero
@@ -4819,8 +4820,14 @@ class ServingEngine:
         ``ops.fused_decode.paged_walk`` builds (``paged_walk_blocks``) from
         the host's positions as the tick leaves them, which are what the
         next step program reads (one a step, whichever of two
-        neighbouring steps it is). A verify step walks densely and an
-        engine with its own step has no such kernel: neither counts."""
+        neighbouring steps it is). A verify step runs the same kernel and
+        the same walk, but its program advances EVERY row's position
+        (``pos2 = min(positions + acc + 1, cap)``, idle rows included;
+        only `_decode_body` leaves a scratch row where it is), so between
+        uploads the device walks idle rows that the host's mirror holds
+        at 0: the mirror is not what the next verify step reads, and a
+        speculative tick is not counted. An engine with its own step has
+        no such kernel and does not count either."""
         if (self._own_step or not self._tick_landed
                 or self._tick_spec is not None):
             return

@@ -3,7 +3,8 @@
 Decode is memory-bandwidth-bound: every serial decode step streams the
 whole model once to produce ONE token per slot. Speculation trades k
 cheap *proposed* tokens per slot for one batched *verify* pass through
-the fused paged kernel (`ops.fused_decode.fused_paged_verify_step`),
+the fused paged kernel (`ops.fused_decode.fused_paged_verify_step`: the
+decode kernel, given a tail of k+1 tokens a slot instead of one),
 committing however many proposals the engine's own sampling stream
 agrees with — fewer serial dispatches per generated token, bit-identical
 tokens (docs/SERVING.md §Speculative decoding).

@@ -532,29 +532,60 @@ def _paged_setup(arch, int8_pool):
                         kv_scales=scales), hd=hd)
 
 
+@pytest.mark.parametrize("K1", [1, 3], ids=["decode", "tail3"])
 @pytest.mark.parametrize("int8_pool", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("arch", ["gpt", "llama"])
-def test_paged_kernel_ragged_batch(arch, int8_pool):
+def test_paged_kernel_ragged_batch(arch, int8_pool, K1):
     """The paged kernel (interpret mode) on rows of every kind at once,
-    against the jnp reference: the step's output and the pool."""
+    against the jnp reference: the step's output and the pool. A tail of
+    one token is a decode step; a tail of three is a verify step, whose
+    row at `max_seq_len` - 1 appends two tokens past its table (they go
+    to the scratch block, which is left out of the comparison)."""
     s = _paged_setup(arch, int8_pool)
-    xr, pr = jax.jit(lambda x, p, pool: fd.fused_paged_decode_reference(
-        x, p, pool, s["tables"], s["positions"], s["cos"], s["sin"],
-        **s["kw"]))(s["x"], s["params"], s["pool"])
+    b, h = s["x"].shape
+    tables, positions = s["tables"], s["positions"]
+    BT = s["pool"].shape[2]
+    cap = tables.shape[1] * BT
+    if K1 == 1:
+        x, ref, cos, sin = (s["x"], fd.fused_paged_decode_reference,
+                            s["cos"], s["sin"])
+    else:
+        x = jnp.asarray(np.random.RandomState(4).randn(b, K1, h) * 0.05,
+                        jnp.bfloat16)
+        ref = fd.fused_paged_verify_reference
+        tail = positions[:, None] + np.arange(K1)
+        cos, sin = rope_cos_sin(cap + K1, s["hd"])
+        cos, sin = cos[tail], sin[tail]
+    xr, pr = jax.jit(lambda x, p, pool: ref(
+        x, p, pool, tables, positions, cos, sin, **s["kw"]))(
+        x, s["params"], s["pool"])
+    # the kernel takes the tail token-major flat
     xk, pk = jax.jit(lambda x, p, pool: fd._fused_paged_decode_pallas(
-        x, p, pool, s["tables"], s["positions"], head_dim=s["hd"],
-        interpret=True, **s["kw"]))(s["x"], s["params"], s["pool"])
-    np.testing.assert_allclose(np.asarray(xk, np.float32),
-                               np.asarray(xr, np.float32),
-                               rtol=2e-2, atol=2e-2)
-    pr, pk = np.asarray(pr, np.float32), np.asarray(pk, np.float32)
+        x, p, pool, tables, positions, head_dim=s["hd"], interpret=True,
+        **s["kw"]))(
+        x if K1 == 1 else x.transpose(1, 0, 2).reshape(K1 * b, h),
+        s["params"], s["pool"])
+    xk, xr = np.asarray(xk, np.float32), np.asarray(xr, np.float32)
+    if K1 > 1:
+        # a tail token past the cap has no place in the table: its output
+        # is garbage by contract (the engine never commits it)
+        inside = (tail < cap)[..., None]
+        xk = np.where(inside, xk.reshape(K1, b, h).transpose(1, 0, 2), 0)
+        xr = np.where(inside, xr, 0)
+    np.testing.assert_allclose(xk, xr, rtol=2e-2, atol=2e-2)
+    # block 0 is the scratch block: with a tail, what idle rows and the
+    # row at the cap append there collides, in no order that is promised
+    lo = 0 if K1 == 1 else 1
+    pr, pk = np.asarray(pr, np.float32)[:, lo:], \
+        np.asarray(pk, np.float32)[:, lo:]
     np.testing.assert_allclose(pk, pr, atol=1.0 if int8_pool else 2e-2)
     # nothing but the appended rows is written
-    p0 = np.asarray(s["pool"], np.float32)
-    BT = p0.shape[2]
-    for i, pos in enumerate(s["positions"]):
-        p0[:, s["tables"][i, pos // BT], pos % BT] = \
-            pk[:, s["tables"][i, pos // BT], pos % BT]
+    p0 = np.asarray(s["pool"], np.float32)[:, lo:]
+    for i, pos in enumerate(positions):
+        for q in range(pos, min(pos + K1, cap)):
+            bid = tables[i, q // BT] - lo
+            if bid >= 0:
+                p0[:, bid, q % BT] = pk[:, bid, q % BT]
     np.testing.assert_array_equal(pk, p0)
 
 

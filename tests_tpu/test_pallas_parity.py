@@ -1232,12 +1232,17 @@ def _paged_cell(arch, b, L):
     return shapes, (L, b * MB + 1, 128, 2 * dkv), MB, hd, h, kw, blocks
 
 
+@pytest.mark.parametrize("K1", [1, 5], ids=["decode", "tail5"])
 @pytest.mark.parametrize("arch, b", [("gpt", 32), ("llama", 16)])
-def test_paged_decode_ragged_parity_at_cell_widths(arch, b):
+def test_paged_decode_ragged_parity_at_cell_widths(arch, b, K1):
     """Rows of every kind in one batch, at the two chat cells' widths and
     slot counts: idle against scratch, 5 tokens, on a block boundary, at
     `max_seq_len` - 1, released with an advanced position, and the rest
-    of uneven lengths; the output and the pool against the jnp twin."""
+    of uneven lengths; the output and the pool against the jnp twin. A
+    tail of one token is a decode step, a tail of five the verify step
+    of `speculate` k = 4 (the row at `max_seq_len` - 1 then appends four
+    tokens past its table: to scratch, their outputs garbage by
+    contract)."""
     from paddle_tpu.ops import fused_decode as fd
     from paddle_tpu.ops.rope import rope_cos_sin
     L = 3
@@ -1246,7 +1251,7 @@ def test_paged_decode_ragged_parity_at_cell_widths(arch, b):
               for i, (k, s) in enumerate(sorted(shapes.items()))}
     params["ln1"], params["ln2"] = 1 + params["ln1"], 1 + params["ln2"]
     S = MB * 128
-    pos = np.random.RandomState(0).randint(1, S - 1, b).astype(np.int32)
+    pos = np.random.RandomState(0).randint(1, S - K1, b).astype(np.int32)
     pos[:5] = [0, 5, 256, S - 1, 11]
     live = np.ones(b, bool)
     live[[0, 4, b - 1, b - 3]] = False          # idle or released rows
@@ -1255,41 +1260,62 @@ def test_paged_decode_ragged_parity_at_cell_widths(arch, b):
     for r in np.flatnonzero(live):
         tables[r] = 1 + r * MB + np.arange(MB)
     pool = rand(1, *pool_shape)
-    x = rand(2, b, h)
-    cos, sin = rope_cos_sin(S, hd)
-    want_x, want_pool = jax.jit(
-        lambda x, p, pool: fd.fused_paged_decode_reference(
-            x, p, pool, tables, pos, cos[pos], sin[pos], **kw))(
-                x, params, pool)
+    cos, sin = rope_cos_sin(S + K1, hd)
+    tail = pos[:, None] + np.arange(K1)                      # (b, K1)
+    if K1 == 1:
+        x, ref = rand(2, b, h), fd.fused_paged_decode_reference
+        cos, sin = cos[pos], sin[pos]
+    else:
+        x, ref = rand(2, b, K1, h), fd.fused_paged_verify_reference
+        cos, sin = cos[tail], sin[tail]
+    want_x, want_pool = jax.jit(lambda x, p, pool: ref(
+        x, p, pool, tables, pos, cos, sin, **kw))(x, params, pool)
+    # the kernel takes the tail token-major flat
     got_x, got_pool = jax.jit(
         lambda x, p, pool: fd._fused_paged_decode_pallas(
             x, p, pool, tables, pos, head_dim=hd, blocks=blocks, **kw))(
-                x, params, pool)
-    assert_close(np.asarray(got_x)[live], np.asarray(want_x)[live])
+                x if K1 == 1 else x.transpose(1, 0, 2).reshape(K1 * b, h),
+                params, pool)
+    got_x, want_x = np.asarray(got_x, np.float32), \
+        np.asarray(want_x, np.float32)
+    if K1 > 1:
+        inside = (tail < S)[..., None]
+        got_x = np.where(inside, got_x.reshape(K1, b, h).transpose(1, 0, 2),
+                         0)
+        want_x = np.where(inside, want_x, 0)
+    assert_close(got_x[live], want_x[live])
     got_pool = np.asarray(got_pool, np.float32)
     want_pool = np.asarray(want_pool, np.float32)
     assert_close(got_pool[:, 1:], want_pool[:, 1:], frac=1.0)
-    # no row but the appended ones is written (scratch takes the idle rows')
+    # no row but the appended ones is written (scratch takes the idle rows'
+    # and what a tail appends past its table)
     p0 = np.asarray(pool, np.float32)
     for r in np.flatnonzero(live):
-        p0[:, tables[r, pos[r] // 128], pos[r] % 128] = \
-            got_pool[:, tables[r, pos[r] // 128], pos[r] % 128]
+        for q in range(pos[r], min(pos[r] + K1, S)):
+            p0[:, tables[r, q // 128], q % 128] = \
+                got_pool[:, tables[r, q // 128], q % 128]
     assert (got_pool[:, 1:] == p0[:, 1:]).all()
 
 
-@pytest.mark.parametrize("arch, b", [("gpt", 64), ("llama", 32)])
-def test_paged_decode_compiles_at_twice_the_cells_slots(arch, b):
-    """The kernel's scratch no longer grows with the slots (a ring of
-    block buffers for 1 MiB a slot), so 64 slots at the 345 M widths and
-    32 at the 1.8 B widths, which the dense walk's scratch did not fit,
-    compile at the cells' 24 layers. A fact for PERF.md §7; no cell uses
-    it."""
+@pytest.mark.parametrize("arch, b, K1", [
+    ("gpt", 64, 1), ("llama", 32, 1), ("gpt", 64, 3), ("llama", 32, 3),
+    ("gpt", 48, 5), ("llama", 24, 5)])
+def test_paged_decode_compiles_at_twice_the_cells_slots(arch, b, K1):
+    """The walk's scratch does not grow with the slots (a ring of block
+    buffers for 1 MiB a slot), so 64 slots at the 345 M widths and 32 at
+    the 1.8 B widths, which the dense walk's scratch did not fit, compile
+    at the cells' 24 layers: a decode step, and a verify step of `k` = 2.
+    What grows with slots x K1 is a row's state (`q_s`, `o_s`): a tail of
+    five (`k` = 4) compiles at one and a half times the cells' slots, and
+    at twice them exceeds the scoped VMEM limit by 5.3 and 3.5 MiB of
+    100 (the parent's verify kernel did not fit the cells' own 32 and
+    16). A fact for PERF.md §7; no cell uses it."""
     from paddle_tpu.ops import fused_decode as fd
     shapes, pool_shape, MB, hd, h, kw, blocks = _paged_cell(arch, b, 24)
     bf = jnp.bfloat16
     S = jax.ShapeDtypeStruct
     jax.jit(lambda x, p, pool, t, q: fd._fused_paged_decode_pallas(
         x, p, pool, t, q, head_dim=hd, blocks=blocks, **kw)).lower(
-            S((b, h), bf), {k: S(s, bf) for k, s in shapes.items()},
+            S((K1 * b, h), bf), {k: S(s, bf) for k, s in shapes.items()},
             S(pool_shape, bf), S((b, MB), jnp.int32),
             S((b,), jnp.int32)).compile()
