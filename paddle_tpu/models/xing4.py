@@ -240,20 +240,14 @@ def route(w: Dict, cfg: Xing4Config, x):
 
 
 def moe_ragged(w: Dict, cfg: Xing4Config, x):
-    """x (T, C): the routed experts by sort + ``ragged_dot`` (no token
-    dropped) plus the shared expert. What prefill and ``generate`` run."""
-    t, c = x.shape
-    k, e = cfg.num_experts_per_tok, cfg.n_routed_experts
+    """x (T, C): the routed experts over rows sorted by expert (no token
+    dropped) plus the shared expert. What prefill and ``generate`` run:
+    the grouped kernel or ``ragged_dot``, as
+    ``ops.moe_grouped.prefill_path`` says for these widths here."""
     idx, wts = route(w, cfg, x)
-    flat = idx.reshape(-1)
-    order = jnp.argsort(flat, stable=True)
-    xs = jnp.take(x, order // k, axis=0)
-    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
-    h = jax.lax.ragged_dot(xs, w["experts.w_gate"], sizes)
-    u = jax.lax.ragged_dot(xs, w["experts.w_up"], sizes)
-    ys = jax.lax.ragged_dot(jax.nn.silu(h) * u, w["experts.w_down"], sizes)
-    ys = jnp.zeros_like(ys).at[order].set(ys).reshape(t, k, c)
-    y = jnp.einsum("tk,tkc->tc", wts.astype(x.dtype), ys)
+    y = moe_grouped.moe_grouped_ffn_prefill(
+        x, idx, wts, w["experts.w_gate"], w["experts.w_up"],
+        w["experts.w_down"])
     return y + _swiglu(_sub(w, "shared_experts."), x)
 
 
@@ -575,7 +569,13 @@ class Xing4ForCausalLM(CausalLMBase):
 
         meta = {"arch": "mla_moe", "cache_lanes": lanes,
                 "to_lanes": to_lanes, "from_lanes": from_lanes,
-                "step_counters": STEP_COUNTERS}
+                "step_counters": STEP_COUNTERS,
+                # what a prefill's routed experts go through here
+                "prefill_moe": {
+                    "layers": cfg.num_layers - cfg.first_k_dense_replace,
+                    "k": cfg.num_experts_per_tok,
+                    "path": moe_grouped.prefill_path(
+                        cfg.hidden_size, cfg.moe_intermediate_size)}}
         if probe:
             return meta
 

@@ -17,12 +17,33 @@ a sort, a gather and a scatter a layer.
 
 ``moe_grouped_ffn_reference`` is the same sum in plain ``jnp`` (every
 expert, masked): the CPU path and the parity oracle.
+
+A prefill is the other case: thousands of rows, each through ``k``
+experts, so multiplying every row by every touched expert would be
+``E / k`` times the flops. There the rows ARE gathered by expert
+(``moe_grouped_ffn_prefill`` in traces): each expert's group starts at a
+row the DMA engine can address (a multiple of ``_ROW_ALIGN``), the grid
+walks the touched experts, an expert's three matrices cross HBM once
+(fetched whole into one of two VMEM slots while the expert before it
+computes), and its rows stream past them in tiles of ``_row_tile`` rows
+with a trip count of ``ceil(rows / tile)``. A group's last tile runs over
+into the rows behind it; they are another expert's, whose own tiles are
+written later and in order, so nothing is masked. The wrapper is traced
+for a few token counts only (``_token_bucket``; the rows that fill a
+bucket pick no expert). ``prefill_path`` says
+which of the two a prefill takes here; ``moe_prefill_ragged_dot`` (sort
++ ``jax.lax.ragged_dot``) is the path elsewhere and the parity oracle.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 KERNEL_NAME = "moe_grouped_ffn_decode"
+PREFILL_KERNEL_NAME = "moe_grouped_ffn_prefill"
+_ROW_TILE = 128     # rows of one pass over an expert's weights (_row_tile)
+_ROW_ALIGN = 16     # a group starts on a whole (16, 128) bf16 tile
 
 
 def dense_weights(idx, w, active, num_experts: int):
@@ -152,3 +173,238 @@ def moe_grouped_ffn_decode(x, dense, wg, wu, wd):
         return _moe_grouped_ffn_pallas(x, dense, wg, wu, wd,
                                        interpret=interp)
     return moe_grouped_ffn_reference(x, dense, wg, wu, wd)
+
+
+def prefill_path(hidden: int, ffn: int) -> str:
+    """``"kernel"`` or ``"ragged_dot"``: what the routed experts of a
+    prefill of these widths go through HERE. The kernel on a TPU (or
+    under ``FLAGS_pallas_interpret``) where it tiles the widths (both
+    multiples of 128), ``jax.lax.ragged_dot`` elsewhere. Decided from
+    backend and widths when a program is traced; nothing else asks."""
+    from paddle_tpu.core.flags import flag
+    from paddle_tpu.ops import use_pallas
+    # tpu-lint: allow(host-sync): flag() is a host-side config read
+    if not (use_pallas() or bool(flag("FLAGS_pallas_interpret"))):
+        return "ragged_dot"
+    return "kernel" if hidden % 128 == 0 and ffn % 128 == 0 else "ragged_dot"
+
+
+def moe_prefill_ragged_dot(x, idx, wts, wg, wu, wd):
+    """x (T, C), idx and wts (T, k) -> ``sum_k wts[t, k] SwiGLU_idx[t, k]
+    (x_t)`` (T, C) by sort + ``jax.lax.ragged_dot`` (no token dropped)."""
+    t, c = x.shape
+    k = idx.shape[1]
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    xs = jnp.take(x, order // k, axis=0)
+    sizes = jnp.bincount(flat, length=wg.shape[0]).astype(jnp.int32)
+    h = jax.lax.ragged_dot(xs, wg, sizes)
+    u = jax.lax.ragged_dot(xs, wu, sizes)
+    ys = jax.lax.ragged_dot(jax.nn.silu(h) * u, wd, sizes)
+    ys = jnp.zeros_like(ys).at[order].set(ys).reshape(t, k, c)
+    return jnp.einsum("tk,tkc->tc", wts.astype(x.dtype), ys)
+
+
+def _row_tile(rows: int, num_experts: int) -> int:
+    """Rows of one pass over an expert's weights: 256 where the mean
+    group fills them (at the cell's widths a pass of 256 costs the v5e's
+    matrix unit 35 us and two of 128 cost 49, PERF.md §6, PR 30), else
+    128, a pass of which hides behind the 27 us its expert's 22 MB take
+    to arrive."""
+    return 2 * _ROW_TILE if rows >= _ROW_TILE * num_experts else _ROW_TILE
+
+
+def _token_bucket(tokens: int) -> int:
+    """Tokens the kernel's wrapper is traced for: the next power of two,
+    1,024 at least. Tracing the wrapper costs a serving host half a
+    second, and an engine has a prefill program a prompt bucket (14 in
+    the cell), so they share four traces; what a bucket adds to a call
+    is a longer sort and some unread rows in two gathers."""
+    return max(1024, 1 << (tokens - 1).bit_length())
+
+
+# jitted on its own so that a program's expert layers, and the programs
+# of one token bucket, share ONE trace and one lowering of the kernel
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
+    """``idx`` may hold ``E`` (no expert): such a pick joins no group,
+    and its row of the result is whatever the gather finds."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, C = x.shape
+    k = idx.shape[1]
+    E, _, F = wg.shape
+    R, al = t * k, _ROW_ALIGN
+    # every group rounded up to ``al``, and one tile of overrun
+    N = R + E * (al - 1)
+    Rp = -(-N // al) * al + tm
+
+    flat = idx.reshape(-1)
+    sizes = (flat[:, None] == jnp.arange(E, dtype=flat.dtype)).sum(
+        0, dtype=jnp.int32)
+    asize = -(-sizes // al) * al
+    astart = jnp.cumsum(asize) - asize
+    ntile = -(-sizes // tm)
+    voff = jnp.cumsum(ntile) - ntile
+    # the aligned layout in ONE stable sort by expert: behind the R
+    # picks go ``al - 1`` fillers an expert, of which a group keeps what
+    # rounds it up to ``al`` (the rest sort past the last group; a filler
+    # holds token 0's row, which nothing reads). Sorts and row gathers
+    # only: a gather or scatter of R scalars costs the TPU's compiler
+    # seconds a layer.
+    experts = jnp.arange(E, dtype=jnp.int32)[:, None]
+    kept = (jnp.arange(al - 1, dtype=jnp.int32)[None, :]
+            < (asize - sizes)[:, None])
+    keys = jnp.concatenate([flat.astype(jnp.int32),
+                            jnp.where(kept, experts, E).reshape(-1)])
+    token = jnp.pad(jnp.arange(R, dtype=jnp.int32) // k, (0, N - R))
+    here = jnp.arange(N, dtype=jnp.int32)
+    _, token, came_from = jax.lax.sort((keys, token, here), num_keys=1,
+                                       is_stable=True)
+    xs = x.at[jnp.pad(token, (0, Rp - N))].get(
+        mode="promise_in_bounds")                            # (Rp, C)
+    # each pick's row in it: the inverse of that sort
+    at = jax.lax.sort((came_from, here), num_keys=1)[1][:R].reshape(t, k)
+    # the touched experts, in order, then the last of them again
+    nt = (sizes > 0).sum(dtype=jnp.int32)
+    by_touch = jnp.argsort(sizes == 0, stable=True).astype(jnp.int32)
+    eids = jnp.where(jnp.arange(E) < nt, by_touch,
+                     by_touch[jnp.maximum(nt - 1, 0)])
+
+    def kernel(eids_ref, nt_ref, astart_ref, ntile_ref, voff_ref,
+               x_hbm, wg_hbm, wu_hbm, wd_hbm, o_hbm,
+               wg_s, wu_s, wd_s, x_s, o_s, wsem, xsem, osem):
+        g = pl.program_id(0)
+        n_touched = nt_ref[0]
+
+        def weights(e, slot):
+            return (pltpu.make_async_copy(wg_hbm.at[e], wg_s.at[slot],
+                                          wsem.at[slot, 0]),
+                    pltpu.make_async_copy(wu_hbm.at[e], wu_s.at[slot],
+                                          wsem.at[slot, 1]),
+                    pltpu.make_async_copy(wd_hbm.at[e], wd_s.at[slot],
+                                          wsem.at[slot, 2]))
+
+        def rows(e, j):
+            return pl.ds(pl.multiple_of(astart_ref[e] + j * tm, al), tm)
+
+        def x_tile(e, j, slot):
+            return pltpu.make_async_copy(x_hbm.at[rows(e, j)], x_s.at[slot],
+                                         xsem.at[slot])
+
+        def o_tile(e, j):
+            return pltpu.make_async_copy(o_s, o_hbm.at[rows(e, j)],
+                                         osem.at[0])
+
+        @pl.when((g == 0) & (n_touched > 0))
+        def _():
+            first = eids_ref[0]
+            for cp in weights(first, 0):
+                cp.start()
+            x_tile(first, 0, 0).start()
+            # one write is always in flight, so that every tile waits
+            # for the one before it: this first one lands on rows the
+            # first expert's own tiles overwrite
+            o_tile(first, 0).start()
+
+        @pl.when(g < n_touched)
+        def _():
+            e, slot = eids_ref[g], g % 2
+            for cp in weights(e, slot):
+                cp.wait()
+
+            @pl.when(g + 1 < n_touched)
+            def _():
+                for cp in weights(eids_ref[g + 1], 1 - slot):
+                    cp.start()
+
+            nj, v0 = ntile_ref[e], voff_ref[e]
+
+            def tile(j, carry):
+                xslot = (v0 + j) % 2
+                x_tile(e, j, xslot).wait()
+
+                @pl.when(j + 1 < nj)
+                def _():
+                    x_tile(e, j + 1, 1 - xslot).start()
+
+                @pl.when((j + 1 == nj) & (g + 1 < n_touched))
+                def _():
+                    x_tile(eids_ref[g + 1], 0, 1 - xslot).start()
+
+                xv = x_s[xslot]
+                h = jnp.dot(xv, wg_s[slot],
+                            preferred_element_type=jnp.float32)
+                u = jnp.dot(xv, wu_s[slot],
+                            preferred_element_type=jnp.float32)
+                a = (h * jax.nn.sigmoid(h) * u).astype(xv.dtype)
+                y = jnp.dot(a, wd_s[slot],
+                            preferred_element_type=jnp.float32)
+                # writes land in order: a group's last tile runs into
+                # the next groups' rows, which their own tiles rewrite
+                o_tile(e, j).wait()
+                o_s[...] = y.astype(o_s.dtype)
+                o_tile(e, j).start()
+                return carry
+
+            jax.lax.fori_loop(0, nj, tile, 0)
+
+        @pl.when((g == E - 1) & (n_touched > 0))
+        def _():
+            o_tile(eids_ref[0], 0).wait()
+
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(E,),
+        in_specs=[hbm, hbm, hbm, hbm],
+        out_specs=hbm,
+        scratch_shapes=[
+            pltpu.VMEM((2, C, F), wg.dtype),
+            pltpu.VMEM((2, C, F), wu.dtype),
+            pltpu.VMEM((2, F, C), wd.dtype),
+            pltpu.VMEM((2, tm, C), x.dtype),
+            pltpu.VMEM((tm, C), x.dtype),
+            pltpu.SemaphoreType.DMA((2, 3)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    wbytes = jnp.dtype(wg.dtype).itemsize
+    vmem = (2 * 3 * C * F * wbytes + 3 * tm * C * x.dtype.itemsize
+            + tm * (3 * F + 2 * C) * 4 + (8 << 20))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Rp, C), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(vmem)),
+        name=PREFILL_KERNEL_NAME,
+        interpret=interpret,
+    )(eids, nt.reshape(1), astart, ntile, voff, xs, wg, wu, wd)
+    # each token's k rows of the aligned layout, weighted and summed
+    # (one (T, C) gather a pick: a (T, k, C) array pads k to a whole tile)
+    y = sum(wts[:, j, None].astype(jnp.float32)
+            * out.at[at[:, j]].get(mode="promise_in_bounds")
+            for j in range(k))
+    return y.astype(x.dtype)
+
+
+def moe_grouped_ffn_prefill(x, idx, wts, wg, wu, wd):
+    """The routed experts of a prefill: x (T, C), idx (T, k) expert
+    picks and wts (T, k) their routing weights, wg and wu (E, C, F), wd
+    (E, F, C) -> ``sum_k wts[t, k] SwiGLU_idx[t, k](x_t)`` (T, C) in
+    ``x.dtype``. The path is :func:`prefill_path`'s."""
+    if prefill_path(x.shape[1], wg.shape[2]) != "kernel":
+        return moe_prefill_ragged_dot(x, idx, wts, wg, wu, wd)
+    from paddle_tpu.ops import use_pallas
+    t, E = x.shape[0], wg.shape[0]
+    # up to the bucket with tokens that pick no expert and weigh nothing
+    rows = ((0, _token_bucket(t) - t), (0, 0))
+    y = _moe_prefill_pallas(
+        jnp.pad(x, rows), jnp.pad(idx, rows, constant_values=E),
+        jnp.pad(wts, rows), wg, wu, wd,
+        tm=_row_tile(t * idx.shape[1], E), interpret=not use_pallas())
+    return y[:t]

@@ -913,6 +913,10 @@ class ServingEngine:
         # int32 counters the plan's step returns behind the hidden
         # state; they ride the tick's one pull behind the tokens
         self._step_counters = tuple(meta.get("step_counters", ()))
+        # a plan whose prefill routes experts says through what
+        # (``prefill_moe``: expert layers, k, path), so that a wave's
+        # kernel calls and routed rows are counted from its shape alone
+        self._prefill_moe = meta.get("prefill_moe")
         # tpu-lint: volatile(a property of the backend)
         self._host_aliased = jax.default_backend() == "cpu"
         blocks_plan = meta.get("blocks")
@@ -1599,8 +1603,13 @@ class ServingEngine:
         (the fused paged kernel's engines only) sum, over the plain and
         fused-chunk steps landed, the (row, block) pairs of the decode
         kernel's walk and what a walk of every slot to the longest row's
-        length would cover (:meth:`_record_walk`). Per-step distributions
-        live in the ``serving.step_*_s`` registry histograms."""
+        length would cover (:meth:`_record_walk`). ``prefill_moe_calls``
+        and ``prefill_moe_rows`` (a plan with ``prefill_moe``) sum, over
+        the waves landed, the expert layers that went through the grouped
+        prefill kernel and their routed rows, pad positions included
+        (:meth:`_wave_moe`); both stay 0 where the path is ``ragged_dot``.
+        Per-step distributions live in the ``serving.step_*_s`` registry
+        histograms."""
         return dict(steps=0, decode_tokens=0, idle_slot_steps=0,
                     prefill_tokens=0, prefill_tokens_reused=0,
                     prefill_chunks=0, replay_tokens=0,
@@ -1620,6 +1629,8 @@ class ServingEngine:
                     lookahead_ticks=0, lookahead_discarded_tokens=0,
                     **({} if self._own_step else
                        dict(kv_blocks_walked=0, kv_blocks_dense=0)),
+                    **({} if self._prefill_moe is None else
+                       dict(prefill_moe_calls=0, prefill_moe_rows=0)),
                     **{name: 0 for name in self._step_counters})
 
     def reset_stats(self):
@@ -3247,14 +3258,28 @@ class ServingEngine:
             wave.append((slot_idx, slot, hits, R, s_pad))
             wave_idx.add(slot_idx)
 
+    def _wave_moe(self, s_pad: int, n: int) -> Dict:
+        """What a wave of ``n`` rows of ``s_pad`` positions sends through
+        the grouped prefill kernel: one call an expert layer, k routed
+        rows a position a layer (pad positions route too). Known from
+        the wave's shape and the plan's ``prefill_moe``; nothing where
+        the plan routes no experts."""
+        pm = self._prefill_moe
+        if pm is None:
+            return {}
+        calls = pm["layers"] if pm["path"] == "kernel" else 0
+        return dict(prefill_moe_calls=calls,
+                    prefill_moe_rows=calls * pm["k"] * s_pad * n)
+
     def _run_prefill_group(self, R, s_pad, grp):
         """Run one batched prefill program and adopt each row's slot
         into the running decode batch. The whole group (program + host
         pulls + slot adoption) is timed as the step's wave-prefill
         segment."""
         n = len(grp)
+        moe = self._wave_moe(s_pad, n)
         with self._phase("serving.step.prefill", rows=n, s_pad=s_pad,
-                         R=R) as ph:
+                         R=R, **moe) as ph:
             BT = self.block_tokens
             L = self._num_layers
             hb = R // BT
@@ -3321,6 +3346,8 @@ class ServingEngine:
                     None if lanes_np is None else lanes_np[:, r],
                     None if kv_np is None else kv_np[:, r])
             self._tick_prefills.append((R, s_pad, n))
+            for key, v in moe.items():
+                self.stats[key] += v
         if warm:        # compile spikes must not poison the estimator
             new_toks = sum(len(s.feed) - s.R for _, s, _, _, _ in grp)
             self._ewma_prefill_tok.update(ph.dur_s / max(new_toks, 1))

@@ -483,7 +483,7 @@ def test_the_six_phases_partition_event_ticks_that_land_inside_admit(llama):
                                max_new_tokens=40))
     joins = {6: 5, 14: 4}
     six = wall = 0.0
-    kinds = set()
+    kinds, uncovered = set(), []
     for i in range(30):
         if i in joins:
             eng.submit(serving.Request(rng.randint(3, 512, (20,)),
@@ -499,6 +499,7 @@ def test_the_six_phases_partition_event_ticks_that_land_inside_admit(llama):
         assert sum(d[k] for k in SIX) <= dt
         six += sum(d[k] for k in SIX)
         wall += dt
+        uncovered.append((dt - sum(d[k] for k in SIX), dt))
         assert 0.0 <= d["step_upload_s"] <= d["step_admit_s"]
         for key, field in (("step_admit_s", "t_admit_s"),
                            ("step_prefill_s", "t_prefill_s"),
@@ -514,7 +515,15 @@ def test_the_six_phases_partition_event_ticks_that_land_inside_admit(llama):
     # upload) and the steady tick, each with a program in flight before
     assert {(True, True, False, 1), (False, True, False, 1),
             (False, False, True, 1)} <= kinds
-    assert 0.97 * wall <= six <= wall
+    assert six <= wall
+    # what no phase covers is the few lines between two phases' clock
+    # reads (30 us a tick here). Another process can be given the core
+    # between any two reads, and five other workers load it, so the
+    # partition is judged tick by tick with an absolute slack, and four
+    # ticks in five have to meet it: a phase left untimed would show in
+    # every tick, a pre-emption shows in the one it hit
+    met = sum(gap <= 0.03 * dt + 60e-6 for gap, dt in uncovered)
+    assert met >= 24, sorted(uncovered)[-8:]
     eng.close()
 
 

@@ -1,0 +1,55 @@
+"""Kernels of the main path compiled at their real widths for a v5e
+that is described and not attached (the TPU's compiler is installed
+here): what Mosaic refuses (a slice off the tiling, too much VMEM) it
+refuses here, at no chip time. Nothing runs, so nothing here says a
+result is right or fast.
+
+Every such compile belongs in THIS file: only one process at a time may
+hold the TPU's library, the topology is described inside a fixture (so
+every xdist worker collects the same tests and only the one given this
+file loads the library), and where it cannot be described the tests
+skip.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip(tmp_path_factory):
+    import os
+    # the compiler logs under /tmp unless told otherwise
+    os.environ.setdefault("TPU_LOG_DIR", str(tmp_path_factory.mktemp("tpu")))
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1), num_slices=1)
+    except Exception as e:  # noqa: BLE001 — no compiler, or its lock held
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("positions, tile", [(1024, 128), (3584, 256)])
+def test_moe_prefill_kernel_compiles_at_the_cells_widths(one_chip, positions,
+                                                         tile):
+    """``moe_grouped_ffn_prefill`` at 64 experts of 3584 x 1024, top-4,
+    over the cell's median and largest bucket (both row tiles): whole
+    experts in two VMEM slots, row tiles DMA'd from 16-aligned offsets."""
+    from paddle_tpu.ops import moe_grouped as mg
+    E, C, F, k = 64, 3584, 1024, 4
+    assert mg._row_tile(positions * k, E) == tile
+    tokens = mg._token_bucket(positions)    # what the wrapper is traced at
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: mg._moe_prefill_pallas(*a, tm=tile)).lower(
+        shape((tokens, C)), shape((tokens, k), jnp.int32),
+        shape((tokens, k), jnp.float32), shape((E, C, F)),
+        shape((E, C, F)), shape((E, F, C))).compile()
+    text = compiled.as_text()
+    assert mg.PREFILL_KERNEL_NAME in text and "ragged" not in text
+    # the aligned copy of the rows and the kernel's output, no more
+    rows = tokens * k + E * 16 + tile
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * C * 2

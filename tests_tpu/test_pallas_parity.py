@@ -1206,6 +1206,91 @@ def test_moe_grouped_ffn_parity_at_published_widths():
     assert np.abs(np.asarray(want, np.float32)).max() > 0.1
 
 
+@pytest.mark.parametrize("R", [1024, 4096, 14336])
+def test_moe_prefill_parity_at_published_widths(R, monkeypatch):
+    """The grouped prefill kernel at the cell's widths (64 experts of
+    3584 x 1024, top-4) over its smallest, its median and its largest
+    bucket's routed rows, against ``ragged_dot``: a few experts left
+    empty, one a good deal fuller than the rest."""
+    from paddle_tpu.ops import moe_grouped as mg
+    E, C, F, k = 64, 3584, 1024, 4
+    T = R // k
+    x = rand(0, T, C, scale=1.0)
+    wg, wu = rand(1, E, C, F, scale=0.02), rand(2, E, C, F, scale=0.02)
+    wd = rand(3, E, F, C, scale=0.02)
+    score = np.array(jax.random.normal(jax.random.PRNGKey(4), (T, E)))
+    score[:, [0, 17, 63]] = -1e9        # nobody picks these
+    score[:, 5] += 1.0                  # the fullest by far
+    idx = jax.lax.top_k(jnp.asarray(score), k)[1].astype(jnp.int32)
+    w = jnp.asarray(np.random.default_rng(0).uniform(0.2, 0.8, (T, k)),
+                    jnp.float32)
+    sizes = np.bincount(np.asarray(idx).reshape(-1), minlength=E)
+    assert sizes[[0, 17, 63]].sum() == 0 and sizes[5] > 2 * np.median(sizes)
+    want = jax.jit(mg.moe_prefill_ragged_dot)(x, idx, w, wg, wu, wd)
+    got = jax.jit(lambda *a: mg._moe_prefill_pallas(
+        *a, tm=mg._row_tile(R, E)))(x, idx, w, wg, wu, wd)
+    # and through the wrapper, which pads the tokens to their bucket
+    # with picks of no expert
+    monkeypatch.setattr(mg, "prefill_path", lambda hidden, ffn: "kernel")
+    padded = jax.jit(lambda *a: mg.moe_grouped_ffn_prefill(*a))(
+        x, idx, w, wg, wu, wd)
+    assert (np.asarray(padded) == np.asarray(got)).all()
+    top = float(np.abs(np.asarray(want, np.float32)).max())
+    assert top > 0.1
+    assert_close(got, want, rtol=2e-2, atol=2e-2 * top)
+    assert np.abs(np.asarray(got, np.float32)
+                  - np.asarray(want, np.float32)).max() < 0.05 * top
+
+
+def test_serving_xing4_prefill_kernel_against_ragged_dot(monkeypatch):
+    """A Xing4 of moderate widths served twice on the chip: its wave
+    prefills through the grouped kernel, and through ``ragged_dot`` as
+    the parent ran them. Token-exact, or parting at a near-tie of the
+    float32 forward; the prefill programs hold the kernel, and the
+    engine counts its calls and rows."""
+    import paddle_tpu
+    from paddle_tpu import serving
+    from paddle_tpu.models.xing4 import Xing4Config, Xing4ForCausalLM
+    from paddle_tpu.ops import moe_grouped as mg
+    cfg = Xing4Config.tiny(
+        vocab_size=512, hidden_size=512, intermediate_size=1024,
+        moe_intermediate_size=256, n_routed_experts=16,
+        num_experts_per_tok=4, num_nextn_predict_layers=0,
+        hc_sinkhorn_iters=4, max_position_embeddings=1024)
+    paddle_tpu.seed(0)
+    m = Xing4ForCausalLM(cfg).bfloat16()
+    m.eval()
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(3, 512, (n,)) for n in (40, 200, 333)]
+
+    def served():
+        eng = serving.ServingEngine(m, max_slots=4, block_tokens=128,
+                                    max_seq_len=512)
+        rids = [eng.submit(serving.Request(p, max_new_tokens=8))
+                for p in prompts]
+        eng.drain(max_steps=200)
+        out = [eng.pop_result(r).tokens.tolist() for r in rids]
+        text = "".join(low.as_text() for low in
+                       eng.lowered_programs("prefill").values())
+        stats = dict(eng.stats)
+        eng.close()
+        return out, text, stats
+
+    assert mg.prefill_path(cfg.hidden_size, cfg.moe_intermediate_size) \
+        == "kernel"
+    got, text, stats = served()
+    assert mg.PREFILL_KERNEL_NAME in text
+    layers = cfg.num_layers - cfg.first_k_dense_replace
+    assert stats["prefill_moe_calls"] >= layers
+    assert stats["prefill_moe_rows"] >= 4 * (128 + 256 + 384) * layers
+    monkeypatch.setattr(mg, "prefill_path", lambda hidden, ffn: "ragged_dot")
+    ref, text, stats = served()
+    assert mg.PREFILL_KERNEL_NAME not in text
+    assert stats["prefill_moe_calls"] == stats["prefill_moe_rows"] == 0
+    for p, a, b in zip(prompts, got, ref):
+        _assert_same_up_to_near_tie(m, p, a, b, tol=0.05)
+
+
 # ---------------------------------------------------------------------------
 # the paged decode kernel's ragged walk at the chat cells' widths (PR 28)
 # ---------------------------------------------------------------------------
