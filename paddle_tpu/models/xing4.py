@@ -59,8 +59,28 @@ def _yarn_default():
             "original_max_position_embeddings": 4096}
 
 
+class MLAConfig:
+    """What the MLA functions below read of a configuration besides its
+    fields (``num_heads``, ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_
+    head_dim``, ``qk_rope_head_dim``, ``v_head_dim``, ``rms_norm_eps``,
+    ``rope_theta``, ``rope_scaling``): the base of every configuration
+    whose attention is MLA (``models.deepseek_v2`` too)."""
+
+    @property
+    def latent_dim(self) -> int:
+        """What one token keeps in the cache, a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        rs = self.rope_scaling
+        m = rope_ops.yarn_mscale(rs["factor"], rs.get("mscale_all_dim", 0))
+        return ((self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+                * m * m)
+
+
 @dataclasses.dataclass
-class Xing4Config:
+class Xing4Config(MLAConfig):
     vocab_size: int = 131072
     hidden_size: int = 3584
     intermediate_size: int = 9216           # the leading dense layers
@@ -106,18 +126,6 @@ class Xing4Config:
         kw.update(over)
         return cls(**kw)
 
-    @property
-    def latent_dim(self) -> int:
-        """What one token keeps in the cache, a layer."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
-
-    @property
-    def softmax_scale(self) -> float:
-        rs = self.rope_scaling
-        m = rope_ops.yarn_mscale(rs["factor"], rs.get("mscale_all_dim", 0))
-        return ((self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
-                * m * m)
-
     def hc_options(self) -> Dict:
         return dict(sinkhorn_iters=self.hc_sinkhorn_iters, eps=self.hc_eps,
                     norm_eps=self.rms_norm_eps,
@@ -130,7 +138,7 @@ def _sub(w: Dict, prefix: str) -> Dict:
     return {k[len(prefix):]: v for k, v in w.items() if k.startswith(prefix)}
 
 
-def rope_tables(cfg: Xing4Config, positions):
+def rope_tables(cfg: MLAConfig, positions):
     """(cos, sin), each ``positions.shape + (qk_rope_head_dim,)``."""
     rs = cfg.rope_scaling
     return rope_ops.yarn_cos_sin(
@@ -148,7 +156,7 @@ def _swiglu(w: Dict, x):
     return jnp.matmul(jax.nn.silu(g) * u, w["down_proj.weight"])
 
 
-def mla_project(w: Dict, cfg: Xing4Config, x, cos, sin):
+def mla_project(w: Dict, cfg: MLAConfig, x, cos, sin):
     """x (b, s, C) -> q_n (b, s, H, d_n), q_r (b, s, H, d_r) after rope,
     and the token's cache row ``[RMSNorm(c_kv) | rope(k_r)]``
     (b, s, d_c + d_r). cos, sin: (s, d_r) or (b, s, d_r)."""
@@ -166,14 +174,14 @@ def mla_project(w: Dict, cfg: Xing4Config, x, cos, sin):
     return q[..., :dn], q_r, jnp.concatenate([c_kv, k_r], axis=-1)
 
 
-def _kvb(w: Dict, cfg: Xing4Config):
+def _kvb(w: Dict, cfg: MLAConfig):
     """``W_kvb`` as (d_c, H, d_n + d_v): a head's key and value halves."""
     return w["kv_b_proj.weight"].reshape(
         cfg.kv_lora_rank, cfg.num_heads,
         cfg.qk_nope_head_dim + cfg.v_head_dim)
 
 
-def mla_expanded(w: Dict, cfg: Xing4Config, q_n, q_r, latent, start_pos):
+def mla_expanded(w: Dict, cfg: MLAConfig, q_n, q_r, latent, start_pos):
     """Causal attention in the expanded form: the cached rows ``latent``
     (b, S, d_c + d_r) become per-head keys and values through ``W_kvb``.
     The queries sit at positions ``start_pos + arange(s)``. Scores in
@@ -210,7 +218,7 @@ def mla_expanded(w: Dict, cfg: Xing4Config, q_n, q_r, latent, start_pos):
     return out.reshape(b, s, H * dv)
 
 
-def mla_absorb_query(w: Dict, cfg: Xing4Config, q_n, q_r, lanes: int):
+def mla_absorb_query(w: Dict, cfg: MLAConfig, q_n, q_r, lanes: int):
     """(b, H, d_n), (b, H, d_r) -> the query in the cache row's own
     layout ``[q_n W_kvb,k^T | q_r | 0]`` (b, H, lanes)."""
     q_c = jnp.einsum("bhd,chd->bhc", q_n,
@@ -218,13 +226,68 @@ def mla_absorb_query(w: Dict, cfg: Xing4Config, q_n, q_r, lanes: int):
     return mla_decode.pad_lanes(jnp.concatenate([q_c, q_r], -1), lanes)
 
 
-def mla_absorb_out(w: Dict, cfg: Xing4Config, o_c):
+def mla_absorb_out(w: Dict, cfg: MLAConfig, o_c):
     """The latent-space attention output (b, H, d_c) through the value
     half of ``W_kvb`` -> (b, H * d_v)."""
     kvb = _kvb(w, cfg)
     o = jnp.einsum("bhc,chd->bhd", o_c.astype(kvb.dtype),
                    kvb[..., cfg.qk_nope_head_dim:])
     return o.reshape(o.shape[0], -1)
+
+
+def mla_layered(w: Dict, cfg: MLAConfig, xn, cos, sin, cache, start_pos):
+    """The attention sub-layer of a layered forward on the normed xn
+    (b, s, C): project, write the rows into ``cache["ckv"]`` at
+    ``start_pos`` (or attend over the block's own rows when ``cache`` is
+    None), expanded attention, ``W_o``. -> (y (b, s, C), cache')."""
+    q_n, q_r, lat = mla_project(w, cfg, xn, cos, sin)
+    if cache is not None:
+        full = jax.lax.dynamic_update_slice_in_dim(
+            cache["ckv"], lat.astype(cache["ckv"].dtype), start_pos, axis=1)
+        cache = {"ckv": full}
+    else:
+        full = lat
+    att = mla_expanded(w, cfg, q_n, q_r, full.astype(lat.dtype), start_pos)
+    return jnp.matmul(att, w["o_proj.weight"]), cache
+
+
+def mla_paged(w: Dict, cfg: MLAConfig, xn, cos, sin, pool, tables, positions,
+              layer: int):
+    """The attention sub-layer of one decode step on the normed xn
+    (b, C) over the paged latent pool, absorbed. -> (y (b, C), pool)."""
+    lanes = pool.shape[-1]
+    q_n, q_r, lat = mla_project(w, cfg, xn[:, None], cos[:, None],
+                                sin[:, None])
+    o_c, pool = mla_decode.mla_paged_decode(
+        mla_absorb_query(w, cfg, q_n[:, 0], q_r[:, 0], lanes),
+        mla_decode.pad_lanes(lat[:, 0], lanes), pool, tables, positions,
+        layer=layer, d_c=cfg.kv_lora_rank, scale=cfg.softmax_scale)
+    return jnp.matmul(mla_absorb_out(w, cfg, o_c).astype(xn.dtype),
+                      w["o_proj.weight"]), pool
+
+
+def latent_plan(cfg: MLAConfig) -> Dict:
+    """The part of a ``fused_decode_plan`` that the latent cache
+    decides: ``arch`` ``"mla_moe"``, the pool's ``cache_lanes``, and
+    ``to_lanes`` / ``from_lanes`` between the prefill cache
+    (``[{"ckv": (n, len, d_c + d_r)}]`` a layer) and pool rows."""
+    lanes = mla_decode.pool_lanes(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+    ld = cfg.latent_dim
+
+    def to_lanes(cache):
+        """[{"ckv": (n, len, ld)}] -> (L, n, len, lanes)."""
+        return jnp.stack([mla_decode.pad_lanes(c["ckv"], lanes)
+                          for c in cache])
+
+    def from_lanes(cache, rows):
+        """Write pool rows (L, n, R, lanes) at the cache's start."""
+        R = rows.shape[2]
+        return [{"ckv": c["ckv"].at[:, :R].set(
+            rows[l, :, :, :ld].astype(c["ckv"].dtype))}
+            for l, c in enumerate(cache)]
+
+    return {"arch": "mla_moe", "cache_lanes": lanes, "to_lanes": to_lanes,
+            "from_lanes": from_lanes}
 
 
 def route(w: Dict, cfg: Xing4Config, x):
@@ -277,19 +340,9 @@ def block_forward(w: Dict, cfg: Xing4Config, moe: bool, X, cos, sin, cache,
     eps = cfg.rms_norm_eps
     h, h_post, h_res = _hc(_sub(w, "attn_hc."), cfg, X)
     with jax.named_scope("xing4.mla"):
-        aw = _sub(w, "self_attn.")
         xn = rms_norm(h, w["input_layernorm.weight"], eps)
-        q_n, q_r, lat = mla_project(aw, cfg, xn, cos, sin)
-        if cache is not None:
-            full = jax.lax.dynamic_update_slice_in_dim(
-                cache["ckv"], lat.astype(cache["ckv"].dtype), start_pos,
-                axis=1)
-            cache = {"ckv": full}
-        else:
-            full = lat
-        att = mla_expanded(aw, cfg, q_n, q_r, full.astype(lat.dtype),
-                           start_pos)
-        y = jnp.matmul(att, aw["o_proj.weight"])
+        y, cache = mla_layered(_sub(w, "self_attn."), cfg, xn, cos, sin,
+                               cache, start_pos)
     X = _hc_write(X, y, h_post, h_res)
     h, h_post, h_res = _hc(_sub(w, "ffn_hc."), cfg, X)
     xn = rms_norm(h, w["post_attention_layernorm.weight"], eps)
@@ -352,7 +405,6 @@ def decode_step(w: Dict, cfg: Xing4Config, x, pool, tables, positions):
     idle: it routes to no expert. -> (the streams' sum (b, C), pool,
     int32 (4,): expert layers, experts touched, the fullest expert's
     rows, active rows x k, the last three summed over the layers)."""
-    lanes = pool.shape[-1]
     eps = cfg.rms_norm_eps
     active = tables[:, 0] != 0
     cos, sin = rope_tables(cfg, positions)                  # (b, d_r)
@@ -363,17 +415,9 @@ def decode_step(w: Dict, cfg: Xing4Config, x, pool, tables, positions):
         lw = _sub(w, f"model.layers.{i}.")
         h, h_post, h_res = _hc(_sub(lw, "attn_hc."), cfg, X)
         with jax.named_scope("xing4.mla"):
-            aw = _sub(lw, "self_attn.")
             xn = rms_norm(h, lw["input_layernorm.weight"], eps)
-            q_n, q_r, lat = mla_project(aw, cfg, xn[:, None], cos[:, None],
-                                        sin[:, None])
-            o_c, pool = mla_decode.mla_paged_decode(
-                mla_absorb_query(aw, cfg, q_n[:, 0], q_r[:, 0], lanes),
-                mla_decode.pad_lanes(lat[:, 0], lanes), pool, tables,
-                positions, layer=i, d_c=cfg.kv_lora_rank,
-                scale=cfg.softmax_scale)
-            y = jnp.matmul(mla_absorb_out(aw, cfg, o_c).astype(x.dtype),
-                           aw["o_proj.weight"])
+            y, pool = mla_paged(_sub(lw, "self_attn."), cfg, xn, cos, sin,
+                                pool, tables, positions, i)
         X = _hc_write(X, y, h_post, h_res)
         h, h_post, h_res = _hc(_sub(lw, "ffn_hc."), cfg, X)
         xn = rms_norm(h, lw["post_attention_layernorm.weight"], eps)
@@ -496,7 +540,24 @@ class Xing4Model(nn.Layer):
             self.mtp = Xing4MTP(cfg)
 
 
-class Xing4ForCausalLM(CausalLMBase):
+class LatentCausalLM(CausalLMBase):
+    """What the causal LMs over a latent (MLA) cache share: ``self.cfg``
+    an :class:`MLAConfig` with ``num_layers``, ``self.loss_fn``."""
+
+    def _weights(self) -> Dict:
+        return {n: p.value for n, p in self.named_parameters()}
+
+    def init_cache(self, batch_size, max_len, dtype=jnp.bfloat16):
+        """The latent cache: one ``{"ckv": (b, len, d_c + d_r)}`` a layer."""
+        shape = (batch_size, max_len, self.cfg.latent_dim)
+        return [{"ckv": jnp.zeros(shape, dtype)}
+                for _ in range(self.cfg.num_layers)]
+
+    def loss(self, logits, labels):
+        return self.loss_fn(logits, labels, reduction="mean")
+
+
+class Xing4ForCausalLM(LatentCausalLM):
     def __init__(self, cfg: Xing4Config):
         super().__init__()
         if cfg.tie_word_embeddings:
@@ -510,15 +571,6 @@ class Xing4ForCausalLM(CausalLMBase):
                              cfg.initializer_range)
         from paddle_tpu.parallel import mp_layers as mp
         self.loss_fn = mp.ParallelCrossEntropy()
-
-    def _weights(self) -> Dict:
-        return {n: p.value for n, p in self.named_parameters()}
-
-    def init_cache(self, batch_size, max_len, dtype=jnp.bfloat16):
-        """The latent cache: one ``{"ckv": (b, len, d_c + d_r)}`` a layer."""
-        shape = (batch_size, max_len, self.cfg.latent_dim)
-        return [{"ckv": jnp.zeros(shape, dtype)}
-                for _ in range(self.cfg.num_layers)]
 
     def forward(self, input_ids, attn_mask=None, cache=None, start_pos=0,
                 positions: Optional[jax.Array] = None, mtp: bool = False):
@@ -539,9 +591,6 @@ class Xing4ForCausalLM(CausalLMBase):
             return logits, mtp_forward(w, cfg, h, input_ids)
         return logits if cache is None else (logits, cache)
 
-    def loss(self, logits, labels):
-        return self.loss_fn(logits, labels, reduction="mean")
-
     def fused_decode_plan(self, state, probe=False):
         """What ``serving.ServingEngine`` asks of a model (docs/SERVING.md
         §Architectures the engine takes): ``arch`` ``"mla_moe"``, the
@@ -552,24 +601,7 @@ class Xing4ForCausalLM(CausalLMBase):
         if "model.layers.0.self_attn.kv_b_proj.weight" not in state:
             return None     # a quantized or otherwise foreign state
         cfg = self.cfg
-        lanes = mla_decode.pool_lanes(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
-        ld = cfg.latent_dim
-
-        def to_lanes(cache):
-            """[{"ckv": (n, len, ld)}] -> (L, n, len, lanes)."""
-            return jnp.stack([mla_decode.pad_lanes(c["ckv"], lanes)
-                              for c in cache])
-
-        def from_lanes(cache, rows):
-            """Write pool rows (L, n, R, lanes) at the cache's start."""
-            R = rows.shape[2]
-            return [{"ckv": c["ckv"].at[:, :R].set(
-                rows[l, :, :, :ld].astype(c["ckv"].dtype))}
-                for l, c in enumerate(cache)]
-
-        meta = {"arch": "mla_moe", "cache_lanes": lanes,
-                "to_lanes": to_lanes, "from_lanes": from_lanes,
-                "step_counters": STEP_COUNTERS,
+        meta = {**latent_plan(cfg), "step_counters": STEP_COUNTERS,
                 # what a prefill's routed experts go through here
                 "prefill_moe": {
                     "layers": cfg.num_layers - cfg.first_k_dense_replace,

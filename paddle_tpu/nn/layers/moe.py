@@ -283,6 +283,31 @@ def sigmoid_topk_routing(logits, correction_bias, k: int, *,
     return idx.astype(jnp.int32), w * scaling
 
 
+def group_limited_topk_routing(logits, k: int, *, n_group: int,
+                               topk_group: int, scaling: float = 1.0,
+                               normalize_topk: bool = False):
+    """DeepSeek-V2's ``group_limited_greedy`` router: scores are
+    ``softmax(logits)`` over ALL experts in float32; the experts lie in
+    ``n_group`` groups of consecutive ids and a group's score is its
+    largest; the ``topk_group`` best groups are kept, every score outside
+    them is set to 0, and the ``k`` largest of what is left are chosen.
+    The weights are the chosen experts' own scores, normalised to sum to
+    1 when ``normalize_topk``, and multiplied by ``scaling``. No
+    capacity: no token is dropped. The groups bound how many chips of an
+    expert-parallel layer a token visits. Returns ``(idx (T, k) int32,
+    weights (T, k) f32)``."""
+    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    t, e = scores.shape
+    best = scores.reshape(t, n_group, e // n_group).max(axis=-1)
+    _, groups = jax.lax.top_k(best, topk_group)
+    kept = jax.nn.one_hot(groups, n_group, dtype=jnp.bool_).any(axis=1)
+    left = jnp.where(jnp.repeat(kept, e // n_group, axis=1), scores, 0.0)
+    w, idx = jax.lax.top_k(left, k)
+    if normalize_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scaling
+
+
 class GShardGate(Layer):
     """Top-2 gate (reference: moe/gate/gshard_gate.py)."""
 

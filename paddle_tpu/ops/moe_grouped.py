@@ -30,9 +30,18 @@ with a trip count of ``ceil(rows / tile)``. A group's last tile runs over
 into the rows behind it; they are another expert's, whose own tiles are
 written later and in order, so nothing is masked. The wrapper is traced
 for a few token counts only (``_token_bucket``; the rows that fill a
-bucket pick no expert). ``prefill_path`` says
+bucket pick no expert). Where two whole experts do not fit VMEM
+(``_slice_width``: 5120 x 1536 needs 94 MB) an expert arrives in slices
+of its width instead, each row tile streaming all of them: with 256
+rows a tile the matrix unit takes as long over a slice as the slice
+takes to arrive (2 flops a weight a row against 2 bytes a weight), so
+an expert whose group is several tiles long reads its weights once a
+tile and loses nothing by it. ``prefill_path`` says
 which of the two a prefill takes here; ``moe_prefill_ragged_dot`` (sort
 + ``jax.lax.ragged_dot``) is the path elsewhere and the parity oracle.
+
+The experts stacked here may be a SHARE of the router's
+(:func:`held_rows`): a pick of ``E`` is no expert.
 """
 
 import functools
@@ -44,6 +53,17 @@ KERNEL_NAME = "moe_grouped_ffn_decode"
 PREFILL_KERNEL_NAME = "moe_grouped_ffn_prefill"
 _ROW_TILE = 128     # rows of one pass over an expert's weights (_row_tile)
 _ROW_ALIGN = 16     # a group starts on a whole (16, 128) bf16 tile
+
+
+def held_rows(idx, offset: int, held: int):
+    """The router's picks (global expert ids) -> rows of the ``held``
+    experts stacked here, which are the global ids ``offset ..
+    offset + held - 1``; a pick that lies on another chip becomes
+    ``held``: no expert, which joins no group, weighs nothing in
+    :func:`dense_weights` and is not counted by :func:`routing_counts`."""
+    local = idx - offset
+    return jnp.where((local >= 0) & (local < held), local,
+                     held).astype(jnp.int32)
 
 
 def dense_weights(idx, w, active, num_experts: int):
@@ -186,7 +206,31 @@ def prefill_path(hidden: int, ffn: int) -> str:
     # tpu-lint: allow(host-sync): flag() is a host-side config read
     if not (use_pallas() or bool(flag("FLAGS_pallas_interpret"))):
         return "ragged_dot"
-    return "kernel" if hidden % 128 == 0 and ffn % 128 == 0 else "ragged_dot"
+    return "kernel" if _slice_width(hidden, ffn) else "ragged_dot"
+
+
+_VMEM_BUDGET = 100 << 20    # of a v5e's 128 MiB, for one kernel
+
+
+def _prefill_vmem(C: int, tf: int, tm: int, wbytes: int = 2) -> int:
+    """Bytes of VMEM the prefill kernel asks for with weight slots of
+    width ``tf``: two slots of three matrices, the row tiles in and out,
+    the float32 products of one tile, and room for the compiler."""
+    return (2 * 3 * C * tf * wbytes + 3 * tm * C * 2
+            + tm * (3 * tf + 2 * C) * 4 + (8 << 20))
+
+
+def _slice_width(hidden: int, ffn: int) -> int:
+    """The width of the weight slots the prefill kernel keeps in VMEM at
+    these widths: ``ffn`` (whole experts) where two of them fit, else
+    the decode kernel's tile of ``ffn`` (:func:`_pick_tile`), else 0: no
+    kernel tiles these widths."""
+    if hidden % 128 or ffn % 128:
+        return 0
+    for tf in (ffn, _pick_tile(ffn)):
+        if _prefill_vmem(hidden, tf, 2 * _ROW_TILE) <= _VMEM_BUDGET:
+            return tf
+    return 0
 
 
 def moe_prefill_ragged_dot(x, idx, wts, wg, wu, wd):
@@ -205,13 +249,16 @@ def moe_prefill_ragged_dot(x, idx, wts, wg, wu, wd):
     return jnp.einsum("tk,tkc->tc", wts.astype(x.dtype), ys)
 
 
-def _row_tile(rows: int, num_experts: int) -> int:
+def _row_tile(rows: int, num_experts: int, sliced: bool = False) -> int:
     """Rows of one pass over an expert's weights: 256 where the mean
     group fills them (at the cell's widths a pass of 256 costs the v5e's
     matrix unit 35 us and two of 128 cost 49, PERF.md §6, PR 30), else
     128, a pass of which hides behind the 27 us its expert's 22 MB take
-    to arrive."""
-    return 2 * _ROW_TILE if rows >= _ROW_TILE * num_experts else _ROW_TILE
+    to arrive. Always 256 where the weights arrive in slices a tile
+    (module docstring): a tile of 128 would wait for them."""
+    if sliced or rows >= _ROW_TILE * num_experts:
+        return 2 * _ROW_TILE
+    return _ROW_TILE
 
 
 def _token_bucket(tokens: int) -> int:
@@ -225,10 +272,12 @@ def _token_bucket(tokens: int) -> int:
 
 # jitted on its own so that a program's expert layers, and the programs
 # of one token bucket, share ONE trace and one lowering of the kernel
-@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
-def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
-    """``idx`` may hold ``E`` (no expert): such a pick joins no group,
-    and its row of the result is whatever the gather finds."""
+@functools.partial(jax.jit, static_argnames=("tm", "tf", "interpret"))
+def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, tf=None,
+                        interpret=False):
+    """``idx`` may hold ``E`` (no expert): such a pick joins no group
+    and adds nothing to its token's sum. ``tf``: the width of the weight
+    slots, ``None`` for whole experts (:func:`_slice_width`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -272,19 +321,33 @@ def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
     eids = jnp.where(jnp.arange(E) < nt, by_touch,
                      by_touch[jnp.maximum(nt - 1, 0)])
 
+    tf = F if tf is None else tf
+    nf = F // tf        # slices of an expert's width; 1: whole experts
+
     def kernel(eids_ref, nt_ref, astart_ref, ntile_ref, voff_ref,
                x_hbm, wg_hbm, wu_hbm, wd_hbm, o_hbm,
                wg_s, wu_s, wd_s, x_s, o_s, wsem, xsem, osem):
+        """Whole experts (``nf`` 1): an expert's weights are fetched
+        once, into slot ``g % 2``, while the expert before it computes.
+        Slices: every row tile streams its expert's ``nf`` slices, and
+        the slices of the whole call form one chain through the two
+        slots (slice ``f`` of the call's ``v``-th tile in slot
+        ``(v nf + f) % 2``), each fetched while the one before it is
+        multiplied."""
         g = pl.program_id(0)
         n_touched = nt_ref[0]
 
-        def weights(e, slot):
-            return (pltpu.make_async_copy(wg_hbm.at[e], wg_s.at[slot],
-                                          wsem.at[slot, 0]),
-                    pltpu.make_async_copy(wu_hbm.at[e], wu_s.at[slot],
-                                          wsem.at[slot, 1]),
-                    pltpu.make_async_copy(wd_hbm.at[e], wd_s.at[slot],
-                                          wsem.at[slot, 2]))
+        def weights(e, f, slot):
+            if nf == 1:
+                srcs = (wg_hbm.at[e], wu_hbm.at[e], wd_hbm.at[e])
+            else:
+                cols = pl.ds(f * tf, tf)
+                srcs = (wg_hbm.at[e, :, cols], wu_hbm.at[e, :, cols],
+                        wd_hbm.at[e, cols, :])
+            return tuple(
+                pltpu.make_async_copy(src, dst.at[slot], wsem.at[slot, i])
+                for i, (src, dst) in enumerate(zip(srcs,
+                                                   (wg_s, wu_s, wd_s))))
 
         def rows(e, j):
             return pl.ds(pl.multiple_of(astart_ref[e] + j * tm, al), tm)
@@ -300,7 +363,7 @@ def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
         @pl.when((g == 0) & (n_touched > 0))
         def _():
             first = eids_ref[0]
-            for cp in weights(first, 0):
+            for cp in weights(first, 0, 0):
                 cp.start()
             x_tile(first, 0, 0).start()
             # one write is always in flight, so that every tile waits
@@ -311,13 +374,14 @@ def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
         @pl.when(g < n_touched)
         def _():
             e, slot = eids_ref[g], g % 2
-            for cp in weights(e, slot):
-                cp.wait()
+            if nf == 1:
+                for cp in weights(e, 0, slot):
+                    cp.wait()
 
-            @pl.when(g + 1 < n_touched)
-            def _():
-                for cp in weights(eids_ref[g + 1], 1 - slot):
-                    cp.start()
+                @pl.when(g + 1 < n_touched)
+                def _():
+                    for cp in weights(eids_ref[g + 1], 0, 1 - slot):
+                        cp.start()
 
             nj, v0 = ntile_ref[e], voff_ref[e]
 
@@ -334,13 +398,35 @@ def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
                     x_tile(eids_ref[g + 1], 0, 1 - xslot).start()
 
                 xv = x_s[xslot]
-                h = jnp.dot(xv, wg_s[slot],
-                            preferred_element_type=jnp.float32)
-                u = jnp.dot(xv, wu_s[slot],
-                            preferred_element_type=jnp.float32)
-                a = (h * jax.nn.sigmoid(h) * u).astype(xv.dtype)
-                y = jnp.dot(a, wd_s[slot],
-                            preferred_element_type=jnp.float32)
+                y = None
+                for f in range(nf):
+                    ws = slot
+                    if nf > 1:
+                        ws = ((v0 + j) * nf + f) % 2
+                        for cp in weights(e, f, ws):
+                            cp.wait()
+                        if f + 1 < nf:
+                            for cp in weights(e, f + 1, 1 - ws):
+                                cp.start()
+                        else:
+                            @pl.when(j + 1 < nj)
+                            def _():
+                                for cp in weights(e, 0, 1 - ws):
+                                    cp.start()
+
+                            @pl.when((j + 1 == nj) & (g + 1 < n_touched))
+                            def _():
+                                for cp in weights(eids_ref[g + 1], 0,
+                                                  1 - ws):
+                                    cp.start()
+                    h = jnp.dot(xv, wg_s[ws],
+                                preferred_element_type=jnp.float32)
+                    u = jnp.dot(xv, wu_s[ws],
+                                preferred_element_type=jnp.float32)
+                    a = (h * jax.nn.sigmoid(h) * u).astype(xv.dtype)
+                    part = jnp.dot(a, wd_s[ws],
+                                   preferred_element_type=jnp.float32)
+                    y = part if y is None else y + part
                 # writes land in order: a group's last tile runs into
                 # the next groups' rows, which their own tiles rewrite
                 o_tile(e, j).wait()
@@ -361,9 +447,9 @@ def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
         in_specs=[hbm, hbm, hbm, hbm],
         out_specs=hbm,
         scratch_shapes=[
-            pltpu.VMEM((2, C, F), wg.dtype),
-            pltpu.VMEM((2, C, F), wu.dtype),
-            pltpu.VMEM((2, F, C), wd.dtype),
+            pltpu.VMEM((2, C, tf), wg.dtype),
+            pltpu.VMEM((2, C, tf), wu.dtype),
+            pltpu.VMEM((2, tf, C), wd.dtype),
             pltpu.VMEM((2, tm, C), x.dtype),
             pltpu.VMEM((tm, C), x.dtype),
             pltpu.SemaphoreType.DMA((2, 3)),
@@ -371,9 +457,7 @@ def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
             pltpu.SemaphoreType.DMA((1,)),
         ],
     )
-    wbytes = jnp.dtype(wg.dtype).itemsize
-    vmem = (2 * 3 * C * F * wbytes + 3 * tm * C * x.dtype.itemsize
-            + tm * (3 * F + 2 * C) * 4 + (8 << 20))
+    vmem = _prefill_vmem(C, tf, tm, jnp.dtype(wg.dtype).itemsize)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -385,9 +469,11 @@ def _moe_prefill_pallas(x, idx, wts, wg, wu, wd, *, tm, interpret=False):
         interpret=interpret,
     )(eids, nt.reshape(1), astart, ntile, voff, xs, wg, wu, wd)
     # each token's k rows of the aligned layout, weighted and summed
-    # (one (T, C) gather a pick: a (T, k, C) array pads k to a whole tile)
-    y = sum(wts[:, j, None].astype(jnp.float32)
-            * out.at[at[:, j]].get(mode="promise_in_bounds")
+    # (one (T, C) gather a pick: a (T, k, C) array pads k to a whole
+    # tile); a pick of no expert finds a row that nothing wrote
+    y = sum(jnp.where(idx[:, j, None] < E,
+                      wts[:, j, None].astype(jnp.float32)
+                      * out.at[at[:, j]].get(mode="promise_in_bounds"), 0.0)
             for j in range(k))
     return y.astype(x.dtype)
 
@@ -401,10 +487,12 @@ def moe_grouped_ffn_prefill(x, idx, wts, wg, wu, wd):
         return moe_prefill_ragged_dot(x, idx, wts, wg, wu, wd)
     from paddle_tpu.ops import use_pallas
     t, E = x.shape[0], wg.shape[0]
+    tf = _slice_width(x.shape[1], wg.shape[2])
     # up to the bucket with tokens that pick no expert and weigh nothing
     rows = ((0, _token_bucket(t) - t), (0, 0))
     y = _moe_prefill_pallas(
         jnp.pad(x, rows), jnp.pad(idx, rows, constant_values=E),
         jnp.pad(wts, rows), wg, wu, wd,
-        tm=_row_tile(t * idx.shape[1], E), interpret=not use_pallas())
+        tm=_row_tile(t * idx.shape[1], E, sliced=tf < wg.shape[2]), tf=tf,
+        interpret=not use_pallas())
     return y[:t]

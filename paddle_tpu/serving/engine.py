@@ -915,8 +915,12 @@ class ServingEngine:
         self._step_counters = tuple(meta.get("step_counters", ()))
         # a plan whose prefill routes experts says through what
         # (``prefill_moe``: expert layers, k, path), so that a wave's
-        # kernel calls and routed rows are counted from its shape alone
+        # kernel calls and routed rows are counted from its shape alone;
+        # where only the program knows the rows (``rows`` ``"counted"``:
+        # a share of an expert-parallel layer), its prefill returns them
         self._prefill_moe = meta.get("prefill_moe")
+        self._prefill_counted = (self._prefill_moe or {}).get(
+            "rows") == "counted"
         # tpu-lint: volatile(a property of the backend)
         self._host_aliased = jax.default_backend() == "cpu"
         blocks_plan = meta.get("blocks")
@@ -1597,7 +1601,8 @@ class ServingEngine:
         before it were pulled, ``lookahead_discarded_tokens`` the tokens
         such a program computed for rows that had left by its pull.
         A plan's ``step_counters`` (``mla_moe``: ``moe_layer_steps``,
-        ``moe_experts_touched``, ``moe_rows_max``, ``moe_rows``) are
+        ``moe_experts_touched``, ``moe_rows_max``, ``moe_rows``, and for
+        a share of an expert-parallel layer ``moe_picks``) are
         summed over the step programs whose tokens were pulled, as the
         program counted them. ``kv_blocks_walked`` and ``kv_blocks_dense``
         (the fused paged kernel's engines only) sum, over the plain and
@@ -1607,7 +1612,9 @@ class ServingEngine:
         and ``prefill_moe_rows`` (a plan with ``prefill_moe``) sum, over
         the waves landed, the expert layers that went through the grouped
         prefill kernel and their routed rows, pad positions included
-        (:meth:`_wave_moe`); both stay 0 where the path is ``ragged_dot``.
+        (:meth:`_wave_moe`; where the plan's ``rows`` are ``"counted"``,
+        the picks that fell on held experts, as the prefill program
+        counted them); both stay 0 where the path is ``ragged_dot``.
         Per-step distributions live in the ``serving.step_*_s`` registry
         histograms."""
         return dict(steps=0, decode_tokens=0, idle_slot_steps=0,
@@ -2055,6 +2062,7 @@ class ServingEngine:
         # the cache adapter and the head's rows: a plan's own, else the
         # [k | v] rows of a llama/gpt cache and every position's logits
         own = self._own_step
+        counted = self._prefill_counted
         lanes_w = self._cache_lanes
         dkv = lanes_w // 2
         if own:
@@ -2095,10 +2103,12 @@ class ServingEngine:
                 cache = from_lanes(cache, pk)
             with jax.named_scope("decode.prefill"):
                 # an own plan's model computes the head at each row's
-                # last position only
-                out, cache = functional_call(
+                # last position only, and counts its routed rows if the
+                # plan says so
+                out, cache, *moe_rows = functional_call(
                     model, state, ids, cache=cache, start_pos=R,
-                    **({"positions": last_idx} if own else {}))
+                    **({"positions": last_idx} if own else {}),
+                    **({"moe_rows": True} if counted else {}))
             kv_flat = to_lanes(cache)            # (L, n, cache_len, lanes)
             logits = out if own else jnp.take_along_axis(
                 out, last_idx[:, None, None], axis=1)[:, 0]   # (n, vocab)
@@ -2107,6 +2117,10 @@ class ServingEngine:
                 tok = _sample_logits(logits, _fold_rows(keys, 0),
                                      self.temperature, self.top_k,
                                      self.top_p)
+            if counted:
+                # the count rides the wave's one pull behind its tokens
+                tok = jnp.concatenate([tok, moe_rows[0][None].astype(
+                    tok.dtype)])
             if int8:
                 # per-request calibration: amax over each row's VALID
                 # prompt positions only — the padded tail holds
@@ -3263,11 +3277,15 @@ class ServingEngine:
         the grouped prefill kernel: one call an expert layer, k routed
         rows a position a layer (pad positions route too). Known from
         the wave's shape and the plan's ``prefill_moe``; nothing where
-        the plan routes no experts."""
+        the plan routes no experts. Where the plan's rows are
+        ``"counted"`` only the calls: the rows are the program's own
+        count, known once the wave is pulled."""
         pm = self._prefill_moe
         if pm is None:
             return {}
         calls = pm["layers"] if pm["path"] == "kernel" else 0
+        if self._prefill_counted:
+            return dict(prefill_moe_calls=calls)
         return dict(prefill_moe_calls=calls,
                     prefill_moe_rows=calls * pm["k"] * s_pad * n)
 
@@ -3340,6 +3358,12 @@ class ServingEngine:
                 # tpu-lint: allow(host-sync): once-per-wave D2H — first
                 # tokens
                 tok_np = np.asarray(tok)
+            if self._prefill_counted:
+                # the picks that fell on held experts, as the program
+                # counted them (0 where no kernel ran)
+                moe["prefill_moe_rows"] = (
+                    int(tok_np[n]) if moe["prefill_moe_calls"] else 0)
+                ph.set(prefill_moe_rows=moe["prefill_moe_rows"])
             for r, (slot_idx, slot, hits, _, _) in enumerate(grp):
                 self._adopt_slot(
                     slot_idx, slot, int(tok_np[r]),

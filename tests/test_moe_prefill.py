@@ -91,6 +91,48 @@ def test_prefill_kernel_matches_ragged_dot_and_the_reference(sizes, k):
         f32(ref)).max()
 
 
+@pytest.mark.parametrize("share", [False, True], ids=["whole", "share"])
+def test_sliced_prefill_kernel_matches_ragged_dot(monkeypatch, share):
+    """Widths at which two whole experts do not fit the (here: lowered)
+    VMEM budget: an expert arrives in three slices of 128, every row
+    tile streaming all of them. Groups of several tiles, an empty
+    expert, and, for a share, picks of no expert (``E``)."""
+    E, C, F, k = 4, 256, 384, 3
+    monkeypatch.setattr(mg, "_VMEM_BUDGET", mg._prefill_vmem(C, 128, 2 * TM))
+    assert mg._slice_width(C, F) == 128 and mg._slice_width(C, 128) == 128
+    assert mg._row_tile(8, E, True) == 2 * TM
+    rng = np.random.default_rng(7)
+    sizes = [4 * TM + 9, 0, 2 * TM + 44, 5]
+    flat = np.repeat(np.arange(E), sizes)
+    flat = np.concatenate([flat, np.full(
+        (-len(flat)) % k + (7 * k if share else 0), E if share else 3)])
+    idx = jnp.asarray(rng.permutation(flat).reshape(k, -1).T, jnp.int32)
+    T = idx.shape[0]
+    x = jnp.asarray(rng.standard_normal((T, C)), jnp.bfloat16)
+    w = jnp.where(idx < E, jnp.asarray(rng.uniform(0.2, 1.0, (T, k)),
+                                       jnp.float32), 0.0)
+    wg, wu, wd = weights(rng, E, C, F)
+    want = jax.jit(mg.moe_prefill_ragged_dot)(x, idx, w, wg, wu, wd)
+    set_flags({"FLAGS_pallas_interpret": True})
+    assert mg.prefill_path(C, F) == "kernel"
+    got = jax.jit(lambda *a: mg.moe_grouped_ffn_prefill(*a))(
+        x, idx, w, wg, wu, wd)
+    assert np.isfinite(f32(got)).all()
+    assert np.abs(f32(got) - f32(want)).max() <= 2 ** -6 * np.abs(
+        f32(want)).max()
+    # no slot wide enough: no kernel at these widths
+    monkeypatch.setattr(mg, "_VMEM_BUDGET", 1 << 20)
+    assert mg.prefill_path(C, F) == "ragged_dot"
+
+
+def test_the_cells_widths_get_the_plan_that_fits_vmem():
+    # Xing4.0: whole experts, as before; DeepSeek-V2: slices of 512
+    assert mg._slice_width(3584, 1024) == 1024
+    assert mg._prefill_vmem(5120, 1536, 256) > mg._VMEM_BUDGET
+    assert mg._slice_width(5120, 1536) == 512
+    assert mg._slice_width(3584, 1000) == 0 == mg._slice_width(200, 128)
+
+
 def test_a_token_bucket_is_shared_by_the_prompt_buckets_under_it():
     assert [mg._token_bucket(t) for t in (1, 256, 1024, 1025, 2048, 3584)] \
         == [1024, 1024, 1024, 2048, 2048, 4096]

@@ -31,25 +31,40 @@ def one_chip(tmp_path_factory):
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("positions, tile", [(1024, 128), (3584, 256)])
-def test_moe_prefill_kernel_compiles_at_the_cells_widths(one_chip, positions,
-                                                         tile):
-    """``moe_grouped_ffn_prefill`` at 64 experts of 3584 x 1024, top-4,
-    over the cell's median and largest bucket (both row tiles): whole
-    experts in two VMEM slots, row tiles DMA'd from 16-aligned offsets."""
+# E, C, F, k: Xing4.0's 64 experts of 3584 x 1024, top-4, over the cell's
+# median and largest bucket (both row tiles), whole experts in two VMEM
+# slots; DeepSeek-V2's 40 held experts of 5120 x 1536, top-6, over its
+# largest bucket, an expert in three slices of 512
+@pytest.mark.parametrize("widths, positions, tile, slot", [
+    ((64, 3584, 1024, 4), 1024, 128, 1024),
+    ((64, 3584, 1024, 4), 3584, 256, 1024),
+    ((40, 5120, 1536, 6), 3584, 256, 512),
+])
+def test_moe_prefill_kernel_compiles_at_the_cells_widths(one_chip, widths,
+                                                         positions, tile,
+                                                         slot):
+    """``moe_grouped_ffn_prefill`` at the cells' widths: the weight
+    slots fit VMEM, row tiles are DMA'd from 16-aligned offsets."""
     from paddle_tpu.ops import moe_grouped as mg
-    E, C, F, k = 64, 3584, 1024, 4
-    assert mg._row_tile(positions * k, E) == tile
+    E, C, F, k = widths
+    assert mg._slice_width(C, F) == slot
+    assert mg._row_tile(positions * k, E, slot < F) == tile
     tokens = mg._token_bucket(positions)    # what the wrapper is traced at
     shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
         s, dt, sharding=one_chip)
-    compiled = jax.jit(
-        lambda *a: mg._moe_prefill_pallas(*a, tm=tile)).lower(
+    compiled = jax.jit(lambda *a: mg._moe_prefill_pallas(
+        *a, tm=tile, tf=slot)).lower(
         shape((tokens, C)), shape((tokens, k), jnp.int32),
         shape((tokens, k), jnp.float32), shape((E, C, F)),
         shape((E, C, F)), shape((E, F, C))).compile()
     text = compiled.as_text()
-    assert mg.PREFILL_KERNEL_NAME in text and "ragged" not in text
+    # the kernel, once, and no ragged-dot beside it. (Not ``"ragged" not
+    # in text``: the text's table of source locations holds the names of
+    # functions this PROCESS traced before, ``moe_ragged`` among them
+    # where the worker ran tests/test_xing4.py first.)
+    assert mg.PREFILL_KERNEL_NAME in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ragged-dot" not in text
     # the aligned copy of the rows and the kernel's output, no more
     rows = tokens * k + E * 16 + tile
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * C * 2

@@ -1292,6 +1292,99 @@ def test_serving_xing4_prefill_kernel_against_ragged_dot(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# DeepSeek-V2's share at the published widths (PR 32): the same kernels at
+# 128 heads and at 40 held experts of 5120 x 1536
+# ---------------------------------------------------------------------------
+
+def test_mla_paged_decode_parity_at_128_heads():
+    """128 heads over 640-lane pool rows, blocks of 256: rows of uneven
+    length, on block edges, and idle."""
+    from paddle_tpu.ops import mla_decode as md
+    L, BT, P, dc, H, MB = 2, 256, 640, 512, 128, 4
+    pos = np.asarray([1000, 0, 255, 256, 257, 0, 700, 1023], np.int32)
+    b = len(pos)
+    tables = np.zeros((b, MB), np.int32)
+    nxt = 1
+    for r in (0, 2, 3, 4, 6, 7):        # rows 1 and 5 idle against scratch
+        n = pos[r] // BT + 1
+        tables[r, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    pool = rand(0, L, nxt, BT, P)
+    q = rand(1, b, H, P, scale=0.2)
+    new = rand(2, b, P)
+    kw = dict(layer=1, d_c=dc, scale=192 ** -0.5 * 1.5896)
+    want_o, want_pool = jax.jit(
+        lambda *a: md.mla_paged_decode_reference(*a, **kw))(
+            q, new, pool, jnp.asarray(tables), jnp.asarray(pos))
+    got_o, got_pool = jax.jit(
+        lambda *a: md._mla_paged_decode_pallas(*a, **kw))(
+            q, new, pool, jnp.asarray(tables), jnp.asarray(pos))
+    live = [0, 2, 3, 4, 6, 7]
+    assert_close(np.asarray(got_o)[live], np.asarray(want_o)[live],
+                 rtol=2e-2, atol=5e-3)
+    got_pool, want_pool = np.asarray(got_pool), np.asarray(want_pool)
+    assert (got_pool[:, 1:] == want_pool[:, 1:]).all()
+
+
+def _share_picks(T, seed):
+    """(T, 6) picks of a 160-wide group-limited router (8 groups, 3
+    kept) mapped onto the 40 experts held by chip 0: about a quarter
+    fall here, the rest are 40 (no expert); experts 3 and 17 get none."""
+    from paddle_tpu.nn.layers.moe import group_limited_topk_routing
+    from paddle_tpu.ops import moe_grouped as mg
+    logits = np.array(jax.random.normal(jax.random.PRNGKey(seed), (T, 160)))
+    logits[:, [3, 17]] = -1e9
+    idx, w = group_limited_topk_routing(jnp.asarray(logits), 6, n_group=8,
+                                        topk_group=3, scaling=16.0)
+    return mg.held_rows(idx, 0, 40), w
+
+
+def test_moe_grouped_ffn_parity_for_a_share_at_published_widths():
+    """40 held experts of 5120 x 1536 under 128 rows top-6 of a router
+    160 wide: three quarters of the picks lie on other chips."""
+    from paddle_tpu.ops import moe_grouped as mg
+    b, E, C, F = 128, 40, 5120, 1536
+    x = rand(0, b, C, scale=1.0)
+    wg, wu = rand(1, E, C, F, scale=0.02), rand(2, E, C, F, scale=0.02)
+    wd = rand(3, E, F, C, scale=0.02)
+    idx, w = _share_picks(b, 4)
+    here = float((np.asarray(idx) < E).mean())
+    assert 0.15 < here < 0.35
+    active = jnp.asarray(np.arange(b) % 3 != 0)
+    dense = mg.dense_weights(idx, w, active, E)
+    want = jax.jit(mg.moe_grouped_ffn_reference)(x, dense, wg, wu, wd)
+    got = jax.jit(mg._moe_grouped_ffn_pallas)(x, dense, wg, wu, wd)
+    assert_close(got, want, rtol=2e-2, atol=2e-2 * float(
+        np.abs(np.asarray(want, np.float32)).max()))
+    assert np.abs(np.asarray(got, np.float32)[::3]).max() == 0.0
+    assert np.abs(np.asarray(want, np.float32)).max() > 0.1
+
+
+@pytest.mark.parametrize("T", [256, 1024, 3584])
+def test_moe_prefill_parity_for_a_share_at_published_widths(T):
+    """The grouped prefill kernel with its weights in slices (two whole
+    experts of 5120 x 1536 do not fit VMEM) over the cell's smallest,
+    median and largest bucket, against ``ragged_dot``, through the
+    wrapper: picks of no expert add nothing."""
+    from paddle_tpu.ops import moe_grouped as mg
+    E, C, F = 40, 5120, 1536
+    assert mg._slice_width(C, F) == 512 and mg.prefill_path(C, F) == "kernel"
+    x = rand(0, T, C, scale=1.0)
+    wg, wu = rand(1, E, C, F, scale=0.02), rand(2, E, C, F, scale=0.02)
+    wd = rand(3, E, F, C, scale=0.02)
+    idx, w = _share_picks(T, 5)
+    w = jnp.where(idx < E, w, 0.0)
+    sizes = np.bincount(np.asarray(idx).reshape(-1), minlength=E + 1)
+    assert sizes[[3, 17]].sum() == 0 and sizes[E] > 2 * sizes[:E].sum()
+    want = jax.jit(mg.moe_prefill_ragged_dot)(x, idx, w, wg, wu, wd)
+    got = jax.jit(mg.moe_grouped_ffn_prefill)(x, idx, w, wg, wu, wd)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    top = float(np.abs(np.asarray(want, np.float32)).max())
+    assert top > 0.1
+    assert_close(got, want, rtol=2e-2, atol=2e-2 * top)
+
+
+# ---------------------------------------------------------------------------
 # the paged decode kernel's ragged walk at the chat cells' widths (PR 28)
 # ---------------------------------------------------------------------------
 
