@@ -38,9 +38,9 @@ import jax.numpy as jnp
 
 from paddle_tpu import nn
 from paddle_tpu.models.xing4 import (LatentCausalLM, MLAConfig,
-                                     Xing4Attention, Xing4MLP, _proj, _sub,
-                                     _swiglu, latent_plan, mla_layered,
-                                     mla_paged, rope_tables)
+                                     Xing4Attention, Xing4MLP, _itemsize,
+                                     _proj, _sub, _swiglu, latent_plan,
+                                     mla_layered, mla_paged, rope_tables)
 from paddle_tpu.nn import initializer as init
 from paddle_tpu.nn.layers.moe import (GroupedSwiGLUExperts,
                                       group_limited_topk_routing)
@@ -334,7 +334,8 @@ class DeepseekV2ForCausalLM(LatentCausalLM):
         if "model.layers.0.self_attn.kv_b_proj.weight" not in state:
             return None     # a quantized or otherwise foreign state
         cfg = self.cfg
-        meta = {**latent_plan(cfg), "step_counters": STEP_COUNTERS,
+        meta = {**latent_plan(cfg, _itemsize(state)),
+                "step_counters": STEP_COUNTERS,
                 "prefill_moe": {
                     "layers": cfg.num_layers - cfg.first_k_dense_replace,
                     "k": cfg.num_experts_per_tok,

@@ -41,13 +41,11 @@ from paddle_tpu.nn import initializer as init
 from paddle_tpu.nn.layers import hyper_connection as hc
 from paddle_tpu.nn.layers.moe import (GroupedSwiGLUExperts,
                                       sigmoid_topk_routing)
-from paddle_tpu.ops import mla_decode, moe_grouped
+from paddle_tpu.ops import mla_decode, mla_prefill, moe_grouped
 from paddle_tpu.ops import rope as rope_ops
 from paddle_tpu.ops.rms_norm import rms_norm
 
 _HI = jax.lax.Precision.HIGHEST
-NEG_INF = -1e30
-_Q_BLOCK = 256      # query rows of one block of the expanded attention
 # what ``decode_step`` counts, in the order it returns them
 STEP_COUNTERS = ("moe_layer_steps", "moe_experts_touched", "moe_rows_max",
                  "moe_rows")
@@ -181,41 +179,36 @@ def _kvb(w: Dict, cfg: MLAConfig):
         cfg.qk_nope_head_dim + cfg.v_head_dim)
 
 
+def _attn_plan(cfg: MLAConfig, s: int, S: int, start_pos, itemsize: int):
+    """``ops.mla_prefill.kernel_plan`` at this configuration's head
+    sizes: not None where the expanded attention of ``s`` queries over
+    ``S`` cached rows takes the flash kernel here."""
+    return mla_prefill.kernel_plan(
+        s, S, start_pos, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+        cfg.v_head_dim, itemsize)
+
+
 def mla_expanded(w: Dict, cfg: MLAConfig, q_n, q_r, latent, start_pos):
     """Causal attention in the expanded form: the cached rows ``latent``
     (b, S, d_c + d_r) become per-head keys and values through ``W_kvb``.
-    The queries sit at positions ``start_pos + arange(s)``. Scores in
-    blocks of ``_Q_BLOCK`` query rows, softmax in float32.
-    -> (b, s, H * d_v)."""
-    b, s, H, dn = q_n.shape
-    dc, dv = cfg.kv_lora_rank, cfg.v_head_dim
-    S = latent.shape[1]
-    kv = jnp.einsum("bsc,chd->bshd", latent[..., :dc], _kvb(w, cfg))
-    k_n, v, k_r = kv[..., :dn], kv[..., dn:], latent[..., dc:]
-    scale = cfg.softmax_scale
-    kpos = jnp.arange(S)
-
-    def block(args):
-        qn, qr, qpos = args                    # (b, qb, H, .), (qb,)
-        sc = (jnp.einsum("bqhd,bkhd->bhqk", qn, k_n,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bqhd,bkd->bhqk", qr, k_r,
-                           preferred_element_type=jnp.float32)) * scale
-        live = kpos[None, :] <= qpos[:, None]
-        p = jax.nn.softmax(jnp.where(live[None, None], sc, NEG_INF), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
-
-    qpos = start_pos + jnp.arange(s)
-    if s <= _Q_BLOCK or s % _Q_BLOCK:
-        out = block((q_n, q_r, qpos))
-    else:
-        nq = s // _Q_BLOCK
-        split = lambda a: jnp.moveaxis(
-            a.reshape(b, nq, _Q_BLOCK, *a.shape[2:]), 1, 0)
-        out = jax.lax.map(block, (split(q_n), split(q_r),
-                                  qpos.reshape(nq, _Q_BLOCK)))
-        out = jnp.moveaxis(out, 0, 1).reshape(b, s, H, dv)
-    return out.reshape(b, s, H * dv)
+    The queries sit at positions ``start_pos + arange(s)``. Through
+    ``ops.mla_prefill``: its flash kernel where :func:`_attn_plan` takes
+    the shapes (a TPU, a Python ``start_pos`` with ``S == start_pos +
+    s``, both multiples of 128), the expansion then written head-major
+    for it; else its ``jnp`` reference. -> (b, s, H * d_v)."""
+    s, dn = q_n.shape[1], q_n.shape[-1]
+    dc = cfg.kv_lora_rank
+    c_kv, k_r, kvb = latent[..., :dc], latent[..., dc:], _kvb(w, cfg)
+    if _attn_plan(cfg, s, latent.shape[1], start_pos,
+                  q_n.dtype.itemsize) is not None:
+        k_n = jnp.einsum("bsc,chd->bhsd", c_kv, kvb[..., :dn])
+        v = jnp.einsum("bsc,chd->bhsd", c_kv, kvb[..., dn:])
+        return mla_prefill.mla_flash_prefill(
+            q_n, q_r, k_n, v, k_r, scale=cfg.softmax_scale,
+            start_pos=start_pos)
+    kv = jnp.einsum("bsc,chd->bshd", c_kv, kvb)
+    return mla_prefill.reference(q_n, q_r, kv[..., :dn], kv[..., dn:], k_r,
+                                 cfg.softmax_scale, start_pos)
 
 
 def mla_absorb_query(w: Dict, cfg: MLAConfig, q_n, q_r, lanes: int):
@@ -266,11 +259,15 @@ def mla_paged(w: Dict, cfg: MLAConfig, xn, cos, sin, pool, tables, positions,
                       w["o_proj.weight"]), pool
 
 
-def latent_plan(cfg: MLAConfig) -> Dict:
+def latent_plan(cfg: MLAConfig, itemsize: int) -> Dict:
     """The part of a ``fused_decode_plan`` that the latent cache
-    decides: ``arch`` ``"mla_moe"``, the pool's ``cache_lanes``, and
+    decides: ``arch`` ``"mla_moe"``, the pool's ``cache_lanes``,
     ``to_lanes`` / ``from_lanes`` between the prefill cache
-    (``[{"ckv": (n, len, d_c + d_r)}]`` a layer) and pool rows."""
+    (``[{"ckv": (n, len, d_c + d_r)}]`` a layer) and pool rows, and
+    ``prefill_attn_calls(R, s_pad)``: the layers of a wave prefill of
+    ``s_pad`` positions behind ``R`` cached ones whose attention takes
+    the flash kernel (the predicate :func:`mla_expanded` dispatches on,
+    at activations of ``itemsize`` bytes)."""
     lanes = mla_decode.pool_lanes(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
     ld = cfg.latent_dim
 
@@ -286,8 +283,18 @@ def latent_plan(cfg: MLAConfig) -> Dict:
             rows[l, :, :, :ld].astype(c["ckv"].dtype))}
             for l, c in enumerate(cache)]
 
+    def prefill_attn_calls(R: int, s_pad: int) -> int:
+        takes = _attn_plan(cfg, s_pad, R + s_pad, R, itemsize) is not None
+        return cfg.num_layers if takes else 0
+
     return {"arch": "mla_moe", "cache_lanes": lanes, "to_lanes": to_lanes,
-            "from_lanes": from_lanes}
+            "from_lanes": from_lanes,
+            "prefill_attn_calls": prefill_attn_calls}
+
+
+def _itemsize(state: Dict) -> int:
+    """Bytes of an activation of a model with this state."""
+    return state["model.embed_tokens.weight"].dtype.itemsize
 
 
 def route(w: Dict, cfg: Xing4Config, x):
@@ -601,7 +608,8 @@ class Xing4ForCausalLM(LatentCausalLM):
         if "model.layers.0.self_attn.kv_b_proj.weight" not in state:
             return None     # a quantized or otherwise foreign state
         cfg = self.cfg
-        meta = {**latent_plan(cfg), "step_counters": STEP_COUNTERS,
+        meta = {**latent_plan(cfg, _itemsize(state)),
+                "step_counters": STEP_COUNTERS,
                 # what a prefill's routed experts go through here
                 "prefill_moe": {
                     "layers": cfg.num_layers - cfg.first_k_dense_replace,

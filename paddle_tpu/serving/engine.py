@@ -921,6 +921,9 @@ class ServingEngine:
         self._prefill_moe = meta.get("prefill_moe")
         self._prefill_counted = (self._prefill_moe or {}).get(
             "rows") == "counted"
+        # a plan whose prefill attention may take a kernel says for
+        # which waves: (R, s_pad) -> the layers whose attention does
+        self._prefill_attn = meta.get("prefill_attn_calls")
         # tpu-lint: volatile(a property of the backend)
         self._host_aliased = jax.default_backend() == "cpu"
         blocks_plan = meta.get("blocks")
@@ -1612,9 +1615,12 @@ class ServingEngine:
         and ``prefill_moe_rows`` (a plan with ``prefill_moe``) sum, over
         the waves landed, the expert layers that went through the grouped
         prefill kernel and their routed rows, pad positions included
-        (:meth:`_wave_moe`; where the plan's ``rows`` are ``"counted"``,
+        (:meth:`_wave_kernels`; where the plan's ``rows`` are ``"counted"``,
         the picks that fell on held experts, as the prefill program
         counted them); both stay 0 where the path is ``ragged_dot``.
+        ``prefill_attn_calls`` (a plan with the key) sums, over the
+        waves landed, the layers whose attention took the flash prefill
+        kernel: the plan's own predicate on the wave's shape.
         Per-step distributions live in the ``serving.step_*_s`` registry
         histograms."""
         return dict(steps=0, decode_tokens=0, idle_slot_steps=0,
@@ -1638,6 +1644,8 @@ class ServingEngine:
                        dict(kv_blocks_walked=0, kv_blocks_dense=0)),
                     **({} if self._prefill_moe is None else
                        dict(prefill_moe_calls=0, prefill_moe_rows=0)),
+                    **({} if self._prefill_attn is None else
+                       dict(prefill_attn_calls=0)),
                     **{name: 0 for name in self._step_counters})
 
     def reset_stats(self):
@@ -3272,22 +3280,27 @@ class ServingEngine:
             wave.append((slot_idx, slot, hits, R, s_pad))
             wave_idx.add(slot_idx)
 
-    def _wave_moe(self, s_pad: int, n: int) -> Dict:
-        """What a wave of ``n`` rows of ``s_pad`` positions sends through
-        the grouped prefill kernel: one call an expert layer, k routed
-        rows a position a layer (pad positions route too). Known from
-        the wave's shape and the plan's ``prefill_moe``; nothing where
-        the plan routes no experts. Where the plan's rows are
+    def _wave_kernels(self, R: int, s_pad: int, n: int) -> Dict:
+        """What a wave of ``n`` rows of ``s_pad`` positions behind ``R``
+        cached ones sends through the plan's prefill kernels, known from
+        the wave's shape. ``prefill_attn_calls``: the layers whose
+        attention takes the flash kernel (the plan's predicate).
+        ``prefill_moe_calls`` and ``prefill_moe_rows``: one call of the
+        grouped kernel an expert layer, k routed rows a position a layer
+        (pad positions route too); where the plan's rows are
         ``"counted"`` only the calls: the rows are the program's own
-        count, known once the wave is pulled."""
+        count, known once the wave is pulled. Nothing for a plan without
+        the keys."""
+        out = {}
+        if self._prefill_attn is not None:
+            out["prefill_attn_calls"] = self._prefill_attn(R, s_pad)
         pm = self._prefill_moe
-        if pm is None:
-            return {}
-        calls = pm["layers"] if pm["path"] == "kernel" else 0
-        if self._prefill_counted:
-            return dict(prefill_moe_calls=calls)
-        return dict(prefill_moe_calls=calls,
-                    prefill_moe_rows=calls * pm["k"] * s_pad * n)
+        if pm is not None:
+            calls = pm["layers"] if pm["path"] == "kernel" else 0
+            out["prefill_moe_calls"] = calls
+            if not self._prefill_counted:
+                out["prefill_moe_rows"] = calls * pm["k"] * s_pad * n
+        return out
 
     def _run_prefill_group(self, R, s_pad, grp):
         """Run one batched prefill program and adopt each row's slot
@@ -3295,9 +3308,9 @@ class ServingEngine:
         pulls + slot adoption) is timed as the step's wave-prefill
         segment."""
         n = len(grp)
-        moe = self._wave_moe(s_pad, n)
+        sent = self._wave_kernels(R, s_pad, n)
         with self._phase("serving.step.prefill", rows=n, s_pad=s_pad,
-                         R=R, **moe) as ph:
+                         R=R, **sent) as ph:
             BT = self.block_tokens
             L = self._num_layers
             hb = R // BT
@@ -3361,16 +3374,16 @@ class ServingEngine:
             if self._prefill_counted:
                 # the picks that fell on held experts, as the program
                 # counted them (0 where no kernel ran)
-                moe["prefill_moe_rows"] = (
-                    int(tok_np[n]) if moe["prefill_moe_calls"] else 0)
-                ph.set(prefill_moe_rows=moe["prefill_moe_rows"])
+                sent["prefill_moe_rows"] = (
+                    int(tok_np[n]) if sent["prefill_moe_calls"] else 0)
+                ph.set(prefill_moe_rows=sent["prefill_moe_rows"])
             for r, (slot_idx, slot, hits, _, _) in enumerate(grp):
                 self._adopt_slot(
                     slot_idx, slot, int(tok_np[r]),
                     None if lanes_np is None else lanes_np[:, r],
                     None if kv_np is None else kv_np[:, r])
             self._tick_prefills.append((R, s_pad, n))
-            for key, v in moe.items():
+            for key, v in sent.items():
                 self.stats[key] += v
         if warm:        # compile spikes must not poison the estimator
             new_toks = sum(len(s.feed) - s.R for _, s, _, _, _ in grp)

@@ -68,3 +68,37 @@ def test_moe_prefill_kernel_compiles_at_the_cells_widths(one_chip, widths,
     # the aligned copy of the rows and the kernel's output, no more
     rows = tokens * k + E * 16 + tile
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * C * 2
+
+
+# heads, queries, cached prefix: DeepSeek-V2's 128 heads and Xing4.0's 32
+# at the cells' smallest and largest bucket, with and without a prefix
+# (behind 256 cached rows 3,584 + 256 keys leave 256 over after the
+# blocks of 512)
+@pytest.mark.parametrize("H", [128, 32])
+@pytest.mark.parametrize("s, R", [(256, 0), (256, 256), (3584, 0),
+                                  (3584, 256)])
+def test_mla_flash_prefill_compiles_at_the_cells_widths(one_chip,
+                                                        monkeypatch, H, s, R):
+    """``mla_flash_prefill`` at the published head sizes: a head's keys
+    and values fit VMEM, one Mosaic call, no loop over score blocks
+    beside it, and nothing kept in HBM but the head-major queries."""
+    import paddle_tpu.ops as ops
+    from paddle_tpu.ops import mla_prefill as mp
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    dn, dr, dv, S = 128, 64, 128, R + s
+    plan = mp.kernel_plan(s, S, R, dn, dr, dv)
+    assert plan == dict(tq=512 if s % 512 == 0 else 256, tk=min(512, S))
+    shape = lambda *d: jax.ShapeDtypeStruct(d, jnp.bfloat16,
+                                            sharding=one_chip)
+    compiled = jax.jit(lambda *a: mp.mla_flash_prefill(
+        *a, scale=0.1147, start_pos=R)).lower(
+        shape(1, s, H, dn), shape(1, s, H, dr), shape(1, H, S, dn),
+        shape(1, H, S, dv), shape(1, S, dr)).compile()
+    text = compiled.as_text()
+    assert mp.KERNEL_NAME in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " while(" not in text
+    # under the expanded k and v plus the output: the two head-major
+    # copies of the queries (the rotary one padded to 128 lanes)
+    expanded = S * H * (dn + dv) * 2 + s * H * dv * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < expanded

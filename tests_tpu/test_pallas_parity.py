@@ -1497,3 +1497,100 @@ def test_paged_decode_compiles_at_twice_the_cells_slots(arch, b, K1):
             S((K1 * b, h), bf), {k: S(s, bf) for k, s in shapes.items()},
             S(pool_shape, bf), S((b, MB), jnp.int32),
             S((b,), jnp.int32)).compile()
+
+
+# ---------------------------------------------------------------------------
+# the prefill's expanded latent attention (PR 33): mla_flash_prefill
+# against the jnp reference at the published widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("H, s, R", [
+    (128, 3584, 0),     # DeepSeek-V2's largest bucket
+    (32, 3584, 0),      # Xing4.0's
+    (128, 768, 256),    # a cached prefix, and keys left over behind the
+    (32, 1280, 256),    # blocks of 512
+    (128, 256, 0),      # the smallest bucket: one block, the diagonal
+])
+def test_mla_flash_prefill_parity_at_published_widths(H, s, R):
+    from paddle_tpu.ops import mla_prefill as mp
+    dn, dr, dv, S = 128, 64, 128, R + s
+    q_n, q_r = rand(0, 1, s, H, dn), rand(1, 1, s, H, dr)
+    k_n, v = rand(2, 1, H, S, dn), rand(3, 1, H, S, dv)
+    k_r = rand(4, 1, S, dr)
+    scale = 192 ** -0.5 * 1.5896
+    assert mp.kernel_plan(s, S, R, dn, dr, dv) is not None
+    got = jax.jit(lambda *a: mp.mla_flash_prefill(
+        *a, scale=scale, start_pos=R))(q_n, q_r, k_n, v, k_r)
+    want = jax.jit(lambda *a: mp.reference(
+        a[0], a[1], jnp.swapaxes(a[2], 1, 2), jnp.swapaxes(a[3], 1, 2),
+        a[4], scale, R))(q_n, q_r, k_n, v, k_r)
+    assert got.shape == want.shape == (1, s, H * dv)
+    assert_close(got, want, rtol=2e-2, atol=5e-3)
+    # the first query sees R + 1 keys and the last all S: both rows are
+    # weighted means of v, and differ from each other
+    assert np.abs(np.asarray(want, np.float32)[0, 0]
+                  - np.asarray(want, np.float32)[0, -1]).max() > 0.05
+
+
+def test_mla_flash_prefill_grad_is_the_references():
+    """``jax.grad`` through the kernel on the chip: the forward is the
+    kernel's, the backward the reference's."""
+    from paddle_tpu.ops import mla_prefill as mp
+    H, s, dn, dr, dv = 4, 256, 128, 64, 128
+    ops = (rand(0, 1, s, H, dn), rand(1, 1, s, H, dr), rand(2, 1, H, s, dn),
+           rand(3, 1, H, s, dv), rand(4, 1, s, dr))
+    loss = lambda f: lambda *a: (f(*a).astype(jnp.float32) ** 2).sum()
+    got = jax.jit(jax.grad(loss(lambda *a: mp.mla_flash_prefill(
+        *a, scale=0.1, start_pos=0)), argnums=(0, 1, 2, 3, 4)))(*ops)
+    want = jax.jit(jax.grad(loss(lambda *a: mp._flash_reference(
+        *a, 0.1, 0)), argnums=(0, 1, 2, 3, 4)))(*ops)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        assert_close(g, w, rtol=5e-2, atol=2e-2)
+
+
+def test_serving_deepseek_v2_prefill_through_the_flash_kernel(monkeypatch):
+    """A DeepSeek-V2 of moderate widths served twice on the chip: its
+    wave prefills' attention through ``mla_flash_prefill`` and through
+    the ``jnp`` reference. Token-exact, or parting at a near-tie; the
+    prefill programs hold one kernel call a layer and no loop over
+    score blocks, and the engine counts the calls."""
+    import paddle_tpu
+    from paddle_tpu import serving
+    from paddle_tpu.models import xing4
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                               DeepseekV2ForCausalLM)
+    from paddle_tpu.ops import mla_prefill as mp
+    cfg = DeepseekV2Config.tiny(
+        vocab_size=512, hidden_size=512, intermediate_size=1024,
+        moe_intermediate_size=256, num_heads=8, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=256,
+        q_lora_rank=256, max_position_embeddings=1024)
+    paddle_tpu.seed(0)
+    m = DeepseekV2ForCausalLM(cfg).bfloat16()
+    m.eval()
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(3, 512, (n,)) for n in (40, 300, 600)]
+
+    def served():
+        eng = serving.ServingEngine(m, max_slots=4, block_tokens=128,
+                                    max_seq_len=1024)
+        rids = [eng.submit(serving.Request(p, max_new_tokens=8))
+                for p in prompts]
+        eng.drain(max_steps=200)
+        out = [eng.pop_result(r).tokens.tolist() for r in rids]
+        text = "".join(low.as_text() for low in
+                       eng.lowered_programs("prefill").values())
+        stats = dict(eng.stats)
+        eng.close()
+        return out, text, stats
+
+    got, text, stats = served()
+    assert mp.KERNEL_NAME in text
+    assert stats["prefill_attn_calls"] == cfg.num_layers * len(prompts)
+    monkeypatch.setattr(xing4, "_attn_plan", lambda *a: None)
+    ref, text, stats = served()
+    assert mp.KERNEL_NAME not in text
+    assert stats["prefill_attn_calls"] == 0
+    for p, a, b in zip(prompts, got, ref):
+        _assert_same_up_to_near_tie(m, p, a, b, tol=0.05)
