@@ -24,3 +24,7 @@ from paddle_tpu.models.ernie import (  # noqa: F401
 )
 from paddle_tpu.models.unet import UNetConfig, UNetModel  # noqa: F401
 from paddle_tpu.models.xing4 import Xing4Config, Xing4ForCausalLM  # noqa: F401
+from paddle_tpu.models.minicpm_sala import (  # noqa: F401
+    MiniCPMSALAConfig,
+    MiniCPMSALAForCausalLM,
+)

@@ -22,6 +22,14 @@ def use_pallas() -> bool:
     return bool(flag("FLAGS_use_pallas_kernels")) and on_tpu()
 
 
+def pallas_mode() -> tuple:
+    """(run the Mosaic kernels, in interpret mode): on a TPU the kernels
+    as compiled; elsewhere only under ``FLAGS_pallas_interpret``."""
+    # tpu-lint: allow(host-sync): flag() is a host-side config read
+    interp = bool(flag("FLAGS_pallas_interpret")) and not use_pallas()
+    return use_pallas() or interp, interp
+
+
 from paddle_tpu.ops import flash_attention  # noqa: F401,E402
 from paddle_tpu.ops import rms_norm  # noqa: F401,E402
 from paddle_tpu.ops import rope  # noqa: F401,E402

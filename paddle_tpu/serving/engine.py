@@ -903,7 +903,11 @@ class ServingEngine:
                 chunk_tokens=chunk_tokens is not None,
                 mesh=mesh is not None and getattr(mesh, "size", 1) > 1,
                 layout=layout is not None,
-                offload=bool(offload))
+                offload=bool(offload),
+                # a fixed-size state a slot is kept at no block's edge,
+                # so a shared prefix cannot be entered
+                prefix_caching=bool(prefix_caching)
+                and "slot_state" in meta)
             for option, given in refused.items():
                 if given:
                     raise ValueError(
@@ -924,6 +928,16 @@ class ServingEngine:
         # a plan whose prefill attention may take a kernel says for
         # which waves: (R, s_pad) -> the layers whose attention does
         self._prefill_attn = meta.get("prefill_attn_calls")
+        # ... or sends other kernels: (R, s_pad) -> {counter: calls}
+        self._prefill_calls = meta.get("prefill_calls")
+        # a hybrid plan (docs/SERVING.md §Architectures the engine
+        # takes): pool rows for ``pool_layers`` of the layers only, a
+        # second paged leaf on the same block tables (``pool_aux``:
+        # one row every ``stride`` tokens, ``lanes`` wide), and leaves of
+        # fixed size a slot (``slot_state``: name -> ((layers, ...),
+        # dtype), held as (layers, max_slots, ...)) that the step carries
+        self._pool_aux = meta.get("pool_aux")
+        self._slot_state = meta.get("slot_state")
         # tpu-lint: volatile(a property of the backend)
         self._host_aliased = jax.default_backend() == "cpu"
         blocks_plan = meta.get("blocks")
@@ -947,7 +961,8 @@ class ServingEngine:
         self.max_slots = int(max_slots)
         self.max_blocks_per_slot = max_seq_len // block_tokens
 
-        L = self._num_layers = self._count_layers()
+        L = self._num_layers = int(meta.get("pool_layers",
+                                            self._count_layers()))
         # one pool row: a plan's own ``cache_lanes``, else [k | v]
         nkv, hd = meta.get("num_kv_heads"), meta.get("head_dim")
         self._cache_lanes = int(meta["cache_lanes"] if self._own_step
@@ -984,9 +999,17 @@ class ServingEngine:
             # already lives on the mesh (no implicit transfer at
             # dispatch — the 0-H2D steady-tick pin holds under mp too)
             self._state = layout.place_replicated(self._state)
+        aux = self._pool_aux
+        if aux is not None and block_tokens % aux["stride"]:
+            raise ValueError(
+                f"block_tokens {block_tokens} must be a multiple of the "
+                f"{aux['stride']} tokens a row of arch {self.arch!r}'s "
+                f"second pool leaf covers")
         bpb = self.block_bytes = (
             L * block_tokens * self._cache_lanes
-            * (1 if self.kv_int8 else 2))
+            * (1 if self.kv_int8 else 2)
+            + (0 if aux is None else
+               L * (block_tokens // aux["stride"]) * aux["lanes"] * 2))
         if num_blocks is None:
             if pool_bytes is not None:
                 num_blocks = max(2, int(pool_bytes) // bpb)
@@ -1000,6 +1023,17 @@ class ServingEngine:
         self.kv_pool = jnp.zeros(
             (L, num_blocks, block_tokens, self._cache_lanes),
             self.cache_dtype)
+        if aux is not None or self._slot_state is not None:
+            # one donated pytree through every program: the paged leaves
+            # (rows, or (rows, aux)) and the per-slot leaves
+            pool = self.kv_pool if aux is None else (
+                self.kv_pool, jnp.zeros(
+                    (L, num_blocks, block_tokens // aux["stride"],
+                     aux["lanes"]), self.cache_dtype))
+            self.kv_pool = {"pool": pool, "state": {
+                name: jnp.zeros((shape[0], max_slots, *shape[1:]), dtype)
+                for name, (shape, dtype) in
+                (self._slot_state or {}).items()}}
         if layout is not None:
             # head-dim sharded: each shard's block-table walk reads only
             # its own heads' [k_s|v_s] lanes (zeros are permutation-
@@ -1646,6 +1680,8 @@ class ServingEngine:
                        dict(prefill_moe_calls=0, prefill_moe_rows=0)),
                     **({} if self._prefill_attn is None else
                        dict(prefill_attn_calls=0)),
+                    **({} if self._prefill_calls is None else
+                       {k: 0 for k in self._prefill_calls(0, 0)}),
                     **{name: 0 for name in self._step_counters})
 
     def reset_stats(self):
@@ -2073,9 +2109,12 @@ class ServingEngine:
         counted = self._prefill_counted
         lanes_w = self._cache_lanes
         dkv = lanes_w // 2
+        hybrid = isinstance(self.kv_pool, dict)
+        aux = self._pool_aux
         if own:
             to_lanes = self.meta["to_lanes"]
-            from_lanes = self.meta["from_lanes"]
+            from_lanes = self.meta.get("from_lanes")
+            to_state = self.meta.get("to_state")
         else:
             nkv, hd = self.meta["num_kv_heads"], self.meta["head_dim"]
             to_lanes, from_lanes = _kv_to_lanes, functools.partial(
@@ -2090,7 +2129,7 @@ class ServingEngine:
         mp_axis = self._mp_axis
 
         def impl(state, pool, prefix, ids, last_idx, seeds, new_bids,
-                 valid_len):
+                 valid_len, slots=None):
             # prefix: bf16 pools pass the (n, hb) shared block ids and
             # gather the prefix KV HERE (no separate dispatch); int8
             # pools pass the host-kept bf16 copies (L, n, R, 2dkv) —
@@ -2118,6 +2157,19 @@ class ServingEngine:
                     **({"positions": last_idx} if own else {}),
                     **({"moe_rows": True} if counted else {}))
             kv_flat = to_lanes(cache)            # (L, n, cache_len, lanes)
+            if hybrid:
+                # a hybrid plan: the second paged leaf lands in the same
+                # fresh blocks, the rows' fixed-size state in their slots
+                slot_state = {
+                    name: pool["state"][name].at[:, slots].set(
+                        leaf.astype(pool["state"][name].dtype))
+                    for name, leaf in to_state(cache).items()}
+                pool = pool["pool"]
+                if aux is not None:
+                    (kv_flat, aux_flat), (pool, aux_pool) = kv_flat, pool
+                    aux_pool = aux_pool.at[:, new_bids].set(aux_flat.reshape(
+                        -1, n, nb_new, BT // aux["stride"],
+                        aux["lanes"]).astype(aux_pool.dtype))
             logits = out if own else jnp.take_along_axis(
                 out, last_idx[:, None, None], axis=1)[:, 0]   # (n, vocab)
             keys = _row_keys(seeds)
@@ -2154,6 +2206,9 @@ class ServingEngine:
             if mp_axis is not None:
                 blk = mp_local_kv_lastdim(blk, mp_axis)
             pool = pool.at[:, new_bids].set(blk.astype(pool.dtype))
+            if hybrid:
+                pool = {"pool": pool if aux is None else (pool, aux_pool),
+                        "state": slot_state}
             return tok, pool
 
         # `state` flows as a traced argument (matching generate) so the
@@ -2161,7 +2216,7 @@ class ServingEngine:
         from jax.sharding import PartitionSpec as P
         lay = self.layout
         pspec = lay.pool_spec() if lay is not None else None
-        in_specs = (P(), pspec) + (P(),) * 6
+        in_specs = (P(), pspec) + (P(),) * (7 if hybrid else 6)
         out_specs = ((P(), pspec, P(), P()) if int8 else (P(), pspec))
         jitted = self._wrap_program(impl, in_specs, out_specs,
                                     donate_argnums=(1,))
@@ -3294,6 +3349,8 @@ class ServingEngine:
         out = {}
         if self._prefill_attn is not None:
             out["prefill_attn_calls"] = self._prefill_attn(R, s_pad)
+        if self._prefill_calls is not None:
+            out.update(self._prefill_calls(R, s_pad))
         pm = self._prefill_moe
         if pm is not None:
             calls = pm["layers"] if pm["path"] == "kernel" else 0
@@ -3309,8 +3366,12 @@ class ServingEngine:
         segment."""
         n = len(grp)
         sent = self._wave_kernels(R, s_pad, n)
+        # a plan that names its prefill kernels also gets the rows' true
+        # lengths: what its kernels' work is counted from
+        true_len = ({} if self._prefill_calls is None else
+                    {"true_len": sum(len(g[1].feed) for g in grp)})
         with self._phase("serving.step.prefill", rows=n, s_pad=s_pad,
-                         R=R, **sent) as ph:
+                         R=R, **true_len, **sent) as ph:
             BT = self.block_tokens
             L = self._num_layers
             hb = R // BT
@@ -3358,7 +3419,9 @@ class ServingEngine:
                 tok, self.kv_pool = fn(
                     self.kv_pool, self._up(prefix), self._up(ids),
                     self._up(last_idx), self._up(seeds),
-                    self._up(new_bids), self._up(valid))
+                    self._up(new_bids), self._up(valid),
+                    *((self._up(np.asarray([g[0] for g in grp], np.int32)),)
+                      if isinstance(self.kv_pool, dict) else ()))
                 lanes_np = kv_np = None
             if self._flight_q:
                 # the wave went out BEHIND the step program in flight
@@ -3558,8 +3621,13 @@ class ServingEngine:
                 # carries the last program's counters behind its tokens
                 plan_t = model.fused_decode_plan(state)
                 x = plan_t["embed"](toks[:ms], positions)
-                x, pool, tallies = plan_t["step"](x, pool, tables,
-                                                  positions)
+                if isinstance(pool, dict):      # a hybrid plan's leaves
+                    x, paged, slot_state, tallies = plan_t["step"](
+                        x, pool["pool"], tables, positions, pool["state"])
+                    pool = {"pool": paged, "state": slot_state}
+                else:
+                    x, pool, tallies = plan_t["step"](x, pool, tables,
+                                                      positions)
                 with jax.named_scope("decode.sample"):
                     keys = _row_keys(seeds)
                     ki = jax.vmap(jax.random.fold_in)(keys, counts)

@@ -102,3 +102,50 @@ def test_mla_flash_prefill_compiles_at_the_cells_widths(one_chip,
     # copies of the queries (the rotary one padded to 128 lanes)
     expanded = S * H * (dn + dv) * 2 + s * H * dv * 2
     assert compiled.memory_analysis().temp_size_in_bytes < expanded
+
+
+# MiniCPM-SALA's five kernels at the published widths (32 heads of 128,
+# 2 KV heads) and the cell's sizes: 16 slots, pages of 2,048 tokens, 17
+# pages a slot, a prefill chunk of 2,048 over the largest bucket
+@pytest.mark.parametrize("kernel", [
+    "lightning_prefill", "lightning_decode", "sparse_select",
+    "sparse_paged_decode", "sparse_prefill_attn"])
+def test_sala_kernels_compile_at_the_published_widths(one_chip, kernel):
+    from paddle_tpu.ops import lightning_attention as la
+    from paddle_tpu.ops import sparse_paged as spg
+    sp = spg.SparseConfig()
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    shape = lambda s, dt=bf: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    b, H, d, MB, NB = 16, 32, 128, 17, 273
+    tok = shape((1, 2048, H, d))
+    calls = {
+        "lightning_prefill": (
+            lambda *a: la._lightning_prefill_pallas(*a, chunk=256,
+                                                    interpret=False),
+            (tok, tok, tok, shape((1, H, d, d), f32), shape((1,), i32))),
+        "lightning_decode": (
+            lambda *a: la._lightning_decode_pallas(*a, layer=11,
+                                                   interpret=False),
+            (shape((b, H, d)),) * 3 + (shape((12, b, H, d, d), f32),
+                                      shape((b,), jnp.bool_))),
+        "sparse_select": (
+            lambda *a: spg._stage1_pallas(*a, layer=3, sp=sp,
+                                          interpret=False),
+            (shape((b, H, d)), shape((4, NB, 128, 256)),
+             shape((b, MB), i32), shape((b,), i32))),
+        "sparse_paged_decode": (
+            lambda *a: spg._sparse_paged_decode_pallas(
+                *a, layer=3, sp=sp, interpret=False),
+            (shape((b, H, d)), shape((4, NB, 2048, 512)),
+             shape((b, MB), i32), shape((b,), i32),
+             shape((b, 2, sp.max_blocks), i32))),
+        "sparse_prefill_attn": (
+            lambda *a: spg._sparse_prefill_pallas(*a, groups=2,
+                                                  interpret=False),
+            (tok, shape((1, 32768, 512)), shape((1, 2, 2048, 32768),
+                                                jnp.int8), shape((), i32))),
+    }
+    fn, args = calls[kernel]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert kernel in text
