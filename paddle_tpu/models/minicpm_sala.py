@@ -184,23 +184,35 @@ def sparse_qkv(w: Dict, cfg: MiniCPMSALAConfig, x):
             _lin(w, "v_proj", x))
 
 
-def prefill_select(q, kc, t, sp: spg.SparseConfig, NB: int, rows: int = 256):
-    """The blocks every query of a chunk reads: q (n, C, H, d), kc (n, J,
-    G, d), t (n, C) -> (n, C, G, NB) bool; ``rows`` queries at a time, so
-    that the (rows, H, J) scores stay small."""
-    n, C = t.shape
+def prefill_select(q, kc, c0, sp: spg.SparseConfig, NB: int, rows: int = 256):
+    """The blocks every query of a chunk at positions ``c0 ..`` reads: q
+    (n, C, H, d), kc (n, J, G, d) -> (n, C, G, NB) bool. A chunk that
+    ends at or under ``dense_len`` reads every block up to each query's
+    own, which is what :func:`~paddle_tpu.ops.sparse_paged.select_mask`
+    would answer for it: no score is computed there. Past it ``rows``
+    queries at a time, so that the (rows, H, J) scores stay small."""
+    n, C = q.shape[:2]
+    G = kc.shape[2]
+    t = jnp.broadcast_to(c0 + jnp.arange(C), (n, C))
+
+    def dense():
+        return jnp.broadcast_to(spg.visible_blocks(t, sp, NB)[:, :, None],
+                                (n, C, G, NB))
 
     def part(args):
         qb, tb = args
         return spg.select_mask(
             spg.block_scores(spg.stage1(qb, kc, tb, sp), sp, NB), tb, sp)
 
-    if C <= rows or C % rows:
-        return part((q, t))
-    out = lax.map(part, (
-        jnp.moveaxis(q.reshape(n, C // rows, rows, *q.shape[2:]), 1, 0),
-        jnp.moveaxis(t.reshape(n, C // rows, rows), 1, 0)))
-    return jnp.moveaxis(out, 0, 1).reshape(n, C, *out.shape[3:])
+    def select():
+        if C <= rows or C % rows:
+            return part((q, t))
+        out = lax.map(part, (
+            jnp.moveaxis(q.reshape(n, C // rows, rows, *q.shape[2:]), 1, 0),
+            jnp.moveaxis(t.reshape(n, C // rows, rows), 1, 0)))
+        return jnp.moveaxis(out, 0, 1).reshape(n, C, *out.shape[3:])
+
+    return lax.cond(c0 + C <= sp.dense_len, dense, select)
 
 
 def sparse_chunk(w: Dict, cfg: MiniCPMSALAConfig, x, kv, ck, tail, c0):
@@ -224,7 +236,7 @@ def sparse_chunk(w: Dict, cfg: MiniCPMSALAConfig, x, kv, ck, tail, c0):
         axis=1)
     t = jnp.broadcast_to(c0 + jnp.arange(C), (n, C))
     with jax.named_scope("sparse.select"):
-        blocks = prefill_select(q, ck[:, lead:].reshape(n, -1, G, d), t, sp,
+        blocks = prefill_select(q, ck[:, lead:].reshape(n, -1, G, d), c0, sp,
                                 -(-S // sp.block_size))
         mask = spg.prefill_token_mask(blocks, t, S, sp)
     o = spg.sparse_prefill_attention(q, kv, mask, c0 + C, groups=G)
@@ -497,9 +509,13 @@ class MiniCPMSALAForCausalLM(CausalLMBase):
         lead = sp.kernel_size // sp.kernel_stride - 1
 
         def prefill_calls(R: int, s_pad: int) -> Dict:
-            chunks = s_pad // max(la.chunk_size(s_pad, cfg.prefill_chunk), 1)
+            C = max(la.chunk_size(s_pad, cfg.prefill_chunk), 1)
+            chunks = s_pad // C
+            # the chunks that end past dense_len: the others select nothing
+            selecting = chunks - min(sp.dense_len // C, chunks)
             return {"lightning_calls": n_light * chunks,
-                    "sparse_calls": n_sparse * chunks}
+                    "sparse_calls": n_sparse * chunks,
+                    "select_calls": n_sparse * selecting}
 
         meta = {
             "arch": "sala", "cache_lanes": 2 * gd, "pool_layers": n_sparse,

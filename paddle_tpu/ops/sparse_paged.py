@@ -18,7 +18,8 @@ page of its first token. A query at position t with n = t + 1 tokens:
    ``topk`` others of largest ``R_b``: :func:`select_mask` for a
    prefill's queries, :func:`select_list` for a decode step's rows. A
    query with ``n <= dense_len`` reads every block up to its own. The
-   top-k is ``lax.top_k``: exact.
+   ``topk``-th score is found exactly, by a search over bit patterns
+   (:func:`kth_largest`), not by a sort.
 3. softmax attention over the tokens ``<= t`` of those blocks:
    :func:`sparse_paged_decode` walks the chosen ``block_size``-token
    sub-blocks of the pool's pages; :func:`sparse_prefill_attention`
@@ -131,13 +132,46 @@ def block_scores(r, sp: SparseConfig, NB: int):
         for o in range(m + per - 1)])
 
 
+def visible_blocks(t, sp: SparseConfig, NB: int):
+    """t (...,) -> (..., NB) bool: the blocks up to the query's own."""
+    return jnp.arange(NB) <= (t // sp.block_size)[..., None]
+
+
 def _kinds(t, sp: SparseConfig, NB: int):
-    """t (...,) -> (visible, forced) (..., NB) bool."""
+    """t (...,) -> (visible, forced) (..., NB) bool: of the visible
+    blocks, those every query reads."""
     b = jnp.arange(NB)
     tb = (t // sp.block_size)[..., None]
-    visible = b <= tb
+    visible = visible_blocks(t, sp, NB)
     return visible, visible & ((b < sp.init_blocks)
                                | (b > tb - sp.window_blocks))
+
+
+def kth_largest(x, k: int):
+    """x (..., N) float32 without NaN, 1 <= k <= N -> the k-th largest
+    entry of every row, (..., 1): what ``lax.top_k(x, k)[0][..., -1:]``
+    is, bit for bit, without the sort. A float's bits, the magnitude
+    flipped where the sign is set, order as the floats do; the answer is
+    the largest pattern v with ``count(x >= v) >= k``, built four bits a
+    pass from the top: the 15 candidates ``v | digit`` are counted in
+    one read of x (counts fall as the digit rises, so the digit is the
+    number of candidates with k entries or more at or over them)."""
+    N = x.shape[-1]
+    i = lax.bitcast_convert_type(x, jnp.int32)
+    top = jnp.int32(-2 ** 31)
+    # unsigned patterns in the floats' order: -inf < ... < -0.0 < 0.0 < ...;
+    # the candidates' axis first, so that a count is adds of whole
+    # registers and not a reduction inside each
+    u = jnp.moveaxis(lax.bitcast_convert_type(
+        jnp.where(i < 0, ~i, i ^ top), jnp.uint32), -1, 0).reshape(N, 1, -1)
+    v = jnp.zeros(u.shape[1:], jnp.uint32)
+    for shift in range(28, -1, -4):
+        cands = v | (jnp.arange(1, 16, dtype=jnp.uint32) << shift)[:, None]
+        enough = (u >= cands).sum(0, dtype=jnp.int32) >= k
+        v = v | (enough.sum(0, keepdims=True).astype(jnp.uint32) << shift)
+    v = lax.bitcast_convert_type(v.reshape(x.shape[:-1] + (1,)), jnp.int32)
+    return lax.bitcast_convert_type(jnp.where(v < 0, v ^ top, ~v),
+                                    jnp.float32)
 
 
 def select_mask(R, t, sp: SparseConfig):
@@ -151,7 +185,7 @@ def select_mask(R, t, sp: SparseConfig):
     visible, forced = _kinds(t, sp, NB)
     cand = (visible & ~forced)[..., None, :]
     Rc = jnp.where(cand, R, -jnp.inf)
-    kth = lax.top_k(Rc, k)[0][..., -1:]
+    kth = kth_largest(Rc, k)
     above = Rc > kth
     tied = (Rc == kth) & (Rc > -jnp.inf)
     room = k - above.sum(-1, keepdims=True)

@@ -106,6 +106,86 @@ def test_forward_and_selection_agree_with_the_reference():
             assert (sel[:64].sum(-1) == (np.arange(64) // 8 + 1)[:, None]).all()
 
 
+@pytest.mark.parametrize("chunk, s", [(32, 160), (48, 144), (80, 160)],
+                         ids=["under_and_past", "under_straddling_past",
+                              "straddling_and_past"])
+def test_chunks_on_either_side_of_dense_len_select_as_the_reference(chunk, s):
+    """``dense_len`` 64: a chunk that ends at or under it computes no
+    score, one that straddles it takes the full path and its rows under
+    it are overridden, one past it selects: every query of every sparse
+    layer reads the reference's blocks."""
+    cfg, m, state = tiny(prefill_chunk=chunk)
+    ids = IDS[:1, :s]
+    with jax.default_matmul_precision("highest"):
+        _, _, blocks = sala.hidden_forward(
+            state, cfg, ids, sala.init_cache(cfg, 1, s, jnp.float32),
+            return_blocks=True)
+        keys = published_keys(cfg)
+        for i, layer in enumerate(cfg.layers_of(SPARSE)):
+            sel = np.asarray(ref.selection(state, ids[0], keys, layer))
+            assert (np.asarray(blocks[i, 0]) == sel).all()
+            assert sel[100:].sum(-1).max() == 5 < sel.shape[-1]
+
+
+def _top_k_select_mask(R, t, sp):
+    """:func:`select_mask` as it was while ``lax.top_k`` found the
+    threshold."""
+    NB = R.shape[-1]
+    k = min(sp.topk, NB)
+    visible, forced = spg._kinds(t, sp, NB)
+    Rc = jnp.where((visible & ~forced)[..., None, :], R, -jnp.inf)
+    kth = jax.lax.top_k(Rc, k)[0][..., -1:]
+    above = Rc > kth
+    tied = (Rc == kth) & (Rc > -jnp.inf)
+    room = k - above.sum(-1, keepdims=True)
+    chosen = above | (tied & (jnp.cumsum(tied, -1) <= room))
+    dense = (t + 1 <= sp.dense_len)[..., None, None]
+    return jnp.where(dense, visible[..., None, :],
+                     forced[..., None, :] | chosen)
+
+
+def _scores(case, rows=64, NB=40):
+    R = np.array(jax.random.uniform(jax.random.key(7), (rows, 2, NB)))
+    if case == "equal_rows":
+        R[:] = R[:, :, :1]
+    elif case == "ties_at_the_kth_place":
+        R = np.round(R * 4) / 4             # five values: ties everywhere
+    elif case == "minus_infinity_among_the_candidates":
+        R[:, :, ::3] = -np.inf
+    elif case == "fewer_than_k_candidates":
+        R[:, :, 5:] = -np.inf
+    elif case == "zeros_of_both_signs":
+        R[:, :, ::2] = 0.0
+        R[:, :, 1::4] = -0.0
+    return jnp.asarray(R, jnp.float32)
+
+
+@pytest.mark.parametrize("case", [
+    "random", "equal_rows", "ties_at_the_kth_place",
+    "minus_infinity_among_the_candidates", "fewer_than_k_candidates",
+    "zeros_of_both_signs", "fewer_blocks_than_k"])
+def test_the_threshold_search_is_top_k_bit_for_bit(case):
+    """``kth_largest`` against ``lax.top_k(...)[0][..., -1:]`` and the
+    masks that follow from either, exactly."""
+    NB = 6 if case == "fewer_blocks_than_k" else 40
+    R = _scores(case, NB=NB)
+    bits = lambda x: np.asarray(x).view(np.int32)
+    for k in {1, min(8, NB), min(17, NB), NB}:
+        want = jax.lax.top_k(R, k)[0][..., -1:]
+        assert np.array_equal(bits(spg.kth_largest(R, k)), bits(want)), k
+    # queries past dense_len 64 whose window leaves 4 to 37 candidates
+    sp = spg.SparseConfig(kernel_size=8, kernel_stride=4, block_size=8,
+                          topk=8, window_size=16, init_blocks=1, dense_len=64)
+    t = jnp.arange(64, 64 + R.shape[0] * 4, 4)
+    got = spg.select_mask(R, t, sp)
+    assert np.array_equal(np.asarray(got),
+                          np.asarray(_top_k_select_mask(R, t, sp)))
+    picked = np.asarray(got).sum(-1)
+    if case in ("random", "equal_rows", "ties_at_the_kth_place"):
+        want = np.minimum(np.asarray(t) // 8 + 1, 3 + 8)
+        assert (picked == np.minimum(want, NB)[:, None]).all()
+
+
 def test_block_scores_are_the_overlap_definition():
     sp = spg.SparseConfig(kernel_size=8, kernel_stride=4, block_size=8,
                           topk=2, window_size=16, init_blocks=1, dense_len=64)

@@ -105,6 +105,9 @@ def test_prefill_then_decode_through_the_engine_agree_with_the_reference():
     chunks = sum(-(-len(p) // 32) for p in prompts)
     assert s["lightning_calls"] == n_light * chunks
     assert s["sparse_calls"] == n_sparse * chunks
+    # of them the chunks that end past dense_len 64 (the third on) select
+    assert s["select_calls"] == n_sparse * sum(
+        max(-(-len(p) // 32) - 2, 0) for p in prompts)
     events = [e for e in eng.flight.events() if "lightning_rows" in e]
     assert sum(e["lightning_rows"] for e in events) == s["lightning_rows"]
     assert set(STEP_COUNTERS) <= set(events[0])

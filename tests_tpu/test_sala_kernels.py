@@ -110,3 +110,71 @@ def test_sparse_prefill_attention_parity():
                                        groups=G)
     want = spg.sparse_prefill_attention_reference(q, kv, mask, groups=G)
     assert float(jnp.abs(got - want).max()) < 2e-2
+
+
+def _compressed_keys(S, seed):
+    """(1, S / 16, G, D): the means of keys normed a head, as the model
+    leaves them."""
+    k = _unit_heads(_normal(seed, (1, S + 16, G, D)))
+    return spg.compress(k.reshape(1, S + 16, G * D), SP).astype(
+        jnp.bfloat16).reshape(1, S // 16, G, D)
+
+
+def _top_k_select_mask(R, t, sp):
+    """:func:`select_mask` as it was while ``lax.top_k`` found the
+    threshold."""
+    NB = R.shape[-1]
+    k = min(sp.topk, NB)
+    visible, forced = spg._kinds(t, sp, NB)
+    Rc = jnp.where((visible & ~forced)[..., None, :], R, -jnp.inf)
+    kth = jax.lax.top_k(Rc, k)[0][..., -1:]
+    above = Rc > kth
+    tied = (Rc == kth) & (Rc > -jnp.inf)
+    room = k - above.sum(-1, keepdims=True)
+    chosen = above | (tied & (jnp.cumsum(tied, -1) <= room))
+    dense = (t + 1 <= sp.dense_len)[..., None, None]
+    return jnp.where(dense, visible[..., None, :],
+                     forced[..., None, :] | chosen)
+
+
+@pytest.mark.parametrize("S", [10240, 16384])
+def test_a_wave_selects_what_the_sort_selected(S):
+    """The blocks of a whole prefill through one sparse layer's
+    selection (no score under ``dense_len``, the threshold searched for
+    past it) against stage 1, block maxima and the ``lax.top_k``
+    threshold for every query: equal, bit for bit, on the chip too."""
+    C, NB = 2048, S // 64
+    from paddle_tpu.models import minicpm_sala as sala
+    q_all, kc = _unit_heads(_normal(11, (1, S, H, D))), _compressed_keys(S, 12)
+    new = jax.jit(lambda q, c0: sala.prefill_select(q, kc, c0, SP, NB))
+
+    @jax.jit
+    def old(q, c0):
+        t = c0 + jnp.arange(256)[None]
+        return _top_k_select_mask(
+            spg.block_scores(spg.stage1(q, kc, t, SP), SP, NB), t, SP)
+
+    got = np.concatenate([np.asarray(new(q_all[:, c0:c0 + C], jnp.int32(c0)))
+                          for c0 in range(0, S, C)], 1)
+    want = np.concatenate([np.asarray(old(q_all[:, c0:c0 + 256],
+                                          jnp.int32(c0)))
+                           for c0 in range(0, S, 256)], 1)
+    assert got.shape == (1, S, G, NB)
+    # under dense_len every visible block, past it 1 + 32 + 64
+    assert (want.sum(-1)[0, :8192] == (np.arange(8192) // 64 + 1)[:, None]
+            ).all()
+    assert (want.sum(-1)[0, 8192:] == 97).all()
+    assert (got == want).all()
+
+
+def test_the_threshold_search_is_top_k_on_the_chip():
+    """A decode step's candidates (16 rows, 2 groups, 544 blocks) with
+    ties and ``-inf``: the same bits."""
+    R = np.array(jax.random.uniform(jax.random.key(3), (16, 2, 544)))
+    R[:, :, ::5] = np.round(R[:, :, ::5] * 8) / 8
+    R[:, :, 1::7] = -np.inf
+    R = jnp.asarray(R, jnp.float32)
+    want = jax.jit(lambda x: jax.lax.top_k(x, 64)[0][..., -1:])(R)
+    got = jax.jit(lambda x: spg.kth_largest(x, 64))(R)
+    assert np.array_equal(np.asarray(got).view(np.int32),
+                          np.asarray(want).view(np.int32))
