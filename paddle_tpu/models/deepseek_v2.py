@@ -46,6 +46,7 @@ from paddle_tpu.nn.layers.moe import (GroupedSwiGLUExperts,
                                       group_limited_topk_routing)
 from paddle_tpu.ops import moe_grouped
 from paddle_tpu.ops.rms_norm import rms_norm
+from paddle_tpu.profiler.parts import part
 
 _HI = jax.lax.Precision.HIGHEST
 # what ``decode_step`` counts, in the order it returns them: the last is
@@ -126,7 +127,7 @@ def route(w: Dict, cfg: DeepseekV2Config, x):
     where the pick lies on another chip; weights (T, k) float32). The
     scores in float32 at full matmul precision (a rounded score picks
     another expert), over the router's full width."""
-    with jax.named_scope("deepseek_v2.route"):
+    with part("router"):
         logits = jnp.matmul(x.astype(jnp.float32),
                             w["gate.weight"].astype(jnp.float32),
                             precision=_HI)
@@ -143,12 +144,15 @@ def moe_prefill(w: Dict, cfg: DeepseekV2Config, x):
     expert, plus the shared experts. -> (y (T, C), the picks that fell
     here: the rows that reach the grouped kernel)."""
     idx, wts = route(w, cfg, x)
-    here = idx < cfg.experts_held
-    y = moe_grouped.moe_grouped_ffn_prefill(
-        x, idx, jnp.where(here, wts, 0.0), w["experts.w_gate"],
-        w["experts.w_up"], w["experts.w_down"])
-    return (y + _swiglu(_sub(w, "shared_experts."), x),
-            here.sum(dtype=jnp.int32))
+    with part("experts"):
+        here = idx < cfg.experts_held
+        y = moe_grouped.moe_grouped_ffn_prefill(
+            x, idx, jnp.where(here, wts, 0.0), w["experts.w_gate"],
+            w["experts.w_up"], w["experts.w_down"])
+    with part("ffn"):
+        y = y + _swiglu(_sub(w, "shared_experts."), x)
+    with part("router"):
+        return y, here.sum(dtype=jnp.int32)
 
 
 def _is_moe(cfg: DeepseekV2Config, layer: int) -> bool:
@@ -162,20 +166,23 @@ def block_forward(w: Dict, cfg: DeepseekV2Config, moe: bool, x, cos, sin,
     when ``cache`` is None). -> (x', cache', picks that fell here)."""
     b, s, _ = x.shape
     eps = cfg.rms_norm_eps
-    with jax.named_scope("deepseek_v2.mla"):
+    with part("norm"):
         xn = rms_norm(x, w["input_layernorm.weight"], eps)
-        y, cache = mla_layered(_sub(w, "self_attn."), cfg, xn, cos, sin,
-                               cache, start_pos)
+    y, cache = mla_layered(_sub(w, "self_attn."), cfg, xn, cos, sin, cache,
+                           start_pos)
+    with part("attn_out"):
         x = x + y
-    xn = rms_norm(x, w["post_attention_layernorm.weight"], eps)
+    with part("norm"):
+        xn = rms_norm(x, w["post_attention_layernorm.weight"], eps)
     rows = jnp.zeros((), jnp.int32)
     if moe:
-        with jax.named_scope("deepseek_v2.moe"):
-            y, rows = moe_prefill(_sub(w, "mlp."), cfg, xn.reshape(b * s, -1))
-            y = y.reshape(b, s, -1)
+        y, rows = moe_prefill(_sub(w, "mlp."), cfg, xn.reshape(b * s, -1))
+        y = y.reshape(b, s, -1)
     else:
-        y = _swiglu(_sub(w, "mlp."), xn)
-    return x + y, cache, rows
+        with part("ffn"):
+            y = _swiglu(_sub(w, "mlp."), xn)
+    with part("ffn"):
+        return x + y, cache, rows
 
 
 def hidden_forward(w: Dict, cfg: DeepseekV2Config, ids, cache=None,
@@ -184,8 +191,10 @@ def hidden_forward(w: Dict, cfg: DeepseekV2Config, ids, cache=None,
     cache', the picks that fell on held experts over all expert
     layers)."""
     s = ids.shape[1]
-    cos, sin = rope_tables(cfg, start_pos + jnp.arange(s))
-    x = jnp.take(w["model.embed_tokens.weight"], ids, axis=0)
+    with part("attn_in"):
+        cos, sin = rope_tables(cfg, start_pos + jnp.arange(s))
+    with part("embed"):
+        x = jnp.take(w["model.embed_tokens.weight"], ids, axis=0)
     new_cache, rows = [], jnp.zeros((), jnp.int32)
     for i in range(cfg.num_layers):
         x, c, r = block_forward(_sub(w, f"model.layers.{i}."), cfg,
@@ -193,12 +202,13 @@ def hidden_forward(w: Dict, cfg: DeepseekV2Config, ids, cache=None,
                                 None if cache is None else cache[i],
                                 start_pos)
         new_cache.append(c)
-        rows = rows + r
+        with part("router"):
+            rows = rows + r
     return x, (None if cache is None else new_cache), rows
 
 
 def head_forward(w: Dict, cfg: DeepseekV2Config, h):
-    with jax.named_scope("deepseek_v2.head"):
+    with part("head"):
         return jnp.matmul(rms_norm(h, w["model.norm.weight"],
                                    cfg.rms_norm_eps), w["lm_head.weight"])
 
@@ -213,33 +223,41 @@ def decode_step(w: Dict, cfg: DeepseekV2Config, x, pool, tables, positions):
     eps = cfg.rms_norm_eps
     held, k = cfg.experts_held, cfg.num_experts_per_tok
     active = tables[:, 0] != 0
-    cos, sin = rope_tables(cfg, positions)                  # (b, d_r)
+    with part("attn_in"):
+        cos, sin = rope_tables(cfg, positions)              # (b, d_r)
     counts = jnp.zeros(3, jnp.int32)
     n_moe = 0
     for i in range(cfg.num_layers):
         lw = _sub(w, f"model.layers.{i}.")
-        with jax.named_scope("deepseek_v2.mla"):
+        with part("norm"):
             xn = rms_norm(x, lw["input_layernorm.weight"], eps)
-            y, pool = mla_paged(_sub(lw, "self_attn."), cfg, xn, cos, sin,
-                                pool, tables, positions, i)
+        y, pool = mla_paged(_sub(lw, "self_attn."), cfg, xn, cos, sin, pool,
+                            tables, positions, i)
+        with part("attn_out"):
             x = x + y
-        xn = rms_norm(x, lw["post_attention_layernorm.weight"], eps)
+        with part("norm"):
+            xn = rms_norm(x, lw["post_attention_layernorm.weight"], eps)
         mw = _sub(lw, "mlp.")
         if _is_moe(cfg, i):
-            with jax.named_scope("deepseek_v2.moe"):
-                idx, wts = route(mw, cfg, xn)
-                y = (moe_grouped.moe_grouped_ffn_decode(
+            idx, wts = route(mw, cfg, xn)
+            with part("experts"):
+                y = moe_grouped.moe_grouped_ffn_decode(
                     xn, moe_grouped.dense_weights(idx, wts, active, held),
                     mw["experts.w_gate"], mw["experts.w_up"],
                     mw["experts.w_down"])
-                    + _swiglu(_sub(mw, "shared_experts."), xn))
+            with part("ffn"):
+                y = y + _swiglu(_sub(mw, "shared_experts."), xn)
+            with part("router"):
                 counts = counts + moe_grouped.routing_counts(idx, active,
                                                              held)
-                n_moe += 1
+            n_moe += 1
         else:
-            y = _swiglu(mw, xn)
-        x = x + y
-    picks = active.sum(dtype=jnp.int32) * (k * n_moe)
+            with part("ffn"):
+                y = _swiglu(mw, xn)
+        with part("ffn"):
+            x = x + y
+    with part("router"):
+        picks = active.sum(dtype=jnp.int32) * (k * n_moe)
     return x, pool, jnp.concatenate([
         jnp.full((1,), n_moe, jnp.int32), counts, picks[None]])
 
@@ -347,7 +365,9 @@ class DeepseekV2ForCausalLM(LatentCausalLM):
 
         def embed(tok, pos):
             del pos
-            return jnp.take(state["model.embed_tokens.weight"], tok, axis=0)
+            with part("embed"):
+                return jnp.take(state["model.embed_tokens.weight"], tok,
+                                axis=0)
 
         def step(x, pool, tables, positions):
             return decode_step(state, cfg, x, pool, tables, positions)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn import initializer as init
+from paddle_tpu.profiler.parts import part
 
 
 @dataclasses.dataclass
@@ -68,29 +69,36 @@ class GPTAttention(nn.Layer):
 
     def forward(self, x, cache=None, start_pos=0):
         b, s, h = x.shape
-        qkv = self.qkv_proj(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, s, self.num_heads, self.head_dim)
-        k = k.reshape(b, s, self.num_heads, self.head_dim)
-        v = v.reshape(b, s, self.num_heads, self.head_dim)
+        with part("attn_in"):
+            qkv = self.qkv_proj(x)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, s, self.num_heads, self.head_dim)
+            k = k.reshape(b, s, self.num_heads, self.head_dim)
+            v = v.reshape(b, s, self.num_heads, self.head_dim)
         if cache is not None:
             # decode: append at [start_pos, start_pos+s), attend the
             # filled prefix (position-masked static buffers)
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), start_pos, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), start_pos, axis=1)
-            q_pos = start_pos + jnp.arange(s)[:, None]
-            k_pos = jnp.arange(k_cache.shape[1])[None, :]
-            mask = (k_pos <= q_pos)[None, None]
-            out = F.scaled_dot_product_attention(
-                q, k_cache, v_cache, attn_mask=mask, is_causal=False)
-            out = self.out_proj(out.reshape(b, s, h))
+            with part("attn"):
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], k.astype(cache["k"].dtype), start_pos,
+                    axis=1)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], v.astype(cache["v"].dtype), start_pos,
+                    axis=1)
+                q_pos = start_pos + jnp.arange(s)[:, None]
+                k_pos = jnp.arange(k_cache.shape[1])[None, :]
+                mask = (k_pos <= q_pos)[None, None]
+                out = F.scaled_dot_product_attention(
+                    q, k_cache, v_cache, attn_mask=mask, is_causal=False)
+            with part("attn_out"):
+                out = self.out_proj(out.reshape(b, s, h))
             return out, {"k": k_cache, "v": v_cache}
-        out = F.scaled_dot_product_attention(
-            q, k, v, is_causal=True, dropout_p=self.attn_dropout,
-            training=self.training)
-        return self.out_proj(out.reshape(b, s, h))
+        with part("attn"):
+            out = F.scaled_dot_product_attention(
+                q, k, v, is_causal=True, dropout_p=self.attn_dropout,
+                training=self.training)
+        with part("attn_out"):
+            return self.out_proj(out.reshape(b, s, h))
 
 
 class GPTBlock(nn.Layer):
@@ -107,17 +115,22 @@ class GPTBlock(nn.Layer):
         self.dropout = nn.Dropout(cfg.hidden_dropout_prob)
 
     def forward(self, x, cache=None, start_pos=0):
-        if cache is not None:
-            attn, new_cache = self.attn(self.ln_1(x), cache=cache,
-                                        start_pos=start_pos)
-            x = x + attn
-            x = x + self.fc_out(F.gelu(self.fc_in(self.ln_2(x)),
-                                       approximate=True))
-            return x, new_cache
-        x = x + self.dropout(self.attn(self.ln_1(x)))
-        x = x + self.dropout(self.fc_out(F.gelu(self.fc_in(self.ln_2(x)),
-                                                approximate=True)))
-        return x
+        with part("norm"):
+            xn = self.ln_1(x)
+        new_cache = None
+        if cache is not None:       # serving: no dropout
+            attn, new_cache = self.attn(xn, cache=cache, start_pos=start_pos)
+        else:
+            attn = self.attn(xn)
+        drop = self.dropout if cache is None else (lambda y: y)
+        with part("attn_out"):
+            x = x + drop(attn)
+        with part("norm"):
+            xn = self.ln_2(x)
+        with part("ffn"):
+            x = x + drop(self.fc_out(F.gelu(self.fc_in(xn),
+                                            approximate=True)))
+        return x if cache is None else (x, new_cache)
 
 
 class GPTModel(nn.Layer):
@@ -135,17 +148,20 @@ class GPTModel(nn.Layer):
     def forward(self, input_ids, cache=None, start_pos=0):
         b, s = input_ids.shape
         pos = (start_pos + jnp.arange(s))[None, :]
-        x = self.wte(input_ids) + self.wpe(pos)
-        if cache is not None:
-            new_cache = []
-            for i, block in enumerate(self.h):
+        with part("embed"):
+            x = self.wte(input_ids) + self.wpe(pos)
+            if cache is None:
+                x = self.drop(x)
+        new_cache = []
+        for i, block in enumerate(self.h):
+            if cache is None:
+                x = block(x)
+            else:
                 x, c = block(x, cache=cache[i], start_pos=start_pos)
                 new_cache.append(c)
-            return self.ln_f(x), new_cache
-        x = self.drop(x)
-        for block in self.h:
-            x = block(x)
-        return self.ln_f(x)
+        with part("head"):
+            x = self.ln_f(x)
+        return x if cache is None else (x, new_cache)
 
 
 class GPTPretrainModel(nn.Layer):
@@ -165,10 +181,11 @@ class GPTPretrainModel(nn.Layer):
                                     start_pos=start_pos)
         else:
             x = self.gpt(input_ids)
-        if self.cfg.tie_word_embeddings:
-            logits = jnp.matmul(x, self.gpt.wte.weight.T)
-        else:
-            logits = self.lm_head(x)
+        with part("head"):
+            if self.cfg.tie_word_embeddings:
+                logits = jnp.matmul(x, self.gpt.wte.weight.T)
+            else:
+                logits = self.lm_head(x)
         if cache is not None:
             return logits, new_cache
         return logits
@@ -203,15 +220,17 @@ class GPTPretrainModel(nn.Layer):
         lnf_w = state["gpt.ln_f.weight"]
         lnf_b = state["gpt.ln_f.bias"]
         def embed(tok, pos):                  # (b,), scalar -> (b, h)
-            return jnp.take(wte, tok, axis=0) + wpe[pos]
+            with part("embed"):
+                return jnp.take(wte, tok, axis=0) + wpe[pos]
 
         def head(x):
-            xn = _ln(x, (x.shape[-1],), lnf_w, lnf_b,
-                     cfg.layer_norm_epsilon)
-            if cfg.tie_word_embeddings:
-                from paddle_tpu.ops import tied_unembed
-                return tied_unembed(xn, wte)
-            return jnp.dot(xn, state["lm_head.weight"])
+            with part("head"):
+                xn = _ln(x, (x.shape[-1],), lnf_w, lnf_b,
+                         cfg.layer_norm_epsilon)
+                if cfg.tie_word_embeddings:
+                    from paddle_tpu.ops import tied_unembed
+                    return tied_unembed(xn, wte)
+                return jnp.dot(xn, state["lm_head.weight"])
 
         return dict(meta, params=params, embed=embed, head=head)
 

@@ -28,6 +28,7 @@ from paddle_tpu.nn import functional as F
 from paddle_tpu.nn import initializer as init
 from paddle_tpu.ops import rope as rope_ops
 from paddle_tpu.parallel import mp_layers as mp
+from paddle_tpu.profiler.parts import part
 
 
 @dataclasses.dataclass
@@ -138,16 +139,30 @@ class LlamaAttention(nn.Layer):
                 start_pos=0):
         cfg = self.cfg
         b, s, _ = x.shape
-        q = self.q_proj(x).reshape(b, s, cfg.num_heads, cfg.head_dim)
-        k = self.k_proj(x).reshape(b, s, cfg.kv_heads, cfg.head_dim)
-        v = self.v_proj(x).reshape(b, s, cfg.kv_heads, cfg.head_dim)
-        if cos is None or sin is None:
-            pos = start_pos + jnp.arange(s)
-            cos, sin = rope_ops.rope_cos_sin(s, cfg.head_dim,
-                                             base=cfg.rope_base,
-                                             position_ids=pos)
-        q = rope_ops.apply_rotary_pos_emb(q, cos, sin)
-        k = rope_ops.apply_rotary_pos_emb(k, cos, sin)
+        with part("attn_in"):
+            q = self.q_proj(x).reshape(b, s, cfg.num_heads, cfg.head_dim)
+            k = self.k_proj(x).reshape(b, s, cfg.kv_heads, cfg.head_dim)
+            v = self.v_proj(x).reshape(b, s, cfg.kv_heads, cfg.head_dim)
+            if cos is None or sin is None:
+                pos = start_pos + jnp.arange(s)
+                cos, sin = rope_ops.rope_cos_sin(s, cfg.head_dim,
+                                                 base=cfg.rope_base,
+                                                 position_ids=pos)
+            q = rope_ops.apply_rotary_pos_emb(q, cos, sin)
+            k = rope_ops.apply_rotary_pos_emb(k, cos, sin)
+        with part("attn"):
+            out, cache = self._attend(q, k, v, attn_mask, cache, start_pos)
+        with part("attn_out"):
+            out = self.o_proj(out.reshape(b, s,
+                                          cfg.num_heads * cfg.head_dim))
+        return out if cache is None else (out, cache)
+
+    def _attend(self, q, k, v, attn_mask, cache, start_pos):
+        """The attention itself on projected q, k, v (b, s, heads, d) ->
+        (out (b, s, num_heads, d), the cache with k and v written or
+        None)."""
+        cfg = self.cfg
+        s = q.shape[1]
         if cache is not None:
             # decode: write k/v at [start_pos, start_pos+s), attend to the
             # filled prefix (static max length, position-masked)
@@ -164,7 +179,6 @@ class LlamaAttention(nn.Layer):
                 mask = mask & (k_pos > q_pos - cfg.sliding_window)[None, None]
             out = F.scaled_dot_product_attention(
                 q, k_cache, v_cache, attn_mask=mask, is_causal=False)
-            out = self.o_proj(out.reshape(b, s, cfg.num_heads * cfg.head_dim))
             return out, {"k": k_cache, "v": v_cache}
         if cfg.context_parallel:
             if cfg.sliding_window is not None:
@@ -188,7 +202,7 @@ class LlamaAttention(nn.Layer):
                 q, k, v, attn_mask=attn_mask, is_causal=True,
                 window_size=cfg.sliding_window)
             out = checkpoint_name(out, "attn_out")
-        return self.o_proj(out.reshape(b, s, cfg.num_heads * cfg.head_dim))
+        return out, None
 
 
 class LlamaMLP(nn.Layer):
@@ -225,16 +239,22 @@ class LlamaDecoderLayer(nn.Layer):
 
     def forward(self, x, cos=None, sin=None, attn_mask=None, cache=None,
                 start_pos=0):
+        with part("norm"):
+            xn = self.input_layernorm(x)
+        new_cache = None
         if cache is not None:
-            attn, new_cache = self.self_attn(self.input_layernorm(x), cos,
-                                             sin, attn_mask, cache=cache,
+            attn, new_cache = self.self_attn(xn, cos, sin, attn_mask,
+                                             cache=cache,
                                              start_pos=start_pos)
+        else:
+            attn = self.self_attn(xn, cos, sin, attn_mask)
+        with part("attn_out"):
             x = x + attn
-            x = x + self.mlp(self.post_attention_layernorm(x))
-            return x, new_cache
-        x = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
-        x = x + self.mlp(self.post_attention_layernorm(x))
-        return x
+        with part("norm"):
+            xn = self.post_attention_layernorm(x)
+        with part("ffn"):
+            x = x + self.mlp(xn)
+        return x if cache is None else (x, new_cache)
 
 
 class LlamaModel(nn.Layer):
@@ -252,16 +272,20 @@ class LlamaModel(nn.Layer):
         cfg = self.cfg
         s = input_ids.shape[1]
         pos = start_pos + jnp.arange(s) if cache is not None else None
-        cos, sin = rope_ops.rope_cos_sin(s, cfg.head_dim, base=cfg.rope_base,
-                                         position_ids=pos)
-        x = self.embed_tokens(input_ids)
+        with part("attn_in"):
+            cos, sin = rope_ops.rope_cos_sin(s, cfg.head_dim,
+                                             base=cfg.rope_base,
+                                             position_ids=pos)
+        with part("embed"):
+            x = self.embed_tokens(input_ids)
         if cache is not None:
             new_cache = []
             for i, layer in enumerate(self.layers):
                 x, c = layer(x, cos, sin, attn_mask, cache=cache[i],
                              start_pos=start_pos)
                 new_cache.append(c)
-            return self.norm(x), new_cache
+            with part("head"):
+                return self.norm(x), new_cache
         if cfg.recompute:
             # per-layer activation recompute (reference: fleet per-layer
             # recompute, fleet/meta_parallel recompute_hybrid). The
@@ -293,7 +317,8 @@ class LlamaModel(nn.Layer):
         else:
             for layer in self.layers:
                 x = layer(x, cos, sin, attn_mask)
-        return self.norm(x)
+        with part("head"):
+            return self.norm(x)
 
 
 class CausalLMBase(nn.Layer):
@@ -327,8 +352,9 @@ class CausalLMBase(nn.Layer):
             x, aux = x
             aux = getattr(self.cfg, "aux_loss_weight", 1.0) * aux
         if chunks <= 1:
-            return self.loss_fn(self._unembed(x), labels,
-                                reduction="mean") + aux
+            logits = self._unembed(x)
+            with part("loss"):
+                return self.loss_fn(logits, labels, reduction="mean") + aux
         b, s, h = x.shape
         if s % chunks:
             raise ValueError(
@@ -340,25 +366,31 @@ class CausalLMBase(nn.Layer):
 
         @jax.checkpoint
         def chunk_sums(x_c, l_c):
-            nll = self.loss_fn(self._unembed(x_c), l_c, reduction="none")
-            return jnp.sum(nll), jnp.sum(l_c != ignore)
+            logits = self._unembed(x_c)
+            with part("loss"):
+                nll = self.loss_fn(logits, l_c, reduction="none")
+                return jnp.sum(nll), jnp.sum(l_c != ignore)
 
         def body(carry, xs):
             loss_sum, cnt = carry
             a, n = chunk_sums(*xs)
-            return (loss_sum + a, cnt + n), None
+            with part("loss"):
+                return (loss_sum + a, cnt + n), None
 
         (loss_sum, cnt), _ = jax.lax.scan(
             body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
             (xc, lc))
-        return loss_sum / jnp.maximum(cnt, 1) + aux
+        with part("loss"):
+            return loss_sum / jnp.maximum(cnt, 1) + aux
 
     def _unembed(self, x):
-        if getattr(self.cfg, "tie_word_embeddings", False):
-            from paddle_tpu.parallel import mp_layers as _mp
-            logits = jnp.matmul(x, self.model.embed_tokens.weight.T)
-            return _mp.constrain(logits, _mp._last_dim_spec(_mp.MP_AXIS))
-        return self.lm_head(x)
+        with part("head"):
+            if getattr(self.cfg, "tie_word_embeddings", False):
+                from paddle_tpu.parallel import mp_layers as _mp
+                logits = jnp.matmul(x, self.model.embed_tokens.weight.T)
+                return _mp.constrain(logits,
+                                     _mp._last_dim_spec(_mp.MP_AXIS))
+            return self.lm_head(x)
 
     def _pipeline_block_apply(self, template):
         """(one_block_state, h) -> h, built over `template`. Subclasses with
@@ -488,7 +520,8 @@ class LlamaForCausalLM(CausalLMBase):
 
         def embed(tok, pos):                  # (b,), scalar -> (b, h)
             del pos                           # rope positions, not learned
-            return jnp.take(embed_w, tok, axis=0)
+            with part("embed"):
+                return jnp.take(embed_w, tok, axis=0)
 
         if cfg.tie_word_embeddings:
             from paddle_tpu.ops import tied_unembed
@@ -501,7 +534,8 @@ class LlamaForCausalLM(CausalLMBase):
             head_mm = lambda xn: jnp.dot(xn, state["lm_head.weight"])
 
         def head(x):                          # (b, h) -> (b, vocab)
-            return head_mm(rms_norm(x, norm_w, cfg.rms_norm_eps))
+            with part("head"):
+                return head_mm(rms_norm(x, norm_w, cfg.rms_norm_eps))
 
         return dict(meta, params=params, embed=embed, head=head)
 

@@ -50,6 +50,7 @@ from paddle_tpu.ops import lightning_attention as la
 from paddle_tpu.ops import rope as rope_ops
 from paddle_tpu.ops import sparse_paged as spg
 from paddle_tpu.ops.rms_norm import rms_norm
+from paddle_tpu.profiler.parts import part
 
 LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
 # what ``decode_step`` counts, in the order it returns them: 64-token
@@ -199,15 +200,15 @@ def prefill_select(q, kc, c0, sp: spg.SparseConfig, NB: int, rows: int = 256):
         return jnp.broadcast_to(spg.visible_blocks(t, sp, NB)[:, :, None],
                                 (n, C, G, NB))
 
-    def part(args):
+    def slab(args):
         qb, tb = args
         return spg.select_mask(
             spg.block_scores(spg.stage1(qb, kc, tb, sp), sp, NB), tb, sp)
 
     def select():
         if C <= rows or C % rows:
-            return part((q, t))
-        out = lax.map(part, (
+            return slab((q, t))
+        out = lax.map(slab, (
             jnp.moveaxis(q.reshape(n, C // rows, rows, *q.shape[2:]), 1, 0),
             jnp.moveaxis(t.reshape(n, C // rows, rows), 1, 0)))
         return jnp.moveaxis(out, 0, 1).reshape(n, C, *out.shape[3:])
@@ -226,21 +227,25 @@ def sparse_chunk(w: Dict, cfg: MiniCPMSALAConfig, x, kv, ck, tail, c0):
     G, d = cfg.num_kv_heads, cfg.head_dim
     S = kv.shape[1]
     lead = tail.shape[1] // sp.kernel_stride
-    q, k, v = sparse_qkv(w, cfg, x)
-    kv = lax.dynamic_update_slice_in_dim(
-        kv, jnp.concatenate([k, v], -1).astype(kv.dtype), c0, axis=1)
-    ext = jnp.concatenate([tail, k.astype(tail.dtype)], 1)
-    # the windows that END in this chunk: rows c0 / stride - lead ..
-    ck = lax.dynamic_update_slice_in_dim(
-        ck, spg.compress(ext, sp).astype(ck.dtype), c0 // sp.kernel_stride,
-        axis=1)
+    with part("attn_in"):
+        q, k, v = sparse_qkv(w, cfg, x)
+    with part("attn"):
+        kv = lax.dynamic_update_slice_in_dim(
+            kv, jnp.concatenate([k, v], -1).astype(kv.dtype), c0, axis=1)
+        ext = jnp.concatenate([tail, k.astype(tail.dtype)], 1)
+        # the windows that END in this chunk: rows c0 / stride - lead ..
+        ck = lax.dynamic_update_slice_in_dim(
+            ck, spg.compress(ext, sp).astype(ck.dtype),
+            c0 // sp.kernel_stride, axis=1)
     t = jnp.broadcast_to(c0 + jnp.arange(C), (n, C))
-    with jax.named_scope("sparse.select"):
+    with part("select"):
         blocks = prefill_select(q, ck[:, lead:].reshape(n, -1, G, d), c0, sp,
                                 -(-S // sp.block_size))
         mask = spg.prefill_token_mask(blocks, t, S, sp)
-    o = spg.sparse_prefill_attention(q, kv, mask, c0 + C, groups=G)
-    y = _gated_out(w, x, o.reshape(n, C, -1))
+    with part("attn"):
+        o = spg.sparse_prefill_attention(q, kv, mask, c0 + C, groups=G)
+    with part("attn_out"):
+        y = _gated_out(w, x, o.reshape(n, C, -1))
     return y, kv, ck, ext[:, ext.shape[1] - tail.shape[1]:], blocks
 
 
@@ -284,9 +289,11 @@ def hidden_forward(w: Dict, cfg: MiniCPMSALAConfig, ids, cache: Dict,
     def chunk(carry, i):
         cache, picked = carry
         c0 = i * C
-        tok = lax.dynamic_slice_in_dim(ids, c0, C, axis=1)
-        x = (cfg.scale_emb * jnp.take(emb, tok, axis=0)).astype(emb.dtype)
-        cos, sin = _rope(cfg, c0 + jnp.arange(C))
+        with part("embed"):
+            tok = lax.dynamic_slice_in_dim(ids, c0, C, axis=1)
+            x = (cfg.scale_emb * jnp.take(emb, tok, axis=0)).astype(emb.dtype)
+        with part("attn_in"):
+            cos, sin = _rope(cfg, c0 + jnp.arange(C))
         nvalid = jnp.clip(true_len - c0, 0, C)
         kv, ck, tail, state = (cache[k] for k in
                                ("kv", "ck", "tail", "state"))
@@ -295,34 +302,41 @@ def hidden_forward(w: Dict, cfg: MiniCPMSALAConfig, ids, cache: Dict,
         for l, kind in enumerate(kinds):
             lw = _sub(w, f"model.layers.{l}.")
             mw = _sub(lw, "self_attn.")
-            xn = rms_norm(x, lw["input_layernorm.weight"], eps)
+            with part("norm"):
+                xn = rms_norm(x, lw["input_layernorm.weight"], eps)
             if kind == LIGHTNING:
-                with jax.named_scope("sala.lightning"):
+                with part("attn_in"):
                     q, k, v = lightning_qkv(mw, cfg, xn, cos, sin)
+                with part("state"):
                     o, st = la.lightning_prefill(q, k, v, state[li], nvalid)
                     state = state.at[li].set(st)
+                with part("attn_out"):
                     y = lightning_out(mw, cfg, xn, o)
                 li += 1
             else:
-                with jax.named_scope("sala.sparse"):
-                    y, kv_l, ck_l, tail_l, blk = sparse_chunk(
-                        mw, cfg, xn, kv[lp], ck[lp], tail[lp], c0)
+                y, kv_l, ck_l, tail_l, blk = sparse_chunk(
+                    mw, cfg, xn, kv[lp], ck[lp], tail[lp], c0)
+                with part("attn"):
                     kv, ck, tail = (kv.at[lp].set(kv_l), ck.at[lp].set(ck_l),
                                     tail.at[lp].set(tail_l))
-                    blocks.append(blk)
+                blocks.append(blk)
                 lp += 1
-            x = x + (a * y).astype(x.dtype)
-            xn = rms_norm(x, lw["post_attention_layernorm.weight"], eps)
-            x = x + (a * _swiglu(_sub(lw, "mlp."), xn)).astype(x.dtype)
+            with part("attn_out"):
+                x = x + (a * y).astype(x.dtype)
+            with part("norm"):
+                xn = rms_norm(x, lw["post_attention_layernorm.weight"], eps)
+            with part("ffn"):
+                x = x + (a * _swiglu(_sub(lw, "mlp."), xn)).astype(x.dtype)
         cache = {"kv": kv, "ck": ck, "tail": tail, "state": state}
         out = {}
         if positions is None:
             out["x"] = x
         else:
-            at = jnp.clip(positions - c0, 0, C - 1)
-            here = (positions >= c0) & (positions < c0 + C)
-            row = jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0]
-            picked = jnp.where(here[:, None], row, picked)
+            with part("head"):
+                at = jnp.clip(positions - c0, 0, C - 1)
+                here = (positions >= c0) & (positions < c0 + C)
+                row = jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0]
+                picked = jnp.where(here[:, None], row, picked)
         if return_blocks:
             out["blocks"] = jnp.stack(blocks)
         return (cache, picked), out
@@ -339,7 +353,7 @@ def hidden_forward(w: Dict, cfg: MiniCPMSALAConfig, ids, cache: Dict,
 
 
 def head_forward(w: Dict, cfg: MiniCPMSALAConfig, h):
-    with jax.named_scope("sala.head"):
+    with part("head"):
         hn = rms_norm(h, w["model.norm.weight"], cfg.rms_norm_eps)
         return jnp.matmul(hn / (cfg.hidden_size / cfg.dim_model_base),
                           w["lm_head.weight"])
@@ -357,37 +371,49 @@ def decode_step(w: Dict, cfg: MiniCPMSALAConfig, x, pool, tables, positions,
     kv_pool, ck_pool = pool
     S = state["lightning"]
     active = tables[:, 0] != 0
-    cos, sin = _rope(cfg, positions[:, None])               # (b, 1, d)
+    with part("attn_in"):
+        cos, sin = _rope(cfg, positions[:, None])           # (b, 1, d)
     tallies = jnp.zeros(3, jnp.int32)
     li = lp = 0
     for l, kind in enumerate(cfg.mixer_types):
         lw = _sub(w, f"model.layers.{l}.")
         mw = _sub(lw, "self_attn.")
-        xn = rms_norm(x, lw["input_layernorm.weight"], eps)
+        with part("norm"):
+            xn = rms_norm(x, lw["input_layernorm.weight"], eps)
         if kind == LIGHTNING:
-            with jax.named_scope("sala.lightning"):
+            with part("attn_in"):
                 q, k, v = lightning_qkv(mw, cfg, xn[:, None], cos, sin)
+            with part("state"):
                 o, S = la.lightning_decode(q[:, 0], k[:, 0], v[:, 0], S,
                                            active, layer=li)
+            with part("attn_out"):
                 y = lightning_out(mw, cfg, xn, o)
             li += 1
         else:
-            with jax.named_scope("sala.sparse"):
+            with part("attn_in"):
                 q, k, v = sparse_qkv(mw, cfg, xn)
+            with part("attn"):
                 kv_pool, ck_pool = spg.append_kv(
                     kv_pool, ck_pool, tables, positions, k, v, active,
                     layer=lp, sp=sp)
+            with part("select"):
                 blocks, c = spg.sparse_select(q, ck_pool, tables, positions,
                                               active, layer=lp, sp=sp)
+                tallies = tallies + c
+            with part("attn"):
                 o = spg.sparse_paged_decode(q, kv_pool, tables, positions,
                                             blocks, layer=lp, sp=sp)
+            with part("attn_out"):
                 y = _gated_out(mw, xn, o.reshape(o.shape[0], -1))
-                tallies = tallies + c
             lp += 1
-        x = x + (a * y).astype(x.dtype)
-        xn = rms_norm(x, lw["post_attention_layernorm.weight"], eps)
-        x = x + (a * _swiglu(_sub(lw, "mlp."), xn)).astype(x.dtype)
-    rows = active.sum(dtype=jnp.int32) * li
+        with part("attn_out"):
+            x = x + (a * y).astype(x.dtype)
+        with part("norm"):
+            xn = rms_norm(x, lw["post_attention_layernorm.weight"], eps)
+        with part("ffn"):
+            x = x + (a * _swiglu(_sub(lw, "mlp."), xn)).astype(x.dtype)
+    with part("state"):
+        rows = active.sum(dtype=jnp.int32) * li
     return (x, (kv_pool, ck_pool), {"lightning": S},
             jnp.concatenate([tallies, rows[None]]))
 
@@ -533,7 +559,9 @@ class MiniCPMSALAForCausalLM(CausalLMBase):
         def embed(tok, pos):
             del pos
             e = state["model.embed_tokens.weight"]
-            return (cfg.scale_emb * jnp.take(e, tok, axis=0)).astype(e.dtype)
+            with part("embed"):
+                return (cfg.scale_emb
+                        * jnp.take(e, tok, axis=0)).astype(e.dtype)
 
         def step(x, pool, tables, positions, slot_state):
             return decode_step(state, cfg, x, pool, tables, positions,

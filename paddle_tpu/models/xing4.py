@@ -44,6 +44,7 @@ from paddle_tpu.nn.layers.moe import (GroupedSwiGLUExperts,
 from paddle_tpu.ops import mla_decode, mla_prefill, moe_grouped
 from paddle_tpu.ops import rope as rope_ops
 from paddle_tpu.ops.rms_norm import rms_norm
+from paddle_tpu.profiler.parts import part
 
 _HI = jax.lax.Precision.HIGHEST
 # what ``decode_step`` counts, in the order it returns them
@@ -201,14 +202,17 @@ def mla_expanded(w: Dict, cfg: MLAConfig, q_n, q_r, latent, start_pos):
     c_kv, k_r, kvb = latent[..., :dc], latent[..., dc:], _kvb(w, cfg)
     if _attn_plan(cfg, s, latent.shape[1], start_pos,
                   q_n.dtype.itemsize) is not None:
-        k_n = jnp.einsum("bsc,chd->bhsd", c_kv, kvb[..., :dn])
-        v = jnp.einsum("bsc,chd->bhsd", c_kv, kvb[..., dn:])
+        with part("attn_in"):
+            k_n = jnp.einsum("bsc,chd->bhsd", c_kv, kvb[..., :dn])
+            v = jnp.einsum("bsc,chd->bhsd", c_kv, kvb[..., dn:])
         return mla_prefill.mla_flash_prefill(
             q_n, q_r, k_n, v, k_r, scale=cfg.softmax_scale,
             start_pos=start_pos)
-    kv = jnp.einsum("bsc,chd->bshd", c_kv, kvb)
-    return mla_prefill.reference(q_n, q_r, kv[..., :dn], kv[..., dn:], k_r,
-                                 cfg.softmax_scale, start_pos)
+    with part("attn_in"):
+        kv = jnp.einsum("bsc,chd->bshd", c_kv, kvb)
+    with part("attn"):
+        return mla_prefill.reference(q_n, q_r, kv[..., :dn], kv[..., dn:],
+                                     k_r, cfg.softmax_scale, start_pos)
 
 
 def mla_absorb_query(w: Dict, cfg: MLAConfig, q_n, q_r, lanes: int):
@@ -233,15 +237,19 @@ def mla_layered(w: Dict, cfg: MLAConfig, xn, cos, sin, cache, start_pos):
     (b, s, C): project, write the rows into ``cache["ckv"]`` at
     ``start_pos`` (or attend over the block's own rows when ``cache`` is
     None), expanded attention, ``W_o``. -> (y (b, s, C), cache')."""
-    q_n, q_r, lat = mla_project(w, cfg, xn, cos, sin)
+    with part("attn_in"):
+        q_n, q_r, lat = mla_project(w, cfg, xn, cos, sin)
     if cache is not None:
-        full = jax.lax.dynamic_update_slice_in_dim(
-            cache["ckv"], lat.astype(cache["ckv"].dtype), start_pos, axis=1)
+        with part("attn"):
+            full = jax.lax.dynamic_update_slice_in_dim(
+                cache["ckv"], lat.astype(cache["ckv"].dtype), start_pos,
+                axis=1)
         cache = {"ckv": full}
     else:
         full = lat
     att = mla_expanded(w, cfg, q_n, q_r, full.astype(lat.dtype), start_pos)
-    return jnp.matmul(att, w["o_proj.weight"]), cache
+    with part("attn_out"):
+        return jnp.matmul(att, w["o_proj.weight"]), cache
 
 
 def mla_paged(w: Dict, cfg: MLAConfig, xn, cos, sin, pool, tables, positions,
@@ -249,14 +257,18 @@ def mla_paged(w: Dict, cfg: MLAConfig, xn, cos, sin, pool, tables, positions,
     """The attention sub-layer of one decode step on the normed xn
     (b, C) over the paged latent pool, absorbed. -> (y (b, C), pool)."""
     lanes = pool.shape[-1]
-    q_n, q_r, lat = mla_project(w, cfg, xn[:, None], cos[:, None],
-                                sin[:, None])
-    o_c, pool = mla_decode.mla_paged_decode(
-        mla_absorb_query(w, cfg, q_n[:, 0], q_r[:, 0], lanes),
-        mla_decode.pad_lanes(lat[:, 0], lanes), pool, tables, positions,
-        layer=layer, d_c=cfg.kv_lora_rank, scale=cfg.softmax_scale)
-    return jnp.matmul(mla_absorb_out(w, cfg, o_c).astype(xn.dtype),
-                      w["o_proj.weight"]), pool
+    with part("attn_in"):
+        q_n, q_r, lat = mla_project(w, cfg, xn[:, None], cos[:, None],
+                                    sin[:, None])
+        q = mla_absorb_query(w, cfg, q_n[:, 0], q_r[:, 0], lanes)
+        new = mla_decode.pad_lanes(lat[:, 0], lanes)
+    with part("attn"):
+        o_c, pool = mla_decode.mla_paged_decode(
+            q, new, pool, tables, positions, layer=layer,
+            d_c=cfg.kv_lora_rank, scale=cfg.softmax_scale)
+    with part("attn_out"):
+        return jnp.matmul(mla_absorb_out(w, cfg, o_c).astype(xn.dtype),
+                          w["o_proj.weight"]), pool
 
 
 def latent_plan(cfg: MLAConfig, itemsize: int) -> Dict:
@@ -301,12 +313,14 @@ def route(w: Dict, cfg: Xing4Config, x):
     """x (T, C) -> (idx (T, k), weights (T, k) float32); the scores in
     float32 at full matmul precision (a rounded score picks another
     expert)."""
-    logits = jnp.matmul(x.astype(jnp.float32),
-                        w["gate.weight"].astype(jnp.float32), precision=_HI)
-    return sigmoid_topk_routing(
-        logits, w["gate.e_score_correction_bias"], cfg.num_experts_per_tok,
-        scaling=cfg.routed_scaling_factor,
-        normalize_topk=cfg.norm_topk_prob)
+    with part("router"):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            w["gate.weight"].astype(jnp.float32),
+                            precision=_HI)
+        return sigmoid_topk_routing(
+            logits, w["gate.e_score_correction_bias"],
+            cfg.num_experts_per_tok, scaling=cfg.routed_scaling_factor,
+            normalize_topk=cfg.norm_topk_prob)
 
 
 def moe_ragged(w: Dict, cfg: Xing4Config, x):
@@ -315,10 +329,12 @@ def moe_ragged(w: Dict, cfg: Xing4Config, x):
     the grouped kernel or ``ragged_dot``, as
     ``ops.moe_grouped.prefill_path`` says for these widths here."""
     idx, wts = route(w, cfg, x)
-    y = moe_grouped.moe_grouped_ffn_prefill(
-        x, idx, wts, w["experts.w_gate"], w["experts.w_up"],
-        w["experts.w_down"])
-    return y + _swiglu(_sub(w, "shared_experts."), x)
+    with part("experts"):
+        y = moe_grouped.moe_grouped_ffn_prefill(
+            x, idx, wts, w["experts.w_gate"], w["experts.w_up"],
+            w["experts.w_down"])
+    with part("ffn"):
+        return y + _swiglu(_sub(w, "shared_experts."), x)
 
 
 def _is_moe(cfg: Xing4Config, layer: int) -> bool:
@@ -326,7 +342,7 @@ def _is_moe(cfg: Xing4Config, layer: int) -> bool:
 
 
 def _hc(w: Dict, cfg: Xing4Config, X):
-    with jax.named_scope("xing4.hc"):
+    with part("mix"):
         h_pre, h_post, h_res = hc.hc_mixers(
             X, w["phi"], w["alpha_pre"], w["alpha_post"], w["alpha_res"],
             w["pre_bias"], w["post_bias"], w["res_bias"], **cfg.hc_options())
@@ -334,7 +350,7 @@ def _hc(w: Dict, cfg: Xing4Config, X):
 
 
 def _hc_write(X, y, h_post, h_res):
-    with jax.named_scope("xing4.hc"):
+    with part("mix"):
         return hc.hc_write(X, y, h_post, h_res)
 
 
@@ -346,19 +362,20 @@ def block_forward(w: Dict, cfg: Xing4Config, moe: bool, X, cos, sin, cache,
     b, s = X.shape[:2]
     eps = cfg.rms_norm_eps
     h, h_post, h_res = _hc(_sub(w, "attn_hc."), cfg, X)
-    with jax.named_scope("xing4.mla"):
+    with part("norm"):
         xn = rms_norm(h, w["input_layernorm.weight"], eps)
-        y, cache = mla_layered(_sub(w, "self_attn."), cfg, xn, cos, sin,
-                               cache, start_pos)
+    y, cache = mla_layered(_sub(w, "self_attn."), cfg, xn, cos, sin, cache,
+                           start_pos)
     X = _hc_write(X, y, h_post, h_res)
     h, h_post, h_res = _hc(_sub(w, "ffn_hc."), cfg, X)
-    xn = rms_norm(h, w["post_attention_layernorm.weight"], eps)
+    with part("norm"):
+        xn = rms_norm(h, w["post_attention_layernorm.weight"], eps)
     if moe:
-        with jax.named_scope("xing4.moe"):
-            y = moe_ragged(_sub(w, "mlp."), cfg,
-                           xn.reshape(b * s, -1)).reshape(b, s, -1)
+        y = moe_ragged(_sub(w, "mlp."), cfg,
+                       xn.reshape(b * s, -1)).reshape(b, s, -1)
     else:
-        y = _swiglu(_sub(w, "mlp."), xn)
+        with part("ffn"):
+            y = _swiglu(_sub(w, "mlp."), xn)
     return _hc_write(X, y, h_post, h_res), cache
 
 
@@ -372,19 +389,23 @@ def hidden_forward(w: Dict, cfg: Xing4Config, ids, cache=None, start_pos=0):
     """ids (b, s) -> (the streams' sum before the final norm (b, s, C),
     cache')."""
     s = ids.shape[1]
-    cos, sin = rope_tables(cfg, start_pos + jnp.arange(s))
-    X = _streams(cfg, jnp.take(w["model.embed_tokens.weight"], ids, axis=0))
+    with part("attn_in"):
+        cos, sin = rope_tables(cfg, start_pos + jnp.arange(s))
+    with part("embed"):
+        X = _streams(cfg, jnp.take(w["model.embed_tokens.weight"], ids,
+                                   axis=0))
     new_cache = []
     for i in range(cfg.num_layers):
         X, c = block_forward(_sub(w, f"model.layers.{i}."), cfg,
                              _is_moe(cfg, i), X, cos, sin,
                              None if cache is None else cache[i], start_pos)
         new_cache.append(c)
-    return X.sum(axis=-2), (None if cache is None else new_cache)
+    with part("mix"):
+        return X.sum(axis=-2), (None if cache is None else new_cache)
 
 
 def head_forward(w: Dict, cfg: Xing4Config, h, norm="model.norm.weight"):
-    with jax.named_scope("xing4.head"):
+    with part("head"):
         return jnp.matmul(rms_norm(h, w[norm], cfg.rms_norm_eps),
                           w["lm_head.weight"])
 
@@ -394,12 +415,14 @@ def mtp_forward(w: Dict, cfg: Xing4Config, h, ids):
     -> logits (b, s - 1, vocab); row t predicts token t + 2."""
     eps = cfg.rms_norm_eps
     s = ids.shape[1] - 1
-    emb = jnp.take(w["model.embed_tokens.weight"], ids[:, 1:], axis=0)
-    both = jnp.concatenate(
-        [rms_norm(h[:, :-1], w["model.mtp.hnorm.weight"], eps),
-         rms_norm(emb, w["model.mtp.enorm.weight"], eps)], axis=-1)
-    x = jnp.matmul(both, w["model.mtp.eh_proj.weight"])
-    cos, sin = rope_tables(cfg, jnp.arange(s))
+    with part("embed"):
+        emb = jnp.take(w["model.embed_tokens.weight"], ids[:, 1:], axis=0)
+        both = jnp.concatenate(
+            [rms_norm(h[:, :-1], w["model.mtp.hnorm.weight"], eps),
+             rms_norm(emb, w["model.mtp.enorm.weight"], eps)], axis=-1)
+        x = jnp.matmul(both, w["model.mtp.eh_proj.weight"])
+    with part("attn_in"):
+        cos, sin = rope_tables(cfg, jnp.arange(s))
     X, _ = block_forward(_sub(w, "model.mtp.block."), cfg, True,
                          _streams(cfg, x), cos, sin, None, 0)
     return head_forward(w, cfg, X.sum(axis=-2), norm="model.mtp.norm.weight")
@@ -414,37 +437,45 @@ def decode_step(w: Dict, cfg: Xing4Config, x, pool, tables, positions):
     rows, active rows x k, the last three summed over the layers)."""
     eps = cfg.rms_norm_eps
     active = tables[:, 0] != 0
-    cos, sin = rope_tables(cfg, positions)                  # (b, d_r)
-    X = _streams(cfg, x)
+    with part("attn_in"):
+        cos, sin = rope_tables(cfg, positions)              # (b, d_r)
+    with part("embed"):
+        X = _streams(cfg, x)
     counts = jnp.zeros(3, jnp.int32)
     n_moe = 0
     for i in range(cfg.num_layers):
         lw = _sub(w, f"model.layers.{i}.")
         h, h_post, h_res = _hc(_sub(lw, "attn_hc."), cfg, X)
-        with jax.named_scope("xing4.mla"):
+        with part("norm"):
             xn = rms_norm(h, lw["input_layernorm.weight"], eps)
-            y, pool = mla_paged(_sub(lw, "self_attn."), cfg, xn, cos, sin,
-                                pool, tables, positions, i)
+        y, pool = mla_paged(_sub(lw, "self_attn."), cfg, xn, cos, sin, pool,
+                            tables, positions, i)
         X = _hc_write(X, y, h_post, h_res)
         h, h_post, h_res = _hc(_sub(lw, "ffn_hc."), cfg, X)
-        xn = rms_norm(h, lw["post_attention_layernorm.weight"], eps)
+        with part("norm"):
+            xn = rms_norm(h, lw["post_attention_layernorm.weight"], eps)
         mw = _sub(lw, "mlp.")
         if _is_moe(cfg, i):
-            with jax.named_scope("xing4.moe"):
-                idx, wts = route(mw, cfg, xn)
+            idx, wts = route(mw, cfg, xn)
+            with part("experts"):
                 dense = moe_grouped.dense_weights(
                     idx, wts, active, cfg.n_routed_experts)
-                y = (moe_grouped.moe_grouped_ffn_decode(
+                y = moe_grouped.moe_grouped_ffn_decode(
                     xn, dense, mw["experts.w_gate"], mw["experts.w_up"],
                     mw["experts.w_down"])
-                    + _swiglu(_sub(mw, "shared_experts."), xn))
+            with part("ffn"):
+                y = y + _swiglu(_sub(mw, "shared_experts."), xn)
+            with part("router"):
                 counts = counts + moe_grouped.routing_counts(
                     idx, active, cfg.n_routed_experts)
-                n_moe += 1
+            n_moe += 1
         else:
-            y = _swiglu(mw, xn)
+            with part("ffn"):
+                y = _swiglu(mw, xn)
         X = _hc_write(X, y, h_post, h_res)
-    return (X.sum(axis=-2), pool,
+    with part("mix"):
+        h = X.sum(axis=-2)
+    return (h, pool,
             jnp.concatenate([jnp.full((1,), n_moe, jnp.int32), counts]))
 
 
@@ -621,7 +652,9 @@ class Xing4ForCausalLM(LatentCausalLM):
 
         def embed(tok, pos):
             del pos
-            return jnp.take(state["model.embed_tokens.weight"], tok, axis=0)
+            with part("embed"):
+                return jnp.take(state["model.embed_tokens.weight"], tok,
+                                axis=0)
 
         def step(x, pool, tables, positions):
             return decode_step(state, cfg, x, pool, tables, positions)

@@ -49,6 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from paddle_tpu.profiler.parts import part
+
 NEG_INF = -1e30
 
 # Per-generation VMEM capacity (MiB). The runtime exposes no VMEM
@@ -370,7 +372,7 @@ def quantize_kv_cache(kv, num_kv_heads: int):
     jnp reference can apply them with a single broadcast multiply (k-half
     scales fold into the q rows, v-half scales apply to the attention
     output)."""
-    with jax.named_scope("fused_decode.quantize_kv_cache"):
+    with part("attn"):
         L, b, S, dkv2 = kv.shape
         hd = dkv2 // (2 * num_kv_heads)
         amax = jnp.abs(kv.astype(jnp.float32)).max(axis=(1, 2))   # (L, 2dkv)
@@ -1575,10 +1577,8 @@ def fused_decode_step(x, params, kv_cache, pos, cos, sin, *,
                 f"cache but the cache dtype is {kv_cache.dtype} ({cb} B); "
                 f"rebuild the plan with decode_block_plan(cache_wbytes="
                 f"{cb})")
-        # named scopes mark the kernel phase boundary in xplane
-        # captures (trace-time only — no runtime cost)
         if arch == "moe":
-            with jax.named_scope("fused_decode.kernel_moe"):
+            with part("layers"):
                 return _fused_decode_moe_pallas(
                     x, params, kv_cache, pos,
                     num_heads=num_heads, num_kv_heads=num_kv_heads,
@@ -1586,7 +1586,7 @@ def fused_decode_step(x, params, kv_cache, pos, cos, sin, *,
                     rope_base=rope_base, eps=eps, chunk=kv_chunk,
                     blocks=blocks, kv_scales=kv_scales,
                     interpret=interp)
-        with jax.named_scope("fused_decode.kernel"):
+        with part("layers"):
             return _fused_decode_pallas(
                 x, params, kv_cache, pos,
                 num_heads=num_heads, num_kv_heads=num_kv_heads,
@@ -1594,7 +1594,7 @@ def fused_decode_step(x, params, kv_cache, pos, cos, sin, *,
                 rope_base=rope_base, eps=eps, chunk=kv_chunk,
                 arch=arch, blocks=blocks,
                 kv_scales=kv_scales, interpret=interp)
-    with jax.named_scope("fused_decode.reference"):
+    with part("layers"):
         return fused_decode_reference(
             x, params, kv_cache, pos, cos, sin,
             num_heads=num_heads, num_kv_heads=num_kv_heads, eps=eps,
@@ -2476,14 +2476,14 @@ def fused_paged_decode_step(x, params, kv_pool, block_tables, positions,
     """
     interp = _paged_pallas_interpret(kv_pool, arch, blocks, mp_axis)
     if interp is not None:
-        with jax.named_scope("fused_decode.kernel_paged"):
+        with part("layers"):
             return _fused_paged_decode_pallas(
                 x, params, kv_pool, block_tables, positions,
                 num_heads=num_heads, num_kv_heads=num_kv_heads,
                 head_dim=kv_pool.shape[-1] // 2 // num_kv_heads,
                 rope_base=rope_base, eps=eps, arch=arch, blocks=blocks,
                 kv_scales=kv_scales, interpret=interp)
-    with jax.named_scope("fused_decode.reference_paged"):
+    with part("layers"):
         return fused_paged_decode_reference(
             x, params, kv_pool, block_tables, positions, cos, sin,
             num_heads=num_heads, num_kv_heads=num_kv_heads, eps=eps,
@@ -2582,7 +2582,7 @@ def fused_paged_tick_step(x, params, kv_pool, block_tables, positions,
         if mp_axis is not None \
                 and chunk_kv.shape[-1] != kv_pool.shape[-1]:
             chunk_kv = mp_local_kv_lastdim(chunk_kv, mp_axis)
-        with jax.named_scope("fused_decode.chunk_scatter"):
+        with part("attn"):
             kv_pool = paged_chunk_scatter(kv_pool, chunk_bids, chunk_kv)
     return fused_paged_decode_step(
         x, params, kv_pool, block_tables, positions, cos, sin,
@@ -2786,7 +2786,7 @@ def fused_paged_verify_step(x, params, kv_pool, block_tables, positions,
     b, K1, h = x.shape
     interp = _paged_pallas_interpret(kv_pool, arch, blocks, mp_axis)
     if interp is not None:
-        with jax.named_scope("fused_decode.kernel_paged_verify"):
+        with part("layers"):
             # token-major flat: token j's rows contiguous at [j*b,
             # (j+1)*b) so the kernel's per-token stages are static
             # slices
@@ -2798,7 +2798,7 @@ def fused_paged_verify_step(x, params, kv_pool, block_tables, positions,
                 rope_base=rope_base, eps=eps, arch=arch, blocks=blocks,
                 kv_scales=kv_scales, interpret=interp)
             return y.reshape(K1, b, h).transpose(1, 0, 2), pool
-    with jax.named_scope("fused_decode.reference_paged_verify"):
+    with part("layers"):
         return fused_paged_verify_reference(
             x, params, kv_pool, block_tables, positions, cos, sin,
             num_heads=num_heads, num_kv_heads=num_kv_heads, eps=eps,

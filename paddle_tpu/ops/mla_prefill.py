@@ -45,6 +45,7 @@ import numpy as np
 from jax import lax
 
 from paddle_tpu.ops.mla_decode import pad_lanes
+from paddle_tpu.profiler.parts import part
 
 NEG_INF = -1e30
 KERNEL_NAME = "mla_flash_prefill"
@@ -235,12 +236,15 @@ def _flash(q_n, q_r, k_n, v, k_r, scale, start_pos, interpret):
     plan = kernel_plan(s, S, start_pos, d_n, q_r.shape[-1], d_v,
                        q_n.dtype.itemsize)
     assert plan is not None, (q_n.shape, v.shape, start_pos)
-    out = _flash_pallas(
-        _lanes(jnp.swapaxes(q_n, 1, 2)), _lanes(jnp.swapaxes(q_r, 1, 2)),
-        _lanes(k_n), _lanes(v), _lanes(k_r), scale=scale,
-        start_pos=start_pos, interpret=interpret, **plan)
-    if _pad_to(d_v) != d_v:
-        out = out.reshape(b, s, H, -1)[..., :d_v].reshape(b, s, H * d_v)
+    with part("attn_in"):       # the head-major, lane-padded copies
+        operands = (_lanes(jnp.swapaxes(q_n, 1, 2)),
+                    _lanes(jnp.swapaxes(q_r, 1, 2)), _lanes(k_n), _lanes(v),
+                    _lanes(k_r))
+    with part("attn"):
+        out = _flash_pallas(*operands, scale=scale, start_pos=start_pos,
+                            interpret=interpret, **plan)
+        if _pad_to(d_v) != d_v:
+            out = out.reshape(b, s, H, -1)[..., :d_v].reshape(b, s, H * d_v)
     return out
 
 

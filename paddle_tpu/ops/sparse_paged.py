@@ -316,14 +316,13 @@ def sparse_select(q, ck_pool, tables, positions, active, *, layer: int,
     NB = tables.shape[1] * ck_pool.shape[2] * sp.kernel_stride \
         // sp.block_size
     on, interp = pallas_mode()
-    with jax.named_scope("sparse.select"):
-        if on and d % 128 == 0 and ck_pool.shape[2] % 8 == 0:
-            r = _stage1_pallas(q, ck_pool, tables, positions, layer=layer,
-                               sp=sp, interpret=interp)
-        else:
-            r = _stage1_reference(q, ck_pool, tables, positions, layer=layer,
-                                  sp=sp)
-        blocks = select_list(block_scores(r, sp, NB), positions, sp)
+    if on and d % 128 == 0 and ck_pool.shape[2] % 8 == 0:
+        r = _stage1_pallas(q, ck_pool, tables, positions, layer=layer,
+                           sp=sp, interpret=interp)
+    else:
+        r = _stage1_reference(q, ck_pool, tables, positions, layer=layer,
+                              sp=sp)
+    blocks = select_list(block_scores(r, sp, NB), positions, sp)
     act = active.astype(jnp.int32)
     read = ((blocks >= 0).sum((1, 2), dtype=jnp.int32) * act).sum()
     visible = ((positions // sp.block_size + 1) * G * act).sum()

@@ -28,6 +28,7 @@ from paddle_tpu.parallel.topology import (
     get_hybrid_communicate_group,
 )
 from paddle_tpu.parallel.data_parallel import DataParallel
+from paddle_tpu.profiler.parts import part
 
 
 class Fleet:
@@ -274,9 +275,11 @@ def make_train_step(model: Layer, optimizer, loss_fn: Callable,
 
     def forward_loss(state, batch, rngs):
         def fwd(s, b):
+            # the model's forward names its own parts
             out = functional_call(model, s, b["input"] if isinstance(b, dict)
                                   and "input" in b else b, rngs=rngs)
-            return loss_fn(out, b)
+            with part("loss"):
+                return loss_fn(out, b)
         if remat_policy is not None:
             fwd = jax.checkpoint(fwd, policy=remat_policy)
         return fwd(state, batch)
@@ -288,7 +291,10 @@ def make_train_step(model: Layer, optimizer, loss_fn: Callable,
         """Plain or gradient-merge (k-microbatch accumulated) grads."""
         def scalar_loss(s, b, r):
             l = forward_loss(s, b, r)
-            return l * scale if scale is not None else l
+            if scale is None:
+                return l
+            with part("optimizer"):
+                return l * scale
 
         if merge_k <= 1:
             return jax.value_and_grad(
@@ -313,8 +319,9 @@ def make_train_step(model: Layer, optimizer, loss_fn: Callable,
                       for name, k in (rngs or {}).items()}
             loss, g = jax.value_and_grad(
                 lambda s: scalar_loss(s, mb, rngs_i))(state)
-            return (loss_acc + loss,
-                    jax.tree_util.tree_map(jnp.add, g_acc, g)), None
+            with part("optimizer"):
+                return (loss_acc + loss,
+                        jax.tree_util.tree_map(jnp.add, g_acc, g)), None
 
         zero_g = jax.tree_util.tree_map(
             lambda p: jnp.zeros(p.shape, jnp.float32), state)
@@ -322,16 +329,18 @@ def make_train_step(model: Layer, optimizer, loss_fn: Callable,
             body, (jnp.zeros((), jnp.float32), zero_g),
             (micro, jnp.arange(merge_k)))
         inv = 1.0 / merge_k
-        return (loss_sum * inv,
-                jax.tree_util.tree_map(lambda g: g * inv, g_sum))
+        with part("optimizer"):
+            return (loss_sum * inv,
+                    jax.tree_util.tree_map(lambda g: g * inv, g_sum))
 
     from paddle_tpu.ops import flash_attention
     from paddle_tpu.parallel.mp_layers import MP_AXIS
     head_axis = MP_AXIS if hcg.axis_size(MP_AXIS) > 1 else None
 
-    def _step(state, opt_state, batch, rngs):
-        # this trace is one GSPMD jit over `mesh`, which cannot partition
-        # a Mosaic call: its flash kernels run per shard
+    def train_step(state, opt_state, batch, rngs):
+        # the program's name: a run on a trace's `XLA Modules` line reads
+        # `jit_train_step`. This trace is one GSPMD jit over `mesh`, which
+        # cannot partition a Mosaic call: its flash kernels run per shard
         with flash_attention.partitioned(mesh, active_batch_axes,
                                          head_axis):
             return _step_on_mesh(state, opt_state, batch, rngs)
@@ -341,20 +350,23 @@ def make_train_step(model: Layer, optimizer, loss_fn: Callable,
             sstate = opt_state["scaler"]
             loss_s, grads = _value_and_grad(state, batch, rngs,
                                             scale=sstate["scale"])
-            loss = loss_s / sstate["scale"]
-            grads, found_inf = scaler.unscale(grads, sstate)
+            with part("optimizer"):
+                loss = loss_s / sstate["scale"]
+                grads, found_inf = scaler.unscale(grads, sstate)
         else:
             loss, grads = _value_and_grad(state, batch, rngs)
         # constrain grads per stage-2 semantics; GSPMD propagates the rest
         grads = {k: jax.lax.with_sharding_constraint(
             g, NamedSharding(mesh, gspecs[k])) for k, g in grads.items()}
-        new_state, new_opt = optimizer.update(grads, opt_state, state)
-        if scaler is not None:
-            # overflow step: keep old params/moments, only the scale moves
-            pick = lambda n, o: jnp.where(found_inf, o, n)
-            new_state = jax.tree_util.tree_map(pick, new_state, state)
-            new_opt = jax.tree_util.tree_map(pick, new_opt, opt_state)
-            new_opt["scaler"] = scaler.update_state(sstate, found_inf)
+        with part("optimizer"):         # the clip is the optimizer's own
+            new_state, new_opt = optimizer.update(grads, opt_state, state)
+            if scaler is not None:
+                # overflow step: keep old params/moments, only the scale
+                # moves
+                pick = lambda n, o: jnp.where(found_inf, o, n)
+                new_state = jax.tree_util.tree_map(pick, new_state, state)
+                new_opt = jax.tree_util.tree_map(pick, new_opt, opt_state)
+                new_opt["scaler"] = scaler.update_state(sstate, found_inf)
         new_state = {k: jax.lax.with_sharding_constraint(
             v, NamedSharding(mesh, pspecs[k])) for k, v in new_state.items()}
         return new_state, new_opt, loss
@@ -376,7 +388,7 @@ def make_train_step(model: Layer, optimizer, loss_fn: Callable,
         return placed, opt_state
 
     jit_step = jax.jit(
-        _step,
+        train_step,
         donate_argnums=(0, 1) if donate else (),
     )
 

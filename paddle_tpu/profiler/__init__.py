@@ -97,7 +97,10 @@ class Profiler:
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
                 time_unit="ms", device_only=True, limit=30):
         """Per-op time table parsed from the captured xplane trace
-        (reference: paddle.profiler summary tables)."""
+        (reference: paddle.profiler summary tables), then, where the
+        trace names them (a TPU's does), each device's time by program
+        and model part (``xplane.part_seconds``: self time, a loop split
+        among its body's parts)."""
         from paddle_tpu.profiler import xplane
 
         planes = xplane.load_latest(self.log_dir)
@@ -106,7 +109,14 @@ class Profiler:
         rows = xplane.op_summary(planes, device_only=device_only)
         if not rows:  # e.g. CPU-only run: fall back to host planes
             rows = xplane.op_summary(planes, device_only=False)
-        return xplane.format_summary(rows, time_unit=time_unit, limit=limit)
+        text = xplane.format_summary(rows, time_unit=time_unit, limit=limit)
+        for plane in planes:
+            table = (xplane.part_seconds(plane)
+                     if xplane.is_device_plane(plane.name) else None)
+            if table:
+                text += (f"\n\n{plane.name}: device time by program and "
+                         f"part\n{xplane.format_parts(table)}")
+        return text
 
     def export_chrome_trace(self, out_path=None):
         from paddle_tpu.profiler import xplane

@@ -13,14 +13,31 @@ Wire schema (field numbers from xplane.proto):
   XPlane:   id=1 name=2 lines=3 event_metadata=4(map) stat_metadata=5(map)
   XLine:    id=1 name=2 timestamp_ns=3 events=4 display_name=11
   XEvent:   metadata_id=1 offset_ps=2 duration_ps=3 num_occurrences=5
-  XEventMetadata: id=1 name=2 display_name=4
+  XEventMetadata: id=1 name=2 display_name=4 stats=5
+  XStatMetadata:  id=1 name=2
+  XStat:    metadata_id=1 double=2 uint64=3 int64=4 str=5 bytes=6 ref=7
   map entry: key=1 value=2
+
+Where a device op's FRAMEWORK name is (TPU v5e, jax 0.9.0, seen on the
+chip in PR 36): not on the event and not among the event's own stats
+(``device_offset_ps``, ``device_duration_ps``, ``Time Scale
+Multiplier``: all that ``jax.profiler.ProfileData`` shows), but among
+the stats of the event's METADATA entry in the plane's ``event_metadata``
+map: ``tf_op`` (``jit(serving_step)/part.attn_in/dot_general:``),
+beside ``hlo_category``, ``program_id``, ``flops``, ``bytes_accessed``,
+``shape_with_layout``, ``source``. ``XEvent.meta.stats`` holds them;
+:func:`part_seconds` splits a device plane's time by program and model
+part (``profiler.parts``) from them.
 """
 
 import glob
 import json
 import os
+import re
+import struct
 from typing import Dict, List, Optional, Tuple
+
+from paddle_tpu.profiler.parts import part_of
 
 
 def _read_varint(buf: bytes, i: int) -> Tuple[int, int]:
@@ -59,14 +76,26 @@ def _fields(buf: bytes):
         yield field, wire, v
 
 
-class XEvent:
-    __slots__ = ("name", "offset_ps", "duration_ps", "occurrences")
+class XEventMeta:
+    """An entry of a plane's ``event_metadata``: what the events that
+    name it share. ``stats``: {stat name: value}."""
+    __slots__ = ("name", "display", "stats")
 
-    def __init__(self, name, offset_ps, duration_ps, occurrences):
+    def __init__(self, name, display="", stats=None):
+        self.name = name
+        self.display = display
+        self.stats = stats or {}
+
+
+class XEvent:
+    __slots__ = ("name", "offset_ps", "duration_ps", "occurrences", "meta")
+
+    def __init__(self, name, offset_ps, duration_ps, occurrences, meta):
         self.name = name
         self.offset_ps = offset_ps
         self.duration_ps = duration_ps
         self.occurrences = occurrences
+        self.meta = meta
 
 
 class XLine:
@@ -86,8 +115,30 @@ class XPlane:
         self.lines = lines
 
 
-def _parse_event_metadata(buf: bytes) -> Tuple[int, str]:
-    mid, name, display = 0, "", ""
+def _parse_stat(buf: bytes, stat_names: Dict[int, str]):
+    """An XStat -> (stat name, value); a ``ref`` value is the NAME of
+    the stat metadata it points at."""
+    sid, val = 0, None
+    for f, w, v in _fields(buf):
+        if f == 1 and w == 0:
+            sid = v
+        elif f == 2 and w == 1:
+            val = struct.unpack("<d", v)[0]
+        elif f == 3 and w == 0:
+            val = v
+        elif f == 4 and w == 0:
+            val = v - (1 << 64) if v >= 1 << 63 else v
+        elif f == 5 and w == 2:
+            val = v.decode("utf-8", "replace")
+        elif f == 6 and w == 2:
+            val = v
+        elif f == 7 and w == 0:
+            val = stat_names.get(v, v)
+    return stat_names.get(sid, f"stat#{sid}"), val
+
+
+def _parse_event_metadata(buf: bytes, stat_names: Dict[int, str]):
+    mid, name, display, stats = 0, "", "", {}
     for f, w, v in _fields(buf):
         if f == 1 and w == 0:
             mid = v
@@ -95,27 +146,80 @@ def _parse_event_metadata(buf: bytes) -> Tuple[int, str]:
             name = v.decode("utf-8", "replace")
         elif f == 4 and w == 2:
             display = v.decode("utf-8", "replace")
-    return mid, (display or name)
+        elif f == 5 and w == 2:
+            key, val = _parse_stat(v, stat_names)
+            stats[key] = val
+    return mid, XEventMeta(name, display, stats)
 
 
-def _parse_plane(buf: bytes) -> XPlane:
+def _parse_event(v: bytes):
+    """(metadata_id, offset_ps, duration_ps, occurrences) of an XEvent;
+    its own stats are skipped by their length. The one loop that runs
+    once an event (a served cell's 3 s hold 400 k), so it reads its
+    varints in line."""
+    mid = off = dur = 0
+    occ = 1
+    i, n = 0, len(v)
+    while i < n:
+        key = v[i]              # XEvent's field numbers fit one byte
+        i += 1
+        wire = key & 7
+        if wire == 1:
+            i += 8
+            continue
+        if wire == 5:
+            i += 4
+            continue
+        b = v[i]
+        i += 1
+        val = b & 0x7F
+        shift = 7
+        while b & 0x80:
+            b = v[i]
+            i += 1
+            val |= (b & 0x7F) << shift
+            shift += 7
+        if wire == 2:
+            i += val            # a length: skip the payload
+        elif key == 8:          # field 1, varint
+            mid = val
+        elif key == 16:
+            off = val
+        elif key == 24:
+            dur = val
+        elif key == 40:
+            occ = val
+    return mid, off, dur, occ
+
+
+def _parse_plane(buf: bytes, lines_named=None) -> XPlane:
+    """``lines_named``: decode the events of these lines only (the
+    others stay, empty)."""
     name = ""
     raw_lines: List[bytes] = []
-    meta: Dict[int, str] = {}
+    raw_meta: List[bytes] = []
+    stat_names: Dict[int, str] = {}
     for f, w, v in _fields(buf):
         if f == 2 and w == 2:
             name = v.decode("utf-8", "replace")
         elif f == 3 and w == 2:
             raw_lines.append(v)
         elif f == 4 and w == 2:  # map<int64, XEventMetadata>
+            raw_meta += [mv for mf, mw, mv in _fields(v)
+                         if mf == 2 and mw == 2]
+        elif f == 5 and w == 2:  # map<int64, XStatMetadata>
             for mf, mw, mv in _fields(v):
                 if mf == 2 and mw == 2:
-                    mid, mname = _parse_event_metadata(mv)
-                    meta[mid] = mname
+                    d = {ef: ev for ef, ew, ev in _fields(mv)}
+                    stat_names[d.get(1, 0)] = d.get(2, b"").decode(
+                        "utf-8", "replace")
+    # the stat names may follow the event metadata in the bytes
+    meta: Dict[int, XEventMeta] = dict(
+        _parse_event_metadata(mv, stat_names) for mv in raw_meta)
     lines = []
     for lb in raw_lines:
         lname, ts_ns = "", 0
-        events = []
+        raw_events = []
         for f, w, v in _fields(lb):
             if f == 2 and w == 2:
                 lname = v.decode("utf-8", "replace")
@@ -124,28 +228,38 @@ def _parse_plane(buf: bytes) -> XPlane:
             elif f == 3 and w == 0:
                 ts_ns = v
             elif f == 4 and w == 2:
-                mid, off, dur, occ = 0, 0, 0, 1
-                for ef, ew, ev in _fields(v):
-                    if ef == 1 and ew == 0:
-                        mid = ev
-                    elif ef == 2 and ew == 0:
-                        off = ev
-                    elif ef == 3 and ew == 0:
-                        dur = ev
-                    elif ef == 5 and ew == 0:
-                        occ = ev
-                events.append(XEvent(meta.get(mid, f"op#{mid}"), off, dur, occ))
+                raw_events.append(v)
+        events = []
+        if lines_named is None or lname in lines_named:
+            for v in raw_events:
+                mid, off, dur, occ = _parse_event(v)
+                m = meta.get(mid)
+                if m is None:
+                    m = meta[mid] = XEventMeta(f"op#{mid}")
+                events.append(XEvent(m.display or m.name, off, dur, occ, m))
         lines.append(XLine(lname, ts_ns, events))
     return XPlane(name, lines)
 
 
-def parse_xspace(path: str) -> List[XPlane]:
+def parse_xspace(path: str, planes_named=None,
+                 lines_named=None) -> List[XPlane]:
+    """The planes of one ``.xplane.pb``. ``planes_named`` (a predicate on
+    the plane's name) and ``lines_named`` (a set of line names) leave
+    the rest undecoded: a served cell's host planes hold most of a
+    trace's events."""
     with open(path, "rb") as f:
         buf = f.read()
     planes = []
     for f_, w, v in _fields(buf):
-        if f_ == 1 and w == 2:
-            planes.append(_parse_plane(v))
+        if f_ != 1 or w != 2:
+            continue
+        if planes_named is not None:
+            pname = next((pv.decode("utf-8", "replace")
+                          for pf, pw, pv in _fields(v)
+                          if pf == 2 and pw == 2), "")
+            if not planes_named(pname):
+                continue
+        planes.append(_parse_plane(v, lines_named))
     return planes
 
 
@@ -211,6 +325,169 @@ def format_summary(rows: List[dict], time_unit: str = "ms",
     if len(rows) > limit:
         lines.append(f"... ({len(rows) - limit} more ops)")
     return "\n".join(lines)
+
+
+# ---- device time by program and model part ---------------------------------
+
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+UNSCOPED = "unscoped"
+NO_PROGRAM = "(no program)"
+
+
+def is_device_plane(name: str) -> bool:
+    return name.startswith("/device:")
+
+
+def program_kind(module_event_name: str) -> str:
+    """``jit_serving_step(6300310404528360631)`` -> ``jit_serving_step``."""
+    return re.sub(r"\(\d+\)$", "", module_event_name)
+
+
+def _op_facts(meta: XEventMeta):
+    """(part or None, backward?, Mosaic kernel?, label) of an op's
+    metadata. The part is the first ``part.<name>`` of the op's framework
+    name, the ``tf_op`` stat; the backward pass reads
+    ``transpose(jvp(part.ffn))``. The label, for ops without a part: the
+    instruction's name and its result type without layouts."""
+    tf_op = str(meta.stats.get("tf_op", ""))
+    inst = meta.display or meta.name.partition(" = ")[0].lstrip("%")
+    shape = re.sub(r"\{[^}]*\}", "",
+                   str(meta.stats.get("shape_with_layout", "")))
+    return (part_of(tf_op), "transpose(" in tf_op,
+            "tpu_custom_call" in meta.name, f"{inst} {shape[:48]}".strip())
+
+
+def part_seconds(plane: XPlane, lo_ns: Optional[float] = None,
+                 hi_ns: Optional[float] = None) -> Dict[str, dict]:
+    """SELF seconds of one device plane by program kind and model part.
+
+    A run of a program is one event of the ``XLA Modules`` line; an op
+    (an event of ``XLA Ops``) is given to the run it starts in, a run
+    to its kind (:func:`program_kind`). A ``while``, ``conditional`` or
+    ``call`` event holds its body's events in time, so an event counts
+    only the time that the events starting inside it do not cover: a
+    loop is split among its children's parts and nothing counts twice.
+    With ``lo_ns`` / ``hi_ns`` only the runs that lie whole inside count
+    (``cut``: the others), ops outside every run only where they lie
+    inside.
+
+    -> {kind: {"runs", "cut", "span_s" (the runs' own durations),
+    "device_s" (all self seconds: the program's busy time), "kernel_s"
+    (Mosaic kernels), "parts": {part: [forward s, backward s]},
+    "kernels": {part: s}, "unscoped": {label: s}}}; ops that carry no
+    part are ``unscoped``, by label."""
+    runs, ops = [], []
+    for line in plane.lines:
+        base = line.timestamp_ns * 1000
+        if line.name == MODULES_LINE:
+            runs += [(base + e.offset_ps, base + e.offset_ps + e.duration_ps,
+                      program_kind(e.meta.name)) for e in line.events]
+        elif line.name == OPS_LINE:
+            # the running number keeps the sort off the metadata
+            ops += [(base + e.offset_ps, -e.duration_ps, len(ops) + i, e.meta)
+                    for i, e in enumerate(line.events)]
+    runs.sort()
+    ops.sort()                                  # a parent before its child
+    lo = -float("inf") if lo_ns is None else lo_ns * 1000
+    hi = float("inf") if hi_ns is None else hi_ns * 1000
+
+    out: Dict[str, dict] = {}
+
+    def entry(kind):
+        return out.setdefault(kind, dict(
+            runs=0, cut=0, span_s=0.0, device_s=0.0, kernel_s=0.0, parts={},
+            kernels={}, unscoped={}))
+
+    whole = []
+    for s, e, kind in runs:
+        inside = lo <= s and e <= hi
+        whole.append(inside)
+        row = entry(kind)
+        row["runs" if inside else "cut"] += 1
+        if inside:
+            row["span_s"] += (e - s) * 1e-12
+
+    facts: Dict[int, tuple] = {}
+    stack: List[list] = []      # [end_ps, self_ps, kind or None, meta]
+    r = 0
+
+    def close(item):
+        end, self_ps, kind, meta = item
+        if kind is None or self_ps <= 0:
+            return
+        f = facts.get(id(meta))
+        if f is None:
+            f = facts[id(meta)] = _op_facts(meta)
+        part, bwd, kernel, label = f
+        row, sec = entry(kind), self_ps * 1e-12
+        row["device_s"] += sec
+        if kernel:
+            row["kernel_s"] += sec
+            if part is not None:
+                row["kernels"][part] = row["kernels"].get(part, 0.0) + sec
+        if part is None:
+            row["unscoped"][label] = row["unscoped"].get(label, 0.0) + sec
+        else:
+            row["parts"].setdefault(part, [0.0, 0.0])[bwd] += sec
+
+    for start, neg_dur, _, meta in ops:
+        end = start - neg_dur
+        while stack and stack[-1][0] <= start:
+            close(stack.pop())
+        while r + 1 < len(runs) and runs[r + 1][0] <= start:
+            r += 1
+        if runs and runs[r][0] <= start < runs[r][1]:
+            kind = runs[r][2] if whole[r] else None
+        else:
+            kind = NO_PROGRAM if lo <= start and end <= hi else None
+        if stack:               # the parent does not count what this covers
+            stack[-1][1] -= min(end, stack[-1][0]) - start
+        stack.append([end, -neg_dur, kind, meta])
+    while stack:
+        close(stack.pop())
+    return {k: v for k, v in out.items() if v["runs"] or v["device_s"]}
+
+
+def format_parts(table: Dict[str, dict], limit: int = 6) -> str:
+    """:func:`part_seconds` as text: a block a program kind, a row a
+    part in ms a run, then the largest ops without a part."""
+    blocks = []
+    for kind, row in sorted(table.items(), key=lambda kv: -kv[1]["device_s"]):
+        n = max(row["runs"], 1)
+        dev = row["device_s"] or 1.0
+        head = (f"{kind}: {row['runs']} runs, {1e3 * row['device_s'] / n:.3f}"
+                f" device ms a run ({1e3 * row['span_s'] / n:.3f} from start "
+                f"to end), Mosaic kernels "
+                f"{100 * row['kernel_s'] / dev:.1f} %")
+        lines = [head, f"  {'part':<12} {'ms a run':>10} {'%':>6} "
+                       f"{'backward ms':>12} {'kernel ms':>10}"]
+        for part, (fwd, bwd) in sorted(row["parts"].items(),
+                                       key=lambda kv: -sum(kv[1])):
+            lines.append(
+                f"  {part:<12} {1e3 * (fwd + bwd) / n:>10.3f} "
+                f"{100 * (fwd + bwd) / dev:>6.1f} {1e3 * bwd / n:>12.3f} "
+                f"{1e3 * row['kernels'].get(part, 0.0) / n:>10.3f}")
+        un = sum(row["unscoped"].values())
+        lines.append(f"  {UNSCOPED:<12} {1e3 * un / n:>10.3f} "
+                     f"{100 * un / dev:>6.1f}")
+        for label, sec in sorted(row["unscoped"].items(),
+                                 key=lambda kv: -kv[1])[:limit]:
+            lines.append(f"    {1e3 * sec / n:>10.3f}  {label}")
+        blocks.append("\n".join(lines))
+    return "\n\n".join(blocks)
+
+
+def parts_report(path: str, lo_ns=None, hi_ns=None) -> Dict[str, dict]:
+    """:func:`part_seconds` of the first device plane of one
+    ``.xplane.pb`` that ran an op (only that plane's two lines are
+    decoded), or {}."""
+    for plane in parse_xspace(path, is_device_plane,
+                              {OPS_LINE, MODULES_LINE}):
+        if any(line.name == OPS_LINE and line.events
+               for line in plane.lines):
+            return part_seconds(plane, lo_ns, hi_ns)
+    return {}
 
 
 # Residual-attribution buckets for the MoE training step (the r5 profile

@@ -119,6 +119,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.profiler.parts import part
 from paddle_tpu.ops.fused_decode import (mp_gather_kv_lastdim,
                                          mp_local_kv_lastdim)
 from paddle_tpu.serving.pool import (SCRATCH_BLOCK, BlockPool,
@@ -1558,21 +1559,27 @@ class ServingEngine:
             self._kv_scales, num_kv_heads=self.meta["num_kv_heads"],
             head_dim=self.meta["head_dim"])
 
-    def _wrap_program(self, impl, in_specs, out_specs, donate_argnums=()):
+    def _wrap_program(self, kind, impl, in_specs, out_specs,
+                      donate_argnums=()):
         """The ONE shard seam (ISSUE 17): every engine program routes
-        through here. mesh=None → plain ``jax.jit`` — the exact pre-mp
-        program. With a mesh, the impl runs under full-manual
+        through here and takes its NAME here: ``serving_<kind>``, the
+        kind its ``lowered_programs`` key starts with, so a run on a
+        trace's ``XLA Modules`` line reads ``jit_serving_step``,
+        ``jit_serving_prefill``, ... (one name a kind, not a bucket:
+        the shapes are on the events). mesh=None → plain ``jax.jit`` —
+        the exact pre-mp program. With a mesh, the impl runs under
+        full-manual
         ``jax.shard_map``: per-head math is local, the o-proj/logits
         boundary gathers (inside fused_decode), and sampling runs
         replicated on every device so per-slot ``fold_in`` RNG streams
         survive verbatim. check_vma=False is REQUIRED: the replication
         checker cannot infer that all_gather outputs under replicated
         out_specs are in fact replicated."""
-        if self.mesh is None:
-            return jax.jit(impl, donate_argnums=donate_argnums)
-        sm = jax.shard_map(impl, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-        return jax.jit(sm, donate_argnums=donate_argnums)
+        if self.mesh is not None:
+            impl = jax.shard_map(impl, mesh=self.mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)
+        impl.__name__ = impl.__qualname__ = "serving_" + kind
+        return jax.jit(impl, donate_argnums=donate_argnums)
 
     def _gather_stacked(self, stacked):
         """fsdp gather-at-use: stacked leaves arrive sharded on the
@@ -2140,72 +2147,78 @@ class ServingEngine:
             # keep only the shard's own lanes.
             cache = model.init_cache(n, cache_len, dtype=jnp.bfloat16)
             if R:
-                if int8:
-                    pk = prefix
-                else:
-                    pk = pool[:, prefix].reshape(
-                        len(cache), n, R, pool.shape[-1])
-                    if mp_axis is not None:
-                        pk = mp_gather_kv_lastdim(pk, mp_axis)
-                cache = from_lanes(cache, pk)
-            with jax.named_scope("decode.prefill"):
-                # an own plan's model computes the head at each row's
-                # last position only, and counts its routed rows if the
-                # plan says so
-                out, cache, *moe_rows = functional_call(
-                    model, state, ids, cache=cache, start_pos=R,
-                    **({"positions": last_idx} if own else {}),
-                    **({"moe_rows": True} if counted else {}))
-            kv_flat = to_lanes(cache)            # (L, n, cache_len, lanes)
-            if hybrid:
-                # a hybrid plan: the second paged leaf lands in the same
-                # fresh blocks, the rows' fixed-size state in their slots
-                slot_state = {
-                    name: pool["state"][name].at[:, slots].set(
-                        leaf.astype(pool["state"][name].dtype))
-                    for name, leaf in to_state(cache).items()}
-                pool = pool["pool"]
-                if aux is not None:
-                    (kv_flat, aux_flat), (pool, aux_pool) = kv_flat, pool
-                    aux_pool = aux_pool.at[:, new_bids].set(aux_flat.reshape(
-                        -1, n, nb_new, BT // aux["stride"],
-                        aux["lanes"]).astype(aux_pool.dtype))
-            logits = out if own else jnp.take_along_axis(
-                out, last_idx[:, None, None], axis=1)[:, 0]   # (n, vocab)
-            keys = _row_keys(seeds)
-            with jax.named_scope("decode.sample"):
-                tok = _sample_logits(logits, _fold_rows(keys, 0),
+                with part("attn"):      # the shared prefix, read back
+                    if int8:
+                        pk = prefix
+                    else:
+                        pk = pool[:, prefix].reshape(
+                            len(cache), n, R, pool.shape[-1])
+                        if mp_axis is not None:
+                            pk = mp_gather_kv_lastdim(pk, mp_axis)
+                    cache = from_lanes(cache, pk)
+            # an own plan's model computes the head at each row's last
+            # position only, and counts its routed rows if the plan
+            # says so; the model's forward names its own parts
+            out, cache, *moe_rows = functional_call(
+                model, state, ids, cache=cache, start_pos=R,
+                **({"positions": last_idx} if own else {}),
+                **({"moe_rows": True} if counted else {}))
+            with part("head"):
+                logits = out if own else jnp.take_along_axis(
+                    out, last_idx[:, None, None], axis=1)[:, 0]  # (n, vocab)
+            with part("sample"):
+                tok = _sample_logits(logits, _fold_rows(_row_keys(seeds), 0),
                                      self.temperature, self.top_k,
                                      self.top_p)
-            if counted:
-                # the count rides the wave's one pull behind its tokens
-                tok = jnp.concatenate([tok, moe_rows[0][None].astype(
-                    tok.dtype)])
-            if int8:
-                # per-request calibration: amax over each row's VALID
-                # prompt positions only — the padded tail holds
-                # pad-token kv, which must not leak into the scales
-                # (matches quantize_kv_cache over a contiguous cache)
-                mask = (jnp.arange(cache_len)[None]
-                        < valid_len[:, None])[None, :, :, None]
-                a = jnp.where(mask, jnp.abs(kv_flat.astype(jnp.float32)),
-                              0.0).max(axis=2)              # (L, n, 2dkv)
-                a = a.reshape(-1, n, 2 * nkv, hd).max(axis=-1)
-                lanes = jnp.repeat(jnp.maximum(a / 127.0, 1e-8), hd,
-                                   axis=-1)                 # (L, n, 2dkv)
-                q = jnp.clip(jnp.round(
-                    kv_flat.astype(jnp.float32) / lanes[:, :, None, :]),
-                    -127, 127).astype(jnp.int8)
-                blkq = q.reshape(-1, n, n0, BT, 2 * dkv)
+                if counted:
+                    # the count rides the wave's one pull behind its
+                    # tokens
+                    tok = jnp.concatenate([tok, moe_rows[0][None].astype(
+                        tok.dtype)])
+            with part("attn"):          # the cache write
+                kv_flat = to_lanes(cache)        # (L, n, cache_len, lanes)
+                if hybrid:
+                    # a hybrid plan: the second paged leaf lands in the
+                    # same fresh blocks, the rows' fixed-size state in
+                    # their slots
+                    slot_state = {
+                        name: pool["state"][name].at[:, slots].set(
+                            leaf.astype(pool["state"][name].dtype))
+                        for name, leaf in to_state(cache).items()}
+                    pool = pool["pool"]
+                    if aux is not None:
+                        (kv_flat, aux_flat), (pool, aux_pool) = kv_flat, pool
+                        aux_pool = aux_pool.at[:, new_bids].set(
+                            aux_flat.reshape(
+                                -1, n, nb_new, BT // aux["stride"],
+                                aux["lanes"]).astype(aux_pool.dtype))
+                if int8:
+                    # per-request calibration: amax over each row's
+                    # VALID prompt positions only — the padded tail
+                    # holds pad-token kv, which must not leak into the
+                    # scales (matches quantize_kv_cache over a
+                    # contiguous cache)
+                    mask = (jnp.arange(cache_len)[None]
+                            < valid_len[:, None])[None, :, :, None]
+                    a = jnp.where(mask,
+                                  jnp.abs(kv_flat.astype(jnp.float32)),
+                                  0.0).max(axis=2)          # (L, n, 2dkv)
+                    a = a.reshape(-1, n, 2 * nkv, hd).max(axis=-1)
+                    lanes = jnp.repeat(jnp.maximum(a / 127.0, 1e-8), hd,
+                                       axis=-1)             # (L, n, 2dkv)
+                    q = jnp.clip(jnp.round(
+                        kv_flat.astype(jnp.float32)
+                        / lanes[:, :, None, :]), -127, 127).astype(jnp.int8)
+                    blkq = q.reshape(-1, n, n0, BT, 2 * dkv)
+                    if mp_axis is not None:
+                        blkq = mp_local_kv_lastdim(blkq, mp_axis)
+                    pool = pool.at[:, new_bids].set(blkq)
+                    return tok, pool, lanes, kv_flat
+                blk = kv_flat[:, :, R:cache_len].reshape(
+                    -1, n, nb_new, BT, lanes_w)
                 if mp_axis is not None:
-                    blkq = mp_local_kv_lastdim(blkq, mp_axis)
-                pool = pool.at[:, new_bids].set(blkq)
-                return tok, pool, lanes, kv_flat
-            blk = kv_flat[:, :, R:cache_len].reshape(
-                -1, n, nb_new, BT, lanes_w)
-            if mp_axis is not None:
-                blk = mp_local_kv_lastdim(blk, mp_axis)
-            pool = pool.at[:, new_bids].set(blk.astype(pool.dtype))
+                    blk = mp_local_kv_lastdim(blk, mp_axis)
+                pool = pool.at[:, new_bids].set(blk.astype(pool.dtype))
             if hybrid:
                 pool = {"pool": pool if aux is None else (pool, aux_pool),
                         "state": slot_state}
@@ -2218,7 +2231,7 @@ class ServingEngine:
         pspec = lay.pool_spec() if lay is not None else None
         in_specs = (P(), pspec) + (P(),) * (7 if hybrid else 6)
         out_specs = ((P(), pspec, P(), P()) if int8 else (P(), pspec))
-        jitted = self._wrap_program(impl, in_specs, out_specs,
+        jitted = self._wrap_program("prefill", impl, in_specs, out_specs,
                                     donate_argnums=(1,))
         fn = _program_handle(jitted, lambda: (self._state,))
         self._jit_cache[key] = fn
@@ -2457,6 +2470,28 @@ class ServingEngine:
             cache = model.init_cache(n, cache_len, dtype=jnp.bfloat16)
             pk = None
             if start:
+                cache, pk = read_prefix(cache, pool, carry, bids, prefix)
+            out, cache = functional_call(
+                model, state, jax.lax.slice_in_dim(
+                    ids, start, cache_len, axis=1),
+                cache=cache, start_pos=start)
+            tok = None
+            if last:
+                with part("head"):
+                    logits = jnp.take_along_axis(
+                        out, last_idx[:, None, None], axis=1)[:, 0]
+                with part("sample"):
+                    tok = _sample_logits(logits,
+                                         _fold_rows(_row_keys(cseeds), 0),
+                                         temperature, top_k, top_p)
+            with part("attn"):          # the cache write
+                chunk_bids, chunk_kv, carry2, lanes, kvfull = write_chunk(
+                    cache, bids, valid, carry, pk)
+            return chunk_bids, chunk_kv, carry2, tok, lanes, kvfull
+
+        def read_prefix(cache, pool, carry, bids, prefix):
+            """The processed prefix back into the forward's cache."""
+            with part("attn"):
                 if not int8:
                     # bf16: every completed chunk already scattered its
                     # blocks into the pool, so the processed prefix
@@ -2485,24 +2520,17 @@ class ServingEngine:
                             kl.astype(cache[l]["k"].dtype)),
                         "v": cache[l]["v"].at[:, :start].set(
                             vl.astype(cache[l]["v"].dtype))}
-            with jax.named_scope("decode.prefill"):
-                out, cache = functional_call(
-                    model, state, jax.lax.slice_in_dim(
-                        ids, start, cache_len, axis=1),
-                    cache=cache, start_pos=start)
+            return cache, pk
+
+        def write_chunk(cache, bids, valid, carry, pk):
+            """The chunk's rows on their way to the pool -> (chunk_bids,
+            chunk_kv, carry2, lanes, kvfull)."""
             kv_flat = jnp.stack([jnp.concatenate(
                 [c["k"].reshape(n, cache_len, dkv),
                  c["v"].reshape(n, cache_len, dkv)], axis=-1)
                 for c in cache])             # (L, n, cache_len, 2dkv)
-            tok = lanes = kvfull = carry2 = None
+            lanes = kvfull = carry2 = None
             chunk_bids = chunk_kv = None
-            if last:
-                logits = jnp.take_along_axis(
-                    out, last_idx[:, None, None], axis=1)[:, 0]
-                with jax.named_scope("decode.sample"):
-                    tok = _sample_logits(logits,
-                                         _fold_rows(_row_keys(cseeds), 0),
-                                         temperature, top_k, top_p)
             if int8 and last:
                 # calibration over the original prompt positions only
                 # (resume appends beyond the prompt were quantized with
@@ -2543,7 +2571,7 @@ class ServingEngine:
                 else:               # RMW in place: donated + aliased
                     carry2 = jax.lax.dynamic_update_slice_in_dim(
                         carry, new_kv, start, axis=2)
-            return chunk_bids, chunk_kv, carry2, tok, lanes, kvfull
+            return chunk_bids, chunk_kv, carry2, lanes, kvfull
 
         return body
 
@@ -2656,7 +2684,7 @@ class ServingEngine:
                      if (int8 and last) else 0))
         out_specs = (*dec_specs, *([P()] * n_outs))
         jitted = self._wrap_program(
-            impl, in_specs, out_specs,
+            "tick", impl, in_specs, out_specs,
             donate_argnums=resident_carry_donate_argnums(*donate))
         fn = _program_handle(jitted,
                              lambda: (self._state, self._stacked))
@@ -3619,6 +3647,7 @@ class ServingEngine:
                 # the plan's step over the state's own leaves (no
                 # stacked copy, no scales: both arrive as None); `toks`
                 # carries the last program's counters behind its tokens
+                # embed, step and head name their own parts
                 plan_t = model.fused_decode_plan(state)
                 x = plan_t["embed"](toks[:ms], positions)
                 if isinstance(pool, dict):      # a hybrid plan's leaves
@@ -3628,14 +3657,16 @@ class ServingEngine:
                 else:
                     x, pool, tallies = plan_t["step"](x, pool, tables,
                                                       positions)
-                with jax.named_scope("decode.sample"):
+                logits = plan_t["head"](x)
+                with part("sample"):
                     keys = _row_keys(seeds)
                     ki = jax.vmap(jax.random.fold_in)(keys, counts)
-                    nxt = _sample_logits(plan_t["head"](x), ki, temperature,
-                                         top_k, top_p)
-                pos2 = jnp.minimum(positions + 1, pos_cap)
-                return (jnp.concatenate([nxt, tallies.astype(nxt.dtype)]),
-                        pool, pos2, counts + 1)
+                    nxt = _sample_logits(logits, ki, temperature, top_k,
+                                         top_p)
+                    pos2 = jnp.minimum(positions + 1, pos_cap)
+                    return (jnp.concatenate([nxt,
+                                             tallies.astype(nxt.dtype)]),
+                            pool, pos2, counts + 1)
 
             return own_body
         # under mp the body runs INSIDE shard_map: each shard walks its
@@ -3658,9 +3689,11 @@ class ServingEngine:
             blocks = plan_t.get("blocks")
             if int8 and blocks is not None:
                 blocks = dict(blocks, cache_wbytes=1)
+            # embed, the fused step and head name their own parts
             x = plan_t["embed"](toks, positions)
-            cos = jnp.take(cos_tab, positions, axis=0)
-            sin = jnp.take(sin_tab, positions, axis=0)
+            with part("attn_in"):
+                cos = jnp.take(cos_tab, positions, axis=0)
+                sin = jnp.take(sin_tab, positions, axis=0)
             x, pool = fused_paged_tick_step(
                 x, stacked, pool, tables, positions, cos, sin,
                 num_heads=nh_loc,
@@ -3669,21 +3702,22 @@ class ServingEngine:
                 kv_scales=kv_scales if int8 else None,
                 chunk_bids=chunk_bids, chunk_kv=chunk_kv,
                 mp_axis=mp_axis)
-            with jax.named_scope("decode.sample"):
+            logits = plan_t["head"](x)
+            with part("sample"):
                 keys = _row_keys(seeds)
                 ki = jax.vmap(jax.random.fold_in)(keys, counts)
-                nxt = _sample_logits(plan_t["head"](x), ki, temperature,
-                                     top_k, top_p)
-            # advance the per-slot state in-program so event-free steps
-            # re-dispatch with NO host->device uploads. A row whose table
-            # row is scratch (idle: released, or still prefilling) stays
-            # where the upload put it, at 0, so the kernel's walk
-            # (`ops.fused_decode.paged_walk`) has no pair for it; the
-            # clamp binds on no active row (its position is bounded by
-            # its admission-checked worst case)
-            pos2 = jnp.where(tables[:, 0] == SCRATCH_BLOCK, positions,
-                             jnp.minimum(positions + 1, pos_cap))
-            return nxt, pool, pos2, counts + 1
+                nxt = _sample_logits(logits, ki, temperature, top_k, top_p)
+                # advance the per-slot state in-program so event-free
+                # steps re-dispatch with NO host->device uploads. A row
+                # whose table row is scratch (idle: released, or still
+                # prefilling) stays where the upload put it, at 0, so
+                # the kernel's walk (`ops.fused_decode.paged_walk`) has
+                # no pair for it; the clamp binds on no active row (its
+                # position is bounded by its admission-checked worst
+                # case)
+                pos2 = jnp.where(tables[:, 0] == SCRATCH_BLOCK, positions,
+                                 jnp.minimum(positions + 1, pos_cap))
+                return nxt, pool, pos2, counts + 1
 
         return body
 
@@ -3706,7 +3740,7 @@ class ServingEngine:
                     P(), P(), P(), P(), P(),
                     lay.kv_scales_spec() if lay is not None else None)
         out_specs = (P(), pspec, P(), P())
-        jitted = self._wrap_program(impl, in_specs, out_specs,
+        jitted = self._wrap_program("step", impl, in_specs, out_specs,
                                     donate_argnums=(2,))
         return _program_handle(jitted,
                                lambda: (self._state, self._stacked))
@@ -3881,7 +3915,7 @@ class ServingEngine:
                     # (the replicated full-model forward); keep this
                     # shard's own [k_s|v_s] lanes before the scatter
                     chunk_kv = mp_local_kv_lastdim(chunk_kv, mp_axis)
-                with jax.named_scope("fused_decode.chunk_scatter"):
+                with part("attn"):
                     pool = paged_chunk_scatter(pool, chunk_bids, chunk_kv)
             plan_t = model.fused_decode_plan(state)
             blocks = plan_t.get("blocks")
@@ -3895,12 +3929,15 @@ class ServingEngine:
                 # past a retiring slot's cap — garbage rows)
                 pj = jnp.minimum(positions + j, pos_cap)
                 xs.append(plan_t["embed"](tail[:, j], pj))
-                coss.append(jnp.take(cos_tab, pj, axis=0))
-                sins.append(jnp.take(sin_tab, pj, axis=0))
-            x = jnp.stack(xs, axis=1)                     # (b, K1, h)
+                with part("attn_in"):
+                    coss.append(jnp.take(cos_tab, pj, axis=0))
+                    sins.append(jnp.take(sin_tab, pj, axis=0))
+            with part("embed"):
+                x = jnp.stack(xs, axis=1)                 # (b, K1, h)
+            with part("attn_in"):
+                cos, sin = jnp.stack(coss, axis=1), jnp.stack(sins, axis=1)
             x, pool = fused_paged_verify_step(
-                x, stacked, pool, tables, positions,
-                jnp.stack(coss, axis=1), jnp.stack(sins, axis=1),
+                x, stacked, pool, tables, positions, cos, sin,
                 num_heads=nh_loc,
                 num_kv_heads=nkv_loc, eps=meta["eps"],
                 rope_base=meta["rope_base"], arch=arch, blocks=blocks,
@@ -3908,38 +3945,40 @@ class ServingEngine:
             keys = _row_keys(seeds)
             gs = []
             for j in range(K1):
-                with jax.named_scope("decode.sample"):
+                logits = plan_t["head"](x[:, j])
+                with part("sample"):
                     # the exact key the non-speculative engine folds for
                     # token count+j — sample-and-match acceptance is
                     # what makes speculation bit-invisible
                     ki = jax.vmap(jax.random.fold_in)(keys, counts + j)
-                    gs.append(_sample_logits(plan_t["head"](x[:, j]), ki,
-                                             temperature, top_k, top_p))
-            g = jnp.stack(gs, axis=1)                     # (b, K1)
-            # per-slot proposal cap: the adaptive-k vector (full k when
-            # adaptivity is off — the clamp is then a no-op)
-            nprop_eff = jnp.minimum(jnp.minimum(nprop, cap), K)
-            match = (proposals == g[:, :K]) \
-                & (jnp.arange(K)[None] < nprop_eff[:, None])
-            acc = jnp.cumprod(match.astype(jnp.int32),
-                              axis=1).sum(axis=1)         # (b,)
-            tok2 = jnp.take_along_axis(g, acc[:, None], axis=1)[:, 0]
-            pos2 = jnp.minimum(positions + acc + 1, pos_cap)
-            counts2 = counts + acc + 1
-            if not ngram:
-                return g, acc, pool, pos2, tok2, counts2
-            # committed-token history: the tail lands at its absolute
-            # indices, then the corrected/bonus token at pos2 — writes
-            # past the accepted prefix are stale and sit beyond the
-            # committed length, exactly like rejected KV
-            rows = jnp.arange(tail.shape[0])
-            idxm = jnp.minimum(
-                positions[:, None] + jnp.arange(K1)[None], pos_cap)
-            hist2 = history.at[rows[:, None], idxm].set(tail)
-            hist2 = hist2.at[rows, pos2].set(tok2)
-            prop2, nprop2 = ngram_propose(hist2, pos2 + 1, K, nmax, nmin)
-            return (g, acc, pool, pos2, tok2, counts2, hist2, prop2,
-                    jnp.minimum(nprop2, cap))
+                    gs.append(_sample_logits(logits, ki, temperature, top_k,
+                                             top_p))
+            with part("sample"):
+                g = jnp.stack(gs, axis=1)                 # (b, K1)
+                # per-slot proposal cap: the adaptive-k vector (full k when
+                # adaptivity is off — the clamp is then a no-op)
+                nprop_eff = jnp.minimum(jnp.minimum(nprop, cap), K)
+                match = (proposals == g[:, :K]) \
+                    & (jnp.arange(K)[None] < nprop_eff[:, None])
+                acc = jnp.cumprod(match.astype(jnp.int32),
+                                  axis=1).sum(axis=1)         # (b,)
+                tok2 = jnp.take_along_axis(g, acc[:, None], axis=1)[:, 0]
+                pos2 = jnp.minimum(positions + acc + 1, pos_cap)
+                counts2 = counts + acc + 1
+                if not ngram:
+                    return g, acc, pool, pos2, tok2, counts2
+                # committed-token history: the tail lands at its absolute
+                # indices, then the corrected/bonus token at pos2 — writes
+                # past the accepted prefix are stale and sit beyond the
+                # committed length, exactly like rejected KV
+                rows = jnp.arange(tail.shape[0])
+                idxm = jnp.minimum(
+                    positions[:, None] + jnp.arange(K1)[None], pos_cap)
+                hist2 = history.at[rows[:, None], idxm].set(tail)
+                hist2 = hist2.at[rows, pos2].set(tok2)
+                prop2, nprop2 = ngram_propose(hist2, pos2 + 1, K, nmax, nmin)
+                return (g, acc, pool, pos2, tok2, counts2, hist2, prop2,
+                        jnp.minimum(nprop2, cap))
 
         return body
 
@@ -3980,7 +4019,7 @@ class ServingEngine:
         out_specs = [P()] * (9 if ngram else 6)
         out_specs[2] = pspec
         jitted = self._wrap_program(
-            impl, in_specs, tuple(out_specs),
+            "verify", impl, in_specs, tuple(out_specs),
             donate_argnums=(2,) + ((12,) if ngram else ()))
         return _program_handle(jitted,
                                lambda: (self._state, self._stacked))
@@ -4015,32 +4054,34 @@ class ServingEngine:
             def draft_step(carry, _):
                 tok, pool, pos = carry
                 x = plan_t["embed"](tok, pos)
-                cos = jnp.take(cos_tab, pos, axis=0)
-                sin = jnp.take(sin_tab, pos, axis=0)
+                with part("attn_in"):
+                    cos = jnp.take(cos_tab, pos, axis=0)
+                    sin = jnp.take(sin_tab, pos, axis=0)
                 x, pool = fused_paged_decode_step(
                     x, dstacked, pool, dtables, pos, cos, sin,
                     num_heads=dmeta["num_heads"],
                     num_kv_heads=dmeta["num_kv_heads"],
                     eps=dmeta["eps"], rope_base=dmeta["rope_base"],
                     arch=darch, blocks=blocks, kv_scales=None)
-                with jax.named_scope("decode.draft_sample"):
+                logits = plan_t["head"](x)
+                with part("sample"):
                     # greedy proposals: acceptance is exact-match
                     # against the target's sample, so the draft's best
                     # guess is its own argmax — no draft RNG stream
-                    nxt = _sample_logits(plan_t["head"](x), None,
-                                         0.0, 0, 1.0)
-                return (nxt, pool, jnp.minimum(pos + 1, pos_cap)), nxt
+                    nxt = _sample_logits(logits, None, 0.0, 0, 1.0)
+                    return (nxt, pool, jnp.minimum(pos + 1, pos_cap)), nxt
 
             (_, pool, _), props = jax.lax.scan(
                 draft_step, (toks, dpool, positions), None, length=K + 1)
-            return props[:K].T.astype(jnp.int32), pool
+            with part("sample"):
+                return props[:K].T.astype(jnp.int32), pool
 
         # the draft runs fully REPLICATED under mp (every spec is P());
         # the shard_map wrap still matters — it pins the draft's inputs
         # and outputs to the mesh so a speculative tick never mixes
         # mesh-committed and single-device buffers
         from jax.sharding import PartitionSpec as P
-        jitted = self._wrap_program(impl, (P(),) * 6, (P(), P()),
+        jitted = self._wrap_program("draft", impl, (P(),) * 6, (P(), P()),
                                     donate_argnums=(2,))
         return _program_handle(
             jitted, lambda: (self._draft_state, self._draft_stacked))
@@ -4066,19 +4107,19 @@ class ServingEngine:
 
         def impl(dstate, pool, ids, new_bids):
             cache = dm.init_cache(1, s_pad, dtype=jnp.bfloat16)
-            with jax.named_scope("decode.draft_prefill"):
-                _, cache = functional_call(dm, dstate, ids, cache=cache,
-                                           start_pos=0)
-            kv_flat = jnp.stack([jnp.concatenate(
-                [c["k"].reshape(1, s_pad, dkv),
-                 c["v"].reshape(1, s_pad, dkv)], axis=-1)
-                for c in cache])             # (Ld, 1, s_pad, 2dkv)
-            blk = kv_flat.reshape(Ld, 1, nb, BT, 2 * dkv)
-            return pool.at[:, new_bids].set(blk.astype(pool.dtype))
+            _, cache = functional_call(dm, dstate, ids, cache=cache,
+                                       start_pos=0)
+            with part("attn"):          # the cache write
+                kv_flat = jnp.stack([jnp.concatenate(
+                    [c["k"].reshape(1, s_pad, dkv),
+                     c["v"].reshape(1, s_pad, dkv)], axis=-1)
+                    for c in cache])         # (Ld, 1, s_pad, 2dkv)
+                blk = kv_flat.reshape(Ld, 1, nb, BT, 2 * dkv)
+                return pool.at[:, new_bids].set(blk.astype(pool.dtype))
 
         from jax.sharding import PartitionSpec as P
-        jitted = self._wrap_program(impl, (P(),) * 4, P(),
-                                    donate_argnums=(1,))
+        jitted = self._wrap_program("draft_prefill", impl, (P(),) * 4,
+                                    P(), donate_argnums=(1,))
         fn = _program_handle(jitted, lambda: (self._draft_state,))
         self._jit_cache[key] = fn
         return fn, False
