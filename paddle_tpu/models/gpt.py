@@ -85,11 +85,19 @@ class GPTAttention(nn.Layer):
                 v_cache = jax.lax.dynamic_update_slice_in_dim(
                     cache["v"], v.astype(cache["v"].dtype), start_pos,
                     axis=1)
-                q_pos = start_pos + jnp.arange(s)[:, None]
-                k_pos = jnp.arange(k_cache.shape[1])[None, :]
-                mask = (k_pos <= q_pos)[None, None]
-                out = F.scaled_dot_product_attention(
-                    q, k_cache, v_cache, attn_mask=mask, is_causal=False)
+                if (isinstance(start_pos, int)
+                        and start_pos + s == k_cache.shape[1]):
+                    # a prefill that fills its cache to the end: the fill
+                    # mask IS the bottom-right causal edge (llama.py)
+                    out = F.scaled_dot_product_attention(
+                        q, k_cache, v_cache, is_causal=True)
+                else:
+                    q_pos = start_pos + jnp.arange(s)[:, None]
+                    k_pos = jnp.arange(k_cache.shape[1])[None, :]
+                    mask = (k_pos <= q_pos)[None, None]
+                    out = F.scaled_dot_product_attention(
+                        q, k_cache, v_cache, attn_mask=mask,
+                        is_causal=False)
             with part("attn_out"):
                 out = self.out_proj(out.reshape(b, s, h))
             return out, {"k": k_cache, "v": v_cache}

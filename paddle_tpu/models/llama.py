@@ -172,6 +172,14 @@ class LlamaAttention(nn.Layer):
             v_cache = _jax.lax.dynamic_update_slice_in_dim(
                 cache["v"], v.astype(cache["v"].dtype), start_pos, axis=1)
             max_len = k_cache.shape[1]
+            if isinstance(start_pos, int) and start_pos + s == max_len:
+                # a prefill that fills its cache to the end (a serving
+                # wave): the fill mask IS the bottom-right causal edge,
+                # which the flash kernels walk without streaming a mask
+                out = F.scaled_dot_product_attention(
+                    q, k_cache, v_cache, is_causal=True,
+                    window_size=cfg.sliding_window)
+                return out, {"k": k_cache, "v": v_cache}
             q_pos = start_pos + jnp.arange(s)[:, None]          # (s, 1)
             k_pos = jnp.arange(max_len)[None, :]                 # (1, max)
             mask = (k_pos <= q_pos)[None, None]                  # causal+fill

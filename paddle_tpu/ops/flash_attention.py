@@ -5,10 +5,35 @@ library (paddle/phi/kernels/gpu/flash_attn_kernel.cu, cmake/external/
 flashattn.cmake; python veneer paddle.nn.functional.flash_attention).
 
 Layouts follow the reference: q/k/v are (batch, seq, num_heads, head_dim).
-GQA/MQA supported via num_kv_heads < num_heads. The Pallas path (blockwise
-online-softmax, fp32 accumulators, causal block skipping, LSE saved for the
-backward; dq and dk/dv backward kernels recompute probabilities per block so
-the (s, s) matrix is never materialized) covers, on TPU:
+GQA/MQA supported via num_kv_heads < num_heads. The Pallas path is a
+blockwise online softmax that never materializes the (s, s) matrix:
+
+* What reaches the matrix unit: q, k, v and the output's cotangent in
+  the dtype they were given (bf16 in training and serving, float32 for
+  float32 inputs), the softmax scale folded into q or k once a block,
+  and the probabilities ``p`` and ``ds`` rounded to that dtype before
+  the second products (`_xla_attention`'s own semantics). Every product
+  accumulates in float32; scores, the running maximum and sum, ``lse``,
+  ``delta`` and the dq / dk / dv accumulators are float32. (Mosaic's
+  default precision rounds a float32 operand to bf16 inside the matrix
+  unit: one pass either way, measured, PERF.md PR 37.)
+* A score block is (keys, queries): the softmax's maximum and sum run
+  down the sublanes, ``lse`` and ``delta`` travel as (b, h, sq) with the
+  positions on the lanes, and the backward's ``p @ do`` and ``ds @ q``
+  are plain products. The (queries, keys) form spent a quarter of the
+  forward on cross-lane reductions and transposed two score-sized
+  blocks a backward step.
+* Forward: key blocks for each block of queries; blocks behind the
+  causal edge are skipped, blocks every query sees whole take no mask,
+  and the selects that guard an empty row run only where kv_lens,
+  segments, a window, a dense mask or sk < sq can empty one. Blocks of
+  512, the keys and queries left over as one last shorter block.
+* Backward: one kernel a key block (`flash_attention_bwd_dkv_dq`) takes
+  every score block once for dq, dk and dv, with a float32 dq held in
+  VMEM over a (batch, head); where that does not fit (long context) a dq
+  kernel and a dk/dv kernel each recompute the scores (`_fused_bwd_fits`).
+
+It covers, on TPU (or under ``FLAGS_pallas_interpret`` elsewhere):
 
 * self-attention AND cross-attention (sq != sk, causal aligned bottom-right
   like the reference / flash-attn-2),
@@ -29,7 +54,7 @@ the (s, s) matrix is never materialized) covers, on TPU:
   dropout in-kernel the same way),
 
 forward and backward — the kernel-surface exclusion list is now EMPTY.
-Kernels compute internally in (b, h, s, d) so the trailing block dims
+Kernels take their operands as (b, h, s, d) so the trailing block dims
 meet TPU tiling (8, 128).
 """
 
@@ -45,7 +70,6 @@ import numpy as np
 from jax import lax
 
 NEG_INF = -1e30
-LANES = 128
 
 
 def _repeat_kv(k, n_rep):
@@ -168,7 +192,7 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
     backends agree; a TRACED mask (built inside jit) can't be inspected,
     so there the threshold convention above is on the caller.
     """
-    from paddle_tpu.ops import use_pallas
+    from paddle_tpu.ops import pallas_mode
     seg_q = segment_ids
     seg_k = kv_segment_ids if kv_segment_ids is not None else segment_ids
     if (seg_q is None) != (seg_k is None):
@@ -211,7 +235,7 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
     # on (seed, b, h, q-block, k-block), identical fwd/bwd masks).
     eff_dropout = float(dropout_p) if training else 0.0
     kmask = _kernel_mask(attn_mask, q.shape, k.shape)
-    pallas_ok = use_pallas() and (attn_mask is None or kmask is not None)
+    pallas_ok = pallas_mode()[0] and (attn_mask is None or kmask is not None)
     if (pallas_ok and kmask is not None
             and jnp.issubdtype(kmask.dtype, jnp.floating)
             and not isinstance(kmask, jax.core.Tracer)):
@@ -352,6 +376,32 @@ def _pad_for_kernel(q, k, v, is_causal, scale, kv_lens, seg_k):
 
 # ---- Pallas kernels (internal layout (b, h, s, d)) -------------------------
 
+_NT = (((1,), (1,)), ((), ()))      # a . b^T
+_NN = (((1,), (0,)), ((), ()))      # a . b
+_TN = (((0,), (0,)), ((), ()))      # a^T . b
+_VMEM_LIMIT = 64 << 20              # of a v5e's 128 MiB, for one kernel
+_FUSED_BWD_BUDGET = 40 << 20        # what the one-kernel backward may hold
+
+
+def _compiler_params(pltpu):
+    """Grids are (batch, head, block): the last axis revisits what a
+    (batch, head) holds in VMEM, so it runs in order."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _dot(a, b, dims):
+    """One product on the matrix unit: the operands in the dtype they
+    come in, float32 out. (In interpret mode the operands are widened
+    first, which changes no product: XLA's CPU runtime has no bf16 dot
+    for every layout a kernel's loop asks of it.)"""
+    from paddle_tpu.ops import pallas_mode
+    if pallas_mode()[1]:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
 def _pick_blk(s):
     """Largest block in (512, 256, 128) dividing s — lets the kernels
     cover any s % 128 == 0, not just 512-multiples."""
@@ -361,60 +411,122 @@ def _pick_blk(s):
     raise ValueError(f"seq {s} not a multiple of 128")
 
 
-def _causal_nk(qi, blk_q, blk_k, off, sk):
-    """Number of k-blocks a causal q-block attends to (bottom-right
-    aligned: q row i sees k cols <= i + off)."""
-    hi = qi * blk_q + blk_q - 1 + off          # last visible k col
-    return jnp.clip((hi // blk_k) + 1, 0, sk // blk_k)
+def _blocks(sq, sk, even):
+    """(queries, keys) of a block. Where ``even`` both divide their
+    sequence (the backward kernels, and the forward under dropout, whose
+    block ids all three kernels must cut alike). Else blocks of 512 with
+    what is left over as one last shorter block: a forward in blocks of
+    128 costs twice one in blocks of 512 (measured, PERF.md PR 37), and a
+    query block's columns are independent, so a last block that reaches
+    past ``sq`` computes columns nobody reads."""
+    if even:
+        return _pick_blk(sq), _pick_blk(sk)
+    return min(512, sq), min(512, sk)
 
 
-def _block_mask(s_blk, qi, ki, blk_q, blk_k, off, is_causal,
-                kvlen_b, segq_blk, segk_ref, window=None, alibi=None,
-                mask_at=None):
-    """Apply the structured masks to one (blk_q, blk_k) score block.
+class _Extras:
+    """Which optional operands a call has, in `_build_operands`' order,
+    and what follows from them for every kernel."""
 
-    kvlen_b: scalar valid length or None; segq_blk: (blk_q, 1) ids or
-    None; segk_ref: callable ki -> (1, blk_k) ids; window: static int
-    sliding-window width (causal: q row i sees the last `window` keys up
-    to i + off); alibi: this head's ALiBi slope (traced fp32 scalar) —
-    score += slope · (k_pos − q_pos − off), the standard ≤ 0 linear bias;
-    mask_at: callable ki -> (blk_q, blk_k) DENSE mask tile — bool (False
-    = masked) or additive float (the reference attn_mask semantics)."""
-    k_pos = ki * blk_k + lax.broadcasted_iota(
-        jnp.int32, (blk_q, blk_k), 1)
-    if is_causal or window is not None or alibi is not None:
-        q_pos = qi * blk_q + lax.broadcasted_iota(
-            jnp.int32, (blk_q, blk_k), 0)
+    def __init__(self, is_causal, off, kv_lens, seg_q, window, alibi_slopes,
+                 mask, dropout_p):
+        self.is_causal = is_causal
+        self.window = window
+        self.has_len = kv_lens is not None
+        self.has_seg = seg_q is not None
+        self.has_alibi = alibi_slopes is not None
+        self.has_mask = mask is not None
+        self.has_drop = dropout_p > 0.0
+        self.keep_p = 1.0 - dropout_p
+        # a mask besides the causal edge: every block walked is masked
+        self.structured = (self.has_len or self.has_seg or self.has_alibi
+                           or self.has_mask or window is not None)
+        # a query row may see no key at all: plain causal self-attention
+        # (and no mask) cannot, and skips the selects that guard it
+        self.can_empty = (self.has_len or self.has_seg or self.has_mask
+                          or window is not None or (is_causal and off < 0))
+
+    def unpack(self, refs):
+        """(lens, segq, segk, slopes, mask, mask_lo, mask_hi, seed) refs,
+        None where absent, and the refs after them."""
+        it = iter(refs)
+        take = lambda have, n=1: [next(it) if have else None
+                                  for _ in range(n)]
+        out = (take(self.has_len) + take(self.has_seg, 2)
+               + take(self.has_alibi) + take(self.has_mask, 3)
+               + take(self.has_drop))
+        return out, list(it)
+
+    def specs(self, pl, pltpu, mask, blk, sq, sk, by_q):
+        """Their BlockSpecs, for a grid whose third axis walks blocks of
+        ``blk`` queries (``by_q``) or keys: the walked side's segment
+        ids and mask band by block, the other side's whole."""
+        smem = lambda: pl.BlockSpec(memory_space=pltpu.SMEM)
+        seg = lambda blocked, full: pl.BlockSpec(
+            (None, 1, blk if blocked else full),
+            (lambda bi, hi, i: (bi, 0, i)) if blocked
+            else (lambda bi, hi, i: (bi, 0, 0)))
+        out = []
+        if self.has_len:
+            out.append(smem())
+        if self.has_seg:
+            out += [seg(by_q, sq), seg(not by_q, sk)]
+        if self.has_alibi:
+            out.append(smem())
+        if self.has_mask:
+            out += _mask_specs(pl, pltpu, mask, blk, sk if by_q else sq,
+                               by_q)
+        if self.has_drop:
+            out.append(smem())
+        return out
+
+
+def _block_mask(s_blk, rel, k0, ex, kvlen_b, segq_blk, segk_blk, alibi,
+                mask_blk):
+    """Apply the masks to one (keys, queries) score block whose first
+    query sees up to key ``k0 + rel`` (bottom-right aligned causal: entry
+    (r, c), key r against query c, is live where r - c <= rel).
+
+    kvlen_b: scalar valid length or None; segq_blk: (1, queries) ids and
+    segk_blk: (keys, 1) ids, or None; ``ex.window``: static sliding-window
+    width; alibi: this head's ALiBi slope (traced fp32 scalar) — score +=
+    slope · (k_pos − q_pos − off), the standard ≤ 0 linear bias;
+    mask_blk: the (keys, queries) tile of a DENSE mask — bool as int8 (0 =
+    masked) or additive float (the reference attn_mask semantics)."""
+    shape = s_blk.shape
+    if ex.is_causal or ex.window is not None or alibi is not None:
+        ahead = (lax.broadcasted_iota(jnp.int32, shape, 0)
+                 - lax.broadcasted_iota(jnp.int32, shape, 1))   # r - c
     if alibi is not None:
-        s_blk = s_blk + alibi * (k_pos - q_pos - off).astype(jnp.float32)
-    if is_causal:
-        s_blk = jnp.where(q_pos + off >= k_pos, s_blk, NEG_INF)
-    if window is not None:
-        s_blk = jnp.where(q_pos + off - k_pos < window, s_blk, NEG_INF)
+        s_blk = s_blk + alibi * (ahead - rel).astype(jnp.float32)
+    if ex.is_causal:
+        s_blk = jnp.where(ahead <= rel, s_blk, NEG_INF)
+    if ex.window is not None:
+        s_blk = jnp.where(ahead > rel - ex.window, s_blk, NEG_INF)
     if kvlen_b is not None:
-        s_blk = jnp.where(k_pos < kvlen_b, s_blk, NEG_INF)
+        row = lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0)
+        s_blk = jnp.where(row < kvlen_b - k0, s_blk, NEG_INF)
     if segq_blk is not None:
-        s_blk = jnp.where(segq_blk == segk_ref(ki), s_blk, NEG_INF)
-    if mask_at is not None:
-        mb = mask_at(ki)
-        if mb.dtype in (jnp.bool_, jnp.int8):   # bool masks ride as int8
-            s_blk = jnp.where(mb != 0, s_blk, NEG_INF)
+        s_blk = jnp.where(segk_blk == segq_blk, s_blk, NEG_INF)
+    if mask_blk is not None:
+        if mask_blk.dtype in (jnp.bool_, jnp.int8):   # bool rides as int8
+            s_blk = jnp.where(mask_blk != 0, s_blk, NEG_INF)
         else:
-            s_blk = s_blk + mb.astype(jnp.float32)
+            s_blk = s_blk + mask_blk.astype(jnp.float32)
     return s_blk
 
 
-def _dropout_keep(pltpu, seed_ref, block_id, blk_q, blk_k, keep_p):
-    """Counter-based in-kernel dropout mask for one (qi, ki) score block
+def _dropout_keep(pltpu, seed_ref, block_id, blk_k, blk_q, keep_p):
+    """Counter-based in-kernel dropout mask for one (keys, queries) block
     (the vendored flash-attn-2 does dropout in-kernel the same way —
     canonical phi/kernels/gpu/flash_attn_kernel.cu). Reseeding the Mosaic
     PRNG on (seed, block_id) — block_id folds (b, h, q-block, k-block)
     into one int32, Mosaic's prng_seed takes at most two values — makes
-    the mask a pure function of the block coordinates, so the dq (loops
-    ki per qi) and dk/dv (loops qi per ki) backward kernels regenerate
+    the mask a pure function of the block coordinates, so the backward
+    kernels (which walk q-blocks per k-block, or the reverse) regenerate
     the exact forward mask regardless of their iteration order."""
     pltpu.prng_seed(seed_ref[0], block_id)
-    bits = pltpu.bitcast(pltpu.prng_random_bits((blk_q, blk_k)),
+    bits = pltpu.bitcast(pltpu.prng_random_bits((blk_k, blk_q)),
                          jnp.uint32)
     return bits < jnp.uint32(min(int(keep_p * 4294967296.0), 4294967295))
 
@@ -448,28 +560,6 @@ def _mask_block_bounds(mask, b, h, nq, nk, blk_q, blk_k, axis_q=True):
     return (jnp.broadcast_to(lo, tgt), jnp.broadcast_to(hi, tgt))
 
 
-def _window_k0(qi, blk_q, blk_k, off, window):
-    """First k-block a sliding-window q-block can see (block skipping):
-    q row q_pos attends k in (q_pos + off − window, q_pos + off]."""
-    lo = qi * blk_q + off - window + 1          # first visible k col
-    return jnp.clip(lo // blk_k, 0, None)
-
-
-def _seg_specs():
-    """Builder for (b, 1, s) segment-id BlockSpecs: spec(blk, full) blocks
-    the axis by `blk` indexed by the grid's third dim, or takes the whole
-    `full` axis when blk is None."""
-    from jax.experimental import pallas as pl
-
-    def spec(blk, full):
-        if blk is None:
-            return pl.BlockSpec((None, 1, full),
-                                lambda bi, hi, i: (bi, 0, 0))
-        return pl.BlockSpec((None, 1, blk), lambda bi, hi, i: (bi, 0, i))
-
-    return spec
-
-
 def _build_operands(qt, kt, vt, kv_lens, seg_q, seg_k, extra,
                     alibi_slopes=None, mask=None, bounds=None, seed=None):
     """Shared operand assembly: [q, k, v, (lens), (segq, segk), (alibi),
@@ -483,30 +573,95 @@ def _build_operands(qt, kt, vt, kv_lens, seg_q, seg_k, extra,
     if alibi_slopes is not None:
         ops.append(alibi_slopes.astype(jnp.float32))   # (h,)
     if mask is not None:
-        ops.append(mask)                               # (mb, mh, sq, sk)
+        ops.append(jnp.swapaxes(mask, 2, 3))           # (mb, mh, sk, sq)
         ops.extend(bounds)                             # lo, hi (b, h, n)
     if seed is not None:
         ops.append(seed)            # (4,) int32, _drop_block_id
     return ops + extra
 
 
-def _mask_specs(pl, pltpu, mask, blk_row, full_col, row_axis_q=True):
-    """BlockSpecs for [mask-tile, lo, hi]: the mask streams one
-    (blk_q, sk) row band (or (sq, blk_k) column band for the dkv kernel)
-    per grid step, broadcast dims pinned by index-map clamping; the lo/hi
-    skip bounds ride SMEM whole."""
-    mb, mh = mask.shape[0], mask.shape[1]
+def _mask_specs(pl, pltpu, mask_t, blk, full, by_q):
+    """BlockSpecs for [mask-tile, lo, hi]. The mask comes TRANSPOSED,
+    (mb, mh, sk, sq), keys by queries like a score block, and streams one
+    (sk, blk_q) band of query columns (or one (blk_k, sq) band of keys
+    for the dk/dv walk) per grid step, broadcast dims pinned by index-map
+    clamping; the lo/hi skip bounds ride SMEM whole."""
+    mb, mh = mask_t.shape[0], mask_t.shape[1]
 
     def imap(bi, hi, i):
         bm = jnp.minimum(bi, mb - 1)
         hm = jnp.minimum(hi, mh - 1)
-        return (bm, hm, i, 0) if row_axis_q else (bm, hm, 0, i)
+        return (bm, hm, 0, i) if by_q else (bm, hm, i, 0)
 
-    shape = ((None, None, blk_row, full_col) if row_axis_q
-             else (None, None, full_col, blk_row))
+    shape = ((None, None, full, blk) if by_q else (None, None, blk, full))
     return [pl.BlockSpec(shape, imap),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM)]
+
+
+def _scaled(x, sc):
+    """``x * sc`` in ``x``'s dtype (the product taken in float32): the
+    softmax scale folded into one operand of the score product, once a
+    block, instead of into every score."""
+    return (x.astype(jnp.float32) * sc).astype(x.dtype)
+
+
+def _column(row):
+    """(1, n) -> (n, 1): the keys' segment ids, stored with the
+    positions on the lanes, as the column a score block's rows take."""
+    return jnp.transpose(row, (1, 0))
+
+
+def _lo(a, b):
+    """min of two loop bounds; Python's where both are static (a
+    ``jnp`` constant would be captured by the kernel)."""
+    both = isinstance(a, int) and isinstance(b, int)
+    return min(a, b) if both else jnp.minimum(a, b)
+
+
+def _hi(a, b):
+    both = isinstance(a, int) and isinstance(b, int)
+    return max(a, b) if both else jnp.maximum(a, b)
+
+
+def _walk_keys(step, ex, qi, blk_q, blk_k, sk, off, kvlen_b, mlo, mhi,
+               carry, split=True):
+    """Run ``step(masked, width)(ki, carry)`` over the key blocks one
+    block of queries sees, counted in blocks of ``blk_k`` keys: first
+    those live for every query whole without a mask (only plain causal,
+    or no mask at all, has any), then the masked ones, then the keys left
+    over as a last shorter block (a loop of no or one round). Without
+    ``split`` one loop masks every block: a second copy of the backward's
+    body costs more than the masks it saves (measured at s 1024, PERF.md
+    PR 37)."""
+    whole, tail = divmod(sk, blk_k)
+    nkb = whole + bool(tail)
+    q0 = qi * blk_q
+    start, stop = 0, nkb
+    if ex.is_causal:   # query i sees keys <= i + off
+        stop = jnp.clip((q0 + blk_q - 1 + off) // blk_k + 1, 0, nkb)
+    if ex.has_len:     # skip k-blocks entirely past the valid length
+        stop = _lo(stop, (kvlen_b + blk_k - 1) // blk_k)
+    if ex.window is not None:
+        # query q_pos attends k in (q_pos + off − window, q_pos + off]
+        start = jnp.clip((q0 + off - ex.window + 1) // blk_k, 0, None)
+    if ex.has_mask:    # all-masked prefix/suffix block skipping
+        start, stop = _hi(start, mlo), _lo(stop, mhi)
+    masks = ex.is_causal or ex.structured
+    if not ex.structured and (split or not masks):
+        full = stop
+        if ex.is_causal:
+            full = jnp.clip((q0 + off + 1) // blk_k, 0, stop)
+        full = _lo(full, whole)
+        carry = lax.fori_loop(start, full, step(False, blk_k), carry)
+        start = full
+    if masks:
+        carry = lax.fori_loop(start, _lo(stop, whole), step(True, blk_k),
+                              carry)
+    if tail:
+        carry = lax.fori_loop(_hi(start, whole), stop, step(masks, tail),
+                              carry)
+    return carry
 
 
 def _fwd_kernels(qt, kt, vt, is_causal, sc, kv_lens=None, seg_q=None,
@@ -514,404 +669,348 @@ def _fwd_kernels(qt, kt, vt, is_causal, sc, kv_lens=None, seg_q=None,
                  dropout_p=0.0, seed=None):
     """qt (b,h,sq,d), kt/vt (b,h,sk,d) → (out (b,h,sq,d), lse (b,h,sq)).
 
+    A score block is (keys, queries): the softmax's maximum and sum run
+    down the sublanes, element by element, where a (queries, keys) block
+    needs a reduction across the 128 lanes of every row (measured: a
+    quarter of the forward, PERF.md PR 37), and a query's statistics sit
+    on the lanes, as ``lse`` is stored. q (with the scale folded in), k
+    and v reach the matrix unit in the dtype they come in; scores,
+    maximum, sum and the (d, queries) accumulator are float32; ``p`` is
+    rounded to v's dtype before ``v^T @ p`` (what `_xla_attention` does).
     mask: dense (mb, mh, sq, sk) bool/float attn_mask (broadcast dims
-    allowed) streamed as (blk_q, sk) row bands, with all-masked prefix/
-    suffix k-blocks skipped. dropout_p/seed: in-kernel counter-based
-    attention dropout (see _dropout_keep) — probabilities drop AFTER the
-    softmax statistics accumulate, matching standard dropout(softmax(s))
-    semantics; the output folds the 1/keep rescale into the final
-    normalization."""
+    allowed) streamed as (sk, blk_q) bands of its transpose, with
+    all-masked prefix/suffix k-blocks skipped. dropout_p/seed: in-kernel
+    counter-based attention dropout (see _dropout_keep) — probabilities
+    drop AFTER the softmax statistics accumulate, matching standard
+    dropout(softmax(s)) semantics; the output folds the 1/keep rescale
+    into the final normalization."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import pallas_mode
 
     b, h, sq, d = qt.shape
     sk = kt.shape[2]
-    blk_q = _pick_blk(sq)
-    blk_k = _pick_blk(sk)
     off = sk - sq
-    grid = (b, h, sq // blk_q)
-    has_len = kv_lens is not None
-    has_seg = seg_q is not None
-    has_alibi = alibi_slopes is not None
-    has_mask = mask is not None
-    has_drop = dropout_p > 0.0
-    keep_p = 1.0 - dropout_p
-    bounds = (_mask_block_bounds(mask, b, h, sq // blk_q, sk // blk_k,
-                                 blk_q, blk_k) if has_mask else None)
+    ex = _Extras(is_causal, off, kv_lens, seg_q, window, alibi_slopes, mask,
+                 dropout_p)
+    blk_q, blk_k = _blocks(sq, sk, even=ex.has_drop)
+    nq, nkb = -(-sq // blk_q), -(-sk // blk_k)
+    bounds = None
+    if mask is not None:
+        # whole blocks for the bounds and the bands; the pad is read for
+        # queries past sq alone, whose columns nobody reads
+        mask = jnp.pad(mask, ((0, 0), (0, 0), (0, nq * blk_q - sq),
+                              (0, nkb * blk_k - sk)))
+        bounds = _mask_block_bounds(mask, b, h, nq, nkb, blk_q, blk_k)
 
-    def kernel(*refs):
-        i = 3
-        lens_ref = refs[i] if has_len else None
-        i += has_len
-        segq_ref = refs[i] if has_seg else None
-        segk_ref = refs[i + 1] if has_seg else None
-        i += 2 * has_seg
-        slopes_ref = refs[i] if has_alibi else None
-        i += has_alibi
-        mask_ref = refs[i] if has_mask else None
-        mlo_ref = refs[i + 1] if has_mask else None
-        mhi_ref = refs[i + 2] if has_mask else None
-        i += 3 * has_mask
-        seed_ref = refs[i] if has_drop else None
-        i += has_drop
-        q_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
-        o_ref, lse_ref = refs[i], refs[i + 1]
+    def kernel(q_ref, k_ref, v_ref, *refs):
+        (lens_ref, segq_ref, segk_ref, slopes_ref, mask_ref, mlo_ref,
+         mhi_ref, seed_ref), (o_ref, lse_ref) = ex.unpack(refs)
+        bi, hi_, qi = (pl.program_id(i) for i in range(3))
+        q = _scaled(q_ref[...], sc)                    # (blk_q, d)
+        kvlen_b = lens_ref[bi] if ex.has_len else None
+        alibi = slopes_ref[hi_] if ex.has_alibi else None
+        segq_blk = segq_ref[...] if ex.has_seg else None     # (1, blk_q)
 
-        bi = pl.program_id(0)
-        hi_ = pl.program_id(1)
-        qi = pl.program_id(2)
-        qv = q_ref[...].astype(jnp.float32) * sc  # (blk_q, d)
-        kvlen_b = lens_ref[bi] if has_len else None
-        alibi = slopes_ref[hi_] if has_alibi else None
-        segq_blk = (jnp.transpose(segq_ref[...], (1, 0))
-                    if has_seg else None)          # (blk_q, 1)
-        seg_at = (lambda ki: segk_ref[:, pl.ds(ki * blk_k, blk_k)]) \
-            if has_seg else None
-        mask_at = (lambda ki: mask_ref[:, pl.ds(ki * blk_k, blk_k)]) \
-            if has_mask else None
+        def step(masked, width):
+            def body(ki, carry):
+                acc, m_prev, l_prev = carry
+                k0 = ki * blk_k
+                ks = pl.ds(pl.multiple_of(k0, 128), width)
+                s_blk = _dot(k_ref[ks, :], q, _NT)     # (width, blk_q)
+                if masked:
+                    s_blk = _block_mask(
+                        s_blk, qi * blk_q + off - k0, k0, ex, kvlen_b,
+                        segq_blk,
+                        _column(segk_ref[:, ks]) if ex.has_seg else None,
+                        alibi, mask_ref[ks, :] if ex.has_mask else None)
+                m_cur = jnp.maximum(
+                    m_prev, jnp.max(s_blk, axis=0, keepdims=True))
+                alpha = jnp.exp(m_prev - m_cur)
+                p = jnp.exp(s_blk - m_cur)
+                if ex.can_empty:
+                    # queries with no valid key yet keep m at NEG_INF —
+                    # their p must be 0, not exp(0), so fully-masked
+                    # rows emit 0
+                    p = jnp.where(m_cur <= NEG_INF * 0.5, 0.0, p)
+                l_cur = l_prev * alpha + jnp.sum(p, axis=0, keepdims=True)
+                if ex.has_drop:   # l accumulates UNdropped p (flash-attn-2)
+                    p = jnp.where(
+                        _dropout_keep(pltpu, seed_ref,
+                                      _drop_block_id(seed_ref, bi, hi_, qi,
+                                                     ki, nq, nkb),
+                                      width, blk_q, ex.keep_p), p, 0.0)
+                acc = acc * alpha + _dot(v_ref[ks, :],
+                                         p.astype(v_ref.dtype), _TN)
+                return acc, m_cur, l_cur
+            return body
 
-        def body(ki, carry):
-            acc, m_prev, l_prev = carry
-            kv = k_ref[pl.ds(ki * blk_k, blk_k), :].astype(jnp.float32)
-            vv = v_ref[pl.ds(ki * blk_k, blk_k), :].astype(jnp.float32)
-            s_blk = qv @ kv.T  # (blk_q, blk_k)
-            s_blk = _block_mask(s_blk, qi, ki, blk_q, blk_k, off,
-                                is_causal, kvlen_b, segq_blk, seg_at,
-                                window=window, alibi=alibi,
-                                mask_at=mask_at)
-            m_cur = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1))
-            alpha = jnp.exp(m_prev - m_cur)
-            # rows with no valid entry yet keep m at NEG_INF — their p
-            # must be 0, not exp(0), so fully-masked rows emit 0
-            p = jnp.where(m_cur[:, None] <= NEG_INF * 0.5, 0.0,
-                          jnp.exp(s_blk - m_cur[:, None]))
-            l_cur = l_prev * alpha + jnp.sum(p, axis=-1)
-            if has_drop:   # l accumulates UNdropped p (flash-attn-2)
-                p = jnp.where(
-                    _dropout_keep(pltpu, seed_ref,
-                                  _drop_block_id(seed_ref, bi, hi_, qi, ki,
-                                                 sq // blk_q, sk // blk_k),
-                                  blk_q, blk_k, keep_p), p, 0.0)
-            acc = acc * alpha[:, None] + p @ vv
-            return acc, m_cur, l_cur
+        acc, m, l = _walk_keys(
+            step, ex, qi, blk_q, blk_k, sk, off, kvlen_b,
+            mlo_ref[bi, hi_, qi] if ex.has_mask else None,
+            mhi_ref[bi, hi_, qi] if ex.has_mask else None,
+            (jnp.zeros((d, blk_q), jnp.float32),
+             jnp.full((1, blk_q), NEG_INF, jnp.float32),
+             jnp.zeros((1, blk_q), jnp.float32)))
+        lsafe = jnp.where(l == 0.0, 1.0, l) if ex.can_empty else l
+        norm = lsafe * ex.keep_p if ex.has_drop else lsafe
+        o_ref[...] = jnp.transpose(acc / norm, (1, 0)).astype(o_ref.dtype)
+        lse_ref[...] = m + jnp.log(lsafe)
 
-        acc0 = jnp.zeros((blk_q, d), jnp.float32)
-        m0 = jnp.full((blk_q,), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((blk_q,), jnp.float32)
-        n_k = _causal_nk(qi, blk_q, blk_k, off, sk) if is_causal \
-            else sk // blk_k
-        if has_len:   # skip k-blocks entirely past the valid length
-            n_k = jnp.minimum(n_k, (kvlen_b + blk_k - 1) // blk_k)
-        k0 = _window_k0(qi, blk_q, blk_k, off, window) if window else 0
-        if has_mask:  # all-masked prefix/suffix block skipping
-            k0 = jnp.maximum(k0, mlo_ref[bi, hi_, qi])
-            n_k = jnp.minimum(n_k, mhi_ref[bi, hi_, qi])
-        acc, m, l = lax.fori_loop(k0, n_k, body, (acc0, m0, l0))
-        lsafe = jnp.where(l == 0.0, 1.0, l)
-        norm = lsafe * keep_p if has_drop else lsafe
-        o_ref[...] = (acc / norm[:, None]).astype(o_ref.dtype)
-        # TPU tiling wants 2-D trailing blocks: replicate lse across lanes
-        lse_ref[...] = jnp.broadcast_to((m + jnp.log(lsafe))[:, None],
-                                        (qv.shape[0], LANES))
-
-    qspec = pl.BlockSpec((None, None, blk_q, d),
-                         lambda bi, hi, qi: (bi, hi, qi, 0))
+    qblk = lambda: pl.BlockSpec((None, None, blk_q, d),
+                                lambda bi, hi, qi: (bi, hi, qi, 0))
     kfull = lambda: pl.BlockSpec((None, None, sk, d),
                                  lambda bi, hi, qi: (bi, hi, 0, 0))
-    in_specs = [qspec, kfull(), kfull()]
-    if has_len:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    if has_seg:
-        spec = _seg_specs()
-        in_specs += [spec(blk_q, sq), spec(None, sk)]
-    if has_alibi:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    if has_mask:
-        in_specs += _mask_specs(pl, pltpu, mask, blk_q, sk)
-    if has_drop:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, None, blk_q, d),
-                         lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, blk_q, LANES),
-                         lambda bi, hi, qi: (bi, hi, qi, 0)),
-        ],
+        grid=(b, h, nq),
+        in_specs=[qblk(), kfull(), kfull()]
+        + ex.specs(pl, pltpu, mask, blk_q, sq, sk, by_q=True),
+        out_specs=[qblk(), pl.BlockSpec((None, None, 1, blk_q),
+                                        lambda bi, hi, qi: (bi, hi, 0, qi))],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         ],
+        compiler_params=_compiler_params(pltpu),
         name="flash_attention_fwd",
+        interpret=pallas_mode()[1],
     )(*_build_operands(qt, kt, vt, kv_lens, seg_q, seg_k, [],
                        alibi_slopes=alibi_slopes, mask=mask, bounds=bounds,
                        seed=seed))
-    return out, lse
+    return out, lse[:, :, 0]
 
 
 def _bwd_dq_kernel(qt, kt, vt, dot, lse, delta, is_causal, sc,
                    kv_lens=None, seg_q=None, seg_k=None, window=None,
                    alibi_slopes=None, mask=None, dropout_p=0.0, seed=None):
-    """dq: loop over k-blocks for each q-block."""
+    """dq: the forward's walk again, key blocks for each block of
+    queries, (keys, queries) score blocks. lse and delta are
+    (b, h, 1, sq)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import pallas_mode
 
     b, h, sq, d = qt.shape
     sk = kt.shape[2]
-    blk_q = _pick_blk(sq)
-    blk_k = _pick_blk(sk)
     off = sk - sq
-    grid = (b, h, sq // blk_q)
-    has_len = kv_lens is not None
-    has_seg = seg_q is not None
-    has_alibi = alibi_slopes is not None
-    has_mask = mask is not None
-    has_drop = dropout_p > 0.0
-    keep_p = 1.0 - dropout_p
-    bounds = (_mask_block_bounds(mask, b, h, sq // blk_q, sk // blk_k,
-                                 blk_q, blk_k) if has_mask else None)
+    ex = _Extras(is_causal, off, kv_lens, seg_q, window, alibi_slopes, mask,
+                 dropout_p)
+    blk_q, blk_k = _blocks(sq, sk, even=True)
+    nq, nkb = sq // blk_q, sk // blk_k
+    bounds = (_mask_block_bounds(mask, b, h, nq, nkb, blk_q, blk_k)
+              if ex.has_mask else None)
 
-    def kernel(*refs):
-        i = 3
-        lens_ref = refs[i] if has_len else None
-        i += has_len
-        segq_ref = refs[i] if has_seg else None
-        segk_ref = refs[i + 1] if has_seg else None
-        i += 2 * has_seg
-        slopes_ref = refs[i] if has_alibi else None
-        i += has_alibi
-        mask_ref = refs[i] if has_mask else None
-        mlo_ref = refs[i + 1] if has_mask else None
-        mhi_ref = refs[i + 2] if has_mask else None
-        i += 3 * has_mask
-        seed_ref = refs[i] if has_drop else None
-        i += has_drop
-        q_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
-        do_ref, lse_ref, dl_ref, dq_ref = refs[i:i + 4]
+    def kernel(q_ref, k_ref, v_ref, *refs):
+        (lens_ref, segq_ref, segk_ref, slopes_ref, mask_ref, mlo_ref,
+         mhi_ref, seed_ref), (do_ref, lse_ref, dl_ref, dq_ref) = \
+            ex.unpack(refs)
+        bi, hi_, qi = (pl.program_id(i) for i in range(3))
+        q = _scaled(q_ref[...], sc)
+        do = do_ref[...]                               # (blk_q, d)
+        lse_q, delta_q = lse_ref[...], dl_ref[...]     # (1, blk_q)
+        kvlen_b = lens_ref[bi] if ex.has_len else None
+        alibi = slopes_ref[hi_] if ex.has_alibi else None
+        segq_blk = segq_ref[...] if ex.has_seg else None
 
-        bi = pl.program_id(0)
-        hi_ = pl.program_id(1)
-        qi = pl.program_id(2)
-        qv = q_ref[...].astype(jnp.float32)
-        do = do_ref[...].astype(jnp.float32)          # (blk_q, d)
-        lse_q = lse_ref[...][:, 0]                    # (blk_q,)
-        delta_q = dl_ref[...][:, 0]                   # (blk_q,)
-        kvlen_b = lens_ref[bi] if has_len else None
-        alibi = slopes_ref[hi_] if has_alibi else None
-        segq_blk = (jnp.transpose(segq_ref[...], (1, 0))
-                    if has_seg else None)
-        seg_at = (lambda ki: segk_ref[:, pl.ds(ki * blk_k, blk_k)]) \
-            if has_seg else None
-        mask_at = (lambda ki: mask_ref[:, pl.ds(ki * blk_k, blk_k)]) \
-            if has_mask else None
+        def step(masked, width):
+            def body(ki, dq_acc):
+                k0 = ki * blk_k
+                ks = pl.ds(pl.multiple_of(k0, 128), width)
+                k_blk = k_ref[ks, :]
+                s_blk = _dot(k_blk, q, _NT)            # (width, blk_q)
+                if masked:
+                    s_blk = _block_mask(
+                        s_blk, qi * blk_q + off - k0, k0, ex, kvlen_b,
+                        segq_blk,
+                        _column(segk_ref[:, ks]) if ex.has_seg else None,
+                        alibi, mask_ref[ks, :] if ex.has_mask else None)
+                p = jnp.exp(s_blk - lse_q)
+                if ex.can_empty:
+                    p = jnp.where(lse_q <= NEG_INF * 0.5, 0.0, p)
+                dp = _dot(v_ref[ks, :], do, _NT)
+                if ex.has_drop:   # regenerate the forward's block mask
+                    dp = jnp.where(
+                        _dropout_keep(pltpu, seed_ref,
+                                      _drop_block_id(seed_ref, bi, hi_, qi,
+                                                     ki, nq, nkb),
+                                      width, blk_q, ex.keep_p),
+                        dp * (1.0 / ex.keep_p), 0.0)
+                ds = (p * (dp - delta_q)).astype(k_blk.dtype)
+                return dq_acc + _dot(k_blk, ds, _TN)   # (d, blk_q)
+            return body
 
-        def body(ki, dq_acc):
-            kv = k_ref[pl.ds(ki * blk_k, blk_k), :].astype(jnp.float32)
-            vv = v_ref[pl.ds(ki * blk_k, blk_k), :].astype(jnp.float32)
-            s_blk = (qv @ kv.T) * sc
-            s_blk = _block_mask(s_blk, qi, ki, blk_q, blk_k, off,
-                                is_causal, kvlen_b, segq_blk, seg_at,
-                                window=window, alibi=alibi,
-                                mask_at=mask_at)
-            p = jnp.where(lse_q[:, None] <= NEG_INF * 0.5, 0.0,
-                          jnp.exp(s_blk - lse_q[:, None]))
-            dp = do @ vv.T                            # (blk_q, blk_k)
-            if has_drop:   # regenerate the forward's block mask
-                dp = jnp.where(
-                    _dropout_keep(pltpu, seed_ref,
-                                  _drop_block_id(seed_ref, bi, hi_, qi, ki,
-                                                 sq // blk_q, sk // blk_k),
-                                  blk_q, blk_k, keep_p),
-                    dp * (1.0 / keep_p), 0.0)
-            ds = p * (dp - delta_q[:, None])
-            return dq_acc + (ds @ kv) * sc
-
-        n_k = _causal_nk(qi, blk_q, blk_k, off, sk) if is_causal \
-            else sk // blk_k
-        if has_len:
-            n_k = jnp.minimum(n_k, (kvlen_b + blk_k - 1) // blk_k)
-        k0 = _window_k0(qi, blk_q, blk_k, off, window) if window else 0
-        if has_mask:
-            k0 = jnp.maximum(k0, mlo_ref[bi, hi_, qi])
-            n_k = jnp.minimum(n_k, mhi_ref[bi, hi_, qi])
-        dq = lax.fori_loop(k0, n_k, body,
-                           jnp.zeros((blk_q, d), jnp.float32))
-        dq_ref[...] = dq.astype(dq_ref.dtype)
+        dq = _walk_keys(step, ex, qi, blk_q, blk_k, sk, off, kvlen_b,
+                        mlo_ref[bi, hi_, qi] if ex.has_mask else None,
+                        mhi_ref[bi, hi_, qi] if ex.has_mask else None,
+                        jnp.zeros((d, blk_q), jnp.float32), split=False)
+        dq_ref[...] = jnp.transpose(dq * sc, (1, 0)).astype(dq_ref.dtype)
 
     kfull = lambda: pl.BlockSpec((None, None, sk, d),
                                  lambda bi, hi, qi: (bi, hi, 0, 0))
     qblk = lambda: pl.BlockSpec((None, None, blk_q, d),
                                 lambda bi, hi, qi: (bi, hi, qi, 0))
-    row = lambda: pl.BlockSpec((None, None, blk_q, LANES),
-                               lambda bi, hi, qi: (bi, hi, qi, 0))
-    in_specs = [qblk(), kfull(), kfull()]
-    if has_len:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    if has_seg:
-        spec = _seg_specs()
-        in_specs += [spec(blk_q, sq), spec(None, sk)]
-    if has_alibi:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    if has_mask:
-        in_specs += _mask_specs(pl, pltpu, mask, blk_q, sk)
-    if has_drop:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    in_specs += [qblk(), row(), row()]
+    row = lambda: pl.BlockSpec((None, None, 1, blk_q),
+                               lambda bi, hi, qi: (bi, hi, 0, qi))
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
+        grid=(b, h, nq),
+        in_specs=[qblk(), kfull(), kfull()]
+        + ex.specs(pl, pltpu, mask, blk_q, sq, sk, by_q=True)
+        + [qblk(), row(), row()],
         out_specs=qblk(),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
+        compiler_params=_compiler_params(pltpu),
         name="flash_attention_bwd_dq",
+        interpret=pallas_mode()[1],
     )(*_build_operands(qt, kt, vt, kv_lens, seg_q, seg_k,
                        [dot, lse, delta], alibi_slopes=alibi_slopes,
                        mask=mask, bounds=bounds, seed=seed))
 
 
+def _fused_bwd_fits(sq, sk, d, itemsize):
+    """Whether one (batch, head)'s q, do and dq (whole), the float32 dq
+    it accumulates, lse and delta and a block of k, v, dk and dv fit in
+    VMEM beside the score blocks: what decides between the one-kernel
+    backward and dq and dk/dv kernels apart."""
+    blk_q, blk_k = _blocks(sq, sk, even=True)
+    held = 3 * sq * d * itemsize + 2 * 8 * sq * 4
+    blocks = 4 * blk_k * d * itemsize
+    work = sq * d * 4 + 6 * blk_q * blk_k * 4
+    return 2 * (held + blocks) + work <= _FUSED_BWD_BUDGET
+
+
 def _bwd_dkv_kernel(qt, kt, vt, dot, lse, delta, is_causal, sc,
                     kv_lens=None, seg_q=None, seg_k=None, window=None,
                     alibi_slopes=None, mask=None, dropout_p=0.0,
-                    seed=None):
-    """dk, dv: loop over q-blocks for each k-block."""
+                    seed=None, with_dq=False):
+    """dk, dv: blocks of queries for each block of keys, (keys, queries)
+    score blocks, so ``dv += p @ do`` and ``dk += ds @ q`` are plain
+    products. ``with_dq``: the one-kernel backward, which also adds every
+    block's ``k^T @ ds`` into a float32 (d, sq) dq that stays in VMEM
+    over a (batch, head)'s key blocks — scores, ``exp`` and ``dp`` taken
+    once for all three gradients. lse and delta are (b, h, 1, sq).
+    Returns (dk, dv) or (dk, dv, dq)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import pallas_mode
 
     b, h, sq, d = qt.shape
     sk = kt.shape[2]
-    blk_q = _pick_blk(sq)
-    blk_k = _pick_blk(sk)
     off = sk - sq
-    grid = (b, h, sk // blk_k)
-    has_len = kv_lens is not None
-    has_seg = seg_q is not None
-    has_alibi = alibi_slopes is not None
-    has_mask = mask is not None
-    has_drop = dropout_p > 0.0
-    keep_p = 1.0 - dropout_p
-    bounds = (_mask_block_bounds(mask, b, h, sq // blk_q, sk // blk_k,
-                                 blk_q, blk_k, axis_q=False)
-              if has_mask else None)
+    ex = _Extras(is_causal, off, kv_lens, seg_q, window, alibi_slopes, mask,
+                 dropout_p)
+    blk_q, blk_k = _blocks(sq, sk, even=True)
+    nq, nkb = sq // blk_q, sk // blk_k
+    bounds = (_mask_block_bounds(mask, b, h, nq, nkb, blk_q, blk_k,
+                                 axis_q=False) if ex.has_mask else None)
 
-    def kernel(*refs):
-        i = 3
-        lens_ref = refs[i] if has_len else None
-        i += has_len
-        segq_ref = refs[i] if has_seg else None
-        segk_ref = refs[i + 1] if has_seg else None
-        i += 2 * has_seg
-        slopes_ref = refs[i] if has_alibi else None
-        i += has_alibi
-        mask_ref = refs[i] if has_mask else None
-        mlo_ref = refs[i + 1] if has_mask else None
-        mhi_ref = refs[i + 2] if has_mask else None
-        i += 3 * has_mask
-        seed_ref = refs[i] if has_drop else None
-        i += has_drop
-        q_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
-        do_ref, lse_ref, dl_ref, dk_ref, dv_ref = refs[i:i + 5]
+    def kernel(q_ref, k_ref, v_ref, *refs):
+        (lens_ref, segq_ref, segk_ref, slopes_ref, mask_ref, mlo_ref,
+         mhi_ref, seed_ref), rest = ex.unpack(refs)
+        do_ref, lse_ref, dl_ref, dk_ref, dv_ref = rest[:5]
+        dq_ref, dq_acc = rest[5:] if with_dq else (None, None)
+        bi, hi_, ki = (pl.program_id(i) for i in range(3))
+        k0 = ki * blk_k
+        k_s = _scaled(k_ref[...], sc)                  # (blk_k, d)
+        v_blk = v_ref[...]
+        kvlen_b = lens_ref[bi] if ex.has_len else None
+        alibi = slopes_ref[hi_] if ex.has_alibi else None
+        # k-side ids for THIS block, as a column; q-side read per block
+        segk_blk = _column(segk_ref[...]) if ex.has_seg else None
 
-        bi = pl.program_id(0)
-        hi_ = pl.program_id(1)
-        ki = pl.program_id(2)
-        kv = k_ref[...].astype(jnp.float32)           # (blk_k, d)
-        vv = v_ref[...].astype(jnp.float32)
-        kvlen_b = lens_ref[bi] if has_len else None
-        alibi = slopes_ref[hi_] if has_alibi else None
-        # k-side ids for THIS block, as (1, blk_k); q-side read per block
-        segk_blk = segk_ref[...] if has_seg else None
-        seg_at = (lambda _ki: segk_blk) if has_seg else None
+        if with_dq:
+            k_t = jnp.transpose(k_s, (1, 0))           # (d, blk_k)
 
-        def body(qi, carry):
-            dk_acc, dv_acc = carry
-            qv = q_ref[pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32)
-            do = do_ref[pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32)
-            lse_q = lse_ref[pl.ds(qi * blk_q, blk_q), 0]
-            delta_q = dl_ref[pl.ds(qi * blk_q, blk_q), 0]
-            s_blk = (qv @ kv.T) * sc                  # (blk_q, blk_k)
-            segq_blk = (jnp.transpose(
-                segq_ref[:, pl.ds(qi * blk_q, blk_q)], (1, 0))
-                if has_seg else None)
-            # mask column band for THIS k-block, rows sliced per q-block
-            # (slice the REF, not a loaded value — dynamic starts only
-            # exist at the ref level)
-            mask_at = ((lambda _ki: mask_ref[pl.ds(qi * blk_q, blk_q), :])
-                       if has_mask else None)
-            s_blk = _block_mask(s_blk, qi, ki, blk_q, blk_k, off,
-                                is_causal, kvlen_b, segq_blk, seg_at,
-                                window=window, alibi=alibi,
-                                mask_at=mask_at)
-            p = jnp.where(lse_q[:, None] <= NEG_INF * 0.5, 0.0,
-                          jnp.exp(s_blk - lse_q[:, None]))
-            dp = do @ vv.T
-            if has_drop:   # same (bi, hi, qi, ki)-keyed mask as forward
-                dmask = _dropout_keep(pltpu, seed_ref,
-                                      _drop_block_id(seed_ref, bi, hi_, qi,
-                                                     ki, sq // blk_q,
-                                                     sk // blk_k),
-                                      blk_q, blk_k, keep_p)
-                dv_acc = dv_acc + jnp.where(
-                    dmask, p * (1.0 / keep_p), 0.0).T @ do
-                dp = jnp.where(dmask, dp * (1.0 / keep_p), 0.0)
-            else:
-                dv_acc = dv_acc + p.T @ do
-            ds = p * (dp - delta_q[:, None])
-            dk_acc = dk_acc + (ds.T @ qv) * sc
-            return dk_acc, dv_acc
+            @pl.when(ki == 0)
+            def _():
+                dq_acc[...] = jnp.zeros_like(dq_acc)
 
-        n_q = sq // blk_q
+        def step(masked):
+            def body(qi, carry):
+                dk_acc, dv_acc = carry
+                cols = pl.ds(pl.multiple_of(qi * blk_q, 128), blk_q)
+                q_blk, do = q_ref[cols, :], do_ref[cols, :]
+                s_blk = _dot(k_s, q_blk, _NT)          # (blk_k, blk_q)
+                if masked:
+                    # the mask's band of THIS k-block, columns sliced per
+                    # q-block (slice the REF, not a loaded value — dynamic
+                    # starts only exist at the ref level)
+                    s_blk = _block_mask(
+                        s_blk, qi * blk_q + off - k0, k0, ex, kvlen_b,
+                        segq_ref[:, cols] if ex.has_seg else None,
+                        segk_blk, alibi,
+                        mask_ref[:, cols] if ex.has_mask else None)
+                lse_q = lse_ref[:, cols]               # (1, blk_q)
+                p = jnp.exp(s_blk - lse_q)
+                if ex.can_empty:
+                    p = jnp.where(lse_q <= NEG_INF * 0.5, 0.0, p)
+                dp = _dot(v_blk, do, _NT)
+                pd = p
+                if ex.has_drop:   # same (bi, hi, qi, ki)-keyed mask as fwd
+                    keep = _dropout_keep(
+                        pltpu, seed_ref,
+                        _drop_block_id(seed_ref, bi, hi_, qi, ki, nq, nkb),
+                        blk_k, blk_q, ex.keep_p)
+                    pd = jnp.where(keep, p * (1.0 / ex.keep_p), 0.0)
+                    dp = jnp.where(keep, dp * (1.0 / ex.keep_p), 0.0)
+                dv_acc = dv_acc + _dot(pd.astype(do.dtype), do, _NN)
+                ds = (p * (dp - dl_ref[:, cols])).astype(q_blk.dtype)
+                dk_acc = dk_acc + _dot(ds, q_blk, _NN)
+                if with_dq:     # (k * sc)^T @ ds: dq's scale rides in k_s
+                    dq_acc[:, cols] += _dot(k_t, ds, _NN)
+                return dk_acc, dv_acc
+            return body
+
+        # the blocks of queries that see this key block, in ONE loop that
+        # masks every block if any (see `_walk_keys`)
+        q_lo, q_hi = 0, nq
         if is_causal:
-            # only q rows with q_pos + off >= ki*blk_k see this k-block
-            q0 = jnp.clip((ki * blk_k - off) // blk_q, 0, n_q)
-        else:
-            q0 = 0
-        q_hi = n_q
+            # only queries with q_pos + off >= k0 see this k-block
+            q_lo = jnp.clip((k0 - off) // blk_q, 0, nq)
         if window is not None:
-            # sliding window: q rows past k_pos + window - 1 - off can't
+            # sliding window: queries past k_pos + window - 1 - off can't
             # see this k-block (loose block bound; the mask is exact)
             q_hi = jnp.clip(
-                (ki * blk_k + blk_k - 1 + window - off) // blk_q + 1,
-                0, n_q)
-        if has_mask:
-            q0 = jnp.maximum(q0, mlo_ref[bi, hi_, ki])
-            q_hi = jnp.minimum(q_hi, mhi_ref[bi, hi_, ki])
-        dk, dv = lax.fori_loop(q0, q_hi, body,
-                               (jnp.zeros((blk_k, d), jnp.float32),
-                                jnp.zeros((blk_k, d), jnp.float32)))
-        dk_ref[...] = dk.astype(dk_ref.dtype)
+                (k0 + blk_k - 1 + window - off) // blk_q + 1, 0, nq)
+        if ex.has_mask:
+            q_lo = _hi(q_lo, mlo_ref[bi, hi_, ki])
+            q_hi = _lo(q_hi, mhi_ref[bi, hi_, ki])
+        dk, dv = lax.fori_loop(
+            q_lo, q_hi, step(is_causal or ex.structured),
+            (jnp.zeros((blk_k, d), jnp.float32),
+             jnp.zeros((blk_k, d), jnp.float32)))
+        dk_ref[...] = (dk * sc).astype(dk_ref.dtype)
         dv_ref[...] = dv.astype(dv_ref.dtype)
+        if with_dq:
+            @pl.when(ki == nkb - 1)
+            def _():
+                dq_ref[...] = jnp.transpose(
+                    dq_acc[...], (1, 0)).astype(dq_ref.dtype)
 
     qfull = lambda: pl.BlockSpec((None, None, sq, d),
                                  lambda bi, hi, ki: (bi, hi, 0, 0))
     kblk = lambda: pl.BlockSpec((None, None, blk_k, d),
                                 lambda bi, hi, ki: (bi, hi, ki, 0))
-    frow = lambda: pl.BlockSpec((None, None, sq, LANES),
+    frow = lambda: pl.BlockSpec((None, None, 1, sq),
                                 lambda bi, hi, ki: (bi, hi, 0, 0))
-    in_specs = [qfull(), kblk(), kblk()]
-    if has_len:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    if has_seg:
-        spec = _seg_specs()
-        in_specs += [spec(None, sq), spec(blk_k, sk)]
-    if has_alibi:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    if has_mask:
-        in_specs += _mask_specs(pl, pltpu, mask, blk_k, sq,
-                                row_axis_q=False)
-    if has_drop:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    in_specs += [qfull(), frow(), frow()]
+    kv_shape = jax.ShapeDtypeStruct((b, h, sk, d), qt.dtype)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[kblk(), kblk()],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), qt.dtype),
-                   jax.ShapeDtypeStruct((b, h, sk, d), qt.dtype)],
-        name="flash_attention_bwd_dkv",
+        grid=(b, h, nkb),
+        in_specs=[qfull(), kblk(), kblk()]
+        + ex.specs(pl, pltpu, mask, blk_k, sq, sk, by_q=False)
+        + [qfull(), frow(), frow()],
+        out_specs=[kblk(), kblk()] + ([qfull()] if with_dq else []),
+        out_shape=[kv_shape, kv_shape] + (
+            [jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype)]
+            if with_dq else []),
+        scratch_shapes=([pltpu.VMEM((d, sq), jnp.float32)]
+                        if with_dq else []),
+        compiler_params=_compiler_params(pltpu),
+        name=("flash_attention_bwd_dkv_dq" if with_dq
+              else "flash_attention_bwd_dkv"),
+        interpret=pallas_mode()[1],
     )(*_build_operands(qt, kt, vt, kv_lens, seg_q, seg_k,
                        [dot, lse, delta], alibi_slopes=alibi_slopes,
                        mask=mask, bounds=bounds, seed=seed))
@@ -985,7 +1084,7 @@ def _flash_call(q, k, v, is_causal, scale, kv_lens, seg_q, seg_k,
         [seed, jnp.asarray([0, 0, q.shape[2]], jnp.int32)])
 
     def kernels(*arrays):
-        return _flash_vjp_entry(*arrays, flags, is_causal, scale, window,
+        return _flash_entry_jit(*arrays, flags, is_causal, scale, window,
                                 float(dropout_p))
 
     arrays = (q, k, v, dummy_len, dummy_sq, dummy_sk, dummy_al, dummy_mk,
@@ -1105,10 +1204,15 @@ def _pallas_bwd_impl(q, k, v, out, lse, g, is_causal, scale, g_lse=None,
                      kv_lens=None, seg_q=None, seg_k=None, window=None,
                      alibi_slopes=None, mask=None, dropout_p=0.0,
                      seed=None):
-    """Shared Pallas backward. `lse` is (b, h, sq, LANES). When `g_lse`
+    """Shared Pallas backward. `lse` is (b, h, sq). When `g_lse`
     (b, h, sq) is given (cotangent on the returned LSE, e.g. from a ring
     merge), it folds into the softmax-grad correction: dS = P·(dP − Δ)
-    with Δ_eff = rowsum(dout·out) − g_lse, since ∂lse/∂S = P."""
+    with Δ_eff = rowsum(dout·out) − g_lse, since ∂lse/∂S = P.
+
+    One algorithm in two forms, chosen by shape: where a (batch, head)'s
+    float32 dq fits in VMEM (`_fused_bwd_fits`) one kernel takes every
+    score block once for dq, dk and dv; else a dq kernel and a dk/dv
+    kernel each take it."""
     b, sq, h, d = q.shape
     n_kv = k.shape[2]
     n_rep = h // n_kv
@@ -1126,14 +1230,19 @@ def _pallas_bwd_impl(q, k, v, out, lse, g, is_causal, scale, g_lse=None,
                     axis=-1)
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
-    delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
+    # positions on the lanes: (b, h, 1, sq)
+    stats = [dot, lse[:, :, None], delta[:, :, None]]
 
     kw = dict(kv_lens=kv_lens, seg_q=seg_q, seg_k=seg_k, window=window,
               alibi_slopes=alibi_slopes, mask=mask, dropout_p=dropout_p,
               seed=seed)
-    dq_t = _bwd_dq_kernel(qt, kt, vt, dot, lse, delta, is_causal, sc, **kw)
-    dk_t, dv_t = _bwd_dkv_kernel(qt, kt, vt, dot, lse, delta, is_causal,
-                                 sc, **kw)
+    if _fused_bwd_fits(sq, sk, d, q.dtype.itemsize):
+        dk_t, dv_t, dq_t = _bwd_dkv_kernel(qt, kt, vt, *stats, is_causal,
+                                           sc, with_dq=True, **kw)
+    else:
+        dq_t = _bwd_dq_kernel(qt, kt, vt, *stats, is_causal, sc, **kw)
+        dk_t, dv_t = _bwd_dkv_kernel(qt, kt, vt, *stats, is_causal, sc,
+                                     **kw)
 
     from_t = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     dq = from_t(dq_t).astype(q.dtype)
@@ -1165,6 +1274,12 @@ def _flash_vjp_bwd(flags, is_causal, scale, window, dropout_p, res, g):
 
 
 _flash_vjp_entry.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+# jitted on its own: the layers of one program share ONE trace of the
+# kernels' bodies (every `jnp` call in a body is a nested trace on the
+# host; 24 layers x 4 prefill programs traced apart were 5 s of a
+# serving engine's warm set-up, PERF.md PR 37)
+_flash_entry_jit = jax.jit(_flash_vjp_entry,
+                           static_argnums=(9, 10, 11, 12, 13))
 
 # Back-compat alias used by benches/tests: plain self-attention entry.
 def _flash_attention_vjp(q, k, v, is_causal, scale):
@@ -1181,9 +1296,9 @@ def _pallas_seq_ok(sq: int, sk: Optional[int] = None) -> bool:
 
 
 def _pallas_lse_ok(q, k):
-    from paddle_tpu.ops import use_pallas
+    from paddle_tpu.ops import pallas_mode
     s = q.shape[1]
-    return (use_pallas() and s == k.shape[1] and _pallas_seq_ok(s)
+    return (pallas_mode()[0] and s == k.shape[1] and _pallas_seq_ok(s)
             and q.shape[-1] in (64, 128, 256))
 
 
@@ -1210,8 +1325,7 @@ def _xla_fwd_lse(q, k, v, is_causal, scale):
 
 def _fwd_lse_dispatch(q, k, v, is_causal, scale):
     if _pallas_lse_ok(q, k):
-        out, lse = _flash_fwd(q, k, v, is_causal, scale)
-        return out, lse[..., 0]
+        return _flash_fwd(q, k, v, is_causal, scale)
     return _xla_fwd_lse(q, k, v, is_causal, scale)
 
 
@@ -1238,9 +1352,8 @@ def _fwd_lse_vjp_bwd(is_causal, scale, res, cts):
     q, k, v, out, lse = res
     g_out, g_lse = cts
     if _pallas_lse_ok(q, k):
-        lse_lanes = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))
-        return _pallas_bwd_impl(q, k, v, out, lse_lanes, g_out,
-                                is_causal, scale, g_lse=g_lse)
+        return _pallas_bwd_impl(q, k, v, out, lse, g_out, is_causal, scale,
+                                g_lse=g_lse)
     _, pull = jax.vjp(
         lambda q_, k_, v_: _xla_fwd_lse(q_, k_, v_, is_causal, scale),
         q, k, v)

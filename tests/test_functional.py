@@ -376,7 +376,7 @@ def test_flash_partitioned_scope(monkeypatch):
         return (jnp.zeros_like(q) + seed[1] * 100 + seed[2]
                 + seed[3] * 10000).astype(q.dtype)
 
-    monkeypatch.setattr(fa, "_flash_vjp_entry", stand_in)
+    monkeypatch.setattr(fa, "_flash_entry_jit", stand_in)
     q = jnp.zeros((8, 128, 4, 64), jnp.float32)
 
     def call(q):
