@@ -237,13 +237,12 @@ def sparse_chunk(w: Dict, cfg: MiniCPMSALAConfig, x, kv, ck, tail, c0):
         ck = lax.dynamic_update_slice_in_dim(
             ck, spg.compress(ext, sp).astype(ck.dtype),
             c0 // sp.kernel_stride, axis=1)
-    t = jnp.broadcast_to(c0 + jnp.arange(C), (n, C))
     with part("select"):
         blocks = prefill_select(q, ck[:, lead:].reshape(n, -1, G, d), c0, sp,
                                 -(-S // sp.block_size))
-        mask = spg.prefill_token_mask(blocks, t, S, sp.block_size)
     with part("attn"):
-        o = spg.sparse_prefill_attention(q, kv, mask, c0 + C, groups=G)
+        o = spg.sparse_prefill_attention(q, kv, blocks, c0 + C, groups=G,
+                                         block_size=sp.block_size)
     with part("attn_out"):
         y = _gated_out(w, x, o.reshape(n, C, -1))
     return y, kv, ck, ext[:, ext.shape[1] - tail.shape[1]:], blocks

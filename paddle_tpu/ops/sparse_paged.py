@@ -23,8 +23,8 @@ page of its first token. A query at position t with n = t + 1 tokens:
 3. softmax attention over the tokens ``<= t`` of those blocks:
    :func:`sparse_paged_decode` walks the chosen ``block_size``-token
    sub-blocks of the pool's pages; :func:`sparse_prefill_attention`
-   is a prefill's, under a per-token mask or the (queries, groups,
-   blocks) selection itself.
+   is a prefill's, under the (queries, groups, blocks) selection
+   itself.
 
 MSA (``ops/msa_index.py``) brings its own stage 1 (learned indexer
 heads against max-pooled keys, one pooled key a block) and shares
@@ -494,7 +494,8 @@ def sparse_paged_decode(q, pool, tables, positions, blocks, *, layer: int,
 def prefill_token_mask(blocks, t, S: int, block_size: int):
     """blocks (n, C, G, NB) bool of ``block_size``-token blocks, t (n, C)
     the queries' positions -> (n, G, C, S) int8: query i may read key
-    j."""
+    j. The ``jnp`` path's form of a selection; the kernel reads the
+    blocks themselves."""
     per_key = jnp.repeat(jnp.swapaxes(blocks, 1, 2), block_size,
                          axis=-1)[..., :S]
     causal = jnp.arange(S)[None, None, :] <= t[:, :, None]
@@ -530,15 +531,32 @@ def sparse_prefill_attention_reference(q, kv, mask, *, groups: int,
     return jnp.moveaxis(out, 0, 1).reshape(n, C, H, d)
 
 
+def _tiles(C: int, S: int):
+    """A prefill kernel's (query tile, key tile) for C queries over S
+    rows."""
+    return (256 if C % 256 == 0 else C), (512 if S % 512 == 0 else S)
+
+
 @functools.partial(jax.jit, static_argnames=("groups", "block_size",
                                              "interpret"))
-def _sparse_prefill_pallas(q, kv, mask, kv_len, *, groups, block_size=None,
+def _sparse_prefill_pallas(q, kv, sel, kv_len, *, groups, block_size,
                            interpret=False):
-    """``block_size`` None: ``mask`` is per token (n, G, C, S) int8; else
-    it is the selection (n, G, C, NBp) bf16 of ``block_size``-token
-    blocks (NBp a whole number of lane tiles) and the kernel widens a
-    tile of it to keys (a 0/1 matrix product: every key lies in ONE
-    block) and adds the causal edge itself."""
+    """q (n, C, H, d), kv (n, S, 2 G d), sel (n, G, S / block_size, C):
+    the blocks each query reads, 0/1, blocks on the sublanes and queries
+    on the lanes.
+
+    A score tile is (keys, queries), ``k @ q_h^T``: the softmax's maximum
+    and sum run down the sublanes and a query's statistics are a
+    lane-dense row (a (queries, keys) tile reduces across the 128 lanes
+    of every row: a quarter of a flash forward on a v5e). Key tile j
+    reads rows ``j tk / bs ..`` of the selection, each repeated ``bs``
+    times down the sublanes, once for all the group's heads; the causal
+    edge is added on the tiles that cross it, and the mask goes onto the
+    scores as one additive ``NEG``. Key tile 0 holds block 0, which every
+    query reads (a forced block) and sees, so every query's maximum is
+    finite after the first tile: a masked score then weighs exp(NEG - m)
+    = 0 exactly, with no second mask on ``p``. The keys must be finite,
+    as the values must (0 x inf is not 0)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -546,33 +564,22 @@ def _sparse_prefill_pallas(q, kv, mask, kv_len, *, groups, block_size=None,
     G = groups
     Hg = H // G
     S = kv.shape[1]
-    tq = 256 if C % 256 == 0 else C
-    tk = 512 if S % 512 == 0 else S
-    nj = S // tk
+    bs = block_size
+    tq, tk = _tiles(C, S)
+    nj, R = S // tk, tk // bs
     scale = 1.0 / math.sqrt(d)
     prec = _HI if q.dtype == jnp.float32 else None
-    bs = block_size
+    # (n, G, key tiles, R, C): a key tile's R rows are one block of the
+    # input, whole in its last two dims, which Mosaic takes at any R
+    sel = sel.reshape(n, G, nj, R, C)
 
     def edge(i, kvl):
         """The last key tile the queries of tile ``i`` may see."""
         return (kvl[0] - C + (i + 1) * tq - 1) // tk
 
-    def visible(m_ref, i, j, kvl):
-        if bs is None:
-            return m_ref[...].astype(jnp.float32) > 0
-        NBp = m_ref.shape[-1]
-        key = j * tk + lax.broadcasted_iota(jnp.int32, (NBp, tk), 1)
-        first = lax.broadcasted_iota(jnp.int32, (NBp, tk), 0) * bs
-        widen = ((first <= key) & (key < first + bs)).astype(m_ref.dtype)
-        chosen = lax.dot_general(m_ref[...], widen, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32) > 0.5
-        row = kvl[0] - C + i * tq + lax.broadcasted_iota(jnp.int32,
-                                                         (tq, tk), 0)
-        col = j * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        return chosen & (col <= row)
-
-    def kernel(kvl, q_ref, k_ref, v_ref, m_ref, o_ref, acc, mx, l):
+    def kernel(kvl, q_ref, k_ref, v_ref, sel_ref, o_ref, acc, mx, l, bias):
         i, j = pl.program_id(2), pl.program_id(3)
+        first = kvl[0] - C + i * tq         # the tile's first query
 
         @pl.when(j == 0)
         def _():
@@ -582,28 +589,38 @@ def _sparse_prefill_pallas(q, kv, mask, kv_len, *, groups, block_size=None,
 
         @pl.when(j <= edge(i, kvl))
         def _():
+            rows = jnp.where(sel_ref[...].astype(jnp.float32) > 0.5, 0.0,
+                             NEG)                           # (R, tq)
+            bias[...] = jnp.concatenate(
+                [jnp.broadcast_to(rows[r:r + 1], (bs, tq)) for r in range(R)],
+                axis=0)
+
+            @pl.when((j + 1) * tk - 1 > first)
+            def _():
+                key = j * tk + lax.broadcasted_iota(jnp.int32, (tk, tq), 0)
+                query = first + lax.broadcasted_iota(jnp.int32, (tk, tq), 1)
+                bias[...] = jnp.where(key <= query, bias[...], NEG)
+
             kt, vt = k_ref[...], v_ref[...]
-            see = visible(m_ref, i, j, kvl)
             for hh in range(Hg):
-                s = lax.dot_general(q_ref[:, hh * d:(hh + 1) * d], kt,
-                                    (((1,), (1,)), ((), ())),
-                                    precision=prec,
-                                    preferred_element_type=jnp.float32) * scale
-                s = jnp.where(see, s, NEG)
-                m_new = jnp.maximum(mx[hh], s.max(-1, keepdims=True))
-                p = jnp.where(see, jnp.exp(s - m_new), 0.0)
+                s = lax.dot_general(kt, q_ref[:, hh * d:(hh + 1) * d],
+                                    (((1,), (1,)), ((), ())), precision=prec,
+                                    preferred_element_type=jnp.float32)
+                s = s * scale + bias[...]                   # (tk, tq)
+                m_new = jnp.maximum(mx[hh], s.max(0, keepdims=True))
+                p = jnp.exp(s - m_new)
                 alpha = jnp.exp(mx[hh] - m_new)
-                l[hh] = alpha * l[hh] + p.sum(-1, keepdims=True)
+                l[hh] = alpha * l[hh] + p.sum(0, keepdims=True)
                 acc[hh] = alpha * acc[hh] + lax.dot_general(
-                    p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
+                    vt, p.astype(vt.dtype), (((0,), (0,)), ((), ())),
                     precision=prec, preferred_element_type=jnp.float32)
                 mx[hh] = m_new
 
         @pl.when(j == nj - 1)
         def _():
             for hh in range(Hg):
-                o_ref[:, hh * d:(hh + 1) * d] = (
-                    acc[hh] / jnp.maximum(l[hh], 1e-30)).astype(o_ref.dtype)
+                o_ref[:, hh * d:(hh + 1) * d] = jnp.transpose(
+                    acc[hh] / l[hh], (1, 0)).astype(o_ref.dtype)
 
     seen = lambda i, j, kvl: jnp.minimum(j, edge(i, kvl))
     out = pl.pallas_call(
@@ -619,15 +636,15 @@ def _sparse_prefill_pallas(q, kv, mask, kv_len, *, groups, block_size=None,
                              lambda r, g, i, j, kvl: (r, seen(i, j, kvl), g)),
                 pl.BlockSpec((None, tk, d), lambda r, g, i, j, kvl: (
                     r, seen(i, j, kvl), G + g)),
-                pl.BlockSpec((None, None, tq, tk), lambda r, g, i, j, kvl: (
-                    r, g, i, seen(i, j, kvl))) if bs is None else
-                pl.BlockSpec((None, None, tq, mask.shape[-1]),
-                             lambda r, g, i, j, kvl: (r, g, i, 0))],
+                pl.BlockSpec((None, None, None, R, tq),
+                             lambda r, g, i, j, kvl: (
+                                 r, g, seen(i, j, kvl), 0, i))],
             out_specs=pl.BlockSpec((None, tq, Hg * d),
                                    lambda r, g, i, j, kvl: (r, i, g)),
-            scratch_shapes=[pltpu.VMEM((Hg, tq, d), jnp.float32),
-                            pltpu.VMEM((Hg, tq, 1), jnp.float32),
-                            pltpu.VMEM((Hg, tq, 1), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((Hg, d, tq), jnp.float32),
+                            pltpu.VMEM((Hg, 1, tq), jnp.float32),
+                            pltpu.VMEM((Hg, 1, tq), jnp.float32),
+                            pltpu.VMEM((tk, tq), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((n, C, H * d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -635,37 +652,38 @@ def _sparse_prefill_pallas(q, kv, mask, kv_len, *, groups, block_size=None,
             vmem_limit_bytes=64 * 1024 * 1024),
         name=PREFILL_KERNEL, interpret=interpret,
     )(jnp.reshape(kv_len, (1,)).astype(jnp.int32), q.reshape(n, C, H * d),
-      kv, kv, mask)
+      kv, kv, sel)
     return out.reshape(n, C, H, d)
 
 
-def sparse_prefill_attention(q, kv, mask, kv_len, *, groups: int,
-                             block_size: int = None):
-    """A prefill chunk's attention under its selection: the C queries at
-    positions ``kv_len - C .. kv_len - 1`` over the first ``kv_len`` rows
-    of ``kv`` (``kv_len`` tells the kernel which key tiles no query can
-    see, and those it never loads). ``mask``: per token, (n, G, C, S)
-    int8 and causal already (:func:`prefill_token_mask`); or, with
-    ``block_size``, the blocks each query reads, (n, C, G, NB) bool, to
-    which the causal edge is added here. The Mosaic kernel on a TPU (or
-    under ``FLAGS_pallas_interpret``) where ``d`` is 128 lanes and the
-    chunk and the cache whole tiles, the ``jnp`` path elsewhere. As
-    :func:`sparse_prefill_attention_reference`."""
+def sparse_prefill_attention(q, kv, blocks, kv_len, *, groups: int,
+                             block_size: int):
+    """A prefill chunk's attention under its block selection: the C
+    queries at positions ``kv_len - C .. kv_len - 1`` over the first
+    ``kv_len`` rows of ``kv`` (``kv_len`` tells the kernel which key
+    tiles no query can see, and those it never loads); ``blocks`` (n, C,
+    G, NB) bool, the ``block_size``-token blocks each query reads, to
+    which the causal edge is added here. Block 0 must be among every
+    query's blocks (it is a forced block of both selections). The Mosaic
+    kernel on a TPU (or under ``FLAGS_pallas_interpret``) where ``d`` is
+    128 lanes and the chunk and the cache whole tiles, the ``jnp`` path
+    elsewhere. As :func:`sparse_prefill_attention_reference` under
+    :func:`prefill_token_mask`."""
     n, C, H, d = q.shape
     S = kv.shape[1]
     on, interp = pallas_mode()
-    if on and d == 128 and C % 32 == 0 and (S % 512 == 0 or S % 128 == 0
-                                            and S <= 2048):
-        if block_size is not None:
-            # (n, G, C, NB) as 0/1 bf16, the blocks padded to whole lane
-            # tiles: a tile of queries holds its rows whole
-            NB = mask.shape[-1]
-            mask = jnp.pad(jnp.swapaxes(mask, 1, 2).astype(jnp.bfloat16),
-                           [(0, 0)] * 3 + [(0, -NB % 128)])
-        return _sparse_prefill_pallas(q, kv, mask, kv_len, groups=groups,
+    if on and d == 128 and C % 32 == 0 and (
+            S % 512 == 0 or S % 128 == 0 and S <= 2048) \
+            and _tiles(C, S)[1] % block_size == 0:
+        # (n, G, NB, C) as 0/1 bf16: blocks on the sublanes, queries on
+        # the lanes, a block for every block_size rows of the cache
+        NB = blocks.shape[-1]
+        sel = jnp.moveaxis(blocks, 1, 3).astype(jnp.bfloat16)
+        sel = jnp.pad(sel, [(0, 0)] * 2 + [(0, S // block_size - NB),
+                                           (0, 0)])
+        return _sparse_prefill_pallas(q, kv, sel, kv_len, groups=groups,
                                       block_size=block_size,
                                       interpret=interp)
-    if block_size is not None:
-        t = jnp.broadcast_to(kv_len - C + jnp.arange(C), (n, C))
-        mask = prefill_token_mask(mask, t, S, block_size)
+    t = jnp.broadcast_to(kv_len - C + jnp.arange(C), (n, C))
+    mask = prefill_token_mask(blocks, t, S, block_size)
     return sparse_prefill_attention_reference(q, kv, mask, groups=groups)

@@ -32,7 +32,7 @@ PARTS = (
     "ffn",        # a dense FFN: a dense layer's, an expert layer's shared
                   # experts; the residual add
     "mix",        # Xing4.0's mHC mixers: read, write, Sinkhorn
-    "select",     # block selection: SALA's stage 1 and token mask; MSA's
+    "select",     # block selection: SALA's stage 1 and block maxima; MSA's
                   # indexer (its projection, the pooled keys of a wave's
                   # chunk, the scores) and the top-k list
     "state",      # the lightning mixer's recurrence over its state

@@ -346,7 +346,9 @@ def test_append_completes_a_compressed_key_from_the_pool():
 
 @pytest.mark.parametrize("interpret", [False, True],
                          ids=["jnp", "kernel_interpreted"])
-def test_prefill_attention_under_a_token_mask(interpret):
+def test_prefill_attention_under_a_block_selection(interpret):
+    """The model's call: the (queries, groups, blocks) selection with
+    ``block_size`` 64, against the reference under the token mask."""
     sp = spg.SparseConfig()
     key = jax.random.key(3)
     n, C, S, H, G, d = 2, 64, 256, 4, 2, 128
@@ -357,11 +359,12 @@ def test_prefill_attention_under_a_token_mask(interpret):
     blocks = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.6,
                                   (n, C, G, S // 64))
     blocks = blocks | jax.nn.one_hot(t // 64, S // 64, dtype=bool)[:, :, None]
+    blocks = blocks.at[..., 0].set(True)        # a forced block
     mask = spg.prefill_token_mask(blocks, t, S, sp.block_size)
     want = spg.sparse_prefill_attention_reference(q, kv, mask, groups=G)
     set_flags({"FLAGS_pallas_interpret": interpret})
-    got = spg.sparse_prefill_attention(q, kv, mask, jnp.int32(kv_len),
-                                       groups=G)
+    got = spg.sparse_prefill_attention(q, kv, blocks, jnp.int32(kv_len),
+                                       groups=G, block_size=sp.block_size)
     assert float(jnp.abs(got - want).max()) < 1e-5
 
 

@@ -283,32 +283,6 @@ def test_the_silu_path_is_the_old_formula_and_swigluoai_another():
         moe_grouped.moe_grouped_ffn_decode(x, dense, wg, wu, wd, act="gelu")
 
 
-@pytest.mark.parametrize("G, bs", [(2, 64), (4, 128)], ids=["sala", "msa"])
-def test_the_prefill_kernel_takes_a_block_selection(G, bs):
-    """A chunk of 256 queries at 256..511 over 512 keys, heads of 128:
-    the kernel (interpreted) under the (queries, groups, blocks)
-    selection, widened and made causal inside it, is the ``jnp`` path
-    under the per-token mask."""
-    n, C, S, H, d = 1, 256, 512, 2 * G, 128
-    NB = S // bs
-    ks = jax.random.split(jax.random.key(6), 3)
-    q = jax.random.normal(ks[0], (n, C, H, d)).astype(jnp.bfloat16)
-    kv = jax.random.normal(ks[1], (n, S, 2 * G * d)).astype(jnp.bfloat16)
-    blocks = jax.random.bernoulli(ks[2], 0.5, (n, C, G, NB))
-    blocks = blocks.at[..., 0].set(True)
-    t = S - C + jnp.arange(C)
-    mask = spg.prefill_token_mask(blocks, t[None], S, bs)
-    want = spg.sparse_prefill_attention_reference(q, kv, mask, groups=G)
-    got_jnp = spg.sparse_prefill_attention(q, kv, blocks, jnp.int32(S),
-                                           groups=G, block_size=bs)
-    np.testing.assert_allclose(np.asarray(got_jnp), np.asarray(want),
-                               atol=1e-6)
-    set_flags({"FLAGS_pallas_interpret": True})
-    got = spg.sparse_prefill_attention(q, kv, blocks, jnp.int32(S), groups=G,
-                                       block_size=bs)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0.03)
-
-
 def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     """Eight chips hold 4 of 32 experts each: the eight routed parts,
     with the shared expert counted once, are the layer of a chip that

@@ -140,10 +140,10 @@ def test_sala_kernels_compile_at_the_published_widths(one_chip, kernel):
              shape((b, MB), i32), shape((b,), i32),
              shape((b, 2, sp.max_blocks), i32))),
         "sparse_prefill_attn": (
-            lambda *a: spg._sparse_prefill_pallas(*a, groups=2,
-                                                  interpret=False),
-            (tok, shape((1, 32768, 512)), shape((1, 2, 2048, 32768),
-                                                jnp.int8), shape((), i32))),
+            lambda *a: spg._sparse_prefill_pallas(
+                *a, groups=2, block_size=sp.block_size, interpret=False),
+            (tok, shape((1, 32768, 512)), shape((1, 2, 512, 2048)),
+             shape((), i32))),
     }
     fn, args = calls[kernel]
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -181,7 +181,7 @@ def test_minimax_m3_kernels_compile_at_the_published_widths(one_chip,
             lambda *a: spg._sparse_prefill_pallas(
                 *a, groups=4, block_size=128, interpret=False),
             (shape((1, 2048, H, d)), shape((1, 32768, 1024)),
-             shape((1, 4, 2048, 256)), shape((), i32))),
+             shape((1, 4, 256, 2048)), shape((), i32))),
         "moe_grouped_ffn_decode": (
             lambda *a: mg._moe_grouped_ffn_pallas(*a, act=act),
             (shape((b, C)), shape((b, E), f32), shape((E, C, F)),

@@ -78,8 +78,9 @@ def test_the_prefill_expert_kernel_at_the_published_widths(act):
 
 def test_the_prefill_kernel_under_a_block_selection():
     """A chunk of 2,048 queries at 4,096..6,143 over 8,192 cached rows,
-    18 blocks of 128 a query and group: the kernel that widens the
-    selection itself against the ``jnp`` path under the token mask."""
+    18 blocks of 128 a query and group: the kernel, which reads the
+    selection down the sublanes of a (keys, queries) tile, against the
+    ``jnp`` path under the token mask."""
     n, C, S, kv_len = 1, 2048, 8192, 6144
     q = _unit_heads(_normal(3, (n, C, H, D)))
     kv = _normal(4, (n, S, 2 * G * D))

@@ -105,9 +105,10 @@ def test_sparse_prefill_attention_parity():
     blocks = jax.random.bernoulli(jax.random.key(5), 0.3,
                                   (n, C, G, S // 64))
     blocks = blocks | jax.nn.one_hot(t // 64, S // 64, dtype=bool)[:, :, None]
+    blocks = blocks.at[..., 0].set(True)        # a forced block
+    got = spg.sparse_prefill_attention(q, kv, blocks, jnp.int32(kv_len),
+                                       groups=G, block_size=SP.block_size)
     mask = spg.prefill_token_mask(blocks, t, S, SP.block_size)
-    got = spg.sparse_prefill_attention(q, kv, mask, jnp.int32(kv_len),
-                                       groups=G)
     want = spg.sparse_prefill_attention_reference(q, kv, mask, groups=G)
     assert float(jnp.abs(got - want).max()) < 2e-2
 
