@@ -279,7 +279,9 @@ def latent_plan(cfg: MLAConfig, itemsize: int) -> Dict:
     ``prefill_attn_calls(R, s_pad)``: the layers of a wave prefill of
     ``s_pad`` positions behind ``R`` cached ones whose attention takes
     the flash kernel (the predicate :func:`mla_expanded` dispatches on,
-    at activations of ``itemsize`` bytes)."""
+    at activations of ``itemsize`` bytes), and ``decode_walk``: the
+    decode kernel's walk of a step, counted on the host
+    (``ops.mla_decode.mla_walk_counts``)."""
     lanes = mla_decode.pool_lanes(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
     ld = cfg.latent_dim
 
@@ -301,7 +303,8 @@ def latent_plan(cfg: MLAConfig, itemsize: int) -> Dict:
 
     return {"arch": "mla_moe", "cache_lanes": lanes, "to_lanes": to_lanes,
             "from_lanes": from_lanes,
-            "prefill_attn_calls": prefill_attn_calls}
+            "prefill_attn_calls": prefill_attn_calls,
+            "decode_walk": mla_decode.mla_walk_counts}
 
 
 def _itemsize(state: Dict) -> int:

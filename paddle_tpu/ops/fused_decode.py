@@ -1665,17 +1665,29 @@ def paged_walk(positions, block_tokens: int, max_blocks: int):
     and never walked). A full batch of equal rows gives the dense walk's
     pairs. numpy in, numpy out, as in `paged_walk_blocks`.
     """
-    nc, total, _ = paged_walk_blocks(positions, block_tokens)
-    xp = np if isinstance(nc, np.ndarray) else jnp
-    b = nc.shape[0]
-    end = xp.cumsum(nc)
-    # pair t belongs to the first row whose running sum passes t; one
-    # masked reduction over (pairs, rows) each, no search loop, no gather
-    t = xp.arange(b * max_blocks)
+    nc, _, _ = paged_walk_blocks(positions, block_tokens)
+    return walk_list(nc, nc.shape[0] * max_blocks)
+
+
+def walk_list(counts, length: int):
+    """The flat work list of ``counts[r]`` items a row, row-major: item
+    t is ``(row[t], chunk[t])`` with ``chunk[t] < counts[row[t]]``.
+
+    Returns ``(row, chunk, total)``: int32 arrays of the fixed ``length``
+    (at least ``counts.sum()``; entries from ``total`` on are in range and
+    never walked) and ``total = counts.sum()``. numpy in, numpy out;
+    anything else is traced with ``jnp``.
+    """
+    xp = np if isinstance(counts, np.ndarray) else jnp
+    b = counts.shape[0]
+    end = xp.cumsum(counts)
+    # item t belongs to the first row whose running sum passes t; one
+    # masked reduction over (items, rows) each, no search loop, no gather
+    t = xp.arange(length)
     before = t[:, None] >= end[None, :]
     row = xp.minimum(before.sum(1), b - 1)
-    chunk = xp.where(before[:, -1], 0, t - (before * nc[None, :]).sum(1))
-    return row.astype(xp.int32), chunk.astype(xp.int32), total
+    chunk = xp.where(before[:, -1], 0, t - (before * counts[None, :]).sum(1))
+    return row.astype(xp.int32), chunk.astype(xp.int32), counts.sum()
 
 
 def paged_pool_shape(num_layers: int, num_blocks: int, block_tokens: int,

@@ -934,6 +934,10 @@ class ServingEngine:
         self._prefill_attn = meta.get("prefill_attn_calls")
         # ... or sends other kernels: (R, s_pad) -> {counter: calls}
         self._prefill_calls = meta.get("prefill_calls")
+        # a plan whose step walks the pool through a list of its own
+        # counts it: (positions, tables, block_tokens) -> (items walked,
+        # slots x the longest row's items, rows walked)
+        self._decode_walk = meta.get("decode_walk")
         # a hybrid plan (docs/SERVING.md §Architectures the engine
         # takes): pool rows for ``pool_layers`` of the layers only, a
         # second paged leaf on the same block tables (``pool_aux``:
@@ -1656,10 +1660,12 @@ class ServingEngine:
         a share of an expert-parallel layer ``moe_picks``) are
         summed over the step programs whose tokens were pulled, as the
         program counted them. ``kv_blocks_walked`` and ``kv_blocks_dense``
-        (the fused paged kernel's engines only) sum, over the plain and
-        fused-chunk steps landed, the (row, block) pairs of the decode
-        kernel's walk and what a walk of every slot to the longest row's
-        length would cover (:meth:`_record_walk`). ``prefill_moe_calls``
+        (the fused paged kernel's engines, and a plan with
+        ``decode_walk``) sum, over the plain and fused-chunk steps landed,
+        the items of the decode kernel's walk and what a walk of every
+        slot to the longest row's length would cover; ``kv_rows_walked``
+        (a plan with ``decode_walk``) the rows that walk, against
+        ``max_slots`` a step (:meth:`_record_walk`). ``prefill_moe_calls``
         and ``prefill_moe_rows`` (a plan with ``prefill_moe``) sum, over
         the waves landed, the expert layers that went through the grouped
         prefill kernel and their routed rows, pad positions included
@@ -1688,8 +1694,10 @@ class ServingEngine:
                     step_commit_s=0.0, step_tail_s=0.0,
                     step_upload_s=0.0, upload_ticks=0,
                     lookahead_ticks=0, lookahead_discarded_tokens=0,
-                    **({} if self._own_step else
-                       dict(kv_blocks_walked=0, kv_blocks_dense=0)),
+                    **({} if self._own_step and self._decode_walk is None
+                       else dict(kv_blocks_walked=0, kv_blocks_dense=0)),
+                    **({} if self._decode_walk is None else
+                       dict(kv_rows_walked=0)),
                     **({} if self._prefill_moe is None else
                        dict(prefill_moe_calls=0, prefill_moe_rows=0)),
                     **({} if self._prefill_attn is None else
@@ -5007,10 +5015,22 @@ class ServingEngine:
         only `_decode_body` leaves a scratch row where it is), so between
         uploads the device walks idle rows that the host's mirror holds
         at 0: the mirror is not what the next verify step reads, and a
-        speculative tick is not counted. An engine with its own step has
-        no such kernel and does not count either."""
-        if (self._own_step or not self._tick_landed
-                or self._tick_spec is not None):
+        speculative tick is not counted. An engine with its own step
+        counts where its plan declares the walk (``decode_walk``, the
+        ``mla_moe`` plan): from the host's positions and tables, with the
+        plan's own count of the list its kernel walks. That walk takes
+        only rows whose table is not scratch, so an idle row, which the
+        own step advances, counts nothing; other own steps do not
+        count."""
+        if not self._tick_landed or self._tick_spec is not None:
+            return
+        if self._own_step:
+            if self._decode_walk is not None:
+                walked, dense, rows = self._decode_walk(
+                    self._positions, self._tables, self.block_tokens)
+                self.stats["kv_blocks_walked"] += walked
+                self.stats["kv_blocks_dense"] += dense
+                self.stats["kv_rows_walked"] += rows
             return
         from paddle_tpu.ops.fused_decode import paged_walk_blocks
         _, walked, dense = paged_walk_blocks(self._positions,

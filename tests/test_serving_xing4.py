@@ -133,6 +133,41 @@ def test_parity_through_the_kernels_in_interpret_mode(monkeypatch):
     eng.close()
 
 
+def test_the_engine_counts_the_latent_walk_of_the_active_rows():
+    """`engine.stats` sums the decode kernel's walk over the landed steps
+    with the kernel's own list counts (`mla_decode.mla_walk_counts`), on
+    the host's positions and tables: the idle slots, which the own step
+    advances, walk nothing."""
+    from paddle_tpu.ops import mla_decode
+    cfg, m = tiny_xing4()
+    eng = serving.ServingEngine(m, max_slots=4, block_tokens=8,
+                                max_seq_len=64, prefix_caching=False)
+    rng = np.random.default_rng(5)
+    for n, new in ((20, 14), (37, 6), (9, 10)):
+        eng.submit(serving.Request(
+            rng.integers(3, cfg.vocab_size, n).astype(np.int32),
+            max_new_tokens=new))
+    walked = dense = rows = 0
+    while not eng.idle:
+        steps0 = eng.stats["steps"]
+        eng.step()
+        if eng.stats["steps"] > steps0:
+            w, d, r = mla_decode.mla_walk_counts(eng._positions,
+                                                 eng._tables, 8)
+            assert r == int((eng._tables[:, 0] != 0).sum())
+            walked, dense, rows = walked + w, dense + d, rows + r
+    s = eng.stats
+    assert s["steps"] >= 12
+    assert (s["kv_blocks_walked"], s["kv_blocks_dense"],
+            s["kv_rows_walked"]) == (walked, dense, rows)
+    assert 0 < s["kv_blocks_walked"] <= s["kv_blocks_dense"]
+    # three requests in four slots: a slot is always idle
+    assert 0 < s["kv_rows_walked"] < 4 * s["steps"]
+    eng.reset_stats()
+    assert eng.stats["kv_rows_walked"] == eng.stats["kv_blocks_walked"] == 0
+    eng.close()
+
+
 def test_a_shared_prefix_is_read_back_from_the_latent_pool():
     cfg, m = tiny_xing4()
     rng = np.random.default_rng(4)

@@ -245,16 +245,58 @@ def test_yarn_frequencies():
 
 
 # --------------------------------------------------------------- kernels
-def test_mla_paged_decode_kernel_matches_its_reference_uneven_rows():
+def _tables_for(pos, active, BT, MB):
+    """Each active row's own blocks, fresh, up to its new token's block;
+    an idle row's table is all scratch (block 0)."""
+    tables, nxt = np.zeros((len(pos), MB), np.int32), 1
+    for r, (p, a) in enumerate(zip(pos, active)):
+        if a:
+            n = p // BT + 1
+            tables[r, :n] = np.arange(nxt, nxt + n)
+            nxt += n
+    return tables, nxt
+
+
+# (positions, active rows or None for every row, heads), with BT 16, MB 4
+_MLA_CASES = {
+    # idle rows at positions the own step has advanced them to (one
+    # past the table: an idle row reads none of it)
+    "idle_advanced": ([5, 20, 300, 33], [False, True, False, True], 4),
+    # a row at 0 is its new token alone; a row at 7 has 7 cached tokens
+    # in its first block, walked before the new token
+    "short_rows": ([0, 7, 0, 12], [True, True, False, True], 4),
+    # rows that end on a block edge and just past one
+    "block_edges": ([15, 16, 17, 32, 33, 47], None, 4),
+    "scattered": ([9, 40, 3, 0, 25, 61, 2, 18],
+                  [False, True, False, True, False, True, False, True], 4),
+    # a one-block row before a long one: the ring runs across rows
+    "ring_across_rows": ([5, 63, 3, 50], None, 4),
+    "heads_16": ([40, 0, 17, 1, 33], [True, True, True, False, True], 16),
+}
+
+
+@pytest.mark.parametrize("case", ["uneven", *_MLA_CASES])
+def test_mla_paged_decode_kernel_matches_its_reference_uneven_rows(case):
+    """The kernel walks the active rows' blocks only; an idle row (scratch
+    table row, at whatever position) writes nothing into the pool and
+    returns zeros, in the kernel as in the reference."""
     rng = np.random.default_rng(0)
-    L, NB, BT, P, dc, H = 2, 10, 16, 256, 128, 4
+    L, BT, P, dc, MB = 2, 16, 256, 128, 4
+    if case == "uneven":
+        H, NB = 4, 10
+        # rows of 41, 1, 18, 0 (idle, scratch) and 32 tokens; 16 and 32
+        # sit on block edges
+        tables = np.asarray([[1, 2, 3], [4, 0, 0], [5, 6, 0], [0, 0, 0],
+                             [7, 8, 9]], np.int32)
+        pos = np.asarray([40, 0, 17, 0, 32], np.int32)
+    else:
+        pos, active, H = _MLA_CASES[case]
+        pos = np.asarray(pos, np.int32)
+        active = [True] * len(pos) if active is None else active
+        tables, NB = _tables_for(pos, active, BT, MB)
     pool = jnp.asarray(rng.standard_normal((L, NB, BT, P)), jnp.bfloat16)
-    # rows of 41, 1, 18, 0 (idle, scratch) and 32 tokens; 16 and 32 sit
-    # on block edges
-    tables = jnp.asarray([[1, 2, 3], [4, 0, 0], [5, 6, 0], [0, 0, 0],
-                          [7, 8, 9]], jnp.int32)
-    pos = jnp.asarray([40, 0, 17, 0, 32], jnp.int32)
     b = len(pos)
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos)
     q = jnp.asarray(rng.standard_normal((b, H, P)), jnp.bfloat16)
     new = jnp.asarray(rng.standard_normal((b, P)), jnp.bfloat16)
     kw = dict(layer=1, d_c=dc, scale=0.07)
@@ -264,11 +306,52 @@ def test_mla_paged_decode_kernel_matches_its_reference_uneven_rows():
     got_o, got_pool = jax.jit(
         lambda *a: mla_decode.mla_paged_decode(*a, **kw))(
             q, new, pool, tables, pos)
+    idle = np.asarray(tables[:, 0] == 0)
+    got_o, want_o = np.asarray(got_o), np.asarray(want_o)
     # the probabilities go to the matrix unit in bfloat16
-    assert np.abs(np.asarray(got_o - want_o)).max() < 5e-3
+    assert np.abs(got_o - want_o)[~idle].max() < 5e-3
+    assert (got_o[idle] == 0).all() and (want_o[idle] == 0).all()
     assert (np.asarray(got_pool) == np.asarray(want_pool)).all()
+    # an idle row wrote nothing: the scratch block is as it was
+    assert (np.asarray(got_pool[:, 0]) == np.asarray(pool[:, 0])).all()
     assert (np.asarray(got_pool[0]) == np.asarray(pool[0])).all()
-    assert (np.asarray(got_pool[1, 9, 0]) == np.asarray(new[4])).all()
+    for r in np.flatnonzero(~idle):
+        p = int(pos[r])
+        assert (np.asarray(got_pool[1, int(tables[r, p // BT]), p % BT])
+                == np.asarray(new[r])).all()
+
+
+def test_mla_walk_list():
+    """`mla_walk`: the active rows' items, row-major, one a block that
+    holds a cached token and at least one (a row at position 0 has its
+    new token alone); none for an idle row, whatever its position; the
+    idle rows in order; numpy in gives numpy out and agrees with the
+    traced lists and with the engine's counts."""
+    BT, MB = 16, 4
+    pos = np.asarray([0, 5, 32, 63, 11, 40, 17], np.int32)
+    tables = np.ones((len(pos), MB), np.int32)
+    tables[[4, 6]] = 0                          # idle, at 11 and 17
+    items = mla_decode.mla_walk_items(pos, tables, BT)
+    assert items.tolist() == [1, 1, 2, 4, 0, 3, 0]
+    row, chunk, total, idle, n_idle = mla_decode.mla_walk(pos, tables, BT)
+    assert isinstance(row, np.ndarray) and row.shape == (len(pos) * MB,)
+    assert row.dtype == chunk.dtype == idle.dtype == np.int32
+    assert int(total) == 11 and int(n_idle) == 2
+    assert list(zip(row[:11].tolist(), chunk[:11].tolist())) == [
+        (0, 0), (1, 0), (2, 0), (2, 1), (3, 0), (3, 1), (3, 2), (3, 3),
+        (5, 0), (5, 1), (5, 2)]
+    assert idle[:2].tolist() == [4, 6]
+    assert row.max() < len(pos) and chunk.min() >= 0 and chunk.max() < MB
+    got = jax.jit(lambda p, t: mla_decode.mla_walk(p, t, BT))(
+        jnp.asarray(pos), jnp.asarray(tables))
+    for a, b in zip(got, (row, chunk, total, idle, n_idle)):
+        assert (np.asarray(a) == b).all()
+    assert mla_decode.mla_walk_counts(pos, tables, BT) == (11, 7 * 4, 5)
+    # every row idle: no item, every row's output zeroed
+    none = np.zeros_like(tables)
+    assert int(mla_decode.mla_walk(pos, none, BT)[2]) == 0
+    assert int(mla_decode.mla_walk(pos, none, BT)[4]) == len(pos)
+    assert mla_decode.mla_walk_counts(pos, none, BT) == (0, 0, 0)
 
 
 def test_moe_grouped_ffn_kernel_matches_its_reference_untouched_experts():

@@ -1244,9 +1244,11 @@ def test_mla_paged_decode_parity_at_published_widths():
     live = [0, 2, 3, 4, 6, 7]
     assert_close(np.asarray(got_o)[live], np.asarray(want_o)[live],
                  rtol=2e-2, atol=5e-3)
-    # the appended rows, bit for bit; scratch block 0 takes the idle rows
+    assert (np.asarray(got_o)[[1, 5]] == 0).all()
+    # the appended rows, bit for bit; the idle rows write nothing
     got_pool, want_pool = np.asarray(got_pool), np.asarray(want_pool)
-    assert (got_pool[:, 1:] == want_pool[:, 1:]).all()
+    assert (got_pool == want_pool).all()
+    assert (got_pool[:, 0] == np.asarray(pool)[:, 0]).all()
 
 
 def test_moe_grouped_ffn_parity_at_published_widths():
@@ -1387,8 +1389,60 @@ def test_mla_paged_decode_parity_at_128_heads():
     live = [0, 2, 3, 4, 6, 7]
     assert_close(np.asarray(got_o)[live], np.asarray(want_o)[live],
                  rtol=2e-2, atol=5e-3)
+    assert (np.asarray(got_o)[[1, 5]] == 0).all()
+    # the appended rows, bit for bit; the idle rows write nothing
     got_pool, want_pool = np.asarray(got_pool), np.asarray(want_pool)
-    assert (got_pool[:, 1:] == want_pool[:, 1:]).all()
+    assert (got_pool == want_pool).all()
+    assert (got_pool[:, 0] == np.asarray(pool)[:, 0]).all()
+
+
+def _mla_walk_twin(H, b, active, seed):
+    """The decode kernel against its reference at the published widths,
+    with ``active`` of ``b`` slots holding rows of ``chat-4k``'s lengths
+    (prompts lognormal about 1,024 in 128-3,584 plus part of an output of
+    up to 512) at scattered slots, in blocks scattered over the pool, and
+    the idle slots at positions the own step has advanced them to."""
+    from paddle_tpu.ops import mla_decode as md
+    L, BT, P, dc, MB = 2, 256, 640, 512, 16
+    rng = np.random.default_rng(seed)
+    rows = np.sort(rng.choice(b, active, replace=False))
+    pos = rng.integers(1, 40, b).astype(np.int32)
+    pos[rows] = (np.clip(rng.lognormal(np.log(1024), 0.8, active), 128,
+                         3584) + rng.integers(0, 512, active)).astype(np.int32)
+    need = pos[rows] // BT + 1
+    NB = 1 + int(need.sum()) + 64
+    ids = 1 + rng.permutation(NB - 1)
+    tables = np.zeros((b, MB), np.int32)
+    k = 0
+    for r, n in zip(rows, need):
+        tables[r, :n] = ids[k:k + n]
+        k += n
+    pool = rand(seed, L, NB, BT, P)
+    q = rand(seed + 1, b, H, P, scale=0.2)
+    new = rand(seed + 2, b, P)
+    kw = dict(layer=1, d_c=dc, scale=192 ** -0.5 * 1.5896)
+    args = (q, new, pool, jnp.asarray(tables), jnp.asarray(pos))
+    want_o, want_pool = jax.jit(
+        lambda *a: md.mla_paged_decode_reference(*a, **kw))(*args)
+    got_o, got_pool = jax.jit(
+        lambda *a: md._mla_paged_decode_pallas(*a, **kw))(*args)
+    idle = np.setdiff1d(np.arange(b), rows)
+    got_o, want_o = np.asarray(got_o), np.asarray(want_o)
+    assert_close(got_o[rows], want_o[rows], rtol=2e-2, atol=5e-3)
+    assert (got_o[idle] == 0).all()
+    got_pool, want_pool = np.asarray(got_pool), np.asarray(want_pool)
+    assert (got_pool == want_pool).all()
+    assert (got_pool[:, 0] == np.asarray(pool)[:, 0]).all()
+
+
+def test_mla_paged_decode_walk_at_128_heads_and_128_slots():
+    """DeepSeek-V2's shape: 28 of 128 slots active."""
+    _mla_walk_twin(128, 128, 28, seed=11)
+
+
+def test_mla_paged_decode_walk_at_32_heads_and_64_slots():
+    """Xing4.0's shape: 24 of 64 slots active."""
+    _mla_walk_twin(32, 64, 24, seed=12)
 
 
 def _share_picks(T, seed):
