@@ -32,8 +32,17 @@ expert-parallel layer.
   of the embedding and the head that are held.
 
 A prefill of s positions runs in chunks of ``prefill_chunk`` inside ONE
-program (a ``lax.scan`` over chunks, the layers unrolled inside it), the
-``[k | v]`` rows and the pooled keys as the carry; a row's pooled keys
+program: a ``lax.scan`` over GROUPS of chunks, the layers unrolled inside
+it, the ``[k | v]`` rows and the pooled keys as the carry. In a group,
+each layer's attention runs chunk by chunk in order (a scan of
+``msa_chunk``), then the group's rows go through the routed experts in
+ONE call of the prefill expert kernel, which streams every held expert
+once a row tile. A group is as many chunks as keep a held expert's mean
+rows (``prefill_chunk k / router_experts`` a chunk) and three standard
+deviations of a random router's spread inside one tile
+(:func:`chunks_a_call`: 3 chunks of 2,048 at the published widths, 1
+where a chunk fills the tile); a smaller last group runs once after the
+scan, at its own size (:func:`prefill_groups`). A row's pooled keys
 stop at its TRUE length, so the pad of a wave never enters a block's
 max. The mathematics is in module-level functions over ``{name:
 array}`` weights: the chunked forward and the paged decode step read the
@@ -41,6 +50,7 @@ same leaves of one state.
 """
 
 import dataclasses
+import math
 from typing import Dict, List, Optional
 
 import jax
@@ -291,40 +301,89 @@ def init_cache(cfg: MiniMaxM3Config, n: int, max_len: int,
             "pk": jnp.zeros((L, n, max_len // bs, gd), jnp.float32)}
 
 
+def chunks_a_call(cfg: MiniMaxM3Config, C: int, chunks: int) -> int:
+    """How many chunks of C positions of one row of a wave send their
+    routed rows through ONE call of the prefill expert kernel: the most,
+    up to ``chunks``, whose mean rows a held expert (``C k /
+    router_experts`` a chunk) plus three standard deviations of a random
+    router's spread (the mean's square root) stay inside one row tile
+    of the kernel where experts arrive in slices. A call streams every
+    held expert once a row tile, so up to the tile the chunks share one
+    stream. One where a chunk's rows already fill the tile."""
+    tile = moe_grouped._row_tile(0, 0, sliced=True)
+    per = C * cfg.num_experts_per_tok / cfg.router_experts
+
+    def fits(g):
+        return g * per + 3 * math.sqrt(g * per) <= tile
+
+    g = 1
+    while g < chunks and fits(g + 1):
+        g += 1
+    return g
+
+
+def prefill_groups(cfg: MiniMaxM3Config, s: int):
+    """A prefill of s positions -> (its chunk C, the chunks of each group
+    in order): groups of :func:`chunks_a_call` chunks, the last one
+    smaller where they do not divide the chunks. One call of the expert
+    kernel a group an expert layer."""
+    C = max(la.chunk_size(s, cfg.prefill_chunk), 1)
+    n = s // C
+    g = chunks_a_call(cfg, C, n)
+    return C, [g] * (n // g) + ([n % g] if n % g else [])
+
+
 def hidden_forward(w: Dict, cfg: MiniMaxM3Config, ids, cache: Dict,
                    true_len=None, positions=None, return_blocks=False):
-    """ids (n, s) from position 0, in chunks -> (the residual before the
-    final norm: (n, s, C), or (n, C) at ``positions`` (n,); the cache
-    after the s positions, its pooled keys over each row's ``true_len``
-    (n,) tokens; the picks that fell on held experts over every expert
-    layer and chunk; with ``return_blocks`` also the blocks every query
-    read, (layers, n, s, G, NB))."""
+    """ids (n, s) from position 0, in groups of chunks
+    (:func:`prefill_groups`) -> (the residual before the final norm: (n,
+    s, C), or (n, C) at ``positions`` (n,); the cache after the s
+    positions, its pooled keys over each row's ``true_len`` (n,) tokens;
+    the picks that fell on held experts over every expert layer and
+    chunk; with ``return_blocks`` also the blocks every query read,
+    (layers, n, s, G, NB))."""
     n, s = ids.shape
-    C = la.chunk_size(s, cfg.prefill_chunk)
-    eps = cfg.rms_norm_eps
+    C, sizes = prefill_groups(cfg, s)
+    L, eps = cfg.num_hidden_layers, cfg.rms_norm_eps
     true_len = (jnp.full((n,), s, jnp.int32) if true_len is None
                 else true_len.astype(jnp.int32))
     emb = w["model.embed_tokens.weight"]
+    # one trace of a chunk's attention for every layer and group: tracing
+    # is part of the set-up of every prefill program
+    attend_chunk = jax.jit(lambda aw, *a: msa_chunk(aw, cfg, *a))
 
-    def chunk(carry, i):
+    def group(carry, p0, m: int):
+        """The m chunks from position p0: a layer's attention a chunk at
+        a time, in order, then its FFN over the m chunks' rows at once.
+        Outputs chunk-major: x (m, n, C, h), blocks (m, L, n, C, G,
+        NB)."""
         cache, picked, rows = carry
-        c0 = i * C
+        c0 = p0 + C * jnp.arange(m)
+        pos = c0[:, None] + jnp.arange(C)                       # (m, C)
         with part("embed"):
-            x = jnp.take(emb, lax.dynamic_slice_in_dim(ids, c0, C, axis=1),
-                         axis=0)
+            tok = lax.dynamic_slice_in_dim(ids, p0, m * C, axis=1)
+            x = jnp.take(emb, tok.reshape(n, m, C).swapaxes(0, 1), axis=0)
         with part("attn_in"):
-            cos, sin = _rope(cfg, c0 + jnp.arange(C))
-        valid = (c0 + jnp.arange(C))[None] < true_len[:, None]
-        kv, pk = cache["kv"], cache["pk"]
+            cos, sin = _rope(cfg, pos)
+        valid = pos[:, None] < true_len[None, :, None]          # (m, n, C)
         blocks = []
-        for l in range(cfg.num_hidden_layers):
+        for l in range(L):
             lw = _sub(w, f"model.layers.{l}.")
+            aw = _sub(lw, "self_attn.")
             with part("norm"):
                 xn = _norm(x, lw["input_layernorm.weight"], eps)
-            y, kv_l, pk_l, blk = msa_chunk(_sub(lw, "self_attn."), cfg, xn,
-                                           kv[l], pk[l], valid, c0, cos, sin)
-            with part("attn"):
-                kv, pk = kv.at[l].set(kv_l), pk.at[l].set(pk_l)
+
+            def attend(cache, xs):
+                xc, vc, cc, co, si = xs
+                y, kv, pk, blk = attend_chunk(aw, xc, cache["kv"][l],
+                                              cache["pk"][l], vc, cc, co, si)
+                with part("attn"):
+                    cache = {"kv": cache["kv"].at[l].set(kv),
+                             "pk": cache["pk"].at[l].set(pk)}
+                return cache, (y, blk if return_blocks else None)
+
+            cache, (y, blk) = lax.scan(attend, cache,
+                                       (xn, valid, c0, cos, sin))
             blocks.append(blk)
             with part("attn_out"):
                 x = x + y
@@ -332,8 +391,8 @@ def hidden_forward(w: Dict, cfg: MiniMaxM3Config, ids, cache: Dict,
                 xn = _norm(x, lw["post_attention_layernorm.weight"], eps)
             if cfg.is_moe(l):
                 y, r = moe_prefill(_sub(lw, "mlp."), cfg,
-                                   xn.reshape(n * C, -1))
-                y = y.reshape(n, C, -1)
+                                   xn.reshape(m * n * C, -1))
+                y = y.reshape(x.shape)
                 with part("router"):
                     rows = rows + r
             else:
@@ -341,28 +400,40 @@ def hidden_forward(w: Dict, cfg: MiniMaxM3Config, ids, cache: Dict,
                     y = _mlp(_sub(lw, "mlp."), cfg, xn)
             with part("ffn"):
                 x = x + y
-        cache = {"kv": kv, "pk": pk}
         out = {}
         if positions is None:
             out["x"] = x
         else:
             with part("head"):
-                at = jnp.clip(positions - c0, 0, C - 1)
-                here = (positions >= c0) & (positions < c0 + C)
-                row = jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0]
+                at = jnp.clip(positions - p0, 0, m * C - 1)
+                here = (positions >= p0) & (positions < p0 + m * C)
+                row = x[at // C, jnp.arange(n), at % C]
                 picked = jnp.where(here[:, None], row, picked)
         if return_blocks:
-            out["blocks"] = jnp.stack(blocks)
+            out["blocks"] = jnp.stack(blocks, 1)
         return (cache, picked, rows), out
 
-    picked = jnp.zeros((n, emb.shape[1]), emb.dtype)
-    (cache, picked, rows), out = lax.scan(
-        chunk, (cache, picked, jnp.zeros((), jnp.int32)), jnp.arange(s // C))
-    merge = lambda o, axis: jnp.moveaxis(o, 0, axis).reshape(
-        *o.shape[1:axis + 1], s, *o.shape[axis + 2:])
-    h = picked if positions is not None else merge(out["x"], 1)
+    g = sizes[0]
+    full = sizes.count(g)
+    carry = (cache, jnp.zeros((n, emb.shape[1]), emb.dtype),
+             jnp.zeros((), jnp.int32))
+    carry, out = lax.scan(lambda c, i: group(c, i * g * C, g), carry,
+                          jnp.arange(full))
+    last = None
+    if len(sizes) > full:       # the smaller last group, at its own size
+        carry, last = group(carry, full * g * C, sizes[-1])
+    cache, picked, rows = carry
+
+    def chunks(name):
+        """Every chunk's ``name`` output in order, the chunks first."""
+        o = out[name].reshape(-1, *out[name].shape[2:])
+        return o if last is None else jnp.concatenate([o, last[name]])
+
+    h = (picked if positions is not None else
+         jnp.moveaxis(chunks("x"), 0, 1).reshape(n, s, -1))
     if return_blocks:
-        return h, cache, rows, merge(out["blocks"], 2)
+        b = jnp.moveaxis(chunks("blocks"), 0, 2)  # (L, n, chunks, C, ..)
+        return h, cache, rows, b.reshape(L, n, s, *b.shape[4:])
     return h, cache, rows
 
 
@@ -559,8 +630,11 @@ class MiniMaxM3ForCausalLM(CausalLMBase):
         rows ``[k | v]`` for every layer, the pooled keys as a second
         paged leaf (``pool_aux``: one row a block), no state a slot,
         ``to_lanes`` from the prefill's cache, the MSA and expert kernels
-        a wave sends (``prefill_calls``, ``prefill_moe`` with ``rows``
-        ``"counted"``), and a step that counts blocks and experts."""
+        a wave sends (``prefill_calls``: a call a chunk a layer;
+        ``prefill_moe`` with ``rows`` ``"counted"`` and ``calls_a_layer``
+        the wave's groups of chunks, :func:`prefill_groups`: one expert
+        kernel call a group an expert layer), and a step that counts
+        blocks and experts."""
         if "model.layers.0.self_attn.index_proj.weight" not in state:
             return None     # a quantized or otherwise foreign state
         cfg = self.cfg
@@ -568,8 +642,8 @@ class MiniMaxM3ForCausalLM(CausalLMBase):
         gd = cfg.num_key_value_heads * cfg.head_dim
         L = cfg.num_hidden_layers
 
-        def chunks(s_pad: int) -> int:
-            return s_pad // max(la.chunk_size(s_pad, cfg.prefill_chunk), 1)
+        def groups(s_pad: int) -> int:
+            return len(prefill_groups(cfg, s_pad)[1])
 
         def prefill_calls(R: int, s_pad: int) -> Dict:
             del R
@@ -588,7 +662,7 @@ class MiniMaxM3ForCausalLM(CausalLMBase):
             "prefill_calls": prefill_calls,
             "prefill_moe": {
                 "layers": sum(cfg.moe_layer_freq),
-                "calls_a_layer": chunks,
+                "calls_a_layer": groups,
                 "k": cfg.num_experts_per_tok,
                 "path": moe_grouped.prefill_path(cfg.hidden_size,
                                                  cfg.intermediate_size),

@@ -90,27 +90,78 @@ def reference_logits(state, ids, cfg):
 IDS = jax.random.randint(jax.random.key(5), (2, 160), 3, 256)
 
 
+def agree_with_the_reference(cfg, m, state, ids):
+    """Logits within ``TOL`` of the reference's and the SAME blocks at
+    every query of every layer (row 0) -> (the reference's logits, its
+    blocks a layer)."""
+    n, s = ids.shape
+    with jax.default_matmul_precision("highest"):
+        lg = functional_call(m, state, ids)
+        want = reference_logits(state, ids, cfg)
+        _, _, _, blocks = mm.hidden_forward(
+            state, cfg, ids, mm.init_cache(cfg, n, s, jnp.float32),
+            return_blocks=True)
+    assert float(jnp.abs(lg - want).max()) < TOL
+    keys = published_keys(cfg)
+    sels = [np.asarray(ref.blocks(state, ids[0], keys, layer))
+            for layer in range(cfg.num_hidden_layers)]
+    for layer, sel in enumerate(sels):
+        assert (np.asarray(blocks[layer, 0]) == sel).all()
+    return want, sels
+
+
 def test_forward_and_selection_agree_with_the_reference():
     """160 tokens in five chunks of 32, ``dense_len`` 32: most queries
     select. Logits close, and the SAME blocks at every query of every
     layer."""
     cfg, m, state = tiny()
+    _, sels = agree_with_the_reference(cfg, m, state, IDS)
+    for sel in sels:
+        # past dense_len a query reads the first, its own and 2 more
+        # blocks of the up to 20 it could; under it every block
+        assert (sel[40:].sum(-1) == 4).all()
+        assert (sel[:32].sum(-1) == (np.arange(32) // 8 + 1)[:, None]).all()
+
+
+@pytest.mark.parametrize("per_call, groups", [(2, [2, 2, 1]), (1, [1] * 5)],
+                         ids=["a_smaller_last_group", "a_call_a_chunk"])
+def test_groups_of_chunks_agree_with_the_reference(monkeypatch, per_call,
+                                                   groups):
+    """At the tiny widths a chunk of 32 gives a held expert 4 rows, so
+    the rule sends all five chunks of 160 tokens through one call of the
+    expert kernel; cut to ``per_call`` chunks a call, the program runs
+    two groups of two and a last group of one, or a group a chunk: the
+    reference's logits, at a row's last position too (in a middle group
+    and in the last one), and its blocks at every layer."""
+    cfg, m, state = tiny()
+    assert mm.prefill_groups(cfg, 160) == (32, [5])
+    monkeypatch.setattr(mm, "chunks_a_call",
+                        lambda cfg, C, chunks: min(per_call, chunks))
+    assert mm.prefill_groups(cfg, 160) == (32, groups)
+    want, _ = agree_with_the_reference(cfg, m, state, IDS)
+    at = jnp.array([70, 159])
     with jax.default_matmul_precision("highest"):
-        lg = functional_call(m, state, IDS)
-        want = reference_logits(state, IDS, cfg)
-        _, _, _, blocks = mm.hidden_forward(
-            state, cfg, IDS, mm.init_cache(cfg, 2, 160, jnp.float32),
-            return_blocks=True)
-        assert float(jnp.abs(lg - want).max()) < TOL
-        keys = published_keys(cfg)
-        for layer in range(cfg.num_hidden_layers):
-            sel = np.asarray(ref.blocks(state, IDS[0], keys, layer))
-            assert (np.asarray(blocks[layer, 0]) == sel).all()
-            # past dense_len a query reads the first, its own and 2 more
-            # blocks of the up to 20 it could; under it every block
-            assert (sel[40:].sum(-1) == 4).all()
-            assert (sel[:32].sum(-1)
-                    == (np.arange(32) // 8 + 1)[:, None]).all()
+        lg = functional_call(m, state, IDS, positions=at)
+    assert float(jnp.abs(lg - want[jnp.arange(2), at]).max()) < TOL
+
+
+def test_three_published_chunks_share_a_call_of_the_expert_kernel():
+    """A chunk of 2,048 gives a held expert 64 rows (4 picks of 128
+    experts): three chunks, 192 rows and three standard deviations, fit
+    the kernel's row tile of 256 and four do not. The ``longdoc-32k``
+    cell's buckets of 5 to 16 chunks, a wave shorter than a group, and a
+    chunk that fills the tile on its own: a call a chunk."""
+    cfg = MiniMaxM3Config(num_local_experts=16, router_experts=128)
+    assert mm.chunks_a_call(cfg, 2048, 16) == 3
+    assert mm.prefill_groups(cfg, 10240) == (2048, [3, 2])
+    assert mm.prefill_groups(cfg, 12288) == (2048, [3, 3])
+    assert mm.prefill_groups(cfg, 16384) == (2048, [3, 3, 2])
+    assert mm.prefill_groups(cfg, 32768) == (2048, [3] * 5 + [1])
+    assert mm.prefill_groups(cfg, 4096) == (2048, [2])
+    assert mm.prefill_groups(cfg, 1024) == (1024, [1])
+    wide = MiniMaxM3Config(num_local_experts=16, router_experts=128,
+                           prefill_chunk=8192)
+    assert mm.prefill_groups(wide, 32768) == (8192, [1] * 4)
 
 
 @pytest.mark.parametrize("chunk", [40, 80])

@@ -12,6 +12,7 @@ the pool and the reference differ by summation order only.
 import os
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,8 +20,10 @@ import pytest
 import paddle_tpu
 from paddle_tpu import serving
 from paddle_tpu.core.flags import set_flags
+from paddle_tpu.models import minimax_m3 as mm
 from paddle_tpu.models.minimax_m3 import (STEP_COUNTERS, MiniMaxM3Config,
                                           MiniMaxM3ForCausalLM)
+from paddle_tpu.ops import moe_grouped
 from paddle_tpu.serving.spec import SpecConfig
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
@@ -137,8 +140,8 @@ def test_prefill_then_decode_through_the_engine_agree_with_the_reference():
 def test_the_kernels_in_interpret_mode_through_the_engine():
     """Heads of 128 lanes, blocks of 16: the sparse decode and prefill
     kernels and both expert kernels (swigluoai) interpreted, and the
-    waves count the expert kernel's calls, one an expert layer a
-    chunk."""
+    waves count the expert kernel's calls, one an expert layer a group
+    of chunks."""
     cfg, m = tiny_model(
         hidden_size=128, intermediate_size=128, shared_intermediate_size=128,
         dense_intermediate_size=128, num_attention_heads=2,
@@ -154,9 +157,51 @@ def test_the_kernels_in_interpret_mode_through_the_engine():
                                    block_tokens=128, max_seq_len=256)
     set_flags({"FLAGS_pallas_interpret": False})
     check_against_the_reference(results, prompts, max_new, m, cfg)
-    # waves of 256 and 128 positions in chunks of 64
-    assert eng.stats["prefill_moe_calls"] == 4 + 2
+    # waves of 256 and 128 positions in chunks of 64, 8 rows an expert a
+    # chunk: each wave's chunks are one group
+    assert [mm.prefill_groups(cfg, s)[1] for s in (256, 128)] == [[4], [2]]
+    assert eng.stats["prefill_moe_calls"] == 1 + 1
     assert eng.stats["prefill_moe_rows"] > 0
+    eng.close()
+
+
+def test_a_wave_counts_the_expert_kernel_calls_it_makes(monkeypatch):
+    """The expert kernel's calls a wave's forward makes, counted as they
+    run (a counting stand-in, no jit), are the wave's
+    ``prefill_moe_calls`` (``_wave_kernels``, what the roofline reads) at
+    5, 6 and 7 chunks of 64, in groups of two (the rule cut to two: the
+    tiny widths' own takes every chunk) and a last group of one where
+    two do not divide the chunks."""
+    cfg, m = tiny_model(
+        hidden_size=128, intermediate_size=128, shared_intermediate_size=128,
+        dense_intermediate_size=128, num_attention_heads=2,
+        num_key_value_heads=1, head_dim=128, rotary_dim=64,
+        msa_index_heads=2, msa_block_size=16, msa_topk=2, prefill_chunk=64,
+        num_hidden_layers=2, moe_layer_freq=[0, 1])
+    monkeypatch.setattr(mm, "chunks_a_call",
+                        lambda cfg, C, chunks: min(2, chunks))
+    set_flags({"FLAGS_pallas_interpret": True})    # the kernel's path
+    eng = serving.ServingEngine(m, prefix_caching=False, max_slots=1,
+                                block_tokens=64, max_seq_len=448)
+    set_flags({"FLAGS_pallas_interpret": False})
+    rows = []
+
+    def counting(x, *args, **kw):
+        rows.append(x.shape[0])
+        return moe_grouped.moe_prefill_ragged_dot(x, *args, **kw)
+
+    monkeypatch.setattr(moe_grouped, "moe_grouped_ffn_prefill", counting)
+    state = m.state_dict(include_buffers=False)
+    rng = np.random.default_rng(3)
+    for chunks, groups in ((5, [2, 2, 1]), (6, [2, 2, 2]), (7, [2, 2, 2, 1])):
+        s = 64 * chunks
+        assert mm.prefill_groups(cfg, s) == (64, groups)
+        ids = jnp.asarray(rng.integers(3, cfg.vocab_size, (1, s)), jnp.int32)
+        rows.clear()
+        with jax.disable_jit():
+            mm.hidden_forward(state, cfg, ids, mm.init_cache(cfg, 1, s))
+        assert rows == [64 * g for g in groups]     # one expert layer
+        assert eng._wave_kernels(0, s, 1)["prefill_moe_calls"] == len(rows)
     eng.close()
 
 
